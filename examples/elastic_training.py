@@ -51,11 +51,8 @@ def main():
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.cpu_mesh}"
         ).strip()
-        os.environ["TORCHMPI_TPU_FORCE_CPU"] = "1"
+        os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-
-    if args.cpu_mesh or os.environ.get("TORCHMPI_TPU_FORCE_CPU"):
-        jax.config.update("jax_platforms", "cpu")
 
     import numpy as np
     import optax

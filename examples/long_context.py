@@ -32,10 +32,10 @@ def main():
     ap.add_argument(
         "--sp-backend",
         default="xla",
-        choices=["xla", "pallas", "pallas_interpret", "auto"],
+        choices=["xla", "pallas", "pallas_interpret"],
         help="ring-attention transport: XLA ppermute ring, the Pallas "
-        "RDMA kernel (real multi-chip TPU), its interpret mode (CPU "
-        "mesh), or auto selection",
+        "RDMA kernel (real multi-chip TPU) or its interpret mode (CPU "
+        "mesh)",
     )
     args = ap.parse_args()
 

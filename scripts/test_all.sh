@@ -39,7 +39,7 @@ python -m torchmpi_tpu.launch --nproc 2 --cpu-devices 2 \
   examples/mnist_allreduce.py -- --epochs 1 || fails=$((fails+1))
 
 echo "=== driver entry points ==="
-TORCHMPI_TPU_FORCE_CPU=1 python __graft_entry__.py 8 || fails=$((fails+1))
+JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 python __graft_entry__.py 8 || fails=$((fails+1))
 
 if [ "$fails" -eq 0 ]; then
   echo "Success"   # the reference's rank-0 pass signal

@@ -280,16 +280,12 @@ def test_hierarchical_pallas_bidir_intra_phase():
             out, np.tile(np.asarray(x).sum(axis=0), (p, 1)), rtol=2e-5,
             atol=1e-5,
         )
-        from torchmpi_tpu._compat import HAS_TPU_INTERPRET
-
-        if p >= 6 and HAS_TPU_INTERPRET:
+        if p >= 6:
             # intra groups of >= 3: the bidir schedule itself runs
             assert "allreduce_bidir" in rk._LAST_STEP_COUNTS
         else:
             # intra groups of 2 share one link per pair (bidir delegates
-            # to the unidirectional kernel by design); the legacy
-            # interpreter cannot run remote DMA on 2-axis meshes at all,
-            # so the wrapper records its ppermute fallback's schedule
+            # to the unidirectional kernel by design)
             assert "allreduce" in rk._LAST_STEP_COUNTS
     finally:
         rk._FORCE_INTERPRET = False

@@ -1,14 +1,6 @@
-"""Pallas kernel tests (interpret mode on the CPU mesh; the same kernels
-run natively on real TPU meshes).
-
-Hardware sweep: on a real multi-chip TPU mesh, set
-``TORCHMPI_TPU_HW_KERNELS=1`` to run this exact file with interpret mode
-OFF — the kernels lower through Mosaic and move real ICI traffic, so the
-interpret-validated schedules get their hardware parity evidence from
-the same closed-form assertions (see docs/PARITY.md "Evidence status").
-"""
-
-import os
+"""Pallas kernel tests: interpret mode on the CPU mesh. The same kernels go
+through the Mosaic compiler and real ICI traffic in ``chip_smoke.py``'s
+kernel phase, against the XLA path."""
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +8,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-INTERPRET = os.environ.get("TORCHMPI_TPU_HW_KERNELS", "") != "1"
+INTERPRET = True
 
 from torchmpi_tpu.ops.reduce_kernel import accumulate, scale_accumulate
 from torchmpi_tpu.ops.ring_kernels import available, ring_allreduce_pallas
@@ -946,6 +938,8 @@ def test_pallas_ring_attention_chunked_matches_unchunked(p, causal):
     k = rs.randn(b, n, h, d).astype(np.float32)
     v = rs.randn(b, n, h, d).astype(np.float32)
     mesh = Mesh(np.array(jax.devices()[:p]), ("sp",))
+    # budgets that admit two (batch, head) cells of the eight per call
+    two_cells = (1, n // p, 2, d)
 
     def fwd(budget):
         f = lambda q, k, v: rak.ring_attention_pallas(  # noqa: E731
@@ -958,7 +952,8 @@ def test_pallas_ring_attention_chunked_matches_unchunked(p, causal):
         )(f))(q, k, v)
 
     np.testing.assert_allclose(
-        np.asarray(fwd(30_000)), np.asarray(fwd(None)),
+        np.asarray(fwd(rak.ring_attention_vmem_bytes(two_cells, q.dtype))),
+        np.asarray(fwd(None)),
         rtol=1e-5, atol=1e-5,
     )
 
@@ -973,7 +968,10 @@ def test_pallas_ring_attention_chunked_matches_unchunked(p, causal):
         )(f))(q, k, v)
 
     np.testing.assert_allclose(
-        np.asarray(fwd_bidir(40_000)), np.asarray(fwd(None)),
+        np.asarray(fwd_bidir(
+            rak.ring_attention_bidir_vmem_bytes(two_cells, q.dtype)
+        )),
+        np.asarray(fwd(None)),
         rtol=1e-5, atol=1e-5,
     )
 
@@ -991,7 +989,8 @@ def test_pallas_ring_attention_chunked_matches_unchunked(p, causal):
         )(lambda q, k, v: jax.lax.psum(loss(q, k, v), "sp")),
             argnums=(0, 1, 2)))(q, k, v)
 
-    for a, g in zip(grads(None), grads(60_000)):
+    bwd_budget = rak.ring_attention_bwd_vmem_bytes(two_cells, q.dtype)
+    for a, g in zip(grads(None), grads(bwd_budget)):
         np.testing.assert_allclose(
             np.asarray(g), np.asarray(a), rtol=1e-4, atol=1e-5
         )

@@ -10,8 +10,6 @@ error is unbounded near sign cancellations of the sum and would test
 the data, not the wire format.
 """
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,7 +20,7 @@ import torchmpi_tpu as mpi
 from torchmpi_tpu import constants
 from torchmpi_tpu.collectives import primitives as prim
 
-INTERPRET = os.environ.get("TORCHMPI_TPU_HW_KERNELS", "") != "1"
+INTERPRET = True
 
 P_SWEEP = [2, 3,
            pytest.param(4, marks=pytest.mark.slow),
@@ -164,7 +162,7 @@ def test_wire_below_cutoff_is_exact():
 
 
 # ---------------------------------------------------------------------------
-# pallas quantized kernels (interpret mode; hardware via HW_KERNELS=1)
+# pallas quantized kernels (interpret mode; compiled in chip_smoke.py)
 # ---------------------------------------------------------------------------
 
 

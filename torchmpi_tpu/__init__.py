@@ -18,12 +18,6 @@ Public API shape follows the reference (``torchmpi/init.lua``):
     mpi.stop()
 """
 
-from . import _compat
-
-# Older jax spells shard_map differently; alias it FIRST so every
-# submodule (and downstream user code) sees the current API surface.
-_compat.install_jax_aliases()
-
 from . import constants, telemetry
 from .collectives import (
     allgather_tensor,

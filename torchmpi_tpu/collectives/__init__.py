@@ -53,17 +53,12 @@ def _dispatch(op, x, comm, mode, backend=None, **kw):
         if backend in ("ring", "pallas"):
             # The selector decides xla-vs-custom-ring; which custom ring
             # implements it is the ring_implementation constant (read per
-            # call — it is mutable until freeze):
+            # call — it is mutable until freeze). A pallas ring asked for
+            # where it cannot run fails in the kernel call, loudly.
             from .. import constants
-            from .selector import backend_availability
 
             impl = constants.get("ring_implementation")
-            if impl in ("pallas", "pallas_bidir") and backend_availability().get(
-                "pallas"
-            ):
-                backend = "pallas"
-            elif impl == "ppermute":
-                backend = "ring"
+            backend = "ring" if impl == "ppermute" else "pallas"
     if mode == "sync":
         return eager.run(op, x, comm, backend=backend, **kw)
     if mode == "fused":

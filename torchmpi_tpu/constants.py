@@ -501,9 +501,14 @@ def register_listener(fn: Callable[[str, Any], None]) -> None:
 
 
 def platform_suffix(platform: str) -> str:
-    """Map a jax platform string to the cutoff-constant suffix (the
-    reference's CPU/GPU constant pairs; any accelerator takes 'tpu')."""
-    return "cpu" if platform == "cpu" else "tpu"
+    """The cutoff-constant suffix for a jax platform string (the
+    reference's CPU/GPU constant pairs)."""
+    if platform not in ("cpu", "tpu"):
+        raise ValueError(
+            f"no routing constants for platform {platform!r} "
+            "(supported: 'cpu', 'tpu')"
+        )
+    return platform
 
 
 def get(name: str) -> Any:

@@ -14,7 +14,7 @@ This is that launcher, TPU-native:
   ``mpi.start()`` reads them, so an unmodified script becomes rank i of N
   (the MPI_Init-reads-mpirun's-env contract);
 - ``--cpu-devices K`` gives each process a K-device virtual CPU mesh
-  (XLA_FLAGS + TORCHMPI_TPU_FORCE_CPU) — the "multi-node without a
+  (XLA_FLAGS + JAX_PLATFORMS=cpu) — the "multi-node without a
   cluster" test mode (SURVEY.md §4);
 - ``--log-dir DIR`` writes ``rank_<i>.log`` per process (wrap.sh's
   ``LOG_TO_FILE``); default streams every line prefixed ``[i]``;
@@ -195,6 +195,21 @@ def main(argv: Optional[List[str]] = None) -> int:
         ap.error("exactly one of a script path or --module is required")
     if args.nproc < 1:
         ap.error(f"--nproc must be >= 1, got {args.nproc}")
+    if (
+        args.nproc > 1
+        and not args.cpu_devices
+        and os.environ.get("JAX_PLATFORMS") != "cpu"
+    ):
+        # measured on a v5e host: the second child dies on libtpu's
+        # multi-process lockfile, the first hangs holding the chip
+        ap.error(
+            f"--nproc {args.nproc} would start {args.nproc} processes that "
+            "each initialise the accelerator backend on this host, and a "
+            "chip belongs to one process at a time. A single host's chips "
+            "are driven by ONE process (mpi.start() sees them all): run "
+            "the script directly or with --nproc 1; for a CPU world pass "
+            "--cpu-devices K or set JAX_PLATFORMS=cpu"
+        )
     if args.nnodes > 1 and args.coordinator is None:
         ap.error("--coordinator host:port is required when nnodes > 1")
     if not 0 <= args.node_rank < args.nnodes:
@@ -318,7 +333,6 @@ def _worker_env(args, rank: int, restart: int = 0) -> dict:
             env.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.cpu_devices}"
         ).strip()
-        env["TORCHMPI_TPU_FORCE_CPU"] = "1"
         env["JAX_PLATFORMS"] = "cpu"
     return env
 

@@ -46,7 +46,7 @@ class RingAttentionBlock(fnn.Module):
     head_dim: int
     mlp_ratio: int = 4
     sp_axis: Optional[str] = None  # None = full attention (single shard)
-    sp_backend: str = "xla"  # 'xla' | 'auto' | 'pallas[_interpret][_bidir][_full]'
+    sp_backend: str = "xla"  # 'xla' | 'pallas[_interpret][_bidir][_full]'
     dtype: Any = jnp.float32
 
     @fnn.compact

@@ -38,10 +38,13 @@ gradient path — training with the pallas backend costs one kernel
 forward plus one analytic backward, the same step economics as the XLA
 ring's autodiff.
 
-With one local chip this path cannot execute on hardware; correctness is
-validated in TPU interpret mode on the virtual CPU mesh (p = 2..8,
-causal x dtypes, vs gathered-sequence full attention), the same evidence
-discipline as the ring collectives.
+Tests validate correctness in TPU interpret mode on the virtual CPU mesh
+(p = 2..8, causal x dtypes, vs gathered-sequence full attention);
+``chip_smoke.py`` compiles forward (uni, bidir) and backward through
+Mosaic on real chips against the XLA ring. A (batch, head) cell holds its
+whole [n_local, n_local] score tile in VMEM, which bounds the local
+sequence (about 2048 forward, 1024 backward at head_dim <= 128) — past
+that the wrappers raise instead of giving way to the XLA ring.
 """
 
 from __future__ import annotations
@@ -56,18 +59,36 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import (
-    dma_device_id,
-    interpret_params,
-    kernel_flow_control,
-    tpu_compiler_params,
-)
+from .ring_kernels import _LANES, neighbor_barrier
 
 NEG_INF = -1e30
 
-# VMEM footprint bound for one kernel invocation (q/k/v/o + 2x2 kv slots
-# + f32 accumulators must fit well under the ~16MB/core VMEM).
-_VMEM_BUDGET_BYTES = 10 * 1024 * 1024
+# Scoped-VMEM limit handed to Mosaic for every kernel here (a v5e core has
+# 128 MiB; the compiler's default scope is 16 MiB), and the share of it
+# the ``*_vmem_bytes`` working-set estimates may fill before a call is
+# chunked over batch/heads — the rest is the compiler's own temporaries.
+_VMEM_LIMIT_BYTES = 100 * 1024 * 1024
+_VMEM_BUDGET_BYTES = 88 * 1024 * 1024
+
+
+def _lane_pad(d: int) -> int:
+    """Head dim as the kernels hold it: whole 128-lane tiles. A remote
+    copy of a [.., n, d] slot with d < 128 is rejected by Mosaic ("slice
+    shape must be aligned to tiling"), so the wrappers zero-pad q/k/v
+    (scores and outputs are unchanged) and slice the result."""
+    return -(-d // _LANES) * _LANES
+
+
+def _column_bytes(b: int, h: int, n: int) -> int:
+    """One [bh, n, 1] f32 column (m, l, lse, D): its single lane pads to
+    a full 128-lane tile in VMEM."""
+    return b * h * n * _LANES * 4
+
+
+def _score_bytes(n: int) -> int:
+    """Per-cell [n, n] f32 intermediates live at once (scores, exp and
+    the masked/scaled copy the compiler keeps)."""
+    return 3 * n * n * 4
 
 
 def _flash_merge_cells(
@@ -120,7 +141,6 @@ def _ring_attn_kernel(
     causal: bool,
     scale: float,
     n: int,
-    fc: bool,
     my_ref,
     q_ref,
     k_ref,
@@ -155,19 +175,7 @@ def _ring_attn_kernel(
     kbuf[0] = k_ref[:]
     vbuf[0] = v_ref[:]
 
-    # neighbor barrier: nobody pushes until both neighbors arrived
-    # (skipped, with the capacity semaphores, under the legacy lockstep
-    # interpreter — _compat.kernel_flow_control)
-    if fc:
-        barrier = pltpu.get_barrier_semaphore()
-        for nbr in (left, right):
-            pltpu.semaphore_signal(
-                barrier,
-                inc=1,
-                device_id={axis: nbr},
-                device_id_type=pltpu.DeviceIdType.MESH,
-            )
-        pltpu.semaphore_wait(barrier, 2)
+    neighbor_barrier(axis, left, right)
 
     def block_merge(s: int, slot: int):
         """Attention of resident q against the slot's K/V block, merged
@@ -185,7 +193,7 @@ def _ring_attn_kernel(
         if s < p - 1:
             # the RIGHT neighbor computes on its slot ``nslot`` at step
             # s-1; wait for its consumed-signal before overwriting
-            if fc and s >= 1:
+            if s >= 1:
                 pltpu.semaphore_wait(cap_sem.at[nslot], 1)
             copies = tuple(
                 pltpu.make_async_remote_copy(
@@ -193,7 +201,7 @@ def _ring_attn_kernel(
                     dst_ref=buf.at[nslot],
                     send_sem=ssem.at[slot],
                     recv_sem=rsem.at[slot],
-                    device_id=dma_device_id(axis, right, not fc),
+                    device_id={axis: right},
                     device_id_type=pltpu.DeviceIdType.MESH,
                 )
                 for buf, ssem, rsem in (
@@ -206,7 +214,7 @@ def _ring_attn_kernel(
         block_merge(s, slot)  # compute overlaps the in-flight DMA
         for c in copies:
             c.wait()  # our send landed + next block fully arrived
-        if fc and s < p - 2:
+        if s < p - 2:
             # tell LEFT our slot is consumed (left overwrites it at its
             # step s+1). Strictly after the wait above: the outgoing DMA
             # reads this slot until the send completes, so an earlier
@@ -286,55 +294,19 @@ def _run_chunked(b, h, fits, sub, concat_axes, cell_bytes, budget, what):
     return tuple(jnp.concatenate(acc, axis=0) for acc in out_rows)
 
 
-def _ring_attention_fwd_xla(q, k, v, axis, causal, p, return_lse):
-    """ppermute-ring forward with the lse residual — the stand-in the
-    kernel wrappers use when the LEGACY pallas interpreter cannot run
-    remote DMA on a multi-axis mesh (``ring_kernels._legacy_multiaxis``).
-    Same streaming-softmax math as the kernels; XLA transport."""
-    b, n, h, d = q.shape
-    r = lax.axis_index(axis)
-    perm = [(i, (i + 1) % p) for i in range(p)]
-    scale = 1.0 / math.sqrt(d)
-    q_pos = r * n + jnp.arange(n)
-    qf = q.astype(jnp.float32)
+def _to_cells(t, dp: int):
+    """[b, n, h, d] -> [b*h, n, dp] (head dim zero-padded to lanes)."""
+    b, n, h, d = t.shape
+    cells = t.transpose(0, 2, 1, 3).reshape(b * h, n, d)
+    if dp != d:
+        cells = jnp.pad(cells, ((0, 0), (0, 0), (0, dp - d)))
+    return cells
 
-    def step(s, carry):
-        o, m, l, kb, vb = carry
-        src = lax.rem(r - s + p, p)
-        k_pos = src * n + jnp.arange(n)
-        sij = (
-            jnp.einsum("bqhd,bkhd->bhqk", qf, kb.astype(jnp.float32))
-            * scale
-        )
-        if causal:
-            mask = q_pos[:, None] >= k_pos[None, :]
-            sij = jnp.where(mask[None, None], sij, NEG_INF)
-        mb = sij.max(-1)  # [b, h, q]
-        pexp = jnp.exp(sij - mb[..., None])
-        lb = pexp.sum(-1)
-        ob = jnp.einsum("bhqk,bkhd->bqhd", pexp, vb.astype(jnp.float32))
-        m_new = jnp.maximum(m, mb)
-        alpha = jnp.exp(m - m_new)
-        beta = jnp.exp(mb - m_new)
-        l_new = l * alpha + lb * beta
-        o_new = (
-            o * alpha.transpose(0, 2, 1)[..., None]
-            + ob * beta.transpose(0, 2, 1)[..., None]
-        )
-        return (
-            o_new, m_new, l_new,
-            lax.ppermute(kb, axis, perm), lax.ppermute(vb, axis, perm),
-        )
 
-    o0 = jnp.zeros((b, n, h, d), jnp.float32)
-    m0 = jnp.full((b, h, n), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((b, h, n), jnp.float32)
-    o, m, l, _, _ = lax.fori_loop(0, p, step, (o0, m0, l0, k, v))
-    l = jnp.maximum(l, 1e-30)
-    out = (o / l.transpose(0, 2, 1)[..., None]).astype(q.dtype)
-    if return_lse:
-        return out, m + jnp.log(l)
-    return out
+def _from_cells(cells, b: int, h: int, d: int):
+    """Inverse of :func:`_to_cells`: drop the lane padding."""
+    n = cells.shape[1]
+    return cells[..., :d].reshape(b, h, n, d).transpose(0, 2, 1, 3)
 
 
 def _make_fwd(kernel_fn, vmem_bytes_fn, scratch_fn, collective_id, what):
@@ -365,12 +337,6 @@ def _make_fwd(kernel_fn, vmem_bytes_fn, scratch_fn, collective_id, what):
             from ..parallel.ring_attention import full_self_attention
 
             return full_self_attention(q, k, v, causal=causal)
-        from .ring_kernels import _legacy_multiaxis
-
-        if _legacy_multiaxis(interpret):
-            return _ring_attention_fwd_xla(
-                q, k, v, axis, causal, p, return_lse
-            )
         budget = vmem_budget_bytes or _VMEM_BUDGET_BYTES
         if vmem_bytes_fn(q.shape, q.dtype) > budget:
             def sub(bi, bb, hi, hh, prev):
@@ -396,18 +362,18 @@ def _make_fwd(kernel_fn, vmem_bytes_fn, scratch_fn, collective_id, what):
             )
             return (out, lse) if return_lse else out
         bh = b * h
-        # [b, n, h, d] -> [bh, n, d]: per-cell 2D math on the MXU
-        to_cells = lambda t: t.transpose(0, 2, 1, 3).reshape(bh, n, d)  # noqa: E731
+        dp = _lane_pad(d)
+        # [b, n, h, d] -> [bh, n, dp]: per-cell 2D math on the MXU
+        to_cells = functools.partial(_to_cells, dp=dp)
         scale = 1.0 / math.sqrt(d)
         my = lax.axis_index(axis).astype(jnp.int32).reshape(1)
         kernel = functools.partial(
-            kernel_fn, p, axis, causal, scale, n,
-            kernel_flow_control(interpret),
+            kernel_fn, p, axis, causal, scale, n
         )
         out, lse = pl.pallas_call(
             kernel,
             out_shape=(
-                jax.ShapeDtypeStruct((bh, n, d), q.dtype),
+                jax.ShapeDtypeStruct((bh, n, dp), q.dtype),
                 jax.ShapeDtypeStruct((bh, n, 1), jnp.float32),
             ),
             in_specs=[
@@ -420,13 +386,14 @@ def _make_fwd(kernel_fn, vmem_bytes_fn, scratch_fn, collective_id, what):
                 pl.BlockSpec(memory_space=pltpu.VMEM),
                 pl.BlockSpec(memory_space=pltpu.VMEM),
             ),
-            scratch_shapes=scratch_fn(bh, n, d, k.dtype, v.dtype),
-            compiler_params=tpu_compiler_params(
-                collective_id=collective_id
+            scratch_shapes=scratch_fn(bh, n, dp, k.dtype, v.dtype),
+            compiler_params=pltpu.CompilerParams(
+                collective_id=collective_id,
+                vmem_limit_bytes=_VMEM_LIMIT_BYTES,
             ),
-            interpret=interpret_params() if interpret else False,
+            interpret=pltpu.InterpretParams() if interpret else False,
         )(my, to_cells(q), to_cells(k), to_cells(v))
-        out = out.reshape(b, h, n, d).transpose(0, 2, 1, 3)
+        out = _from_cells(out, b, h, d)
         if return_lse:
             return out, lse.reshape(b, h, n)
         return out
@@ -450,13 +417,17 @@ def _uni_scratch(bh, n, d, k_dtype, v_dtype):
 
 
 def ring_attention_vmem_bytes(local_shape, dtype) -> int:
-    """Kernel working-set estimate for the given local q shape: q/k/v/o
-    plus the 2x2 double-buffered slots in ``dtype``, the f32 accumulator,
-    and the [.., n, 1] m/l columns."""
+    """Kernel working-set estimate for the given local q shape, as VMEM
+    holds it: q/k/v/o plus the 2x2 double-buffered slots in ``dtype``, the
+    f32 accumulator, the m/l/lse columns and one cell's score tiles."""
     b, n, h, d = local_shape
-    cells = b * h * n * d
+    cells = b * h * n * _lane_pad(d)
     itemsize = jnp.dtype(dtype).itemsize
-    return cells * (8 * itemsize + 4) + 2 * 4 * b * h * n
+    return (
+        cells * (8 * itemsize + 4)
+        + 3 * _column_bytes(b, h, n)
+        + _score_bytes(n)
+    )
 
 
 ring_attention_pallas = _make_fwd(
@@ -509,7 +480,6 @@ def _ring_attn_bidir_kernel(
     causal: bool,
     scale: float,
     n: int,
-    fc: bool,
     my_ref,
     q_ref,
     k_ref,
@@ -563,16 +533,7 @@ def _ring_attn_bidir_kernel(
     kbufL[0] = k_ref[:]
     vbufL[0] = v_ref[:]
 
-    if fc:
-        barrier = pltpu.get_barrier_semaphore()
-        for nbr in (left, right):
-            pltpu.semaphore_signal(
-                barrier,
-                inc=1,
-                device_id={axis: nbr},
-                device_id_type=pltpu.DeviceIdType.MESH,
-            )
-        pltpu.semaphore_wait(barrier, 2)
+    neighbor_barrier(axis, left, right)
 
     # distances delivered per chain; nR >= nL, nR + nL = p - 1
     nR = (p - 1 + 1) // 2
@@ -597,21 +558,14 @@ def _ring_attn_bidir_kernel(
         for (bufs, sems, cap, dst, cap_to, ndist, is_l) in chains:
             if t < ndist:  # this chain still has a farther block to push
                 # causal L-chain hops that can never contribute are
-                # skipped — but only where the flow-control machinery
-                # runs (hardware / modern interpreter): the LEGACY
-                # interpreter cannot discharge DMAs under
-                # device-divergent pl.when (each remote copy lowers to
-                # an all_gather that deadlocks inside a divergent cond,
-                # see ring_kernels._legacy_interpret), so it keeps the
-                # unconditional schedule (its transport is simulated;
-                # the merge skip below still carries the numerics).
-                gated = causal and is_l and fc
+                # skipped (transport and, below, the merge)
+                gated = causal and is_l
                 # gates agree pairwise across neighbors: my send at t is
                 # my-1's recv at t (both l_needed(my + t) from the
                 # sender's frame); my cap signal at t enables my+1's
                 # send at t+1 (both l_needed(my + t + 2))
                 p_out = l_needed(my + t) if gated else None
-                if fc and t >= 1:
+                if t >= 1:
                     if p_out is None:
                         pltpu.semaphore_wait(cap.at[nslot], 1)
                     else:
@@ -625,7 +579,7 @@ def _ring_attn_bidir_kernel(
                         dst_ref=buf.at[nslot],
                         send_sem=ssem.at[slot],
                         recv_sem=rsem.at[slot],
-                        device_id=dma_device_id(axis, dst, not fc),
+                        device_id={axis: dst},
                         device_id_type=pltpu.DeviceIdType.MESH,
                     )
                     for buf, ssem, rsem in (
@@ -699,7 +653,7 @@ def _ring_attn_bidir_kernel(
             # slot consumed + our outgoing read landed: upstream may
             # overwrite it at its next send. Its sends stop at t = ndist-1,
             # so signals stop one step earlier (semaphores end drained).
-            if fc and t < ndist - 1:
+            if t < ndist - 1:
                 if not gated:
                     pltpu.semaphore_signal(
                         cap.at[slot],
@@ -732,9 +686,13 @@ def ring_attention_bidir_vmem_bytes(local_shape, dtype) -> int:
     """Bidir working set: the unidirectional envelope plus the second
     chain's 2x2 K/V slots."""
     b, n, h, d = local_shape
-    cells = b * h * n * d
+    cells = b * h * n * _lane_pad(d)
     itemsize = jnp.dtype(dtype).itemsize
-    return cells * (12 * itemsize + 4) + 2 * 4 * b * h * n
+    return (
+        cells * (12 * itemsize + 4)
+        + 3 * _column_bytes(b, h, n)
+        + _score_bytes(n)
+    )
 
 
 def _bidir_scratch(bh, n, d, k_dtype, v_dtype):
@@ -771,14 +729,12 @@ batch/head auto-chunking.
 Causal caveat: under ``causal=True`` the L chain mostly carries blocks
 from strictly-future ranks (source ``my + t`` with no wraparound), whose
 scores are fully masked. The kernel SKIPS both the merge compute for
-those blocks AND — on hardware / the modern interpreter — their K/V
-sends: an L-chain hop runs only when its block already wrapped past
-rank 0 or still can within the chain (:func:`_l_hop_needed`), with
-send / recv / capacity-semaphore gates matched pairwise across
-neighbors so the transport discipline stays deadlock-free. Wire bytes
-saved, not just FLOPs (ADVICE r5). The LEGACY pallas interpreter keeps
-the unconditional schedule (conditional DMAs cannot discharge there;
-its transport is simulated anyway). Even so, causal workloads get less
+those blocks AND their K/V sends: an L-chain hop runs only when its
+block already wrapped past rank 0 or still can within the chain
+(:func:`_l_hop_needed`), with send / recv / capacity-semaphore gates
+matched pairwise across neighbors so the transport discipline stays
+deadlock-free. Wire bytes saved, not just FLOPs (ADVICE r5). Even so,
+causal workloads get less
 than the full ~2x: the R chain carries ``ceil((p-1)/2)`` useful blocks
 regardless — measure (``utils.autotune``) rather than assume."""
 
@@ -855,7 +811,6 @@ def _ring_attn_bwd_kernel(
     causal: bool,
     scale: float,
     n: int,
-    fc: bool,
     my_ref,
     q_ref,
     o_ref,
@@ -920,16 +875,7 @@ def _ring_attn_bwd_kernel(
 
     lax.fori_loop(0, bh, dinit, 0)
 
-    if fc:
-        barrier = pltpu.get_barrier_semaphore()
-        for nbr in (left, right):
-            pltpu.semaphore_signal(
-                barrier,
-                inc=1,
-                device_id={axis: nbr},
-                device_id_type=pltpu.DeviceIdType.MESH,
-            )
-        pltpu.semaphore_wait(barrier, 2)
+    neighbor_barrier(axis, left, right)
 
     def block_grad(s: int, slot: int):
         """Analytic flash gradients of the visiting block, accumulated
@@ -987,7 +933,7 @@ def _ring_attn_bwd_kernel(
         # forward the mutated payload; the right neighbor's slot must be
         # consumed (its step s-1 compute done AND its own send of that
         # slot landed — it signals after its c.wait())
-        if fc and s >= 1:
+        if s >= 1:
             pltpu.semaphore_wait(cap_sem.at[nslot], 1)
         copies = tuple(
             pltpu.make_async_remote_copy(
@@ -995,7 +941,7 @@ def _ring_attn_bwd_kernel(
                 dst_ref=buf.at[nslot],
                 send_sem=ssem.at[slot],
                 recv_sem=rsem.at[slot],
-                device_id=dma_device_id(axis, right, not fc),
+                device_id={axis: right},
                 device_id_type=pltpu.DeviceIdType.MESH,
             )
             for buf, ssem, rsem in (
@@ -1009,7 +955,7 @@ def _ring_attn_bwd_kernel(
             c.start()
         for c in copies:
             c.wait()  # our payload landed + next block fully arrived
-        if fc and s < p - 1:
+        if s < p - 1:
             # my slot is consumed and my outgoing read of it is complete:
             # left may overwrite it at its step s+1. No signal after the
             # last step so every semaphore ends the kernel drained.
@@ -1036,9 +982,13 @@ def ring_attention_bwd_vmem_bytes(local_shape, dtype) -> int:
     + 2x2 K/V slots in ``dtype``, 2x2 dK/dV slots + dq accumulator in f32,
     plus the [.., n, 1] lse/D columns."""
     b, n, h, d = local_shape
-    cells = b * h * n * d
+    cells = b * h * n * _lane_pad(d)
     itemsize = jnp.dtype(dtype).itemsize
-    return cells * (12 * itemsize + 20) + 2 * 4 * b * h * n
+    return (
+        cells * (12 * itemsize + 20)
+        + 2 * _column_bytes(b, h, n)
+        + 2 * _score_bytes(n)
+    )
 
 
 def ring_attention_bwd_pallas(
@@ -1057,10 +1007,6 @@ def ring_attention_bwd_pallas(
     p = axis_size or lax.axis_size(axis)
     b, n, h, d = q.shape
     assert p > 1, "p == 1 has no ring; callers differentiate locally"
-    from .ring_kernels import _legacy_multiaxis
-
-    if _legacy_multiaxis(interpret):
-        return _ring_attention_bwd_xla(q, k, v, o, lse, do, axis, causal, p)
     budget = vmem_budget_bytes or _VMEM_BUDGET_BYTES
     if ring_attention_bwd_vmem_bytes(q.shape, q.dtype) > budget:
         def sub(bi, bb, hi, hh, prev):
@@ -1088,19 +1034,19 @@ def ring_attention_bwd_pallas(
             "ring-attention backward",
         )
     bh = b * h
-    to_cells = lambda t: t.transpose(0, 2, 1, 3).reshape(bh, n, d)  # noqa: E731
+    dp = _lane_pad(d)
+    to_cells = functools.partial(_to_cells, dp=dp)
     scale = 1.0 / math.sqrt(d)
     my = lax.axis_index(axis).astype(jnp.int32).reshape(1)
     kernel = functools.partial(
-        _ring_attn_bwd_kernel, p, axis, causal, scale, n,
-        kernel_flow_control(interpret),
+        _ring_attn_bwd_kernel, p, axis, causal, scale, n
     )
     dq, dk, dv = pl.pallas_call(
         kernel,
         out_shape=(
-            jax.ShapeDtypeStruct((bh, n, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, n, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, n, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, n, dp), q.dtype),
+            jax.ShapeDtypeStruct((bh, n, dp), k.dtype),
+            jax.ShapeDtypeStruct((bh, n, dp), v.dtype),
         ),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -1117,11 +1063,11 @@ def ring_attention_bwd_pallas(
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ),
         scratch_shapes=[
-            pltpu.VMEM((2, bh, n, d), k.dtype),
-            pltpu.VMEM((2, bh, n, d), v.dtype),
-            pltpu.VMEM((2, bh, n, d), jnp.float32),
-            pltpu.VMEM((2, bh, n, d), jnp.float32),
-            pltpu.VMEM((bh, n, d), jnp.float32),
+            pltpu.VMEM((2, bh, n, dp), k.dtype),
+            pltpu.VMEM((2, bh, n, dp), v.dtype),
+            pltpu.VMEM((2, bh, n, dp), jnp.float32),
+            pltpu.VMEM((2, bh, n, dp), jnp.float32),
+            pltpu.VMEM((bh, n, dp), jnp.float32),
             pltpu.VMEM((bh, n, 1), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
@@ -1133,13 +1079,15 @@ def ring_attention_bwd_pallas(
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.REGULAR((2,)),
         ],
-        compiler_params=tpu_compiler_params(collective_id=12),
-        interpret=interpret_params() if interpret else False,
+        compiler_params=pltpu.CompilerParams(
+            collective_id=12, vmem_limit_bytes=_VMEM_LIMIT_BYTES
+        ),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(
         my, to_cells(q), to_cells(o), to_cells(do),
         lse.reshape(bh, n, 1), to_cells(k), to_cells(v),
     )
-    back = lambda t: t.reshape(b, h, n, d).transpose(0, 2, 1, 3)  # noqa: E731
+    back = functools.partial(_from_cells, b=b, h=h, d=d)
     return back(dq), back(dk), back(dv)
 
 

@@ -24,10 +24,11 @@ enforced with interprocess events and per-step MPI barriers
 (``:65-66,100-101``). ``cap_sem`` closes the fast-sender/slow-receiver
 race (see kernel docstring).
 
-The kernels run under ``shard_map`` (one program per device). With one
-local chip this path cannot execute on hardware; correctness is validated
-in TPU interpret mode (``pltpu.InterpretParams``) on the virtual CPU mesh,
-and ``available()`` gates the eager selector to real multi-chip TPU meshes.
+The kernels run under ``shard_map`` (one program per device). Tests
+validate them in TPU interpret mode (``pltpu.InterpretParams``) on the
+virtual CPU mesh; ``chip_smoke.py`` compiles each through Mosaic and
+checks it against the XLA path on real chips. ``available()`` gates the
+eager selector to real multi-chip TPU meshes.
 """
 
 from __future__ import annotations
@@ -41,43 +42,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import (
-    HAS_TPU_INTERPRET,
-    dma_device_id,
-    interpret_params,
-    kernel_flow_control,
-    tpu_compiler_params,
-)
-
 _LANES = 128
-
-
-def _legacy_interpret(interpret: bool) -> bool:
-    """True when ``interpret`` would run on the LEGACY pallas interpreter
-    (jax without the TPU interpret machinery). Kernels whose DMAs sit
-    under device-divergent ``pl.when`` conditions (pipelined broadcast,
-    root-directed gather) cannot discharge there — each remote copy
-    lowers to an ``all_gather``, which deadlocks inside a divergent cond
-    — so their wrappers substitute an equivalent transport. The
-    unconditional-schedule kernels (allreduce/rs/ag phases, quantized
-    ring) run fine."""
-    return interpret and not HAS_TPU_INTERPRET
-
-
-def _legacy_multiaxis(interpret: bool) -> bool:
-    """True when the legacy interpreter additionally cannot run remote
-    DMA AT ALL: its discharge rule rejects meshes with more than one
-    named axis (hierarchical intra/inter compositions). Wrappers fall
-    back to their ppermute equivalents — same results, XLA transport."""
-    if not _legacy_interpret(interpret):
-        return False
-    try:
-        from jax._src import core as _core
-
-        names = [n for n in _core.get_axis_env().axis_sizes if n is not None]
-    except Exception:  # noqa: BLE001 - private-API probe; assume 1 axis
-        return False
-    return len(names) > 1
 
 # dtypes the kernels move/reduce natively; everything else is routed
 # through a same-kind carrier (ints -> int32, floats -> float32) by the
@@ -172,10 +137,7 @@ def _bitcast_to_bytes(flat, force: bool = False):
 def available() -> bool:
     """True when the pallas ring can service eager collectives: a real TPU
     platform with more than one device."""
-    try:
-        devs = jax.devices()
-    except Exception:
-        return False
+    devs = jax.devices()
     return devs[0].platform == "tpu" and len(devs) > 1
 
 
@@ -193,6 +155,20 @@ _FORCE_INTERPRET = False
 _LAST_STEP_COUNTS: dict = {}
 
 
+def neighbor_barrier(axis: str, left, right) -> None:
+    """Nobody starts pushing until both ring neighbors entered the kernel
+    (the reference's per-collective MPI barrier before the IPC ring)."""
+    barrier = pltpu.get_barrier_semaphore()
+    for nbr in (left, right):
+        pltpu.semaphore_signal(
+            barrier,
+            inc=1,
+            device_id={axis: nbr},
+            device_id_type=pltpu.DeviceIdType.MESH,
+        )
+    pltpu.semaphore_wait(barrier, 2)
+
+
 # ---------------------------------------------------------------------------
 # allreduce / reduce-scatter
 # ---------------------------------------------------------------------------
@@ -202,7 +178,6 @@ def _ring_phases_kernel(
     p: int,
     axis: str,
     mode: str,
-    fc: bool,
     my_ref,
     x_ref,
     o_ref,
@@ -238,38 +213,20 @@ def _ring_phases_kernel(
     left = lax.rem(my + p - 1, p)
     o_ref[:] = x_ref[:]
 
-    # neighbor barrier: nobody starts pushing until both neighbors arrived
-    # (the reference's per-collective MPI barrier before the IPC ring).
-    # ``fc`` gates all flow control — off only under the legacy lockstep
-    # interpreter, which cannot express remote signals (_compat).
-    if fc:
-        barrier = pltpu.get_barrier_semaphore()
-        pltpu.semaphore_signal(
-            barrier,
-            inc=1,
-            device_id={axis: left},
-            device_id_type=pltpu.DeviceIdType.MESH,
-        )
-        pltpu.semaphore_signal(
-            barrier,
-            inc=1,
-            device_id={axis: right},
-            device_id_type=pltpu.DeviceIdType.MESH,
-        )
-        pltpu.semaphore_wait(barrier, 2)
+    neighbor_barrier(axis, left, right)
 
     total = 2 * (p - 1) if mode == "allreduce" else (p - 1)
 
     def ring_step(t: int, send_idx, recv_idx, accumulate: bool):
         slot = t % 2
-        if fc and t >= 2:  # slot reuse: wait until right consumed t-2 data
+        if t >= 2:  # slot reuse: wait until right consumed t-2 data
             pltpu.semaphore_wait(cap_sem.at[slot], 1)
         copy = pltpu.make_async_remote_copy(
             src_ref=o_ref.at[send_idx],
             dst_ref=comm_buf.at[slot],
             send_sem=send_sem.at[slot],
             recv_sem=recv_sem.at[slot],
-            device_id=dma_device_id(axis, right, not fc),
+            device_id={axis: right},
             device_id_type=pltpu.DeviceIdType.MESH,
         )
         copy.start()
@@ -278,7 +235,7 @@ def _ring_phases_kernel(
             o_ref[recv_idx] = o_ref[recv_idx] + comm_buf[slot]
         else:
             o_ref[recv_idx] = comm_buf[slot]
-        if fc and t < total - 2:  # tell LEFT its slot frees for step t+2
+        if t < total - 2:  # tell LEFT its slot frees for step t+2
             pltpu.semaphore_signal(
                 cap_sem.at[slot],
                 inc=1,
@@ -328,7 +285,7 @@ def _max_rows(p: int, itemsize: int, min_rows: int) -> int:
 def _ring_phases_call(chunks, p, axis, rows, dtype, mode, interpret):
     my = lax.axis_index(axis).astype(jnp.int32).reshape(1)
     kernel = functools.partial(
-        _ring_phases_kernel, p, axis, mode, kernel_flow_control(interpret)
+        _ring_phases_kernel, p, axis, mode
     )
     return pl.pallas_call(
         kernel,
@@ -344,8 +301,8 @@ def _ring_phases_call(chunks, p, axis, rows, dtype, mode, interpret):
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.REGULAR((2,)),
         ],
-        compiler_params=tpu_compiler_params(collective_id=7),
-        interpret=interpret_params() if interpret else False,
+        compiler_params=pltpu.CompilerParams(collective_id=7),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(my, chunks)
 
 
@@ -399,14 +356,6 @@ def ring_allreduce_pallas(
     p = axis_size or lax.axis_size(axis)
     if p == 1:
         return x
-    if _legacy_multiaxis(interpret or _FORCE_INTERPRET):
-        from ..collectives import primitives as _prim
-
-        # same ring economics; record the schedule for introspection
-        _LAST_STEP_COUNTS["allreduce"] = 2 * (p - 1)
-        return _prim.ring_allreduce(
-            x, axis, axis_size=axis_size, wire_dtype=wire_dtype
-        )
     wire = _wire_requested(x, wire_dtype)
     if wire is not None:
         return ring_allreduce_quant_pallas(
@@ -453,13 +402,6 @@ def ring_reduce_scatter_pallas(
     p = axis_size or lax.axis_size(axis)
     if p == 1:
         return x
-    if _legacy_multiaxis(interpret or _FORCE_INTERPRET):
-        from ..collectives import primitives as _prim
-
-        _LAST_STEP_COUNTS["reduce_scatter"] = p - 1
-        return _prim.ring_reduce_scatter(
-            x, axis, dim=0, axis_size=axis_size, wire_dtype=wire_dtype
-        )
     wire = _wire_requested(x, wire_dtype)
     if wire is not None:
         return ring_reduce_scatter_quant_pallas(
@@ -548,12 +490,39 @@ def _max_rows_quant(p: int, wire: str) -> int:
     )
 
 
+def _diag_mask():
+    """[1, 128, 128] identity mask: moves per-row values between the
+    sublane-major [rows, 1] and lane-dense [rows/128, 128] layouts with
+    broadcast + select + reduce only (Mosaic has no sublane<->lane
+    reshape; exact — one nonzero term per sum)."""
+    shape = (1, _QUANT_ROW_ALIGN, _LANES)
+    return lax.broadcasted_iota(jnp.int32, shape, 1) == lax.broadcasted_iota(
+        jnp.int32, shape, 2
+    )
+
+
+def _rows_to_lanes(col, nsr: int):
+    """[nsr*128, 1] -> [nsr, 128]; row i*128+j lands at [i, j]."""
+    wide = jnp.broadcast_to(col, (nsr * _QUANT_ROW_ALIGN, _LANES)).reshape(
+        nsr, _QUANT_ROW_ALIGN, _LANES
+    )
+    return jnp.sum(jnp.where(_diag_mask(), wide, 0.0), axis=1)
+
+
+def _lanes_to_rows(mat, nsr: int):
+    """[nsr, 128] -> [nsr*128, 1], the inverse of :func:`_rows_to_lanes`."""
+    wide = jnp.broadcast_to(
+        mat[:, None, :], (nsr, _QUANT_ROW_ALIGN, _LANES)
+    )
+    col = jnp.sum(jnp.where(_diag_mask(), wide, 0.0), axis=2, keepdims=True)
+    return col.reshape(nsr * _QUANT_ROW_ALIGN, 1)
+
+
 def _ring_quant_kernel(
     p: int,
     axis: str,
     mode: str,
     wire: str,
-    fc: bool,
     nsr: int,
     my_ref,
     x_ref,
@@ -594,17 +563,7 @@ def _ring_quant_kernel(
         # deterministic bytes in the padded scale rows (never read back)
         sstage[...] = jnp.zeros_like(sstage)
 
-    if fc:
-        barrier = pltpu.get_barrier_semaphore()
-        pltpu.semaphore_signal(
-            barrier, inc=1, device_id={axis: left},
-            device_id_type=pltpu.DeviceIdType.MESH,
-        )
-        pltpu.semaphore_signal(
-            barrier, inc=1, device_id={axis: right},
-            device_id_type=pltpu.DeviceIdType.MESH,
-        )
-        pltpu.semaphore_wait(barrier, 2)
+    neighbor_barrier(axis, left, right)
 
     total = 2 * (p - 1) if mode == "allreduce" else (p - 1)
 
@@ -615,13 +574,13 @@ def _ring_quant_kernel(
                 jnp.max(jnp.abs(xv), axis=1, keepdims=True), 1e-30
             ) / 127.0
             qstage[...] = jnp.round(xv / scale).astype(jnp.int8)
-            sstage[0:nsr] = scale.reshape(nsr, _LANES)
+            sstage[0:nsr] = _rows_to_lanes(scale, nsr)
         else:
             qstage[...] = xv.astype(jnp.bfloat16)
 
     def decode(slot: int):
         if wire == "int8":
-            sc = comm_s[slot, 0:nsr].reshape(rows, 1)
+            sc = _lanes_to_rows(comm_s[slot, 0:nsr], nsr)
             return comm_q[slot].astype(jnp.float32) * sc
         return comm_q[slot].astype(jnp.float32)
 
@@ -630,7 +589,7 @@ def _ring_quant_kernel(
         # staging reuse is safe: step t-1's copy.wait() proved the
         # previous staging bytes left the chip
         encode(send_idx)
-        if fc and t >= 2:
+        if t >= 2:
             pltpu.semaphore_wait(cap_sem.at[slot], 1)
         copies = [
             pltpu.make_async_remote_copy(
@@ -638,7 +597,7 @@ def _ring_quant_kernel(
                 dst_ref=comm_q.at[slot],
                 send_sem=send_q.at[slot],
                 recv_sem=recv_q.at[slot],
-                device_id=dma_device_id(axis, right, not fc),
+                device_id={axis: right},
                 device_id_type=pltpu.DeviceIdType.MESH,
             )
         ]
@@ -649,7 +608,7 @@ def _ring_quant_kernel(
                     dst_ref=comm_s.at[slot],
                     send_sem=send_s.at[slot],
                     recv_sem=recv_s.at[slot],
-                    device_id=dma_device_id(axis, right, not fc),
+                    device_id={axis: right},
                     device_id_type=pltpu.DeviceIdType.MESH,
                 )
             )
@@ -662,7 +621,7 @@ def _ring_quant_kernel(
             o_ref[recv_idx] = o_ref[recv_idx] + val
         else:
             o_ref[recv_idx] = val
-        if fc and t < total - 2:
+        if t < total - 2:
             pltpu.semaphore_signal(
                 cap_sem.at[slot], inc=1, device_id={axis: left},
                 device_id_type=pltpu.DeviceIdType.MESH,
@@ -713,8 +672,7 @@ def _ring_quant_call(chunks, p, axis, rows, mode, wire, interpret):
             pltpu.SemaphoreType.REGULAR((2,)),
         ]
     kernel = functools.partial(
-        _ring_quant_kernel, p, axis, mode, wire,
-        kernel_flow_control(interpret), nsr,
+        _ring_quant_kernel, p, axis, mode, wire, nsr
     )
     return pl.pallas_call(
         kernel,
@@ -725,8 +683,8 @@ def _ring_quant_call(chunks, p, axis, rows, mode, wire, interpret):
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=scratch,
-        compiler_params=tpu_compiler_params(collective_id=14),
-        interpret=interpret_params() if interpret else False,
+        compiler_params=pltpu.CompilerParams(collective_id=14),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(my, chunks)
 
 
@@ -851,10 +809,6 @@ def ring_allgather_pallas(
     if p == 1:
         return x[None]
     interpret = interpret or _FORCE_INTERPRET
-    if _legacy_multiaxis(interpret):
-        # XLA transport stand-in (legacy interpreter, multi-axis mesh):
-        # same stacked-[p, ...] contract
-        return lax.all_gather(x, axis, axis=0)
     orig_shape, orig_dtype = x.shape, x.dtype
     flat, restore = _bitcast_to_bytes(x.reshape(-1))
     carrier = flat.dtype
@@ -897,7 +851,6 @@ def ring_allgather_pallas(
 def _ring_bidir_kernel(
     p: int,
     axis: str,
-    fc: bool,
     my_ref,
     xa_ref,
     xb_ref,
@@ -931,17 +884,7 @@ def _ring_bidir_kernel(
     oa_ref[:] = xa_ref[:]
     ob_ref[:] = xb_ref[:]
 
-    if fc:
-        barrier = pltpu.get_barrier_semaphore()
-        pltpu.semaphore_signal(
-            barrier, inc=1, device_id={axis: left},
-            device_id_type=pltpu.DeviceIdType.MESH,
-        )
-        pltpu.semaphore_signal(
-            barrier, inc=1, device_id={axis: right},
-            device_id_type=pltpu.DeviceIdType.MESH,
-        )
-        pltpu.semaphore_wait(barrier, 2)
+    neighbor_barrier(axis, left, right)
 
     total = 2 * (p - 1)
 
@@ -951,14 +894,14 @@ def _ring_bidir_kernel(
         slot = t % 2
         to = right if d == 1 else left
         frm = left if d == 1 else right
-        if fc and t >= 2:
+        if t >= 2:
             pltpu.semaphore_wait(cap_sem.at[slot], 1)
         copy = pltpu.make_async_remote_copy(
             src_ref=o_ref.at[send_idx],
             dst_ref=comm_buf.at[slot],
             send_sem=send_sem.at[slot],
             recv_sem=recv_sem.at[slot],
-            device_id=dma_device_id(axis, to, not fc),
+            device_id={axis: to},
             device_id_type=pltpu.DeviceIdType.MESH,
         )
         copy.start()
@@ -969,7 +912,7 @@ def _ring_bidir_kernel(
                 o_ref[recv_idx] = o_ref[recv_idx] + comm_buf[slot]
             else:
                 o_ref[recv_idx] = comm_buf[slot]
-            if fc and t < total - 2:
+            if t < total - 2:
                 pltpu.semaphore_signal(
                     cap_sem.at[slot], inc=1, device_id={axis: frm},
                     device_id_type=pltpu.DeviceIdType.MESH,
@@ -1017,11 +960,10 @@ def ring_allreduce_bidir_pallas(
     p = axis_size or lax.axis_size(axis)
     if p == 1:
         return x
-    if p == 2 or _legacy_multiaxis(interpret or _FORCE_INTERPRET):
+    if p == 2:
         # two devices: both "directions" address the same single neighbor
         # link; the unidirectional kernel is the same schedule with half
-        # the semaphore traffic. (The legacy multi-axis case delegates
-        # for its ppermute fallback.)
+        # the semaphore traffic
         return ring_allreduce_pallas(
             x, axis, axis_size=axis_size, interpret=interpret
         )
@@ -1045,7 +987,7 @@ def ring_allreduce_bidir_pallas(
     assert rows_a == rows_b
     my = lax.axis_index(axis).astype(jnp.int32).reshape(1)
     kernel = functools.partial(
-        _ring_bidir_kernel, p, axis, kernel_flow_control(interpret)
+        _ring_bidir_kernel, p, axis
     )
     outs = []
     for seg_a, seg_b in zip(ca, cb):
@@ -1075,8 +1017,8 @@ def ring_allreduce_bidir_pallas(
                 pltpu.SemaphoreType.REGULAR((2,)),
                 pltpu.SemaphoreType.REGULAR((2,)),
             ],
-            compiler_params=tpu_compiler_params(collective_id=10),
-            interpret=interpret_params() if interpret else False,
+            compiler_params=pltpu.CompilerParams(collective_id=10),
+            interpret=pltpu.InterpretParams() if interpret else False,
         )(my, seg_a, seg_b)
         outs.append((oa, ob))
     flat_a = jnp.concatenate([o.reshape(-1) for o, _ in outs])[:half]
@@ -1118,7 +1060,7 @@ def _segmented_pair_ready(flat, p, dtype):
 
 
 def _ring_gather_root_kernel(
-    p: int, axis: str, root: int, fc: bool, my_ref, x_ref, o_ref,
+    p: int, axis: str, root: int, my_ref, x_ref, o_ref,
     send_sem, recv_sem, cap_sem
 ):
     """Gather every device's owned chunk to ``root`` along the ring — the
@@ -1147,17 +1089,7 @@ def _ring_gather_root_kernel(
     left_d = lax.rem(d + p - 1, p)
     o_ref[:] = x_ref[:]
 
-    if fc:
-        barrier = pltpu.get_barrier_semaphore()
-        pltpu.semaphore_signal(
-            barrier, inc=1, device_id={axis: left},
-            device_id_type=pltpu.DeviceIdType.MESH,
-        )
-        pltpu.semaphore_signal(
-            barrier, inc=1, device_id={axis: right},
-            device_id_type=pltpu.DeviceIdType.MESH,
-        )
-        pltpu.semaphore_wait(barrier, 2)
+    neighbor_barrier(axis, left, right)
 
     for s in range(p - 1):
         slot = s % 2
@@ -1171,25 +1103,23 @@ def _ring_gather_root_kernel(
                 dst_ref=o_ref.at[ridx],
                 send_sem=send_sem.at[slot],
                 recv_sem=recv_sem.at[slot],
-                device_id=dma_device_id(axis, right, not fc),
+                device_id={axis: right},
                 device_id_type=pltpu.DeviceIdType.MESH,
             )
             incoming.wait_recv()
 
-        if fc:
-            @pl.when(recv_now & (s + 2 < left_d))
-            def _():
-                pltpu.semaphore_signal(
-                    cap_sem.at[slot], inc=1, device_id={axis: left},
-                    device_id_type=pltpu.DeviceIdType.MESH,
-                )
+        @pl.when(recv_now & (s + 2 < left_d))
+        def _():
+            pltpu.semaphore_signal(
+                cap_sem.at[slot], inc=1, device_id={axis: left},
+                device_id_type=pltpu.DeviceIdType.MESH,
+            )
 
         send_now = s < d
 
-        if fc:
-            @pl.when(send_now & (s >= 2))
-            def _():
-                pltpu.semaphore_wait(cap_sem.at[slot], 1)
+        @pl.when(send_now & (s >= 2))
+        def _():
+            pltpu.semaphore_wait(cap_sem.at[slot], 1)
 
         @pl.when(send_now)
         def _():
@@ -1199,7 +1129,7 @@ def _ring_gather_root_kernel(
                 dst_ref=o_ref.at[idx],  # same slot in the consumer
                 send_sem=send_sem.at[slot],
                 recv_sem=recv_sem.at[slot],
-                device_id=dma_device_id(axis, right, not fc),
+                device_id={axis: right},
                 device_id_type=pltpu.DeviceIdType.MESH,
             )
             copy.start()
@@ -1209,7 +1139,7 @@ def _ring_gather_root_kernel(
 def _ring_gather_call(chunks, p, axis, root, rows, dtype, interpret):
     my = lax.axis_index(axis).astype(jnp.int32).reshape(1)
     kernel = functools.partial(
-        _ring_gather_root_kernel, p, axis, root, kernel_flow_control(interpret)
+        _ring_gather_root_kernel, p, axis, root
     )
     return pl.pallas_call(
         kernel,
@@ -1224,8 +1154,8 @@ def _ring_gather_call(chunks, p, axis, root, rows, dtype, interpret):
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.REGULAR((2,)),
         ],
-        compiler_params=tpu_compiler_params(collective_id=9),
-        interpret=interpret_params() if interpret else False,
+        compiler_params=pltpu.CompilerParams(collective_id=9),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(my, chunks)
 
 
@@ -1246,15 +1176,6 @@ def ring_reduce_pallas(
     if p == 1:
         return x
     interpret = interpret or _FORCE_INTERPRET
-    if _legacy_interpret(interpret):
-        # the root-directed gather's conditional DMAs cannot discharge on
-        # the legacy interpreter: reduce = allreduce (same phases kernel)
-        # masked to root — identical results, full-ring wire traffic
-        total = ring_allreduce_pallas(
-            x, axis, axis_size=axis_size, interpret=interpret
-        )
-        _LAST_STEP_COUNTS["reduce"] = 2 * (p - 1)
-        return jnp.where(lax.axis_index(axis) == root, total, x)
     orig_shape, orig_dtype = x.shape, x.dtype
     carrier = _carrier_dtype(orig_dtype)
     flat = x.reshape(-1).astype(carrier)
@@ -1280,7 +1201,7 @@ def ring_reduce_pallas(
 
 
 def _ring_broadcast_kernel(
-    p: int, k: int, axis: str, root: int, fc: bool, my_ref, x_ref, o_ref,
+    p: int, k: int, axis: str, root: int, my_ref, x_ref, o_ref,
     send_sem, recv_sem, cap_sem
 ):
     """Pipelined chunk flow down the ring (the reference's large-message
@@ -1309,17 +1230,7 @@ def _ring_broadcast_kernel(
     def _():
         o_ref[:] = x_ref[:]
 
-    if fc:
-        barrier = pltpu.get_barrier_semaphore()
-        pltpu.semaphore_signal(
-            barrier, inc=1, device_id={axis: left},
-            device_id_type=pltpu.DeviceIdType.MESH,
-        )
-        pltpu.semaphore_signal(
-            barrier, inc=1, device_id={axis: right},
-            device_id_type=pltpu.DeviceIdType.MESH,
-        )
-        pltpu.semaphore_wait(barrier, 2)
+    neighbor_barrier(axis, left, right)
 
     for t in range(k + p - 2):
         # receive chunk c_recv = t - d + 1 (sent by left at distance d-1):
@@ -1337,21 +1248,20 @@ def _ring_broadcast_kernel(
                 dst_ref=o_ref.at[ridx],
                 send_sem=send_sem.at[t % 2],
                 recv_sem=recv_sem.at[t % 2],
-                device_id=dma_device_id(axis, right, not fc),
+                device_id={axis: right},
                 device_id_type=pltpu.DeviceIdType.MESH,
             )
             incoming.wait_recv()
 
         # free the consumed slot for the sender's next-but-one send
-        if fc:
-            @pl.when(recv_now & (c_recv <= k - 3))
-            def _():
-                pltpu.semaphore_signal(
-                    cap_sem.at[t % 2],
-                    inc=1,
-                    device_id={axis: left},
-                    device_id_type=pltpu.DeviceIdType.MESH,
-                )
+        @pl.when(recv_now & (c_recv <= k - 3))
+        def _():
+            pltpu.semaphore_signal(
+                cap_sem.at[t % 2],
+                inc=1,
+                device_id={axis: left},
+                device_id_type=pltpu.DeviceIdType.MESH,
+            )
 
         # send chunk c_send = t - d to right (received at step t-1; root
         # sends its own chunks). The receiver at distance d+1 waits for it
@@ -1363,10 +1273,9 @@ def _ring_broadcast_kernel(
 
         # slot reuse (3rd+ send): wait until right consumed the chunk sent
         # two steps ago on this slot
-        if fc:
-            @pl.when(send_now & (c_send >= 2))
-            def _():
-                pltpu.semaphore_wait(cap_sem.at[t % 2], 1)
+        @pl.when(send_now & (c_send >= 2))
+        def _():
+            pltpu.semaphore_wait(cap_sem.at[t % 2], 1)
 
         @pl.when(send_now)
         def _():
@@ -1376,7 +1285,7 @@ def _ring_broadcast_kernel(
                 dst_ref=o_ref.at[idx],  # same offset in the consumer
                 send_sem=send_sem.at[t % 2],
                 recv_sem=recv_sem.at[t % 2],
-                device_id=dma_device_id(axis, right, not fc),
+                device_id={axis: right},
                 device_id_type=pltpu.DeviceIdType.MESH,
             )
             copy.start()
@@ -1401,18 +1310,6 @@ def ring_broadcast_pallas(
     if p == 1:
         return x
     interpret = interpret or _FORCE_INTERPRET
-    if _legacy_interpret(interpret):
-        # (covers the multi-axis case too)
-        # the pipelined chunk flow's conditional DMAs cannot discharge on
-        # the legacy interpreter: ride the ppermute pipelined broadcast
-        # (identical chunk schedule, XLA transport)
-        from ..collectives.primitives import ring_broadcast as _ring_bcast
-
-        k = num_chunks or min(8, max(1, p))
-        _LAST_STEP_COUNTS["broadcast"] = k + p - 2
-        return _ring_bcast(
-            x, root, axis, axis_size=axis_size, num_chunks=num_chunks
-        )
     orig_shape, orig_dtype = x.shape, x.dtype
     flat, restore = _bitcast_to_bytes(x.reshape(-1))
     carrier = flat.dtype
@@ -1439,8 +1336,7 @@ def ring_broadcast_pallas(
         chunks = seg_flat.reshape(k, rows, _LANES)
         my = lax.axis_index(axis).astype(jnp.int32).reshape(1)
         kernel = functools.partial(
-            _ring_broadcast_kernel, p, k, axis, root,
-            kernel_flow_control(interpret),
+            _ring_broadcast_kernel, p, k, axis, root
         )
         out = pl.pallas_call(
             kernel,
@@ -1455,8 +1351,8 @@ def ring_broadcast_pallas(
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.REGULAR((2,)),
             ],
-            compiler_params=tpu_compiler_params(collective_id=8),
-            interpret=interpret_params() if interpret else False,
+            compiler_params=pltpu.CompilerParams(collective_id=8),
+            interpret=pltpu.InterpretParams() if interpret else False,
         )(my, chunks)
         return out.reshape(-1)[:n]
 

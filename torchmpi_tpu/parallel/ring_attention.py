@@ -14,9 +14,9 @@ on TPU) and the Pallas kernel with explicit double-buffered K/V RDMA and
 the streaming-softmax merge in-kernel
 (``backend='pallas'``/``'pallas_interpret'``, ``ops/ring_attention_kernel
 .py``). Oversized working sets auto-chunk over batch/heads (each chunk
-rides its own ring); ``backend='auto'`` picks the kernel on real
-multi-chip TPU whenever a single (batch, head) cell fits the VMEM
-envelope, the XLA path otherwise.
+rides its own ring); a single (batch, head) cell beyond the kernel's
+VMEM envelope raises — the kernel never gives way to the XLA path on
+its own.
 
 Derived from the ring-attention pattern in the public pallas guide and the
 scaling-book recipe: shift-K/V ring + online softmax.
@@ -69,9 +69,7 @@ def ring_self_attention(
     identical (up to float error) to full attention over the gathered
     sequence.
 
-    ``backend``: ``'xla'`` (ppermute ring); ``'auto'`` (the RDMA kernel
-    on real multi-chip TPU when a single (batch, head) cell fits VMEM —
-    larger working sets auto-chunk — else the XLA ring); or any
+    ``backend``: ``'xla'`` (ppermute ring) or any
     combination of ``'pallas'`` with the suffix tokens ``_interpret``
     (interpret mode — CPU-mesh validation), ``_bidir`` (bidirectional
     forward: both ICI directions carry K/V chains, ~half the ring
@@ -85,37 +83,20 @@ def ring_self_attention(
     offset is known statically per step.
     """
     if backend != "xla":
-        from ..ops.ring_attention_kernel import (
-            _VMEM_BUDGET_BYTES,
-            ring_attention,
-            ring_attention_vmem_bytes,
-        )
+        from ..ops.ring_attention_kernel import ring_attention
 
         tokens = set(backend.split("_"))
-        if backend.startswith("pallas") and tokens <= {
+        if not backend.startswith("pallas") or not tokens <= {
             "pallas", "interpret", "full", "bidir"
         }:
-            return ring_attention(
-                q, k, v, axis, causal, axis_size,
-                "interpret" in tokens,
-                "full" in tokens,
-                None,
-                "bidir" in tokens,
-            )
-        if backend == "auto":
-            from ..ops.ring_kernels import available
-
-            # the kernel auto-chunks over batch/heads, so it is usable
-            # whenever a single (batch, head) cell fits the envelope
-            b, n, h, d = q.shape
-            if (
-                available()
-                and ring_attention_vmem_bytes((1, n, 1, d), q.dtype)
-                <= _VMEM_BUDGET_BYTES
-            ):
-                return ring_attention(q, k, v, axis, causal, axis_size, False)
-        else:
             raise ValueError(f"unknown ring-attention backend {backend!r}")
+        return ring_attention(
+            q, k, v, axis, causal, axis_size,
+            "interpret" in tokens,
+            "full" in tokens,
+            None,
+            "bidir" in tokens,
+        )
     p = axis_size or lax.axis_size(axis)
     b, n_local, h, d = q.shape
     r = lax.axis_index(axis)

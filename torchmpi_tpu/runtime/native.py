@@ -1,7 +1,8 @@
 """ctypes bridge to the native runtime (csrc/tpumpi.cpp).
 
-Loads ``libtpumpi.so`` (building it with the bundled Makefile on first use
-when a toolchain exists) and exposes the C API. Everything degrades
+Loads ``libtpumpi.so`` (rebuilt through the bundled, incremental Makefile
+on every load, so the binary always matches ``tpumpi.cpp``) and exposes
+the C API. Everything degrades
 gracefully: ``available()`` is False when no compiler/library is present and
 callers fall back to the pure-Python implementations — the analog of the
 reference's optional NCCL/Gloo feature detection (``lib/CMakeLists.txt``).
@@ -102,7 +103,9 @@ def get_lib() -> Optional[ctypes.CDLL]:
         if _lib is not None or _load_attempted:
             return _lib
         _load_attempted = True
-        if not _SO.exists() and not _build():
+        # make every time (a no-op when up to date): a binary left from
+        # an older tpumpi.cpp is never what executes
+        if not _build():
             return None
         try:
             lib = ctypes.CDLL(str(_SO))

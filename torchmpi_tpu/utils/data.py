@@ -256,11 +256,14 @@ class DistributedIterator:
         stage_in_producer = self._device_transfer_in_producer()
 
         def put_on_device(xb, yb):
-            xb_d, yb_d = jnp.asarray(xb), jnp.asarray(yb)
+            # straight from host memory to each device's shard: a
+            # jnp.asarray first would land the whole batch on device 0
             if self.sharding is not None:
-                xb_d = jax.device_put(xb_d, self.sharding)
-                yb_d = jax.device_put(yb_d, self.sharding)
-            return xb_d, yb_d
+                return (
+                    jax.device_put(xb, self.sharding),
+                    jax.device_put(yb, self.sharding),
+                )
+            return jnp.asarray(xb), jnp.asarray(yb)
 
         def producer():
             try:

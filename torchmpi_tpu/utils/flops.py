@@ -114,28 +114,29 @@ def train_flops(forward_flops: int) -> int:
     return 3 * forward_flops
 
 
-# Per-chip dense peak FLOP/s (bf16 unless noted), from published TPU specs.
-# Keys are matched as substrings of jax's ``device.device_kind``.
-_TPU_PEAK_BF16 = (
-    ("v6", 918e12),     # Trillium / v6e
-    ("v5p", 459e12),
-    ("v5", 197e12),     # v5e / "TPU v5 lite" (checked after v5p)
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 46e12),
-)
+# Per-chip dense bf16 peak FLOP/s, keyed by the exact ``device_kind`` a chip
+# reported to jax (not a substring guess). Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16). A kind is added here when it
+# has been read off such a chip.
+_TPU_PEAK_BF16 = {
+    "TPU v5 lite": 197e12,  # v5e
+}
 
 
 def device_peak_flops(device) -> Optional[float]:
-    """Per-chip bf16 peak for a jax device, or None if unknown."""
-    kind = getattr(device, "device_kind", "") or ""
-    kind = kind.lower()
-    if "tpu" not in kind and getattr(device, "platform", "") != "tpu":
+    """Per-chip bf16 peak for a jax device. ``None`` only for the CPU
+    (no MFU there); a TPU whose ``device_kind`` is not in the table is an
+    error, not a default."""
+    if device.platform == "cpu":
         return None
-    for tag, peak in _TPU_PEAK_BF16:
-        if tag in kind:
-            return peak
-    return None
+    if device.platform != "tpu" or device.device_kind not in _TPU_PEAK_BF16:
+        raise ValueError(
+            f"no peak-FLOP/s entry for platform {device.platform!r}, "
+            f"device_kind {device.device_kind!r} (known: "
+            f"{sorted(_TPU_PEAK_BF16)}); add it to utils/flops.py with "
+            "its source"
+        )
+    return _TPU_PEAK_BF16[device.device_kind]
 
 
 def mfu(samples_per_sec_per_chip: float, flops_per_sample: int,
