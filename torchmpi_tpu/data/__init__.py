@@ -45,12 +45,15 @@ from __future__ import annotations
 
 import threading
 import time
+from collections import deque
 from typing import Any, Callable, Optional, Tuple
 
 import numpy as np
 
 from .. import constants, telemetry as _telemetry
 
+_ring = _telemetry.spans  # the process-global span recorder
+_names = _telemetry.names
 _MET = None
 
 
@@ -312,14 +315,21 @@ class InputPipeline:
             xb, yb = self.transform(xb, yb)
         return xb, yb
 
-    def _producer(self, ring: _Ring, order: np.ndarray, total: int) -> None:
+    def _producer(self, ring: _Ring, order: np.ndarray, total: int,
+                  epoch: int, cause: int) -> None:
+        """``cause``: the id of the epoch's ``input.epoch_start`` span,
+        which started this thread; an assembly's step is the pipeline's
+        own count, ``(epoch, batch)``."""
         try:
             telemetry_on = _telemetry.enabled()
             while True:
                 b = ring.ticket(total)
                 if b is None:
                     return
-                stall = ring.put(b, self._assemble(order, b))
+                with _ring.span(_names.INPUT_ASSEMBLE, parent=cause,
+                                step=(epoch, b)):
+                    host = self._assemble(order, b)
+                stall = ring.put(b, host)
                 if telemetry_on:
                     _, prod_stall, _, batches = _metric_handles()
                     if stall:
@@ -349,44 +359,56 @@ class InputPipeline:
             return jax.device_put(xb, xs), jax.device_put(yb, ys)
         return jnp.asarray(xb), jnp.asarray(yb)
 
+    def _fetch(self, ring: _Ring, alive: Callable[[], bool]):
+        """Wait for the next host batch and dispatch its copy to the
+        device."""
+        with _ring.span(_names.INPUT_RING_WAIT):
+            host, stall, depth_now = ring.get(alive)
+        self.consumer_stall_s += stall
+        if _telemetry.enabled():
+            qdepth, _, cons_stall, batches = _metric_handles()
+            qdepth.set(depth_now)
+            if stall:
+                cons_stall.inc(stall)
+            batches.inc(path="device")
+        with _ring.span(_names.INPUT_STAGE):
+            return self._stage(host)
+
     def _run_epoch(self, epoch: int):
         order = self.epoch_order(epoch)
         total = self.batches_per_epoch
         ring = _Ring(self.prefetch)
-        threads = [
-            threading.Thread(
-                target=self._producer, args=(ring, order, total),
-                name=f"tm-input-{epoch}-{w}", daemon=True,
-            )
-            for w in range(min(self.workers, total))
-        ]
-        for t in threads:
-            t.start()
+        threads = []
 
         def alive() -> bool:
             return any(t.is_alive() for t in threads)
 
-        telemetry_on = _telemetry.enabled()
-        inflight = None
+        staged = deque()
         try:
-            for _ in range(total):
-                host, stall, depth_now = ring.get(alive)
-                self.consumer_stall_s += stall
-                if telemetry_on:
-                    qdepth, _, cons_stall, batches = _metric_handles()
-                    qdepth.set(depth_now)
-                    if stall:
-                        cons_stall.inc(stall)
-                    batches.inc(path="device")
-                dev = self._stage(host)
-                # hand out the PREVIOUS batch (its transfer dispatched
-                # one iteration ago, overlapped with this batch's host
-                # assembly and the caller's training step)
-                if inflight is not None:
-                    yield inflight
-                inflight = dev
-            if inflight is not None:
-                yield inflight
+            # what an epoch pays before its first batch: new producer
+            # threads, an empty ring, and the double buffer, which hands
+            # out batch k only once batch k+1's copy is dispatched
+            with _ring.span(_names.INPUT_EPOCH_START,
+                            {"epoch": epoch}) as start:
+                threads += [
+                    threading.Thread(
+                        target=self._producer,
+                        args=(ring, order, total, epoch, start.id),
+                        name=f"tm-input-{epoch}-{w}", daemon=True,
+                    )
+                    for w in range(min(self.workers, total))
+                ]
+                for t in threads:
+                    t.start()
+                for _ in range(min(2, total)):
+                    staged.append(self._fetch(ring, alive))
+            # hand out batch k while batch k+1's transfer, dispatched one
+            # fetch ago, overlaps the caller's training step
+            for _ in range(total - len(staged)):
+                yield staged.popleft()
+                staged.append(self._fetch(ring, alive))
+            while staged:
+                yield staged.popleft()
         finally:
             ring.close()
 

@@ -23,8 +23,10 @@ Reference behaviors preserved, re-designed for XLA:
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
+import weakref
 import zlib
 from functools import partial
 from typing import Any, Callable, Dict, Optional
@@ -42,57 +44,126 @@ from ..telemetry import flightrecorder as _flight
 from ..telemetry import tracecontext as _tracecontext
 
 _AXIS = "mpi"
+_NO_BATCH = object()  # next()'s default: the epoch's iterator is exhausted
 
-# engine telemetry handles (created on first telemetry-enabled engine)
-_ENG_MET = None
+_ring = _telemetry.spans  # the process-global span recorder
+_names = _telemetry.names
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
-def _engine_metrics():
+class _EngineMetrics:
+    """The engine's handles in the process-wide registry."""
+
+    def __init__(self):
+        m = _telemetry.metrics
+        self.steps = m.counter(
+            "tm_engine_steps_total", "optimizer steps taken")
+        self.epoch_seconds = m.histogram(
+            "tm_engine_epoch_seconds",
+            "wall time per epoch, to the point where the engine waits for "
+            "the chip (train: the epoch's loss read; train_resident: the "
+            "epoch program's end)",
+        )
+        self.examples_per_sec = m.gauge(
+            "tm_engine_examples_per_sec",
+            "training throughput over the last epoch, its measured input "
+            "wait taken out",
+        )
+        self.mfu = m.gauge(
+            "tm_engine_mfu",
+            "model-FLOPs utilization vs the chip's bf16 peak over the last "
+            "epoch less its measured input wait: what the loop would reach "
+            "with input free (engines constructed with flops_per_sample "
+            "only)",
+        )
+        self.tflops = m.gauge(
+            "tm_engine_tflops_per_chip",
+            "achieved TFLOP/s per chip (flops_per_sample engines)",
+        )
+        self.mfu_incl_input = m.gauge(
+            "tm_engine_mfu_incl_input",
+            "MFU over the whole epoch INCLUDING measured input-stall "
+            "time — diverges from tm_engine_mfu exactly when the run "
+            "is input-bound (streamed-iterator engines only)",
+        )
+        self.input_stall = m.counter(
+            "tm_engine_input_stall_seconds",
+            "seconds the training loop spent waiting on the input "
+            "iterator (excluded from tm_engine_mfu's window; joins "
+            "tm_input_consumer_stall_seconds)",
+        )
+        self.programs_built = m.counter(
+            "tm_engine_programs_built_total",
+            "programs jax compiled or loaded from its cache while an "
+            "engine existed (one engine.program_build span each)",
+        )
+        self.program_build_seconds = m.counter(
+            "tm_engine_program_build_seconds_total",
+            "seconds jax spent compiling or loading those programs",
+        )
+        self.init_seconds = m.gauge(
+            "tm_engine_init_seconds",
+            "host seconds the newest engine's construction took (the "
+            "copies of the caller's parameters and optimizer state)",
+        )
+
+
+_ENG_MET: Optional[_EngineMetrics] = None
+
+
+def _engine_metrics() -> _EngineMetrics:
     global _ENG_MET
     if _ENG_MET is None:
-        m = _telemetry.metrics
-        _ENG_MET = (
-            m.counter("tm_engine_steps_total", "optimizer steps taken"),
-            m.histogram(
-                "tm_engine_step_seconds",
-                "blocking wall time per training step (telemetry-enabled "
-                "engines block on the step to time it honestly)",
-            ),
-            m.histogram(
-                "tm_engine_epoch_seconds",
-                "wall time per device-resident epoch",
-            ),
-            m.gauge(
-                "tm_engine_examples_per_sec",
-                "training throughput over the last step/epoch",
-            ),
-            m.gauge(
-                "tm_engine_grad_norm",
-                "global gradient norm after synchronization",
-            ),
-            m.gauge(
-                "tm_engine_mfu",
-                "model-FLOPs utilization vs the chip's bf16 peak "
-                "(engines constructed with flops_per_sample only)",
-            ),
-            m.gauge(
-                "tm_engine_tflops_per_chip",
-                "achieved TFLOP/s per chip (flops_per_sample engines)",
-            ),
-            m.gauge(
-                "tm_engine_mfu_incl_input",
-                "MFU over the step window INCLUDING measured input-stall "
-                "time — diverges from tm_engine_mfu exactly when the run "
-                "is input-bound (streamed-iterator engines only)",
-            ),
-            m.counter(
-                "tm_engine_input_stall_seconds",
-                "seconds the training loop spent waiting on the input "
-                "iterator (excluded from tm_engine_mfu's step window; "
-                "joins tm_input_consumer_stall_seconds)",
-            ),
-        )
+        _ENG_MET = _EngineMetrics()
     return _ENG_MET
+
+
+# The newest engine alive: a program jax builds while it exists is recorded
+# as an ``engine.program_build`` span with that engine's step count, so a
+# recompilation at step N is seen from inside.
+_newest_engine: Callable[[], Optional["AllReduceSGDEngine"]] = lambda: None
+_build_listener_lock = threading.Lock()
+_build_listener_on = False
+_cache_hit = threading.local()
+
+
+def _on_jax_duration(event: str, seconds: float, **kw) -> None:
+    if event == _CACHE_RETRIEVAL:
+        # fires inside the backend-compile event of the same program
+        _cache_hit.seconds = seconds
+        return
+    if event != _BACKEND_COMPILE:
+        return
+    hit = getattr(_cache_hit, "seconds", None)
+    _cache_hit.seconds = None
+    engine = _newest_engine()
+    if engine is None:
+        return
+    dur = int(seconds * 1e9)
+    _ring.record(
+        _names.ENGINE_PROGRAM_BUILD, time.time_ns() - dur, dur,
+        {"event": event, "seconds": seconds, "from_cache": hit is not None,
+         "program": kw.get("fun_name")},
+        step=(engine.epochs_run, engine.steps_run),
+    )
+    met = _engine_metrics()
+    met.programs_built.inc()
+    met.program_build_seconds.inc(seconds)
+
+
+def _listen_for_builds(engine: "AllReduceSGDEngine") -> None:
+    """Point the process's one jax.monitoring listener at ``engine``."""
+    global _newest_engine, _build_listener_on
+    with _build_listener_lock:
+        _newest_engine = weakref.ref(engine)
+        if not _build_listener_on:
+            import jax.monitoring
+
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            _build_listener_on = True
 
 
 class _IdRef:
@@ -244,17 +315,22 @@ class AllReduceSGDEngine:
 
         ``flops_per_sample``: analytic per-sample training FLOPs (see
         ``utils/flops.py``). Only consulted when telemetry is enabled:
-        per-step/epoch throughput is converted to achieved TFLOP/s and
-        MFU gauges. Telemetry state is captured at construction — the
-        step function is compiled against it (enabled engines additionally
-        return the global grad norm from the jitted step)."""
+        each epoch's throughput is converted to achieved TFLOP/s and MFU
+        gauges where the engine already waits for the chip (the epoch's
+        loss read in ``train``, the epoch program's end in
+        ``train_resident``). Telemetry changes neither the compiled step
+        nor where the host waits: the engine's own spans
+        (``telemetry/spans.py``) are recorded whatever the switch says."""
         if comm is None:
             from .. import runtime_state
 
             comm = runtime_state.current_communicator()
-        # step ordinal for per-step trace-context roots: every SPMD rank
-        # advances it identically, so step N is ONE trace fleet-wide
-        self._trace_steps = 0
+        # the engine's own count of what it has run, over its lifetime:
+        # the (epoch, step) its spans carry, and the ordinal of per-step
+        # trace-context roots (every SPMD rank advances it identically,
+        # so step N is ONE trace fleet-wide)
+        self.epochs_run = 0
+        self.steps_run = 0
         if mode not in ("sync", "async"):
             raise ValueError(f"mode must be 'sync' or 'async', got {mode!r}")
         if batch_format not in ("auto", "flat", "stacked"):
@@ -309,8 +385,6 @@ class AllReduceSGDEngine:
         from .. import constants as _constants
 
         self._coalesce = _constants.get("fusion_buffer_bytes") > 0
-        # captured once: the compiled step's output tree depends on it
-        self._telemetry = _telemetry.enabled()
         self.flops_per_sample = flops_per_sample
         self.accum_steps = accum_steps
         self.param_sharding = param_sharding
@@ -335,7 +409,16 @@ class AllReduceSGDEngine:
             if (mode == "async" or wire_bucketed)
             else None
         )
+        _listen_for_builds(self)
+        with _ring.span(_names.ENGINE_INIT, step=(0, 0)) as init:
+            self._place_state(params, model_state)
+        _engine_metrics().init_seconds.set(init.seconds)
 
+    def _place_state(self, params, model_state) -> None:
+        """Construction's work on the device: the mesh, the engine's own
+        copies of the caller's parameters, model state and optimizer
+        state, and the (lazily compiled) step."""
+        comm, broadcast_parameters = self.comm, self.broadcast_parameters
         self.mesh = comm.flat_mesh(_AXIS)
         self.batch_sharding = NamedSharding(self.mesh, P(_AXIS))
         self.replicated = NamedSharding(self.mesh, P())
@@ -463,35 +546,55 @@ class AllReduceSGDEngine:
         grads = jax.tree_util.tree_map(lambda g: g / k, gsum)
         return jnp.mean(losses), new_state, grads
 
-    def _step_core(self, params, opt_state, model_state, batch):
-        """Per-rank step body (inside shard_map): grad, sync, update."""
-        loss_fn, optimizer = self.loss_fn, self.optimizer
-        has_state = model_state is not None
-        k = self.accum_steps
-        if k > 1:
-
-            def split(a):
-                if a.shape[0] % k:
-                    raise ValueError(
-                        f"per-rank batch {a.shape[0]} not divisible by "
-                        f"accum_steps={k}"
-                    )
-                return a.reshape((k, a.shape[0] // k) + a.shape[1:])
-
-            loss, new_state, grads = self._accum_value_and_grad(
-                params, model_state, batch, split
-            )
-        elif has_state:
-            (loss, new_state), grads = jax.value_and_grad(
-                loss_fn, has_aux=True
-            )(params, model_state, batch)
-        else:
+    def _value_and_grad(self, params, model_state, batch, split):
+        """(loss, new model state, gradients) of one step's batch, under
+        the ``tm.fwd_bwd`` scope; ``split`` cuts a batch leaf into
+        ``accum_steps`` microbatches."""
+        loss_fn = self.loss_fn
+        with jax.named_scope(_names.SCOPE_FWD_BWD):
+            if self.accum_steps > 1:
+                return self._accum_value_and_grad(
+                    params, model_state, batch, split
+                )
+            if model_state is not None:
+                (loss, new_state), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True
+                )(params, model_state, batch)
+                return loss, new_state, grads
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-            new_state = model_state
-        if has_state:
-            new_state = jax.tree_util.tree_map(
-                lambda s: jax.lax.pmean(s, _AXIS), new_state
+            return loss, model_state, grads
+
+    def _apply_update(self, params, opt_state, grads):
+        with jax.named_scope(_names.SCOPE_OPTIMIZER):
+            updates, opt_state = self.optimizer.update(
+                grads, opt_state, params
             )
+            return optax.apply_updates(params, updates), opt_state
+
+    def _step_core(self, params, opt_state, model_state, batch):
+        """Per-rank step body (inside shard_map): grad, sync, update. The
+        named scopes are metadata on the operations (the schedule is
+        XLA's as before): a device trace puts each operation down to one
+        of them by its ``op_name``."""
+        k = self.accum_steps
+
+        def split(a):
+            if a.shape[0] % k:
+                raise ValueError(
+                    f"per-rank batch {a.shape[0]} not divisible by "
+                    f"accum_steps={k}"
+                )
+            return a.reshape((k, a.shape[0] // k) + a.shape[1:])
+
+        loss, new_state, grads = self._value_and_grad(
+            params, model_state, batch, split
+        )
+        if model_state is not None:
+            with jax.named_scope(_names.SCOPE_STATE_SYNC):
+                new_state = jax.tree_util.tree_map(
+                    lambda s: jax.lax.pmean(s, _AXIS), new_state
+                )
+        # each sync function opens tm.grad_sync and its phases itself
         if self.buckets is not None:
             grads = mpinn.in_graph_synchronize_gradients_bucketed(
                 grads, self.buckets, _AXIS,
@@ -506,59 +609,56 @@ class AllReduceSGDEngine:
             grads = mpinn.in_graph_synchronize_gradients(
                 grads, _AXIS, average=self.average_gradients
             )
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        loss = jax.lax.pmean(loss, _AXIS)
-        if self._telemetry:
-            # grads are already synchronized: the norm is replica-identical
-            loss = (loss, optax.global_norm(grads))
+        params, opt_state = self._apply_update(params, opt_state, grads)
+        with jax.named_scope(_names.SCOPE_LOSS_SYNC):
+            loss = jax.lax.pmean(loss, _AXIS)
         return params, opt_state, new_state, loss
 
     def _fsdp_step_core(self, params, opt_state, model_state, batch):
         """GSPMD step: ONE logical computation over the global batch; the
         sharded params/opt-state make XLA insert the all-gathers before
         use and reduce-scatter the gradients — ZeRO-3 for free from the
-        sharding annotations."""
-        loss_fn, optimizer = self.loss_fn, self.optimizer
-        k = self.accum_steps
-        if k > 1:
-            p = self.comm.size
+        sharding annotations. There is no sync call to scope: a
+        collective GSPMD inserts carries the scope of the operation it
+        was inserted for, so this step shows ``tm.fwd_bwd`` and
+        ``tm.optimizer`` alone."""
+        k, p = self.accum_steps, self.comm.size
 
-            def split(a):
-                n = a.shape[0]
-                if n % (p * k):
-                    raise ValueError(
-                        f"global batch {n} not divisible by world size x "
-                        f"accum_steps = {p}x{k}"
-                    )
-                # rank-major [p, k, b, ...]: each microbatch takes b rows
-                # from EVERY rank's contiguous shard, so the batch axis
-                # stays evenly sharded through the scan
-                b = n // (p * k)
-                a = a.reshape((p, k, b) + a.shape[1:])
-                a = jnp.moveaxis(a, 1, 0)  # [k, p, b, ...]
-                return a.reshape((k, p * b) + a.shape[3:])
+        def split(a):
+            n = a.shape[0]
+            if n % (p * k):
+                raise ValueError(
+                    f"global batch {n} not divisible by world size x "
+                    f"accum_steps = {p}x{k}"
+                )
+            # rank-major [p, k, b, ...]: each microbatch takes b rows
+            # from EVERY rank's contiguous shard, so the batch axis
+            # stays evenly sharded through the scan
+            b = n // (p * k)
+            a = a.reshape((p, k, b) + a.shape[1:])
+            a = jnp.moveaxis(a, 1, 0)  # [k, p, b, ...]
+            return a.reshape((k, p * b) + a.shape[3:])
 
-            loss, new_state, grads = self._accum_value_and_grad(
-                params, model_state, batch, split
-            )
-        elif model_state is not None:
-            (loss, new_state), grads = jax.value_and_grad(
-                loss_fn, has_aux=True
-            )(params, model_state, batch)
-        else:
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-            new_state = model_state
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        if self._telemetry:
-            loss = (loss, optax.global_norm(grads))
+        loss, new_state, grads = self._value_and_grad(
+            params, model_state, batch, split
+        )
+        params, opt_state = self._apply_update(params, opt_state, grads)
         return params, opt_state, new_state, loss
 
     def _build_step(self):
+        # The step's program is named ``tm_step`` (the resident epoch's
+        # ``tm_epoch``): a stable name in a device trace's module line.
+        # It is also part of the persistent compilation cache's key, which
+        # scope names are not (jax strips metadata from the key): a change
+        # to the scopes alone must change this name too, or a warm cache
+        # hands back the old executable with the old scopes.
         if self.param_sharding in ("fsdp", "zero1"):
+            def tm_step(params, opt_state, model_state, batch):
+                return self._fsdp_step_core(
+                    params, opt_state, model_state, batch)
+
             return jax.jit(
-                self._fsdp_step_core,
+                tm_step,
                 donate_argnums=(0, 1, 2),
                 out_shardings=self._out_shardings,
             )
@@ -569,7 +669,11 @@ class AllReduceSGDEngine:
             out_specs=(P(), P(), P(), P()),
             check_vma=False,
         )
-        return jax.jit(shmapped, donate_argnums=(0, 1, 2))
+
+        def tm_step(params, opt_state, model_state, batch):
+            return shmapped(params, opt_state, model_state, batch)
+
+        return jax.jit(tm_step, donate_argnums=(0, 1, 2))
 
     def _build_broadcast(self):
         if self.param_sharding in ("fsdp", "zero1"):
@@ -587,37 +691,66 @@ class AllReduceSGDEngine:
         return jax.jit(bcast)
 
     # ------------------------------------------------------------------
-    # telemetry plumbing: a telemetry-enabled engine's jitted step returns
-    # ``(loss, grad_norm)`` in the loss slot; these helpers unpack and
-    # feed the process-wide registry.
+    # telemetry plumbing. Nothing here waits for the chip or changes the
+    # compiled step: per-step records are stamped at dispatch, and the
+    # gauges that describe a rate are set once an epoch, where the engine
+    # waits for the chip anyway.
     # ------------------------------------------------------------------
-    def _split_aux(self, aux):
-        """(loss, grad_norm-or-None) from a step/epoch fn's loss output."""
-        if self._telemetry:
-            return aux[0], aux[1]
-        return aux, None
+    def _dispatch(self, batch):
+        """``_call_step`` under its span (always) and, with telemetry on,
+        under the step's trace-context root, with the labelled step count
+        and the flight recorder's ``engine.step`` event: all stamped at
+        dispatch, none waits for the step."""
+        _ring.set_step(self.epochs_run, self.steps_run)
+        telemetry_on = _telemetry.enabled()
+        # each step is one causal trace root: the ids are derived from
+        # the step ordinal, so every SPMD rank running the same program
+        # lands on the SAME trace id for the same step and the analyzer
+        # can group cross-rank work per step
+        root = (
+            _tracecontext.use(
+                _tracecontext.new_trace("engine.step", self.steps_run + 1))
+            if telemetry_on else contextlib.nullcontext()
+        )
+        with root:
+            t0 = time.time()
+            with _ring.span(_names.ENGINE_DISPATCH):
+                out = self._call_step(batch)
+            if telemetry_on:
+                _engine_metrics().steps.inc(
+                    mode=self.mode, sharding=self.param_sharding)
+                if _flight.enabled():
+                    # step events join the comm's flight stream (wall-clock
+                    # stamps): per-seq issue-time spread across ranks is
+                    # the analyzer's engine-level straggler signal
+                    examples = jax.tree_util.tree_leaves(batch)[0].shape[0]
+                    _flight.recorder.record_complete(
+                        _flight.comm_key(self.comm), "engine.step",
+                        t0, time.time(),
+                        payload=f"examples={examples},steps=1",
+                        routing=self.mode,
+                    )
+        self.steps_run += 1
+        return out
 
-    def _record_step(self, examples: int, t0: float, t1: float,
-                     gnorm=None, steps: int = 1, epoch: bool = False,
-                     input_stall_s: float = 0.0):
-        """``[t0, t1]`` is the COMPUTE window (the batch was already
-        resident when it opened); ``input_stall_s`` is the measured wait
-        on the input iterator that preceded it. Throughput/MFU come from
-        the compute window — an input-bound run must not masquerade as a
-        compute-bound one — and ``tm_engine_mfu_incl_input`` reports the
-        stall-inclusive figure next to it so the gap IS the verdict."""
-        (n_steps, step_s, epoch_s, eps, gn, mfu_g, tflops_g,
-         mfu_incl_g, stall_c) = _engine_metrics()
-        dt = max(t1 - t0, 1e-12)
-        stall = max(float(input_stall_s), 0.0)
-        n_steps.inc(steps, mode=self.mode, sharding=self.param_sharding)
-        (epoch_s if epoch else step_s).observe(dt)
+    def _record_epoch(self, examples: int, seconds: float,
+                      input_stall_s: float = 0.0) -> None:
+        """Telemetry at an epoch's end, after the engine waited for the
+        chip. ``seconds`` is the epoch's wall time and ``input_stall_s``
+        the part of it the training thread waited on its iterator.
+        Throughput and MFU come from the rest, what the loop would reach
+        with input free, and ``tm_engine_mfu_incl_input`` from the whole,
+        so the gap IS the input-bound verdict. (In a loop that never
+        blocks the device may work through a wait, so the first is an
+        upper bound; the second is what the user got.)"""
+        met = _engine_metrics()
+        stall = min(max(float(input_stall_s), 0.0), seconds)
+        dt = max(seconds - stall, 1e-12)
+        met.epoch_seconds.observe(seconds)
         rate = examples / dt
-        eps.set(rate)
+        met.examples_per_sec.set(rate)
         if stall > 0:
-            stall_c.inc(stall)
-        if gnorm is not None:
-            gn.set(float(gnorm))
+            met.input_stall.inc(stall)
         if self.flops_per_sample:
             from ..utils.flops import mfu
 
@@ -625,27 +758,10 @@ class AllReduceSGDEngine:
                 rate / self.comm.size, self.flops_per_sample,
                 self.comm._devices[0],
             )
-            tflops_g.set(achieved / 1e12)
+            met.tflops.set(achieved / 1e12)
             if frac is not None:
-                mfu_g.set(frac)
-                mfu_incl_g.set(frac * dt / (dt + stall))
-        _telemetry.spans.record(
-            "engine.epoch" if epoch else "engine.step",
-            t0 * 1e6, dt * 1e6,
-            {"examples": examples, "steps": steps},
-        )
-        if _flight.enabled():
-            # step events join the comm's flight stream (wall-clock
-            # stamps): per-seq issue-time spread across ranks is the
-            # analyzer's engine-level straggler signal
-            wall_t1 = time.time()
-            _flight.recorder.record_complete(
-                _flight.comm_key(self.comm),
-                "engine.epoch" if epoch else "engine.step",
-                wall_t1 - dt, wall_t1,
-                payload=f"examples={examples},steps={steps}",
-                routing=self.mode,
-            )
+                met.mfu.set(frac)
+                met.mfu_incl_input.set(frac * dt / (dt + stall))
 
     # ------------------------------------------------------------------
     # AOT warm-up (the latency path): declare the collectives and compile
@@ -777,34 +893,15 @@ class AllReduceSGDEngine:
 
         ``batch`` may be flat ``[p*B, ...]`` or rank-stacked ``[p, B, ...]``
         (see ``batch_format``). Updates ``self.params/opt_state/model_state``
-        in place. The returned loss is a device scalar (not blocked on —
-        except under telemetry, which blocks to time the step honestly).
+        in place. The returned loss is a device scalar, not blocked on,
+        with telemetry on or off: a single step has no point at which it
+        waits for the chip, so it sets no rate gauge (``train`` does, at
+        each epoch's end).
         """
         batch = self._prepare_batch(batch)
-        if not self._telemetry:
-            self.params, self.opt_state, self.model_state, loss = (
-                self._call_step(batch)
-            )
-            self._maybe_checkpoint()
-            return loss
-        # each telemetry-enabled step is one causal trace root: the ids
-        # are derived from the step ordinal, so every SPMD rank running
-        # the same program lands on the SAME trace id for the same step
-        # and the analyzer can group cross-rank work per step
-        self._trace_steps = self._trace_steps + 1
-        with _tracecontext.use(
-            _tracecontext.new_trace("engine.step", self._trace_steps)
-        ):
-            t0 = time.perf_counter()
-            self.params, self.opt_state, self.model_state, aux = (
-                self._call_step(batch)
-            )
-            loss, gnorm = self._split_aux(aux)
-            jax.block_until_ready(loss)
-            self._record_step(
-                jax.tree_util.tree_leaves(batch)[0].shape[0],
-                t0, time.perf_counter(), gnorm,
-            )
+        self.params, self.opt_state, self.model_state, loss = (
+            self._dispatch(batch)
+        )
         self._maybe_checkpoint()
         return loss
 
@@ -848,19 +945,21 @@ class AllReduceSGDEngine:
         # arrays are immutable but not undeletable — the next step()'s
         # donation consumes the old buffers, so a writer thread holding
         # device refs races an "Array has been deleted" error. The
-        # device->host copy is the synchronous part; the file I/O (the
-        # slow part) stays on the background thread.
-        state = {"params": self.params, "opt_state": self.opt_state}
-        if self.model_state is not None:
-            state["model_state"] = self.model_state
-        state = jax.tree_util.tree_map(
-            lambda a: np.asarray(jax.device_get(a)), state
-        )
-        self._ckpt_thread = threading.Thread(
-            target=self._save_checkpoint, args=(step, state),
-            name="tm-engine-ckpt", daemon=True,
-        )
-        self._ckpt_thread.start()
+        # device->host copy is the synchronous part (the span: what a
+        # save costs the training thread); the file I/O (the slow part)
+        # stays on the background thread.
+        with _ring.span(_names.ENGINE_CHECKPOINT, {"ckpt_step": step}):
+            state = {"params": self.params, "opt_state": self.opt_state}
+            if self.model_state is not None:
+                state["model_state"] = self.model_state
+            state = jax.tree_util.tree_map(
+                lambda a: np.asarray(jax.device_get(a)), state
+            )
+            self._ckpt_thread = threading.Thread(
+                target=self._save_checkpoint, args=(step, state),
+                name="tm-engine-ckpt", daemon=True,
+            )
+            self._ckpt_thread.start()
 
     def _save_checkpoint(self, step: int, state) -> None:
         import sys
@@ -892,7 +991,8 @@ class AllReduceSGDEngine:
 
     def broadcast_parameters_now(self):
         """One-shot replica equalization (sgdengine.lua:140-144), blocking."""
-        self.params = jax.block_until_ready(self._bcast_fn(self.params))
+        with _ring.span(_names.ENGINE_BROADCAST):
+            self.params = jax.block_until_ready(self._bcast_fn(self.params))
 
     # ------------------------------------------------------------------
     # live world resize: redistribute fsdp/zero1 shards in place
@@ -1151,11 +1251,12 @@ class AllReduceSGDEngine:
                 wall_t1, payload=f"{old_world}->{new_comm.size}",
                 backend="engine", routing=self.param_sharding, seq=epoch,
             )
-        if self._telemetry:
-            _telemetry.spans.record(
-                "engine.resize", t0 * 1e6, stats["seconds"] * 1e6,
-                {"old": old_world, "new": new_comm.size, "epoch": epoch},
-            )
+        dur = int(stats["seconds"] * 1e9)
+        _ring.record(
+            _names.ENGINE_RESIZE, time.time_ns() - dur, dur,
+            {"old": old_world, "new": new_comm.size, "resize_epoch": epoch},
+            step=(self.epochs_run, self.steps_run),
+        )
         return stats
 
     # ------------------------------------------------------------------
@@ -1224,15 +1325,16 @@ class AllReduceSGDEngine:
 
                 def body(carry, i):
                     params, opt_state, model_state = carry
-                    idx = jax.lax.dynamic_slice_in_dim(
-                        perms, i * B, B, axis=1
-                    )  # [p, B] per-rank LOCAL indices
-                    xb = take_rows(xs_r, idx)
-                    yb = take_rows(ys_r, idx)
-                    batch = (
-                        xb.reshape((p * B,) + xs.shape[1:]),
-                        yb.reshape((p * B,) + ys.shape[1:]),
-                    )
+                    with jax.named_scope(_names.SCOPE_RESIDENT_GATHER):
+                        idx = jax.lax.dynamic_slice_in_dim(
+                            perms, i * B, B, axis=1
+                        )  # [p, B] per-rank LOCAL indices
+                        xb = take_rows(xs_r, idx)
+                        yb = take_rows(ys_r, idx)
+                        batch = (
+                            xb.reshape((p * B,) + xs.shape[1:]),
+                            yb.reshape((p * B,) + ys.shape[1:]),
+                        )
                     params, opt_state, model_state, loss = (
                         self._fsdp_step_core(
                             params, opt_state, model_state, batch
@@ -1245,8 +1347,12 @@ class AllReduceSGDEngine:
                 )
                 return params, opt_state, model_state, losses
 
+            def tm_epoch(params, opt_state, model_state, xs, ys, rngkey):
+                return fsdp_epoch(
+                    params, opt_state, model_state, xs, ys, rngkey)
+
             fn = jax.jit(
-                fsdp_epoch,
+                tm_epoch,
                 donate_argnums=(0, 1, 2),
                 out_shardings=self._out_shardings,
             )
@@ -1266,8 +1372,11 @@ class AllReduceSGDEngine:
 
             def body(carry, i):
                 params, opt_state, model_state = carry
-                idx = jax.lax.dynamic_slice_in_dim(perm, i * B, B)
-                batch = (jnp.take(xs, idx, axis=0), jnp.take(ys, idx, axis=0))
+                with jax.named_scope(_names.SCOPE_RESIDENT_GATHER):
+                    idx = jax.lax.dynamic_slice_in_dim(perm, i * B, B)
+                    batch = (
+                        jnp.take(xs, idx, axis=0), jnp.take(ys, idx, axis=0)
+                    )
                 params, opt_state, model_state, loss = self._step_core(
                     params, opt_state, model_state, batch
                 )
@@ -1285,7 +1394,11 @@ class AllReduceSGDEngine:
             out_specs=(P(), P(), P(), P()),
             check_vma=False,
         )
-        fn = jax.jit(shmapped, donate_argnums=(0, 1, 2))
+
+        def tm_epoch(params, opt_state, model_state, xs, ys, rngkey):
+            return shmapped(params, opt_state, model_state, xs, ys, rngkey)
+
+        fn = jax.jit(tm_epoch, donate_argnums=(0, 1, 2))
         self._epoch_fns[key] = fn
         return fn
 
@@ -1310,7 +1423,10 @@ class AllReduceSGDEngine:
         cannot — steps live inside a compiled ``lax.scan``.
         """
         p = self.comm.size
-        xd, yd = self.stage_dataset(x, y, dtype=image_dtype)
+        _ring.set_step(self.epochs_run, self.steps_run)
+        with _ring.span(_names.ENGINE_STAGE_DATASET):
+            xd, yd = self.stage_dataset(x, y, dtype=image_dtype)
+            jax.block_until_ready((xd, yd))
         ns = xd.shape[0] // p
         nb = ns // per_rank_batch
         if nb == 0:
@@ -1321,7 +1437,6 @@ class AllReduceSGDEngine:
         fn = self._build_epoch_fn(nb, per_rank_batch, shuffle)
         if self.broadcast_parameters:
             self.broadcast_parameters_now()
-        jax.block_until_ready((xd, yd))
 
         state: Dict[str, Any] = {
             "engine": self,
@@ -1338,33 +1453,52 @@ class AllReduceSGDEngine:
         t_start = time.perf_counter()
         for epoch in range(max_epochs):
             state["epoch"] = epoch
+            _ring.set_step(self.epochs_run, self.steps_run)
             self._hook("on_start_epoch", state)
-            te = time.perf_counter()
-            self.params, self.opt_state, self.model_state, losses = fn(
-                self.params,
-                self.opt_state,
-                self.model_state,
-                xd,
-                yd,
-                jax.random.fold_in(jax.random.PRNGKey(seed), epoch),
-            )
-            jax.block_until_ready(self.params)
-            state["epoch_times"].append(time.perf_counter() - te)
+            with _ring.span(_names.ENGINE_EPOCH, {"steps": nb}) as whole:
+                with _ring.span(_names.ENGINE_EPOCH_DISPATCH):
+                    self.params, self.opt_state, self.model_state, losses = (
+                        fn(
+                            self.params,
+                            self.opt_state,
+                            self.model_state,
+                            xd,
+                            yd,
+                            jax.random.fold_in(
+                                jax.random.PRNGKey(seed), epoch),
+                        )
+                    )
+                with _ring.span(_names.ENGINE_EPOCH_WAIT):
+                    jax.block_until_ready(self.params)
+            state["epoch_times"].append(whole.seconds)
             state["t"] += nb
             state["samples"] += nb * per_rank_batch * p
-            loss_arr, gnorms = self._split_aux(losses)
-            if self._telemetry:
-                self._record_step(
-                    nb * per_rank_batch * p,
-                    te, te + state["epoch_times"][-1],
-                    gnorms[-1], steps=nb, epoch=True,
-                )
-            losses_h = np.asarray(jax.device_get(loss_arr))
-            state["loss"] = float(losses_h[-1])
-            state["losses"].append(float(losses_h.mean()))
-            if epoch_callback is not None:
-                epoch_callback(epoch, state["losses"][-1], state["epoch_times"][-1])
-            self._hook("on_end_epoch", state)
+            if _telemetry.enabled():
+                examples = nb * per_rank_batch * p
+                self._record_epoch(examples, whole.seconds)
+                # a resident epoch is one dispatch: its steps are counted
+                # and its flight event stamped here, at the epoch's end
+                _engine_metrics().steps.inc(
+                    nb, mode=self.mode, sharding=self.param_sharding)
+                if _flight.enabled():
+                    wall_t1 = time.time()
+                    _flight.recorder.record_complete(
+                        _flight.comm_key(self.comm), "engine.epoch",
+                        wall_t1 - whole.seconds, wall_t1,
+                        payload=f"examples={examples},steps={nb}",
+                        routing=self.mode,
+                    )
+            with _ring.span(_names.ENGINE_EPOCH_END):
+                losses_h = np.asarray(jax.device_get(losses))
+                state["loss"] = float(losses_h[-1])
+                state["losses"].append(float(losses_h.mean()))
+                if epoch_callback is not None:
+                    epoch_callback(
+                        epoch, state["losses"][-1], state["epoch_times"][-1]
+                    )
+                self._hook("on_end_epoch", state)
+            self.epochs_run += 1
+            self.steps_run += nb
         state["time"] = time.perf_counter() - t_start
         state["training"] = False
         self._hook("on_end", state)
@@ -1398,6 +1532,7 @@ class AllReduceSGDEngine:
             "time": 0.0,
             "input_stall": 0.0,
         }
+        _ring.set_step(self.epochs_run, self.steps_run)
         self._hook("on_start", state)
 
         if self.broadcast_parameters:
@@ -1420,24 +1555,30 @@ class AllReduceSGDEngine:
             if self.profile_dir
             else None
         )
+        telemetry_on = _telemetry.enabled()
+        per_step_hooks = any(
+            h in self.hooks for h in ("on_forward", "on_backward", "on_update")
+        )
         t_start = time.perf_counter()
         try:
             for epoch in range(max_epochs):
                 state["epoch"] = epoch
                 loss = None
+                t_epoch = time.perf_counter()
+                stall0, samples0 = state["input_stall"], state["samples"]
+                _ring.set_step(self.epochs_run, self.steps_run)
                 self._hook("on_start_epoch", state)
                 # explicit next() so the wait on the iterator is MEASURED:
                 # a streaming pipeline that can't keep up shows here as
                 # input stall, not as silently-slower steps (the MFU fix)
                 batch_iter = iter(iterator_fn())
                 while True:
-                    t_fetch = time.perf_counter()
-                    try:
-                        batch = next(batch_iter)
-                    except StopIteration:
+                    _ring.set_step(self.epochs_run, self.steps_run)
+                    with _ring.span(_names.ENGINE_INPUT_WAIT) as wait:
+                        batch = next(batch_iter, _NO_BATCH)
+                    if batch is _NO_BATCH:
                         break
-                    fetch_s = time.perf_counter() - t_fetch
-                    state["input_stall"] += fetch_s
+                    state["input_stall"] += wait.seconds
                     batch = self._prepare_batch(batch)
                     state["sample"] = batch
                     self._hook("on_sample", state)
@@ -1450,24 +1591,17 @@ class AllReduceSGDEngine:
                             jax.block_until_ready(self.params)
                         win.step(state["t"])
 
-                    if self._telemetry:
-                        t_step = time.perf_counter()
-                    self.params, self.opt_state, self.model_state, aux = (
-                        self._call_step(batch)
+                    self.params, self.opt_state, self.model_state, loss = (
+                        self._dispatch(batch)
                     )
-                    loss, gnorm = self._split_aux(aux)
                     state["loss"] = loss
-                    self._hook("on_forward", state)
-                    self._hook("on_backward", state)
-                    self._hook("on_update", state)
-
-                    if self._telemetry:
-                        jax.block_until_ready(loss)
-                        self._record_step(
-                            jax.tree_util.tree_leaves(batch)[0].shape[0],
-                            t_step, time.perf_counter(), gnorm,
-                            input_stall_s=fetch_s,
-                        )
+                    if per_step_hooks:
+                        # where a script reads its loss: the one place a
+                        # step's host time can wait for the chip
+                        with _ring.span(_names.ENGINE_HOOKS):
+                            self._hook("on_forward", state)
+                            self._hook("on_backward", state)
+                            self._hook("on_update", state)
                     state["t"] += 1
                     state["samples"] += jax.tree_util.tree_leaves(batch)[0].shape[0]
                 if loss is None:
@@ -1476,8 +1610,18 @@ class AllReduceSGDEngine:
                         "must return a fresh iterator each call (pass a factory, "
                         "e.g. lambda: iter(make_iterator()))"
                     )
-                state["losses"].append(float(jax.device_get(loss)))
-                self._hook("on_end_epoch", state)
+                with _ring.span(_names.ENGINE_EPOCH_END):
+                    state["losses"].append(float(jax.device_get(loss)))
+                    if telemetry_on:
+                        # the loss read above waited for the epoch's last
+                        # step: the one place this loop may set a rate
+                        self._record_epoch(
+                            state["samples"] - samples0,
+                            time.perf_counter() - t_epoch,
+                            input_stall_s=state["input_stall"] - stall0,
+                        )
+                    self._hook("on_end_epoch", state)
+                self.epochs_run += 1
         finally:
             if win is not None:
                 if win.active:

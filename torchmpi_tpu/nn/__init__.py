@@ -35,10 +35,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax, tree_util
 
-from .. import collectives
+from .. import collectives, telemetry as _telemetry
 from ..collectives import eager
 from ..runtime.communicator import Communicator
 from ..runtime.handles import SyncHandle
+from ..telemetry import names as _names
 
 
 def _comm(comm: Optional[Communicator]) -> Communicator:
@@ -464,14 +465,56 @@ class GradientBuckets:
 # ---------------------------------------------------------------------------
 
 
+def _note_sync(reduced) -> None:
+    """Publish what one step's in-graph gradient sync reduces, from the
+    static shapes of the buffers handed to the collective: called while a
+    sync function is traced, so the gauges describe the step most recently
+    traced (``tm_engine_sync_bytes_per_step``: bytes each rank
+    contributes; ``tm_engine_sync_calls_per_step``: collectives)."""
+    m = _telemetry.metrics
+    m.gauge(
+        "tm_engine_sync_bytes_per_step",
+        "bytes each rank hands to the in-graph gradient sync per step "
+        "(static shapes of the step most recently traced)",
+    ).set(sum(int(np.prod(b.shape)) * b.dtype.itemsize for b in reduced))
+    m.gauge(
+        "tm_engine_sync_calls_per_step",
+        "collectives the in-graph gradient sync issues per step (the "
+        "step most recently traced)",
+    ).set(len(reduced))
+
+
 def in_graph_synchronize_gradients(grads, axis: str = "mpi", average: bool = True):
     """psum every leaf over the mesh axis — the compiled analog of
     synchronizeGradients, fused and scheduled by XLA."""
-    summed = tree_util.tree_map(lambda g: lax.psum(g, axis), grads)
-    if average:
-        n = lax.psum(1, axis)
-        summed = tree_util.tree_map(lambda g: g / n, summed)
+    with jax.named_scope(_names.SCOPE_GRAD_SYNC):
+        with jax.named_scope(_names.SCOPE_REDUCE):
+            summed = tree_util.tree_map(lambda g: lax.psum(g, axis), grads)
+        _note_sync(tree_util.tree_leaves(grads))
+        if average:
+            n = lax.psum(1, axis)
+            with jax.named_scope(_names.SCOPE_UNPACK):
+                summed = tree_util.tree_map(lambda g: g / n, summed)
     return summed
+
+
+def _sync_flat_group(leaves, idxs, dtype, n, reduce_one):
+    """Pack the leaves ``idxs`` of one dtype into a flat buffer, reduce it
+    with ``reduce_one`` and cut it back into ``leaves``, each phase under
+    its own scope (``n``: what to divide the sum by, None for a plain
+    sum); returns the buffer that went to the collective."""
+    with jax.named_scope(_names.SCOPE_PACK):
+        flats = [jnp.reshape(leaves[i], (-1,)) for i in idxs]
+        splits = np.cumsum([f.shape[0] for f in flats])[:-1]
+        cat = jnp.concatenate(flats)
+    with jax.named_scope(_names.SCOPE_REDUCE):
+        buf = reduce_one(cat)
+    with jax.named_scope(_names.SCOPE_UNPACK):
+        if n is not None:
+            buf = (buf / n).astype(dtype)
+        for part, i in zip(jnp.split(buf, splits), idxs):
+            leaves[i] = jnp.reshape(part, leaves[i].shape)
+    return cat
 
 
 def in_graph_synchronize_gradients_flat(
@@ -486,21 +529,17 @@ def in_graph_synchronize_gradients_flat(
     mixed-precision trees un-promoted. Numerics are identical to the
     per-leaf psum: concatenation commutes with the elementwise sum."""
     leaves, treedef = tree_util.tree_flatten(grads)
-    n = lax.psum(1, axis) if average else 1
+    n = lax.psum(1, axis) if average else None
     by_dtype: Dict = {}
     for i, l in enumerate(leaves):
         by_dtype.setdefault(jnp.result_type(l), []).append(i)
-    out = list(leaves)
-    for dtype, idxs in by_dtype.items():
-        flats = [jnp.reshape(leaves[i], (-1,)) for i in idxs]
-        splits = np.cumsum([f.shape[0] for f in flats])[:-1]
-        buf = lax.psum(jnp.concatenate(flats), axis)
-        if average:
-            buf = (buf / n).astype(dtype)
-        parts = jnp.split(buf, splits)
-        for part, i in zip(parts, idxs):
-            out[i] = jnp.reshape(part, leaves[i].shape)
-    return tree_util.tree_unflatten(treedef, out)
+    reduced = []
+    with jax.named_scope(_names.SCOPE_GRAD_SYNC):
+        for dtype, idxs in by_dtype.items():
+            reduced.append(_sync_flat_group(
+                leaves, idxs, dtype, n, lambda c: lax.psum(c, axis)))
+    _note_sync(reduced)
+    return tree_util.tree_unflatten(treedef, leaves)
 
 
 def in_graph_synchronize_gradients_bucketed(
@@ -517,27 +556,28 @@ def in_graph_synchronize_gradients_bucketed(
     compressed-wire ppermute ring for f32 buckets above the tuned cutoff
     (block-quantized send, f32 accumulate) — the in-graph path of the
     EQuARX-style wire format; other buckets keep the psum."""
+    from ..collectives import primitives as _prim
+
     leaves = list(tree_util.tree_leaves(grads))
-    n = lax.psum(1, axis) if average else 1
+    n = lax.psum(1, axis) if average else None
+    reduced = []
+
+    def reduce_one(cat):
+        if _prim.wire_engages(wire_dtype, cat.dtype, int(cat.shape[0])):
+            return _prim.ring_allreduce(cat, axis, wire_dtype=wire_dtype)
+        return lax.psum(cat, axis)
+
     for b in range(buckets.num_buckets):
         by_dtype: Dict = {}
         for i in buckets.buckets[b]:
             by_dtype.setdefault(jnp.result_type(leaves[i]), []).append(i)
-        for dtype, idxs in by_dtype.items():
-            flats = [jnp.reshape(leaves[i], (-1,)) for i in idxs]
-            splits = np.cumsum([f.shape[0] for f in flats])[:-1]
-            cat = jnp.concatenate(flats)
-            from ..collectives import primitives as _prim
-
-            if _prim.wire_engages(wire_dtype, dtype, int(cat.shape[0])):
-                buf = _prim.ring_allreduce(cat, axis, wire_dtype=wire_dtype)
-            else:
-                buf = lax.psum(cat, axis)
-            if average:
-                buf = (buf / n).astype(dtype)
-            parts = jnp.split(buf, splits)
-            for part, i in zip(parts, idxs):
-                leaves[i] = jnp.reshape(part, leaves[i].shape)
+        # the bucket's index in the name: tm.grad_sync/b<b>/pack, ...
+        with jax.named_scope(_names.SCOPE_GRAD_SYNC), \
+                jax.named_scope(f"b{b}"):
+            for dtype, idxs in by_dtype.items():
+                reduced.append(_sync_flat_group(
+                    leaves, idxs, dtype, n, reduce_one))
+    _note_sync(reduced)
     return tree_util.tree_unflatten(buckets.treedef, leaves)
 
 

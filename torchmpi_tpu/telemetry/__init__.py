@@ -12,8 +12,10 @@ production serving actually needs:
 2. **Spans** (:func:`span`): a low-overhead timed-region context manager
    recording into a bounded ring buffer, exported as Chrome
    ``trace_event`` JSON loadable in Perfetto / chrome://tracing
-   (:func:`export_trace`), with ``jax.profiler.TraceAnnotation``
-   pass-through so the same names appear in XLA traces.
+   (:func:`export_trace`). Spans start on ``time.time_ns()``, the clock a
+   device trace's origin is set on, so the two join by subtraction
+   (``telemetry/spans.py``). The engine's and the input pipeline's own
+   spans are recorded always, through ``spans.span``, not this switch.
 3. **Audit log** (:func:`audit`): a small bounded journal of discrete
    decisions (autotuner knob choices, tuning-cache loads) included in
    every snapshot.
@@ -48,7 +50,10 @@ from .registry import (  # noqa: F401 - re-exported
     Histogram,
     MetricsRegistry,
 )
-from .spans import NOOP_SPAN, Span, SpanRecorder
+# the span and scope names, as ``telemetry.names``: the attribute ``spans``
+# below is the process-global recorder, which hides the module of that name
+from . import spans as names  # noqa: F401 - re-exported
+from .spans import NOOP_SPAN, Span, SpanRecorder  # noqa: F401
 from . import flightrecorder
 from .flightrecorder import FlightRecorder  # noqa: F401 - re-exported
 
@@ -95,21 +100,22 @@ def disable() -> None:
 def span(name: str, **attrs):
     """Timed-region context manager. Disabled -> a shared no-op object
     (zero allocation); enabled -> records wall time + ``attrs`` into the
-    ring buffer and passes through as a ``jax.profiler.TraceAnnotation``.
+    ring buffer.
 
     Hot paths that build attrs dicts should guard the whole call with
     ``if telemetry.enabled():`` so the disabled path stays one branch.
     """
     if not _enabled:
         return NOOP_SPAN
-    return Span(spans, name, attrs or None)
+    return spans.span(name, attrs or None)
 
 
 # --- clock-sync record (written by runtime_state.start()) -------------------
 # One (wall_time, perf_counter, monotonic) triple captured at the same
-# instant. Span timestamps are perf_counter-based and rank-local; this
-# record is the per-rank offset handshake the offline analyzer uses to put
-# every rank's events on one wall-clock axis (telemetry/analyze.py).
+# instant: the offset handshake the offline analyzer (telemetry/analyze.py)
+# needs for snapshots whose spans were stamped with perf_counter. Spans are
+# on the wall clock now and a snapshot says so (``spans.clock``), so for
+# them the offset is the identity.
 _clock_sync: Optional[dict] = None
 
 
@@ -182,6 +188,9 @@ def snapshot() -> dict:
             "recorded": spans.total_recorded,
             "capacity": spans.capacity,
             "dropped": spans.dropped,
+            # the clock of a span's ts: the flight recorder's and a
+            # device trace's (no perf_counter offset to apply)
+            "clock": "time_ns",
         },
         "flight_recorder": flightrecorder.recorder.snapshot(),
     }

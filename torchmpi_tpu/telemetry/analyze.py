@@ -153,8 +153,12 @@ def _flight_entries(data: dict) -> List[dict]:
 
 
 def _wall_offset_us(data: dict) -> Optional[float]:
-    """µs to add to a rank's perf_counter-based span ts to land on the
-    wall clock; None when the rank never recorded a clock sync."""
+    """µs to add to a rank's span ts to land on the wall clock: nothing
+    for a snapshot whose spans are already on it (``spans.clock``), the
+    clock-sync offset for an older one stamped with perf_counter; None
+    when such a rank never recorded a clock sync."""
+    if (data["snapshot"].get("spans") or {}).get("clock") == "time_ns":
+        return 0.0
     cs = data["snapshot"].get("clock_sync")
     if not cs:
         return None

@@ -915,16 +915,6 @@ class FleetAggregator:
                 ),
                 default=0,
             )
-            step = (
-                rv["metrics"].get("tm_engine_step_seconds", {})
-                .get("series", {})
-            )
-            step_p50_ms = None
-            for h in step.values():
-                q = (h.get("quantiles") or {}).get("0.5")
-                if q is not None:
-                    step_p50_ms = round(float(q) * 1e3, 3)
-                break
             busy = sum(
                 (rv["metrics"].get("tm_ps_busy_rejected_total", {})
                  .get("series", {}) or {}).values()
@@ -944,7 +934,6 @@ class FleetAggregator:
                 "frames": rv["frames"],
                 "seq_high_water": rv["seq_high_water"],
                 "seq_lag": lag,
-                "step_p50_ms": step_p50_ms,
                 "busy_rejected": busy,
                 # rolling per-window rate (summed over this rank's
                 # listeners), captured by the last evaluate(): the trend

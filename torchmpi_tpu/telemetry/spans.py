@@ -1,48 +1,102 @@
-"""Structured spans: a bounded ring buffer of wall-time events with Chrome
+"""Structured spans: a bounded ring buffer of wall-clock events with Chrome
 ``trace_event`` export (loadable in Perfetto / chrome://tracing).
 
 A span is one timed region of host-side work — an eager collective
-dispatch, an engine step, a PS RPC. Recording is designed for the hot
-path: one ``perf_counter`` pair, one tuple append into a ``deque(maxlen)``
-under a lock, no I/O until :meth:`SpanRecorder.export`. When the process
-also runs a ``jax.profiler`` trace, spans pass through as
-``TraceAnnotation``s so the same names appear on the XLA timeline.
+dispatch, the engine's wait for a batch, a PS RPC. Recording is designed
+for the hot path: three clock reads, one tuple append into a
+``deque(maxlen)`` under a lock, no I/O until :meth:`SpanRecorder.export`.
 
-The disabled path never reaches this module: ``telemetry.span`` returns a
-shared no-op singleton (:data:`NOOP_SPAN`), so a disabled call site costs
-one branch and zero allocation.
+**One clock.** A span's start is ``time.time_ns()``: the clock the flight
+recorder stamps with (``time.time()``) and the clock a device trace's
+origin is set on (``ProfileOptions.start_timestamp_ns``, see
+``utils.tracing.ProfilerWindow``). A span therefore joins a device trace
+by subtraction: ``start_ns - origin_ns`` is its time in the trace. Nothing
+here opens an annotation of jax's profiler: that needs the profiler's
+host tracer, which on a TPU host writes a million events per copied batch
+and slows the step it traces. A span's duration comes from the monotonic
+clock, so a stepped wall clock cannot make it negative.
+
+Each record carries its name, start, duration, thread, attributes, an id,
+the id of the span that caused it (the span open on the same thread when
+it started, or one given explicitly across threads) and the ``(epoch,
+step)`` its thread was working on (:meth:`SpanRecorder.set_step`).
+
+The names the engine, the input pipeline and the jitted step use are the
+constants below: the benchmark's per-layer metrics find spans and device
+operations by them, and ``tests/test_engine_tracing.py`` pins them.
 """
 
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 from ..analysis import lockmon as _lockmon
 import time
 from collections import deque
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
-# jax.profiler.TraceAnnotation, resolved lazily: this module must import
-# (and spans must record) without jax — the bench launcher reads traces
-# from processes that never had a backend.
-_TRACE_ANNOTATION = None
-_TRACE_ANNOTATION_RESOLVED = False
+# -- host spans: engine/sgd.py ---------------------------------------------
+ENGINE_INIT = "engine.init"
+ENGINE_BROADCAST = "engine.broadcast"
+ENGINE_STAGE_DATASET = "engine.stage_dataset"
+ENGINE_PROGRAM_BUILD = "engine.program_build"
+ENGINE_INPUT_WAIT = "engine.input_wait"
+ENGINE_DISPATCH = "engine.dispatch"
+ENGINE_HOOKS = "engine.hooks"
+ENGINE_EPOCH_END = "engine.epoch_end"
+ENGINE_EPOCH = "engine.epoch"
+ENGINE_EPOCH_DISPATCH = "engine.epoch.dispatch"
+ENGINE_EPOCH_WAIT = "engine.epoch.wait"
+ENGINE_CHECKPOINT = "engine.checkpoint"
+ENGINE_RESIZE = "engine.resize"
+# -- host spans: data/__init__.py (InputPipeline) --------------------------
+INPUT_EPOCH_START = "input.epoch_start"
+INPUT_ASSEMBLE = "input.assemble"
+INPUT_STAGE = "input.stage"
+INPUT_RING_WAIT = "input.ring_wait"
+# -- host span: utils/tracing.py (ProfilerWindow) --------------------------
+PROFILER_WINDOW = "profiler.window"
+
+SPAN_NAMES = (
+    ENGINE_INIT, ENGINE_BROADCAST, ENGINE_STAGE_DATASET,
+    ENGINE_PROGRAM_BUILD, ENGINE_INPUT_WAIT, ENGINE_DISPATCH, ENGINE_HOOKS,
+    ENGINE_EPOCH_END, ENGINE_EPOCH, ENGINE_EPOCH_DISPATCH, ENGINE_EPOCH_WAIT,
+    ENGINE_CHECKPOINT, ENGINE_RESIZE, INPUT_EPOCH_START, INPUT_ASSEMBLE,
+    INPUT_STAGE, INPUT_RING_WAIT, PROFILER_WINDOW,
+)
+
+# -- jax.named_scope names inside the jitted step (metadata only) ----------
+SCOPE_FWD_BWD = "tm.fwd_bwd"
+SCOPE_STATE_SYNC = "tm.state_sync"
+SCOPE_GRAD_SYNC = "tm.grad_sync"
+# nested under SCOPE_GRAD_SYNC; a bucketed sync nests ``b<index>`` below
+SCOPE_PACK, SCOPE_REDUCE, SCOPE_UNPACK = "pack", "reduce", "unpack"
+SCOPE_OPTIMIZER = "tm.optimizer"
+SCOPE_LOSS_SYNC = "tm.loss_sync"
+SCOPE_RESIDENT_GATHER = "tm.resident_gather"
+
+SCOPE_NAMES = (
+    SCOPE_FWD_BWD, SCOPE_STATE_SYNC, SCOPE_GRAD_SYNC,
+    f"{SCOPE_GRAD_SYNC}/{SCOPE_PACK}", f"{SCOPE_GRAD_SYNC}/{SCOPE_REDUCE}",
+    f"{SCOPE_GRAD_SYNC}/{SCOPE_UNPACK}", SCOPE_OPTIMIZER, SCOPE_LOSS_SYNC,
+    SCOPE_RESIDENT_GATHER,
+)
 
 
-def _trace_annotation_cls():
-    global _TRACE_ANNOTATION, _TRACE_ANNOTATION_RESOLVED
-    if not _TRACE_ANNOTATION_RESOLVED:
-        _TRACE_ANNOTATION_RESOLVED = True
-        if os.environ.get(
-            "TORCHMPI_TPU_TELEMETRY_XLA", "1"
-        ).lower() in ("1", "true", "yes", "on"):
-            try:
-                import jax
+class SpanRecord(NamedTuple):
+    name: str
+    start_ns: int            # time.time_ns() at the span's start
+    dur_ns: int              # monotonic
+    tid: int
+    attrs: Optional[dict]
+    id: int
+    parent: Optional[int]    # id of the span that caused this one
+    step: Optional[Tuple[int, int]]  # (epoch, step) the thread worked on
 
-                _TRACE_ANNOTATION = jax.profiler.TraceAnnotation
-            except Exception:  # noqa: BLE001 - no jax / no profiler: skip
-                _TRACE_ANNOTATION = None
-    return _TRACE_ANNOTATION
+    @property
+    def end_ns(self) -> int:
+        return self.start_ns + self.dur_ns
 
 
 class SpanRecorder:
@@ -51,6 +105,9 @@ class SpanRecorder:
     def __init__(self, capacity: int = 4096):
         self._lock = _lockmon.make_lock("spans.py:SpanRecorder._lock")
         self._buf: deque = deque(maxlen=int(capacity))
+        self._ids = itertools.count(1)
+        # per thread: the ids of the spans open on it, and its (epoch, step)
+        self._ctx = threading.local()
         self.total_recorded = 0
         # spans evicted by ring wrap-around: > 0 means the exported trace
         # is TRUNCATED (detectable instead of silent — snapshot()["spans"]
@@ -65,28 +122,74 @@ class SpanRecorder:
         with self._lock:
             return len(self._buf)
 
-    def record(self, name: str, ts_us: float, dur_us: float,
-               attrs: Optional[dict] = None) -> None:
-        tid = threading.get_ident() & 0xFFFFFFFF
+    # -- the calling thread's context -----------------------------------
+    def set_step(self, epoch: int, step: int) -> None:
+        """Name the ``(epoch, step)`` this thread works on from here: every
+        span it opens until the next call carries it."""
+        self._ctx.step = (epoch, step)
+
+    def _open(self) -> list:
+        try:
+            return self._ctx.open
+        except AttributeError:
+            self._ctx.open = []
+            return self._ctx.open
+
+    def span(self, name: str, attrs: Optional[dict] = None,
+             parent: Optional[int] = None,
+             step: Optional[Tuple[int, int]] = None) -> "Span":
+        """A context manager that records one span, whatever
+        ``telemetry.enabled()`` says. ``parent`` and ``step`` default to
+        the span still open on the calling thread, and the thread's step,
+        when this one closes."""
+        return Span(self, name, attrs, parent, step)
+
+    def record(self, name: str, start_ns: int, dur_ns: int,
+               attrs: Optional[dict] = None, parent: Optional[int] = None,
+               step: Optional[Tuple[int, int]] = None,
+               span_id: Optional[int] = None) -> int:
+        """Append a finished span; returns its id. ``parent`` and ``step``
+        default to the calling thread's open span and step."""
+        if span_id is None:
+            span_id = next(self._ids)
+        if parent is None:
+            stack = self._open()
+            parent = stack[-1] if stack else None
+        if step is None:
+            step = getattr(self._ctx, "step", None)
+        rec = SpanRecord(
+            name, int(start_ns), max(0, int(dur_ns)),
+            threading.get_ident() & 0xFFFFFFFF, attrs, span_id, parent, step,
+        )
         with self._lock:
             if len(self._buf) == self._buf.maxlen:
                 self.dropped += 1
-            self._buf.append((name, ts_us, dur_us, tid, attrs))
+            self._buf.append(rec)
             self.total_recorded += 1
+        return span_id
+
+    def records(self) -> list:
+        """The buffered spans, oldest first."""
+        with self._lock:
+            return list(self._buf)
 
     def reset(self) -> None:
+        """Forget the recorded spans, and every thread's step (a span open
+        across the call keeps its own record, and loses its place as a
+        parent)."""
         with self._lock:
             self._buf.clear()
             self.total_recorded = 0
             self.dropped = 0
+            self._ctx = threading.local()
 
     def trace_events(self) -> list:
         """Chrome ``trace_event`` list: one complete ('X') event per span
         (``ph``/``ts``/``dur``/``name``/``pid``/``tid`` + ``args``), plus a
-        process-name metadata event so Perfetto labels the track."""
+        process-name metadata event so Perfetto labels the track. ``ts`` is
+        microseconds of the wall clock: less a device trace's origin it is
+        the span's place in that trace."""
         pid = os.getpid()
-        with self._lock:
-            spans = list(self._buf)
         events = [
             {
                 "ph": "M",
@@ -97,19 +200,23 @@ class SpanRecorder:
                 "args": {"name": f"torchmpi_tpu pid {pid}"},
             }
         ]
-        for name, ts_us, dur_us, tid, attrs in spans:
-            ev = {
+        for r in self.records():
+            args = {k: _jsonable(v) for k, v in (r.attrs or {}).items()}
+            args["span_id"] = r.id
+            if r.parent is not None:
+                args["parent"] = r.parent
+            if r.step is not None:
+                args["epoch"], args["step"] = r.step
+            events.append({
                 "ph": "X",
-                "name": name,
+                "name": r.name,
                 "cat": "torchmpi_tpu",
-                "ts": round(ts_us, 3),
-                "dur": round(dur_us, 3),
+                "ts": r.start_ns / 1e3,
+                "dur": r.dur_ns / 1e3,
                 "pid": pid,
-                "tid": tid,
-            }
-            if attrs:
-                ev["args"] = {k: _jsonable(v) for k, v in attrs.items()}
-            events.append(ev)
+                "tid": r.tid,
+                "args": args,
+            })
         return events
 
     def export(self, path) -> None:
@@ -123,7 +230,8 @@ class SpanRecorder:
                  "displayTimeUnit": "ms",
                  # extra top-level keys are legal in the Chrome trace
                  # object form; > 0 flags a truncated (ring-wrapped) trace
-                 "spanDropped": self.dropped},
+                 "spanDropped": self.dropped,
+                 "spanClock": "time_ns"},
                 f,
             )
 
@@ -135,39 +243,39 @@ def _jsonable(v):
 
 
 class Span:
-    """Context manager timing one region into ``recorder``; enters a
-    ``jax.profiler.TraceAnnotation`` of the same name when jax is present
-    (so spans also land on XLA profiler timelines)."""
+    """Context manager timing one region into ``recorder``. After exit,
+    ``seconds`` holds its duration and ``id`` its record's id."""
 
-    __slots__ = ("_recorder", "name", "attrs", "_t0", "_ann")
+    __slots__ = ("_recorder", "name", "attrs", "parent", "step", "id",
+                 "seconds", "_start_ns", "_t0")
 
     def __init__(self, recorder: SpanRecorder, name: str,
-                 attrs: Optional[dict] = None):
+                 attrs: Optional[dict] = None, parent: Optional[int] = None,
+                 step: Optional[Tuple[int, int]] = None):
         self._recorder = recorder
         self.name = name
         self.attrs = attrs
-        self._ann = None
+        self.parent = parent
+        self.step = step
+        self.seconds = 0.0
 
     def __enter__(self):
-        cls = _trace_annotation_cls()
-        if cls is not None:
-            try:
-                self._ann = cls(self.name)
-                self._ann.__enter__()
-            except Exception:  # noqa: BLE001 - annotation is best-effort
-                self._ann = None
-        self._t0 = time.perf_counter()
+        rec = self._recorder
+        self.id = next(rec._ids)
+        rec._open().append(self.id)
+        self._start_ns = time.time_ns()
+        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        t1 = time.perf_counter()
-        if self._ann is not None:
-            try:
-                self._ann.__exit__(exc_type, exc, tb)
-            except Exception:  # noqa: BLE001
-                pass
+        dur = time.perf_counter_ns() - self._t0
+        stack = self._recorder._open()
+        if stack:  # empty only after a reset() inside this span
+            stack.pop()
+        self.seconds = dur * 1e-9
         self._recorder.record(
-            self.name, self._t0 * 1e6, (t1 - self._t0) * 1e6, self.attrs
+            self.name, self._start_ns, dur, self.attrs, self.parent,
+            self.step, self.id,
         )
         return False
 

@@ -31,9 +31,8 @@ def _fetch(base: str, path: str, timeout: float = 5.0) -> dict:
         return json.loads(resp.read())
 
 
-def _fmt(v, width: int, suffix: str = "") -> str:
-    s = "-" if v is None else f"{v}{suffix}"
-    return s.rjust(width)
+def _fmt(v, width: int) -> str:
+    return ("-" if v is None else str(v)).rjust(width)
 
 
 def render(health: dict, verdicts: dict) -> str:
@@ -54,7 +53,7 @@ def render(health: dict, verdicts: dict) -> str:
     lines.append("")
     header = (
         f"{'rank':>5} {'age_s':>7} {'seq_hw':>8} {'lag':>5} "
-        f"{'step_p50':>9} {'busy':>6} {'busy/s':>7} {'epoch':>6} "
+        f"{'busy':>6} {'busy/s':>7} {'epoch':>6} "
         f"{'ps_term':>8} {'cp_term':>13} {'state':>6}"
     )
     lines.append(header)
@@ -67,7 +66,6 @@ def render(health: dict, verdicts: dict) -> str:
         lines.append(
             f"{rank:>5} {_fmt(row.get('age_s'), 7)} {_fmt(seq_hw, 8)} "
             f"{_fmt(row.get('seq_lag'), 5)} "
-            f"{_fmt(row.get('step_p50_ms'), 9, 'ms')} "
             f"{_fmt(row.get('busy_rejected'), 6)} "
             f"{_fmt(row.get('busy_rate_per_s'), 7)} "
             f"{_fmt(row.get('resize_epoch'), 6)} "

@@ -70,6 +70,18 @@ class ProfilerWindow:
         finally:
             win.close()          # loops shorter than the window, and
                                  # exception exits, must still stop it
+
+    The trace is of the device alone: the profiler's host and Python
+    tracers are off (on a TPU host the host tracer writes a million
+    events per copied batch and slows the step it traces). Host-side
+    work is in the program's own spans instead (``telemetry/spans.py``),
+    and the two join by one subtraction: the trace's origin is set on
+    ``time.time_ns()``, the spans' clock, and recorded when the window
+    closes as a ``profiler.window`` span whose ``origin_ns`` attribute is
+    that origin. An event at ``t`` ns of the trace happened at
+    ``origin_ns + t`` on the wall clock; a span of
+    ``telemetry.export_trace``'s file with ``ts`` microseconds lies at
+    ``ts - origin_ns / 1e3`` microseconds of the trace.
     """
 
     def __init__(self, log_dir: str, begin: int = 3, end: int = 8):
@@ -84,31 +96,47 @@ class ProfilerWindow:
         self.log_dir = log_dir
         self.begin = begin
         self.end = end
-        self._active = False
+        self.origin_ns: Optional[int] = None
+        self._t0 = 0
 
     @property
     def active(self) -> bool:
         """Whether a trace is currently open (callers that can name the
         in-flight arrays should block on them before the stopping
         ``step``/``close`` so async dispatch tails land in the trace)."""
-        return self._active
+        return self.origin_ns is not None
 
     def step(self, step: int) -> None:
-        import jax
-
-        if step == self.begin and not self._active:
-            jax.profiler.start_trace(self.log_dir)
-            self._active = True
-        elif step >= self.end and self._active:
-            jax.profiler.stop_trace()
-            self._active = False
-
-    def close(self) -> None:
-        if self._active:
+        if step == self.begin and not self.active:
             import jax
 
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 0
+            options.start_timestamp_ns = origin = time.time_ns()
+            jax.profiler.start_trace(
+                self.log_dir, profiler_options=options
+            )
+            self.origin_ns, self._t0 = origin, time.perf_counter_ns()
+        elif step >= self.end:
+            self.close()
+
+    def close(self) -> None:
+        if not self.active:
+            return
+        import jax
+
+        from .. import telemetry
+
+        origin, self.origin_ns = self.origin_ns, None
+        try:
             jax.profiler.stop_trace()
-            self._active = False
+        finally:
+            telemetry.spans.record(
+                telemetry.names.PROFILER_WINDOW, origin,
+                time.perf_counter_ns() - self._t0,
+                {"origin_ns": origin, "log_dir": str(self.log_dir)},
+            )
 
 
 def redirect_logs_per_process(directory: str = "/tmp", prefix: str = "tm_") -> Path:
