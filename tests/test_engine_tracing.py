@@ -4,6 +4,7 @@ boundaries, and telemetry that changes neither the compiled program nor
 where the host waits."""
 
 import json
+import re
 import threading
 import time
 
@@ -57,12 +58,14 @@ def _data(steps, per_rank=2):
     return x, x.sum(axis=1).astype(np.float32)
 
 
-def _engine(layout="flat", **kw):
+def _engine(layout="sync", **kw):
     """An engine with the gradient sync or parameter layout ``layout``."""
-    if layout == "per_leaf":
-        constants.set("fusion_buffer_bytes", 0)
-    elif layout == "bucketed":
+    if layout == "bucketed":
         kw.update(mode="async", num_buckets=2)
+    elif layout == "bucketed_int8":
+        # one bucket, and a cutoff low enough for the wire to engage
+        constants.set("wire_quant_min_elements", 1)
+        kw.update(wire_dtype="int8")
     elif layout in ("fsdp", "zero1"):
         kw.update(param_sharding=layout)
     return AllReduceSGDEngine(
@@ -78,14 +81,26 @@ def _step_text(engine, per_rank=2):
 
 
 SYNC_SCOPES = {
-    "per_leaf": ("tm.grad_sync/reduce", "tm.grad_sync/unpack"),
-    "flat": ("tm.grad_sync/pack", "tm.grad_sync/reduce",
-             "tm.grad_sync/unpack"),
-    "bucketed": ("tm.grad_sync/b0/pack", "tm.grad_sync/b1/reduce",
+    "sync": ("tm.grad_sync/reduce", "tm.grad_sync/unpack"),
+    "bucketed": ("tm.grad_sync/b0/reduce", "tm.grad_sync/b1/reduce",
                  "tm.grad_sync/b1/unpack"),
+    "bucketed_int8": ("tm.grad_sync/b0/pack", "tm.grad_sync/b0/reduce",
+                      "tm.grad_sync/b0/unpack"),
     "fsdp": (),
     "zero1": (),
 }
+
+
+def _sync_ops(text):
+    """The ``op_name`` of every operation of the lowered step under
+    ``tm.grad_sync``, once for each use: ``tm.grad_sync/reduce/psum``."""
+    scoped = dict(re.findall(
+        r'^(#loc\d+) = loc\("(%s/[^"]*)"' % names.SCOPE_GRAD_SYNC, text,
+        re.M))
+    return [scoped[ref] for line in text.splitlines()
+            if "stablehlo.return" not in line  # a reducer's own
+            for ref in re.findall(r"loc\((#loc\d+)\)", line)
+            if ref in scoped]
 
 
 # -- (a) the scope names reach the lowered step -------------------------
@@ -103,7 +118,7 @@ def test_scope_names_in_lowered_step(layout):
         assert names.SCOPE_GRAD_SYNC not in text
 
 
-@pytest.mark.parametrize("layout", ["flat", "fsdp"])
+@pytest.mark.parametrize("layout", ["sync", "fsdp"])
 def test_scope_names_in_resident_epoch_program(layout):
     engine = _engine(layout)
     x, y = _data(3)
@@ -117,7 +132,7 @@ def test_scope_names_in_resident_epoch_program(layout):
     for scope in (names.SCOPE_RESIDENT_GATHER, names.SCOPE_FWD_BWD,
                   names.SCOPE_OPTIMIZER):
         assert scope + "/" in text, scope
-    assert (names.SCOPE_GRAD_SYNC in text) == (layout == "flat")
+    assert (names.SCOPE_GRAD_SYNC in text) == (layout == "sync")
 
 
 def test_state_sync_scope_with_model_state():
@@ -148,7 +163,7 @@ def test_names_are_pinned():
 
 
 # -- (b) telemetry changes neither the program nor where the host waits --
-@pytest.mark.parametrize("layout", ["flat", "bucketed", "fsdp"])
+@pytest.mark.parametrize("layout", ["sync", "bucketed", "fsdp"])
 def test_telemetry_leaves_the_lowered_step_unchanged(layout):
     texts = []
     for switch in (telemetry.disable, telemetry.enable):
@@ -342,13 +357,42 @@ def test_new_shape_in_mid_run_is_one_program_build():
 
 # -- (e) what the in-graph sync reduces ---------------------------------
 @pytest.mark.parametrize("layout,calls", [
-    ("flat", 1), ("bucketed", 2), ("per_leaf", 3)])
+    ("sync", 3), ("bucketed", 3), ("bucketed_int8", 1)])
 def test_sync_counters_equal_the_tree(layout, calls):
+    """One collective per leaf where the leaves are reduced as they lie,
+    one per flat buffer where a wire format packs them; the same bytes
+    either way."""
     telemetry.metrics.reset()
     _step_text(_engine(layout))
     met = telemetry.metrics
     assert met.gauge("tm_engine_sync_bytes_per_step").value() == 4 * TREE_SIZE
     assert met.gauge("tm_engine_sync_calls_per_step").value() == calls
+
+
+# -- (f) a flat buffer only where a wire format needs one ---------------
+@pytest.mark.parametrize("layout", ["sync", "bucketed"])
+def test_full_wire_step_holds_no_flat_buffer(layout):
+    text = _step_text(_engine(layout))
+    ops = _sync_ops(text)
+    # jax binds one psum for each leaf of a list: XLA's combiner groups them
+    assert sum(op.endswith("/psum") for op in ops) == 3
+    assert not [op for op in ops if "concatenate" in op]
+    assert f"/{names.SCOPE_PACK}/" not in text
+
+
+def test_int8_wire_step_still_packs():
+    ops = _sync_ops(_step_text(_engine("bucketed_int8")))
+    assert "tm.grad_sync/b0/pack/concatenate" in ops
+    assert not [op for op in ops if op.endswith("/psum")]
+
+
+@pytest.mark.parametrize("layout", ["sync", "bucketed"])
+def test_fusion_buffer_bytes_does_not_reach_the_lowered_step(layout):
+    texts = []
+    for size in (0, 4 << 20):
+        constants.set("fusion_buffer_bytes", size)
+        texts.append(_step_text(_engine(layout)))
+    assert texts[0] == texts[1]
 
 
 # -- one clock -----------------------------------------------------------

@@ -12,8 +12,7 @@ Covers the PR-4 tentpole end to end:
 - AOT ``precompile``: pinned entries survive LRU eviction pressure,
   warm dispatch compiles nothing (the telemetry miss counter is the
   assertion), ``free_collective_resources`` still frees wholesale;
-- GradientBuckets' persistent donated flat buffers and the engine's
-  coalesced in-graph sync;
+- GradientBuckets' persistent donated flat buffers;
 - the causal bidirectional ring-attention L-chain gating algebra
   (send / recv / capacity-semaphore pairing across neighbors).
 """
@@ -469,36 +468,6 @@ def test_gradient_buckets_persistent_with_donation():
         out = bk.wait_and_unflatten(tree, bk.allreduce_async(tree))
         np.testing.assert_allclose(np.asarray(out["a"]), float(p))
     np.testing.assert_array_equal(np.asarray(tree["a"]), 1.0)
-
-
-def test_engine_coalesced_sync_matches_per_leaf():
-    import optax
-
-    def loss_fn(params, batch):
-        x, y = batch
-        return jnp.mean((x @ params["w"] + params["b"] - y) ** 2)
-
-    p = mpi.size()
-    params = {"w": jnp.ones((4, 3)), "b": jnp.zeros((3,))}
-    rng = np.random.RandomState(2)
-    batch = (
-        rng.randn(p * 2, 4).astype(np.float32),
-        rng.randn(p * 2, 3).astype(np.float32),
-    )
-    from torchmpi_tpu.engine import AllReduceSGDEngine
-
-    flat = AllReduceSGDEngine(loss_fn, params, optimizer=optax.sgd(0.1))
-    assert flat._coalesce
-    constants.set("fusion_buffer_bytes", 0)
-    leaf = AllReduceSGDEngine(loss_fn, params, optimizer=optax.sgd(0.1))
-    assert not leaf._coalesce
-    lf, ll = flat.step(batch), leaf.step(batch)
-    np.testing.assert_allclose(float(lf), float(ll), rtol=1e-6)
-    for k in params:
-        np.testing.assert_allclose(
-            np.asarray(flat.params[k]), np.asarray(leaf.params[k]),
-            rtol=1e-6,
-        )
 
 
 def test_engine_precompile_aot_step():

@@ -138,6 +138,60 @@ def test_in_graph_bucketed_matches_fused():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
 
 
+@pytest.mark.parametrize("average", [False, True], ids=["sum", "mean"])
+@pytest.mark.parametrize("route", ["per_leaf", "bucketed"])
+def test_in_graph_sync_equals_psum_of_the_concatenated_tree(route, average):
+    """The leaves reduced where they lie give, bit for bit and in each
+    leaf's own dtype, what one psum of the flat buffer gave: a mixed
+    tree, the integers beyond float32's 2**24."""
+    from jax.sharding import PartitionSpec as P
+
+    p = mpi.size()
+    mesh = mpi.current_communicator().flat_mesh("mpi")
+    rng = np.random.RandomState(7)
+    big = 2**24 + 1
+    tree = {
+        "w": jnp.asarray(rng.randn(p * 2, 17).astype(np.float32)),
+        "h": jnp.asarray(rng.randn(p * 3), jnp.bfloat16),
+        "b": jnp.asarray(rng.randn(p * 5).astype(np.float32)),
+        "n": jnp.asarray(big + rng.randint(0, 9, size=(p * 2, 2)), jnp.int32),
+    }
+    template = jax.tree_util.tree_map(lambda a: a[: a.shape[0] // p], tree)
+    buckets = GradientBuckets(template, 2)
+
+    def lies(t):
+        if route == "per_leaf":
+            return mpinn.in_graph_synchronize_gradients(
+                t, "mpi", average=average)
+        return mpinn.in_graph_synchronize_gradients_bucketed(
+            t, buckets, "mpi", average=average)
+
+    def flat(t):
+        leaves, treedef = jax.tree_util.tree_flatten(t)
+        for dtype in {leaf.dtype for leaf in leaves}:
+            idxs = [i for i, leaf in enumerate(leaves) if leaf.dtype == dtype]
+            buf = jax.lax.psum(
+                jnp.concatenate([leaves[i].reshape(-1) for i in idxs]), "mpi")
+            if average:
+                buf = (buf / p).astype(dtype)
+            cuts = np.cumsum([leaves[i].size for i in idxs])[:-1]
+            for i, part in zip(idxs, jnp.split(buf, cuts)):
+                leaves[i] = part.reshape(leaves[i].shape)
+        return jax.tree_util.tree_unflatten(treedef, leaves)
+
+    run = lambda f: jax.jit(jax.shard_map(
+        f, mesh=mesh, in_specs=P("mpi"), out_specs=P("mpi"),
+        check_vma=False))(tree)
+    got, want = run(lies), run(flat)
+    for k in tree:
+        assert got[k].dtype == tree[k].dtype, k
+        np.testing.assert_array_equal(
+            np.asarray(got[k], np.float64), np.asarray(want[k], np.float64))
+    if not average:  # the integers summed exactly
+        total = np.asarray(tree["n"]).reshape(p, 2, 2).sum(axis=0)
+        np.testing.assert_array_equal(np.asarray(got["n"])[:2], total)
+
+
 def test_fused_sync_preserves_integer_leaves():
     """Fused sync must not round-trip int leaves through float32 (values
     above 2^24 would corrupt)."""

@@ -466,6 +466,7 @@ class _Constants:
     # as a SINGLE collective when the per-rank payload reaches this many
     # bytes (or on wait()/sync_all()). 0 disables coalescing entirely —
     # every submit dispatches immediately, the pre-fusion behavior.
+    # Eager dispatch only: it does not reach the engine's compiled step.
     fusion_buffer_bytes: int = 4 << 20
     # Minimum pending tensors for a flush to dispatch FUSED: below this,
     # packing overhead (the gather executable) exceeds the saved

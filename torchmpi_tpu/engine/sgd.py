@@ -379,12 +379,6 @@ class AllReduceSGDEngine:
                 "inserted by GSPMD, which has no wire-format hook)"
             )
         self.wire_dtype = wire_dtype
-        # coalescing decision captured once (the step function is compiled
-        # against it): fusion_buffer_bytes > 0 -> the sync path ships ONE
-        # flat-buffer psum per dtype group instead of one psum per leaf
-        from .. import constants as _constants
-
-        self._coalesce = _constants.get("fusion_buffer_bytes") > 0
         self.flops_per_sample = flops_per_sample
         self.accum_steps = accum_steps
         self.param_sharding = param_sharding
@@ -399,10 +393,13 @@ class AllReduceSGDEngine:
         self.profile_dir = profile_dir
         self.profile_window = profile_window
         self.hooks = hooks or {}
-        # a compressed wire needs the bucketed (flattened-buffer) sync
-        # path even in sync mode: quantization works on fused flat
-        # buffers, not leaf-shaped psums — one bucket keeps sync-mode
-        # step economics (a single collective)
+        # a compressed wire needs the bucketed sync path even in sync
+        # mode: quantization works on a flat buffer, not on leaf-shaped
+        # psums, and that path packs one where the wire engages — one
+        # bucket keeps sync-mode step economics (a single collective).
+        # At full wire the compiled step holds no flat buffer at all
+        # (fusion_buffer_bytes governs the eager FusionBuffer only): on
+        # the chip the packing cost twice the all-reduce it fed (PERF.md)
         wire_bucketed = wire_dtype in ("bf16", "int8")
         self.buckets = (
             GradientBuckets(params, num_buckets if mode == "async" else 1)
@@ -600,10 +597,6 @@ class AllReduceSGDEngine:
                 grads, self.buckets, _AXIS,
                 average=self.average_gradients,
                 wire_dtype=self.wire_dtype,
-            )
-        elif self._coalesce:
-            grads = mpinn.in_graph_synchronize_gradients_flat(
-                grads, _AXIS, average=self.average_gradients
             )
         else:
             grads = mpinn.in_graph_synchronize_gradients(

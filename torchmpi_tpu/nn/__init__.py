@@ -484,25 +484,40 @@ def _note_sync(reduced) -> None:
     ).set(len(reduced))
 
 
+def _sync_leaves(leaves, idxs, n, axis):
+    """Reduce the leaves ``idxs`` where they lie, in the shapes backward
+    made them: one ``lax.psum`` over the list (no buffer to copy into or
+    out of; grouping the all-reduces and placing them against backward
+    is XLA's) and, for a mean, each leaf divided by ``n`` in its own
+    dtype (None: a plain sum). Returns what went to the collective."""
+    sent = [leaves[i] for i in idxs]
+    with jax.named_scope(_names.SCOPE_REDUCE):
+        summed = lax.psum(sent, axis)
+    if n is not None:
+        with jax.named_scope(_names.SCOPE_UNPACK):
+            summed = [(s / n).astype(s.dtype) for s in summed]
+    for i, s in zip(idxs, summed):
+        leaves[i] = s
+    return sent
+
+
 def in_graph_synchronize_gradients(grads, axis: str = "mpi", average: bool = True):
     """psum every leaf over the mesh axis — the compiled analog of
-    synchronizeGradients, fused and scheduled by XLA."""
+    synchronizeGradients, fused and scheduled by XLA. No flat buffer:
+    integer leaves stay exact and no leaf is promoted."""
+    leaves, treedef = tree_util.tree_flatten(grads)
+    n = lax.psum(1, axis) if average else None
     with jax.named_scope(_names.SCOPE_GRAD_SYNC):
-        with jax.named_scope(_names.SCOPE_REDUCE):
-            summed = tree_util.tree_map(lambda g: lax.psum(g, axis), grads)
-        _note_sync(tree_util.tree_leaves(grads))
-        if average:
-            n = lax.psum(1, axis)
-            with jax.named_scope(_names.SCOPE_UNPACK):
-                summed = tree_util.tree_map(lambda g: g / n, summed)
-    return summed
+        _note_sync(_sync_leaves(leaves, range(len(leaves)), n, axis))
+    return tree_util.tree_unflatten(treedef, leaves)
 
 
 def _sync_flat_group(leaves, idxs, dtype, n, reduce_one):
     """Pack the leaves ``idxs`` of one dtype into a flat buffer, reduce it
     with ``reduce_one`` and cut it back into ``leaves``, each phase under
     its own scope (``n``: what to divide the sum by, None for a plain
-    sum); returns the buffer that went to the collective."""
+    sum); returns the buffer that went to the collective. Only a wire
+    format that quantizes flat buffers needs this."""
     with jax.named_scope(_names.SCOPE_PACK):
         flats = [jnp.reshape(leaves[i], (-1,)) for i in idxs]
         splits = np.cumsum([f.shape[0] for f in flats])[:-1]
@@ -514,69 +529,45 @@ def _sync_flat_group(leaves, idxs, dtype, n, reduce_one):
             buf = (buf / n).astype(dtype)
         for part, i in zip(jnp.split(buf, splits), idxs):
             leaves[i] = jnp.reshape(part, leaves[i].shape)
-    return cat
-
-
-def in_graph_synchronize_gradients_flat(
-    grads, axis: str = "mpi", average: bool = True,
-):
-    """Coalesced in-graph gradient sync: ONE flat-buffer psum per dtype
-    group instead of one psum per leaf. The per-leaf variant hands XLA
-    O(#leaves) collectives to schedule; on the latency-bound path each
-    carries its own launch cost, so the flat buffer is the in-graph twin
-    of the eager :class:`FusionBuffer` (arXiv:1810.11112's coalescing
-    lever). Grouping by dtype keeps integer leaves exact and
-    mixed-precision trees un-promoted. Numerics are identical to the
-    per-leaf psum: concatenation commutes with the elementwise sum."""
-    leaves, treedef = tree_util.tree_flatten(grads)
-    n = lax.psum(1, axis) if average else None
-    by_dtype: Dict = {}
-    for i, l in enumerate(leaves):
-        by_dtype.setdefault(jnp.result_type(l), []).append(i)
-    reduced = []
-    with jax.named_scope(_names.SCOPE_GRAD_SYNC):
-        for dtype, idxs in by_dtype.items():
-            reduced.append(_sync_flat_group(
-                leaves, idxs, dtype, n, lambda c: lax.psum(c, axis)))
-    _note_sync(reduced)
-    return tree_util.tree_unflatten(treedef, leaves)
+    return [cat]
 
 
 def in_graph_synchronize_gradients_bucketed(
     grads, buckets: GradientBuckets, axis: str = "mpi", average: bool = True,
     wire_dtype: Optional[str] = None,
 ):
-    """Bucketed psum: one collective per bucket (per dtype) so XLA's
-    async-collective scheduler can overlap buckets with remaining compute —
-    the in-graph analog of registerAsyncMPIBackward's per-layer overlap.
-    Leaves are grouped by dtype within each bucket so mixed-precision
-    gradients (bf16 weights + f32 norms) keep their dtypes exactly.
+    """Bucketed psum: the leaves of one bucket reduced together (per
+    dtype), bucket by bucket, so XLA's async-collective scheduler can
+    overlap buckets with remaining compute — the in-graph analog of
+    registerAsyncMPIBackward's per-layer overlap. At full precision a
+    bucket's leaves go to ``lax.psum`` as they lie, dtypes kept exactly.
 
-    ``wire_dtype`` ('bf16' | 'int8') replaces the fused psum with the
-    compressed-wire ppermute ring for f32 buckets above the tuned cutoff
+    ``wire_dtype`` ('bf16' | 'int8') replaces the psum with the
+    compressed-wire ppermute ring for f32 groups above the tuned cutoff
     (block-quantized send, f32 accumulate) — the in-graph path of the
-    EQuARX-style wire format; other buckets keep the psum."""
+    EQuARX-style wire format. Quantization works on a flat buffer, so
+    such a group, and only such a group, is packed into one."""
     from ..collectives import primitives as _prim
 
     leaves = list(tree_util.tree_leaves(grads))
     n = lax.psum(1, axis) if average else None
     reduced = []
-
-    def reduce_one(cat):
-        if _prim.wire_engages(wire_dtype, cat.dtype, int(cat.shape[0])):
-            return _prim.ring_allreduce(cat, axis, wire_dtype=wire_dtype)
-        return lax.psum(cat, axis)
-
     for b in range(buckets.num_buckets):
         by_dtype: Dict = {}
         for i in buckets.buckets[b]:
             by_dtype.setdefault(jnp.result_type(leaves[i]), []).append(i)
-        # the bucket's index in the name: tm.grad_sync/b<b>/pack, ...
+        # the bucket's index in the name: tm.grad_sync/b<b>/reduce, ...
         with jax.named_scope(_names.SCOPE_GRAD_SYNC), \
                 jax.named_scope(f"b{b}"):
             for dtype, idxs in by_dtype.items():
-                reduced.append(_sync_flat_group(
-                    leaves, idxs, dtype, n, reduce_one))
+                nelem = sum(int(np.prod(leaves[i].shape)) for i in idxs)
+                if _prim.wire_engages(wire_dtype, dtype, nelem):
+                    reduced += _sync_flat_group(
+                        leaves, idxs, dtype, n,
+                        lambda c: _prim.ring_allreduce(
+                            c, axis, wire_dtype=wire_dtype))
+                else:
+                    reduced += _sync_leaves(leaves, idxs, n, axis)
     _note_sync(reduced)
     return tree_util.tree_unflatten(buckets.treedef, leaves)
 

@@ -548,7 +548,9 @@ def tune_fusion_threshold(
     and set the constant to the fastest. Coalescing must EARN its flush
     boundary: a tiny capacity flushes mid-set (several fused dispatches),
     a huge one defers everything to the drain — the measurement, not a
-    guess, picks where the knob sits on this host.
+    guess, picks where the knob sits on this host. The knob governs the
+    eager dispatch alone: the engine's compiled step holds no flat
+    buffer at full precision, whatever this is set to.
 
     Requires unfrozen constants even with ``apply=False``: each candidate
     is measured by temporarily setting ``fusion_buffer_bytes``."""
