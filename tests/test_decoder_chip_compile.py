@@ -1,0 +1,101 @@
+"""The benchmark configuration's whole training step, compiled at its real
+size for a v5e that is described and not attached (the TPU's compiler is
+installed here): what the chip's compiler would refuse, it refuses here.
+One file, and the topology is described inside a fixture, so that only the
+worker that runs this file loads the TPU's library."""
+
+import json
+import math
+import re
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+ROOT = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT))
+CONFIG = "smallthinker-21b-a3b"
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps it from describing
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_cells_step_fits_the_chip_and_holds_no_dispatch_tensor(one_chip):
+    from benchmark import configs
+
+    cfg = configs.load(CONFIG)
+    built = configs.build(CONFIG, cfg)
+    batch, seq = cfg["per_chip_batch"], cfg["sequence_length"]
+
+    def step(params, opt_state, state, tokens):
+        (loss, state), grads = jax.value_and_grad(
+            built.loss_fn, has_aux=True)(params, state, tokens)
+        updates, opt_state = built.optimizer.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, state, loss
+
+    params, state = jax.eval_shape(built.state_at, jax.random.PRNGKey(0))
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one_chip)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+            place(params), place(jax.eval_shape(built.optimizer.init, params)),
+            place(state), (tokens, tokens)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    count = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    assert 370e6 < count < 371e6  # 4 layers of 68.3 M + 97.2 M of vocabulary
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    # parameters and AdamW's moments are 12 B each; the temporaries (the
+    # float32 logits, one pair of attention blocks, the routed rows)
+    # measured 3.44 GiB here: well inside the chip's 15.75 GiB
+    assert memory.argument_size_in_bytes > 12 * count
+    assert held < 9 * 2**30, memory
+    text = compiled.as_text()
+    # the grouped products are XLA's kernel, not a product an expert
+    assert "ragged-dot" in text
+    # no [tokens, experts, capacity] tensor, and no t x t scores: every
+    # array's element count stays under the float32 logits'
+    tokens_a_step, experts = batch * seq, cfg["model"]["router_outputs"]
+    largest = max(
+        math.prod(int(d) for d in dims.split(","))
+        for dims in re.findall(r"(?:f32|bf16|s32|pred)\[([\d,]+)\]", text))
+    assert largest <= tokens_a_step * cfg["vocab_size"], largest
+    # ep.moe_dispatch_combine's default capacity at these sizes
+    capacity = 2 * -(-cfg["moe_num_active_primary_experts"] * tokens_a_step
+                     // experts)
+    assert largest < tokens_a_step * experts * capacity / 10
+
+
+def test_the_configuration_is_a_cell_of_the_benchmark():
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    cell = next(c for c in spec["workloads"] if c["config"] == CONFIG)
+    assert cell == {**cell, "name": CONFIG + ".stream.x1",
+                    "traffic": "stream", "chips": 1}
+    assert len(cell["why"]) <= 200
+    new = [m for m in spec["per_layer"] if m["workloads"] == [cell["name"]]]
+    assert sorted(m["name"] for m in new) == [
+        "attn_full_ms_per_step", "attn_window_ms_per_step",
+        "moe_experts_ms_per_step", "moe_grouped_rows_per_step",
+        "moe_held_route_share", "moe_max_over_mean_load",
+        "moe_route_ms_per_step"]
+    for m in new:
+        assert (ROOT / "benchmark" / "layer_metrics"
+                / f"{m['name']}.py").is_file()

@@ -423,6 +423,9 @@ def test_supervisor_actions_land_in_the_flight_recorder():
     constants.set("supervisor_hysteresis_windows", 1)
     telemetry.enable()
     _flight.enable()
+    # the recorder is the process's: whatever file ran before this one on
+    # the same worker may have left supervisor entries in it
+    _flight.recorder.reset()
     try:
         sup = mk(Recorder())
         sup.observe(doc("rank-dead", dead=[2]), now=0.0)

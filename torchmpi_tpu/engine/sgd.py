@@ -1483,6 +1483,7 @@ class AllReduceSGDEngine:
                     )
             with _ring.span(_names.ENGINE_EPOCH_END):
                 losses_h = np.asarray(jax.device_get(losses))
+                self._observe_state()
                 state["loss"] = float(losses_h[-1])
                 state["losses"].append(float(losses_h.mean()))
                 if epoch_callback is not None:
@@ -1498,6 +1499,16 @@ class AllReduceSGDEngine:
         return state
 
     # ------------------------------------------------------------------
+    def _observe_state(self) -> None:
+        """Hand the model state to ``loss_fn.observe_state``, where a loss
+        function has one (``models.make_moe_lm_loss_fn``: what the step
+        measured of its routing becomes gauges). Called only where an
+        epoch's loss has just been read: the step that made the state has
+        ended, so the read waits for nothing."""
+        observe = getattr(self.loss_fn, "observe_state", None)
+        if observe is not None and self.model_state is not None:
+            observe(jax.device_get(self.model_state))
+
     def _hook(self, name: str, state: Dict[str, Any]) -> None:
         fn = self.hooks.get(name)
         if fn is not None:
@@ -1605,6 +1616,7 @@ class AllReduceSGDEngine:
                     )
                 with _ring.span(_names.ENGINE_EPOCH_END):
                     state["losses"].append(float(jax.device_get(loss)))
+                    self._observe_state()
                     if telemetry_on:
                         # the loss read above waited for the epoch's last
                         # step: the one place this loop may set a rate

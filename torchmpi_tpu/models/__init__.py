@@ -1,3 +1,9 @@
+from .decoder import (
+    MoEDecoder,
+    MoEDecoderBlock,
+    init_moe_state,
+    make_moe_lm_loss_fn,
+)
 from .mlp import MLP6
 from .mnist import (
     LeNet,
@@ -30,6 +36,10 @@ __all__ = [
     "ResNet50",
     "LongContextTransformer",
     "RingAttentionBlock",
+    "MoEDecoder",
+    "MoEDecoderBlock",
+    "make_moe_lm_loss_fn",
+    "init_moe_state",
     "cross_entropy_loss",
     "accuracy",
     "make_loss_fn",

@@ -18,6 +18,14 @@ import jax.numpy as jnp
 from ..parallel.ring_attention import full_self_attention, ring_self_attention
 
 
+def lm_cross_entropy(logits, targets):
+    """Mean next-token cross-entropy over every position, the log-softmax
+    in float32: the one loss of every language model here."""
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+    picked = jnp.take_along_axis(logp, targets[..., None], axis=-1)
+    return -jnp.mean(picked)
+
+
 def make_lm_loss_fn(model: fnn.Module):
     """Next-token loss for the engine: ``loss_fn(params, batch)`` with
     ``batch = (tokens_in, tokens_target)``, both ``[B, T]`` int32. Mean
@@ -27,10 +35,8 @@ def make_lm_loss_fn(model: fnn.Module):
 
     def loss_fn(params, batch):
         tokens, targets = batch
-        logits = model.apply({"params": params}, tokens)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        picked = jnp.take_along_axis(logp, targets[..., None], axis=-1)
-        return -jnp.mean(picked)
+        return lm_cross_entropy(
+            model.apply({"params": params}, tokens), targets)
 
     return loss_fn
 

@@ -1,22 +1,28 @@
-from .ep import moe_dispatch_combine, moe_load_stats
+from .ep import moe_dispatch_combine, moe_load_stats, moe_local_experts
 from .mesh import make_parallel_mesh
 from .pp import (
     pipeline_1f1b_value_and_grad,
     pipeline_forward,
     pipeline_loss_fn,
 )
-from .ring_attention import full_self_attention, ring_self_attention
+from .ring_attention import (
+    blocked_self_attention,
+    full_self_attention,
+    ring_self_attention,
+)
 from .tp import MPLinear, MPLinearOutputSplit, shard_input_features
 
 __all__ = [
     "make_parallel_mesh",
     "moe_dispatch_combine",
     "moe_load_stats",
+    "moe_local_experts",
     "pipeline_1f1b_value_and_grad",
     "pipeline_forward",
     "pipeline_loss_fn",
     "ring_self_attention",
     "full_self_attention",
+    "blocked_self_attention",
     "MPLinear",
     "MPLinearOutputSplit",
     "shard_input_features",

@@ -17,15 +17,31 @@ is a capability extension in the modern taxonomy, built TPU-first:
   all-to-all EP traffic in modern MoE stacks;
 - everything is differentiable: gradients flow through the gate values
   and the expert parameters (the dispatch mask is constant wrt inputs).
+
+Two expert layers, for two layouts:
+
+- :func:`moe_dispatch_combine`: ONE expert on each device of the axis,
+  a fixed capacity, routes past it dropped, an ``all_to_all`` each way.
+- :func:`moe_local_experts`: MANY experts held on this device, which is
+  told which of all ``E`` they are; it routes every token over all ``E``,
+  keeps every route to an expert it holds (no capacity, nothing dropped,
+  no ``[T, E, C]`` tensor) and computes its own experts' part of the
+  result by grouped matrix products. What the experts held elsewhere
+  would add is not computed here: across devices that part arrives by an
+  exchange this function does not make.
 """
 
 from __future__ import annotations
 
-from typing import Callable
+from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+
+from .. import telemetry as _telemetry
+from ..telemetry import names as _names
 
 
 def moe_dispatch_combine(
@@ -141,3 +157,155 @@ def moe_load_stats(router_logits, axis: str = "ep", top_k: int = 1):
     me = lax.pmean(jnp.mean(gates, axis=0), axis)
     ce = lax.pmean(jnp.mean(first, axis=0), axis)
     return tokens_per_expert, E * jnp.sum(me * ce)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation ``perm`` of the rows whose inverse is
+    known: backward is the gather ``g[inverse]``, not a scatter."""
+    return x[perm]
+
+
+_permute_rows.defvjp(
+    lambda x, perm, inverse: (x[perm], (perm, inverse)),
+    lambda saved, g: (g[saved[1]], None, None),
+)
+
+
+def moe_local_experts(
+    x,
+    router_logits,
+    top_k: int,
+    w_gate,
+    w_up,
+    w_down,
+    held: Sequence[int],
+    activation: Callable = jax.nn.relu,
+):
+    """This device's experts' part of a gated-feed-forward expert layer,
+    with no route dropped.
+
+    Parameters
+    ----------
+    x : ``[T, d]`` the tokens (any float dtype; the products run in it).
+    router_logits : ``[T, E]`` float32 scores over ALL ``E`` experts.
+    top_k : experts a token; its weights are the softmax over its ``top_k``
+        largest logits (a softmax over all ``E`` renormalised over the
+        chosen is the same numbers).
+    w_gate, w_up : ``[held, d, f]``; w_down : ``[held, f, d]``: the stacked
+        parameters of the experts held here, expert ``held[i]`` at ``i``.
+    held : the ids, among the ``E``, of the experts held here (static).
+    activation : the gate's (ReLU: ReGLU).
+
+    Every route to a held expert is kept: the routes are ordered by
+    expert, the three products of ``activation(x W_g) * (x W_u)) W_d`` run
+    as grouped products over the held experts' row groups
+    (``lax.ragged_dot``), and the weighted rows are added back to their
+    tokens. Shapes are static and sized for the worst case, all ``T *
+    top_k`` routes landing here; rows of routes to experts held elsewhere
+    sort behind every group, belong to none, and count for nothing (they
+    are set to zero on either side of every grouped product).
+    Bookkeeping is int32. Returns ``(y [T, d], load [held] float32)``:
+    the tokens each held expert received.
+    """
+    T, d = x.shape
+    E = router_logits.shape[-1]
+    k, n = int(top_k), len(held)
+    if router_logits.shape != (T, E) or not 1 <= k <= E:
+        raise ValueError(
+            f"router_logits must be [T={T}, E>={k}], got "
+            f"{tuple(router_logits.shape)} with top_k={k}")
+    if len(set(held)) != n or not all(0 <= e < E for e in held):
+        raise ValueError(f"held must be distinct ids under {E}, got {held}")
+    if not (w_gate.shape == w_up.shape == (n, d, w_gate.shape[-1])
+            and w_down.shape == (n, w_gate.shape[-1], d)):
+        raise ValueError(
+            f"expected [{n}, {d}, f], [{n}, {d}, f], [{n}, f, {d}]; got "
+            f"{w_gate.shape}, {w_up.shape}, {w_down.shape}")
+    R = T * k
+    with jax.named_scope(_names.SCOPE_MOE_ROUTE):
+        top, chosen = lax.top_k(router_logits.astype(jnp.float32), k)
+        weight = jax.nn.softmax(top, axis=-1)  # [T, k] float32
+        # slot of each route: its expert's place among the held, or n
+        slot_of = np.full((E,), n, np.int32)
+        slot_of[list(held)] = np.arange(n, dtype=np.int32)
+        slot = jnp.asarray(slot_of)[chosen].reshape(R)
+        order = jnp.argsort(slot, stable=True).astype(jnp.int32)
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        sizes = jnp.sum(
+            slot[:, None] == jnp.arange(n, dtype=jnp.int32)[None, :],
+            axis=0, dtype=jnp.int32)  # [held] rows of each group
+        # sorted as the rows are: which of them belong to a group at all,
+        # and each row's route's weight
+        held_row = _permute_rows(
+            (slot < n).reshape(R, 1), order, inverse)
+        gate_row = _permute_rows(weight.reshape(R, 1), order, inverse)
+        rows = _permute_rows(jnp.repeat(x, k, axis=0), order, inverse)
+
+    def own(a):
+        """``a`` with the rows of no group set to zero, forward and (the
+        select's transpose) backward. A grouped product writes its groups'
+        rows and leaves the others as it found them, in its result and in
+        the gradient it hands back alike: on the chip that is whatever the
+        buffer held, NaN included, and 0 x NaN is NaN. So such rows are
+        selected out wherever they would meet a product or a sum."""
+        return jnp.where(held_row, a, 0)
+
+    with jax.named_scope(_names.SCOPE_MOE_EXPERTS):
+        dt = x.dtype
+        rows = own(rows)
+        hidden = own(
+            activation(lax.ragged_dot(rows, w_gate.astype(dt), sizes))
+            * lax.ragged_dot(rows, w_up.astype(dt), sizes))
+        # the route's weight goes onto the narrow side of the down
+        # projection (w (h W_d) = (w h) W_d): f columns a row, not d
+        hidden = own(hidden * gate_row.astype(dt))
+        rows = own(lax.ragged_dot(hidden, w_down.astype(dt), sizes))
+    with jax.named_scope(_names.SCOPE_MOE_COMBINE):
+        routes = _permute_rows(rows, inverse, order).reshape(T, k, d)
+        y = jnp.sum(routes, axis=1, dtype=jnp.float32).astype(dt)
+    return y, sizes.astype(jnp.float32)
+
+
+def note_expert_layers(tokens: int, top_k: int, layers: int,
+                       held: int) -> None:
+    """Publish what one step's expert layers route, from static shapes:
+    called by a model while its forward pass is traced, so the gauges
+    describe the step most recently traced (as ``nn._note_sync``'s do).
+    ``tokens`` are this rank's; every route is sized for, so the rows of
+    the grouped products are the routes."""
+    m = _telemetry.metrics
+    routes = int(tokens) * int(top_k) * int(layers)
+    m.gauge(
+        "tm_moe_routes_per_step",
+        "routes each rank makes per step: tokens x top_k x expert layers "
+        "(static shapes of the step most recently traced)",
+    ).set(routes)
+    m.gauge(
+        "tm_moe_grouped_rows_per_step",
+        "rows each rank's grouped expert products are sized for per step: "
+        "every route, wherever its expert is held",
+    ).set(routes)
+    m.gauge(
+        "tm_moe_experts_held",
+        "experts this rank holds in each expert layer",
+    ).set(int(held))
+
+
+def note_expert_load(load) -> None:
+    """Publish a step's measured routing from ``load`` (host array,
+    ``[layers, held]``: the tokens each held expert received): the routes
+    kept here, and the worst layer's largest load over its mean load."""
+    load = np.asarray(load, np.float64)
+    m = _telemetry.metrics
+    m.gauge(
+        "tm_moe_held_routes_last_step",
+        "routes to experts held on this rank in the last step read, "
+        "summed over layers",
+    ).set(float(load.sum()))
+    mean = load.mean(axis=-1)
+    m.gauge(
+        "tm_moe_max_over_mean_load",
+        "largest held expert's tokens over the mean held expert's, of "
+        "the layer where that is worst, in the last step read",
+    ).set(float(np.max(load.max(axis=-1) / np.maximum(mean, 1e-9))))

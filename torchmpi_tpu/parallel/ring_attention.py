@@ -53,6 +53,21 @@ def _block_attn(q, k, v, bias):
     return o, m, l
 
 
+def _merge(acc, blk):
+    """Streaming-softmax merge of two partial results ``(o, m, l)``: the
+    numerators ``[b, q, h, d]`` and denominators ``[b, h, q]`` brought to
+    the larger of the two running maxima."""
+    (o, m, l), (ob, mb, lb) = acc, blk
+    m_new = jnp.maximum(m, mb)
+    alpha = jnp.exp(m - m_new)
+    beta = jnp.exp(mb - m_new)
+    o_new = (
+        o * alpha.transpose(0, 2, 1)[..., None]
+        + ob * beta.transpose(0, 2, 1)[..., None]
+    )
+    return o_new, m_new, l * alpha + lb * beta
+
+
 def ring_self_attention(
     q,
     k,
@@ -113,16 +128,8 @@ def ring_self_attention(
         if causal:
             mask = q_pos[:, None] >= k_pos[None, :]  # [q, k]
             bias = jnp.where(mask, 0.0, NEG_INF)[None, None, :, :]
-        ob, mb, lb = _block_attn(q, kb, vb, bias)
-        # streaming softmax merge
-        m_new = jnp.maximum(m, mb)
-        alpha = jnp.exp(m - m_new)
-        beta = jnp.exp(mb - m_new)
-        l_new = l * alpha + lb * beta
-        o_new = (
-            o * alpha.transpose(0, 2, 1)[..., None]
-            + ob * beta.transpose(0, 2, 1)[..., None]
-        )
+        o_new, m_new, l_new = _merge(
+            (o, m, l), _block_attn(q, kb, vb, bias))
         # rotate k/v to the next rank (skip the final, unused rotation is
         # harmless and keeps the loop body uniform)
         kb = lax.ppermute(kb, axis, perm)
@@ -146,3 +153,174 @@ def full_self_attention(q, k, v, causal: bool = False):
         s = jnp.where(mask[None, None], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", w, v)
+
+
+# ---------------------------------------------------------------------------
+# blocked attention on one device: causal band, grouped KV heads
+# ---------------------------------------------------------------------------
+
+
+def _first_block(i, block: int, window: Optional[int]):
+    """The first block of keys that holds a key visible to any query of
+    query block ``i`` (the last is ``i`` itself): blocks wholly behind the
+    window are not visited."""
+    if window is None:
+        return 0
+    return jnp.maximum(0, (i * block - (window - 1)) // block)
+
+
+def _band_bias(i, j, block: int, window: Optional[int], groups: int):
+    """The additive mask ``[groups * block, block]`` of query block ``i``
+    against key block ``j`` (the query rows repeat once for each head of a
+    KV group, head-major): key ``b`` is visible to query ``a`` iff ``0 <=
+    a - b`` and, with a window, ``a - b < window``."""
+    a = i * block + jnp.arange(block)[:, None]
+    b = j * block + jnp.arange(block)[None, :]
+    seen = b <= a
+    if window is not None:
+        seen = seen & (a - b < window)
+    return jnp.tile(jnp.where(seen, 0.0, NEG_INF), (groups, 1))
+
+
+def _fold(x, groups):
+    """``[b, t, hkv * g, d] -> [b, g * t, hkv, d]``: the ``g`` query heads
+    that share a KV head become further query rows of that head, so that a
+    block routine written for equal head counts serves grouped heads and K
+    and V are never repeated."""
+    b, t, h, d = x.shape
+    x = x.reshape(b, t, h // groups, groups, d)
+    return x.transpose(0, 3, 1, 2, 4).reshape(b, groups * t, h // groups, d)
+
+
+def _unfold(x, groups):
+    b, gt, hkv, d = x.shape
+    x = x.reshape(b, groups, gt // groups, hkv, d)
+    return x.transpose(0, 2, 3, 1, 4).reshape(b, gt // groups, hkv * groups, d)
+
+
+def _rows(x, i, block: int):
+    """Block ``i`` of ``x``'s sequence axis (axis 1)."""
+    return lax.dynamic_slice_in_dim(x, i * block, block, axis=1)
+
+
+def _blocked_forward(q, k, v, window, block):
+    """(output ``[b, t, hq, d]`` in ``q``'s dtype; the log-sum-exp of each
+    query's scores, ``[blocks, b, hkv, g * block]``). ``t`` is a multiple
+    of ``block`` here. Loops, not unrolled pairs: one pair's scores live
+    at a time, and the program stays small."""
+    b, t, hq, d = q.shape
+    g = hq // k.shape[2]
+
+    def one(i):
+        qf = _fold(_rows(q, i, block), g)
+
+        def visit(j, acc):
+            blk = _block_attn(qf, _rows(k, j, block), _rows(v, j, block),
+                              _band_bias(i, j, block, window, g))
+            return _merge(acc, blk)
+
+        hkv = k.shape[2]
+        acc = (jnp.zeros((b, g * block, hkv, d), jnp.float32),
+               jnp.full((b, hkv, g * block), NEG_INF, jnp.float32),
+               jnp.zeros((b, hkv, g * block), jnp.float32))
+        o, m, l = lax.fori_loop(_first_block(i, block, window), i + 1,
+                                visit, acc)
+        out = _unfold(o / l.transpose(0, 2, 1)[..., None], g)
+        return out.astype(q.dtype), m + jnp.log(l)
+
+    outs, lses = lax.map(one, jnp.arange(t // block))
+    return jnp.moveaxis(outs, 0, 1).reshape(b, t, hq, d), lses
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _blocked(q, k, v, window, block):
+    return _blocked_forward(q, k, v, window, block)[0]
+
+
+def _blocked_fwd(q, k, v, window, block):
+    out, lses = _blocked_forward(q, k, v, window, block)
+    return out, (q, k, v, out, lses)
+
+
+def _blocked_bwd(window, block, saved, dout):
+    """Backward from the saved output and log-sum-exps: each block pair's
+    probabilities are made again from its scores, so nothing of size
+    ``t x t`` is ever kept."""
+    q, k, v, out, lses = saved
+    f32 = jnp.float32
+    g = q.shape[2] // k.shape[2]
+    scale = 1.0 / math.sqrt(q.shape[-1])
+
+    def per_query_block(i, grads):
+        qf = _fold(_rows(q, i, block), g).astype(f32)
+        do = _fold(_rows(dout, i, block), g).astype(f32)
+        delta = jnp.sum(
+            do * _fold(_rows(out, i, block), g).astype(f32), axis=-1
+        ).transpose(0, 2, 1)  # [b, h, q]
+        lse = lses[i]
+
+        def visit(j, carry):
+            dqf, dk, dv = carry
+            kb = _rows(k, j, block).astype(f32)
+            vb = _rows(v, j, block).astype(f32)
+            s = jnp.einsum("bqhd,bkhd->bhqk", qf, kb) * scale
+            s = s + _band_bias(i, j, block, window, g)
+            p = jnp.exp(s - lse[..., None])
+            dp = jnp.einsum("bqhd,bkhd->bhqk", do, vb)
+            ds = p * (dp - delta[..., None]) * scale
+            dqf = dqf + jnp.einsum("bhqk,bkhd->bqhd", ds, kb)
+            at = j * block
+            dk = lax.dynamic_update_slice_in_dim(
+                dk, _rows(dk, j, block)
+                + jnp.einsum("bhqk,bqhd->bkhd", ds, qf), at, axis=1)
+            dv = lax.dynamic_update_slice_in_dim(
+                dv, _rows(dv, j, block)
+                + jnp.einsum("bhqk,bqhd->bkhd", p, do), at, axis=1)
+            return dqf, dk, dv
+
+        dq, dk, dv = grads
+        dqf, dk, dv = lax.fori_loop(
+            _first_block(i, block, window), i + 1, visit,
+            (jnp.zeros_like(qf), dk, dv))
+        dq = lax.dynamic_update_slice_in_dim(
+            dq, _unfold(dqf, g).astype(dq.dtype), i * block, axis=1)
+        return dq, dk, dv
+
+    dq, dk, dv = lax.fori_loop(
+        0, q.shape[1] // block, per_query_block,
+        (jnp.zeros_like(q), jnp.zeros(k.shape, f32), jnp.zeros(v.shape, f32)))
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+_blocked.defvjp(_blocked_fwd, _blocked_bwd)
+
+
+def blocked_self_attention(q, k, v, window: Optional[int] = None,
+                           block: int = 1024):
+    """Exact causal self-attention on one device that never holds a
+    ``t x t`` tensor: blocks of ``block`` queries against blocks of keys
+    through ``_block_attn`` and the streaming-softmax merge of the ring.
+
+    ``q`` is ``[batch, t, heads, head_dim]``; ``k`` and ``v`` may have fewer
+    heads (grouped KV heads: query head ``n`` reads KV head ``n // (heads //
+    kv_heads)``), and are not repeated. Key ``j`` is visible to query ``i``
+    iff ``0 <= i - j`` and, with ``window``, ``i - j < window``. Block
+    pairs wholly outside that band are skipped, not masked (they are
+    outside the loops' bounds); ``t`` need not be a multiple of ``block``
+    (it is padded to one). Backward makes each pair's probabilities again
+    from the saved output and log-sum-exp."""
+    if q.shape[2] % k.shape[2] or k.shape != v.shape:
+        raise ValueError(
+            f"query heads {q.shape[2]} must be a multiple of the KV heads "
+            f"{k.shape[2]}, and k and v alike (got {k.shape}, {v.shape})")
+    if window is not None and window < 1:
+        raise ValueError(f"window must be positive, got {window}")
+    t = q.shape[1]
+    block = min(int(block), t)
+    pad = -t % block
+    if pad:
+        # keys past the end lie in every real query's future; the rows of
+        # the queries past the end are cut off again
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for a in (q, k, v))
+    return _blocked(q, k, v, window, block)[:, :t]

@@ -83,6 +83,20 @@ SCOPE_NAMES = (
     SCOPE_RESIDENT_GATHER,
 )
 
+# -- named scopes a model opens inside its forward pass, so nested under
+# SCOPE_FWD_BWD (backward's operations carry them inside jax's
+# ``transpose(jvp(...))`` wrappers): models/decoder.py, parallel/ep.py
+SCOPE_ATTN_FULL = "tm.attn.full"      # rotary or none, blocked attention
+SCOPE_ATTN_WINDOW = "tm.attn.window"  # the same within a sliding window
+SCOPE_MOE_ROUTE = "tm.moe.route"      # top-k, softmax, ordering, dispatch
+SCOPE_MOE_EXPERTS = "tm.moe.experts"  # the grouped products
+SCOPE_MOE_COMBINE = "tm.moe.combine"  # weighted rows back to their tokens
+
+MODEL_SCOPE_NAMES = (
+    SCOPE_ATTN_FULL, SCOPE_ATTN_WINDOW, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
+    SCOPE_MOE_COMBINE,
+)
+
 
 class SpanRecord(NamedTuple):
     name: str
