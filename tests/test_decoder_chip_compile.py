@@ -35,6 +35,7 @@ def one_chip():
 
 def test_the_cells_step_fits_the_chip_and_holds_no_dispatch_tensor(one_chip):
     from benchmark import configs
+    from torchmpi_tpu.telemetry import names
 
     cfg = configs.load(CONFIG)
     built = configs.build(CONFIG, cfg)
@@ -64,24 +65,73 @@ def test_the_cells_step_fits_the_chip_and_holds_no_dispatch_tensor(one_chip):
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     # parameters and AdamW's moments are 12 B each; the temporaries (the
-    # float32 logits, one pair of attention blocks, the routed rows)
-    # measured 3.44 GiB here: well inside the chip's 15.75 GiB
+    # float32 logits, the routed rows; attention's kernels keep their
+    # scores in VMEM; their largest own array is dQ's 8 parts, 1.75 GiB)
+    # measured 3.45 GiB here: well inside the chip's 15.75 GiB
     assert memory.argument_size_in_bytes > 12 * count
     assert held < 9 * 2**30, memory
     text = compiled.as_text()
     # the grouped products are XLA's kernel, not a product an expert
     assert "ragged-dot" in text
+    # a lowering for the TPU takes the fused attention kernels, though this
+    # process's backend is the CPU: in each of the 4 layers a forward, the
+    # block's recomputed forward and one backward, under the name the
+    # benchmark's reader looks for
+    kernels = re.findall(r"%(\w+?)[.\d]* = [^\n]*tpu_custom_call", text)
+    assert len(kernels) == 3 * cfg["num_hidden_layers"], kernels
+    assert all(k.startswith(names.ATTN_KERNEL_EVENT) for k in kernels)
+    # ... and no block pair's scores are left to cross HBM: neither the
+    # loops' [batch, KV heads, group x block, block] nor any array of four
+    # or more axes whose last two are both half a tile (512) or more
+    assert "f32[2,4,7168,1024]" not in text
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text)}
+    assert not [s for s in shapes if len(s) >= 4 and min(s[-2:]) >= 512]
     # no [tokens, experts, capacity] tensor, and no t x t scores: every
-    # array's element count stays under the float32 logits'
+    # array's element count stays under the float32 logits', but for the
+    # attention backward's dQ, which the one kernel hands back as a part
+    # from each tile of keys (8 of 1,024: 1.5 times the logits) for XLA
+    # to sum
     tokens_a_step, experts = batch * seq, cfg["model"]["router_outputs"]
+    dq_parts = (seq // 1024) * tokens_a_step * (
+        cfg["num_attention_heads"] * cfg["head_dim"])
     largest = max(
-        math.prod(int(d) for d in dims.split(","))
-        for dims in re.findall(r"(?:f32|bf16|s32|pred)\[([\d,]+)\]", text))
+        {math.prod(int(d) for d in dims.split(","))
+         for dims in re.findall(r"(?:f32|bf16|s32|pred)\[([\d,]+)\]", text)}
+        - {dq_parts})
     assert largest <= tokens_a_step * cfg["vocab_size"], largest
     # ep.moe_dispatch_combine's default capacity at these sizes
     capacity = 2 * -(-cfg["moe_num_active_primary_experts"] * tokens_a_step
                      // experts)
     assert largest < tokens_a_step * experts * capacity / 10
+
+
+@pytest.mark.parametrize("head_dim,fused", [(128, True), (256, True),
+                                            (64, False)])
+def test_a_tpu_lowering_takes_the_kernels_where_the_heads_allow(
+        one_chip, head_dim, fused):
+    """The choice is the lowering's: from this CPU process, a program
+    lowered for the described chip holds the kernels, forward and
+    backward, for heads of a multiple of 128, and the loops otherwise."""
+    from torchmpi_tpu.parallel import blocked_self_attention
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(blocked_self_attention(
+            *a, window=300, block=256).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    q = jax.ShapeDtypeStruct((1, 1000, 4, head_dim), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 1000, 2, head_dim), jnp.bfloat16,
+                             sharding=one_chip)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(grads).lower(q, k, k).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert (text.count("tpu_custom_call") == 2) == fused  # forward, backward
+    assert ("while(" in text) != fused
 
 
 def test_the_configuration_is_a_cell_of_the_benchmark():
@@ -92,7 +142,8 @@ def test_the_configuration_is_a_cell_of_the_benchmark():
     assert len(cell["why"]) <= 200
     new = [m for m in spec["per_layer"] if m["workloads"] == [cell["name"]]]
     assert sorted(m["name"] for m in new) == [
-        "attn_full_ms_per_step", "attn_window_ms_per_step",
+        "attn_full_ms_per_step", "attn_kernel_ms_per_step",
+        "attn_kernel_share", "attn_window_ms_per_step",
         "moe_experts_ms_per_step", "moe_grouped_rows_per_step",
         "moe_held_route_share", "moe_max_over_mean_load",
         "moe_route_ms_per_step"]

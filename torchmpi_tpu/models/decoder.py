@@ -7,7 +7,9 @@ Built from a layer pattern: ``window_layout[l % period]`` says whether layer
 ``l`` attends within ``window`` (else over the whole causal prefix), and
 ``rope_layout[l % period]`` whether its queries and keys are rotated (else
 the layer has no positional encoding at all). Attention is
-``parallel.ring_attention.blocked_self_attention`` (no ``t x t`` tensor);
+``parallel.ring_attention.blocked_self_attention`` (no ``t x t`` tensor; on
+a TPU with heads of a multiple of 128 in fused kernels, else in loops of
+XLA operations: the call decides, the model sets nothing);
 the experts are ``parallel.ep.moe_local_experts`` (dropless, told which of
 all the experts it holds: what the others would add is left out, the part
 an exchange across devices would bring). Parameters are float32, the
@@ -34,7 +36,10 @@ from ..parallel.ep import (
     note_expert_layers,
     note_expert_load,
 )
-from ..parallel.ring_attention import blocked_self_attention
+from ..parallel.ring_attention import (
+    blocked_self_attention,
+    note_attention_step,
+)
 from ..telemetry import names as _names
 from .transformer import lm_cross_entropy
 
@@ -139,6 +144,7 @@ class MoEDecoder(fnn.Module):
     def __call__(self, tokens):
         note_expert_layers(
             tokens.size, self.top_k, self.num_layers, len(self.held))
+        note_attention_step()  # each layer's call below counts itself
         x = fnn.Embed(
             self.vocab_size, self.d_model, dtype=self.dtype, name="embed"
         )(tokens)

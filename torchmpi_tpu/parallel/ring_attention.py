@@ -18,6 +18,12 @@ rides its own ring); a single (batch, head) cell beyond the kernel's
 VMEM envelope raises — the kernel never gives way to the XLA path on
 its own.
 
+On ONE device ``blocked_self_attention`` is the same streaming softmax over
+blocks of one sequence (causal, optionally within a window, grouped KV
+heads), in two executions it chooses between itself: on a TPU, with heads
+of a multiple of 128, jax's fused splash-attention kernels, in which a
+tile's scores stay in VMEM; elsewhere loops of XLA operations.
+
 Derived from the ring-attention pattern in the public pallas guide and the
 scaling-book recipe: shift-K/V ring + online softmax.
 """
@@ -25,12 +31,14 @@ scaling-book recipe: shift-K/V ring + online softmax.
 from __future__ import annotations
 
 import math
-from functools import partial
+from functools import lru_cache, partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from .. import telemetry as _telemetry
 
 NEG_INF = -1e30
 
@@ -295,32 +303,166 @@ def _blocked_bwd(window, block, saved, dout):
 _blocked.defvjp(_blocked_fwd, _blocked_bwd)
 
 
+# ---------------------------------------------------------------------------
+# the same attention in fused kernels: a tile's scores never leave VMEM
+# ---------------------------------------------------------------------------
+
+LANES = 128  # the TPU's vector lanes: what the kernels' heads and tiles
+#              must be multiples of
+
+
+def _fused_tile(t: int) -> int:
+    """The kernels' tile of queries and of keys for a sequence of ``t``:
+    1,024 (on a v5e the tile that ran fastest forward and backward at 8,192
+    positions: PERF.md, PR 27; 2,048 queries do not fit VMEM), and for a
+    shorter sequence the multiple of the lanes that holds it."""
+    return min(1024, -(-t // LANES) * LANES)
+
+
+@lru_cache(maxsize=32)
+def _fused_kernel(t: int, groups: int, window: Optional[int],
+                  interpret: bool):
+    """jax's fused attention kernels (``pallas.ops.tpu.splash_attention``)
+    over the causal band of a ``t x t`` square (``t`` a multiple of its
+    tile), one KV head at a time with its ``groups`` query heads: a forward
+    kernel that saves the log-sum-exp and ONE backward kernel that makes
+    the probabilities again and gives dQ, dK and dV (a second kernel for dQ
+    alone would make them twice; it measured slower). Built once a shape.
+    Tiles wholly outside the band do no work, tiles wholly inside it run
+    without a mask, and the mask of the others is computed in the kernel
+    from the positions. A tile of keys is worked through in pieces of 512
+    where it divides so."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as kernel,
+        splash_attention_mask as masks,
+    )
+
+    if window is None or window >= t:
+        band = masks.CausalMask((t, t))
+    else:
+        band = masks.LocalMask((t, t), window_size=(window - 1, 0), offset=0)
+    tile = _fused_tile(t)
+    piece = 512 if tile % 512 == 0 else tile
+    with jax.ensure_compile_time_eval():  # the tile tables: constants
+        return kernel.make_splash_mqa_single_device(
+            masks.MultiHeadMask([band] * groups),
+            block_sizes=kernel.BlockSizes(
+                block_q=tile, block_kv=tile, block_kv_compute=piece,
+                block_q_dkv=tile, block_kv_dkv=tile,
+                block_kv_dkv_compute=piece, use_fused_bwd_kernel=True),
+            interpret=interpret)
+
+
+def _pad_sequence(q, k, v, multiple: int):
+    """``q``, ``k``, ``v`` with their sequence axis padded to a multiple of
+    ``multiple``: keys past the end lie in every real query's future, and
+    the caller cuts the rows of the queries past the end off again."""
+    pad = -q.shape[1] % multiple
+    if not pad:
+        return q, k, v
+    return tuple(jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                 for a in (q, k, v))
+
+
+def _fused(q, k, v, window: Optional[int], interpret: bool = False):
+    """``blocked_self_attention`` in the fused kernels; ``head_dim`` is a
+    multiple of the lanes here. The kernels apply no scale: ``q`` is
+    multiplied by ``1 / sqrt(head_dim)`` in its own dtype on the way in
+    (one more rounding of a bfloat16 ``q``, where the loops scale the
+    float32 scores). The products take ``q``, ``k``, ``v`` as they come
+    (bfloat16 into the MXU), the running maximum, normaliser and
+    accumulator are float32. ``interpret``: on the CPU, for the tests."""
+    b, t, hq, d = q.shape
+    hkv = k.shape[2]
+    g = hq // hkv
+    q, k, v = _pad_sequence(q, k, v, _fused_tile(t))
+    padded = q.shape[1]
+    q = (q * (1.0 / math.sqrt(d))).astype(q.dtype)
+    # [b, t, hkv * g, d] -> [b, hkv, g, t, d]: a KV head's query heads share
+    # its K and V, which are not repeated
+    q = q.reshape(b, padded, hkv, g, d).transpose(0, 2, 3, 1, 4)
+    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    one_kv_head = _fused_kernel(padded, g, window, interpret)
+    out = jax.vmap(jax.vmap(one_kv_head))(q, k, v)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, padded, hq, d)[:, :t]
+
+
+def _loops(q, k, v, window: Optional[int], block: int):
+    """``blocked_self_attention`` as XLA operations: ``_blocked``'s loops."""
+    t = q.shape[1]
+    block = min(int(block), t)
+    q, k, v = _pad_sequence(q, k, v, block)
+    return _blocked(q, k, v, window, block)[:, :t]
+
+
+def _attention_gauges():
+    m = _telemetry.metrics
+    return (
+        m.gauge(
+            "tm_attn_calls_per_step",
+            "blocked_self_attention calls in the step most recently traced "
+            "(one a layer; since the model's last note_attention_step)"),
+        m.gauge(
+            "tm_attn_kernel_calls_per_step",
+            "those of tm_attn_calls_per_step that take the fused kernels: "
+            "head_dim a multiple of 128, traced where jax's backend is a "
+            "TPU"),
+    )
+
+
+def note_attention_step() -> None:
+    """Start the count of a step's attention calls: a model calls this
+    where its forward pass begins to be traced, so that the two gauges
+    describe the step most recently traced (as ``ep.note_expert_layers``'
+    do)."""
+    for gauge in _attention_gauges():
+        gauge.set(0)
+
+
+def _note_attention_call(fused: bool) -> None:
+    calls, taken = _attention_gauges()
+    calls.set((calls.value() or 0) + 1)
+    taken.set((taken.value() or 0) + int(fused))
+
+
 def blocked_self_attention(q, k, v, window: Optional[int] = None,
                            block: int = 1024):
     """Exact causal self-attention on one device that never holds a
-    ``t x t`` tensor: blocks of ``block`` queries against blocks of keys
-    through ``_block_attn`` and the streaming-softmax merge of the ring.
+    ``t x t`` tensor: blocked streaming-softmax attention, in one of two
+    executions chosen by what the call can observe.
 
     ``q`` is ``[batch, t, heads, head_dim]``; ``k`` and ``v`` may have fewer
     heads (grouped KV heads: query head ``n`` reads KV head ``n // (heads //
     kv_heads)``), and are not repeated. Key ``j`` is visible to query ``i``
-    iff ``0 <= i - j`` and, with ``window``, ``i - j < window``. Block
-    pairs wholly outside that band are skipped, not masked (they are
-    outside the loops' bounds); ``t`` need not be a multiple of ``block``
-    (it is padded to one). Backward makes each pair's probabilities again
-    from the saved output and log-sum-exp."""
+    iff ``0 <= i - j`` and, with ``window``, ``i - j < window``. Blocks
+    wholly outside that band are skipped, not masked; ``t`` need not be a
+    multiple of a block (it is padded to one).
+
+    Where the step is lowered for a TPU and ``head_dim`` is a multiple of
+    the 128 lanes: fused kernels (``_fused``), forward and backward, in
+    which a tile's scores, probabilities and their gradients live in VMEM
+    and never reach HBM; the tiles are chosen from ``t``. Everywhere else
+    (the CPU, narrower heads): XLA operations (``_loops``), blocks of
+    ``block`` queries against blocks of keys through ``_block_attn`` and
+    the streaming-softmax merge of the ring, each block pair's float32
+    scores passing through memory. Both keep the log-sum-exp and make each
+    pair's probabilities again in backward. The platform is the one the
+    program is lowered for (``lax.platform_dependent``), not the process's
+    default; both executions are traced wherever the heads allow the
+    kernels, and the kernels' results declare no varying mesh axes, so
+    inside a ``shard_map`` this wants ``check_vma=False`` (the engine's)."""
     if q.shape[2] % k.shape[2] or k.shape != v.shape:
         raise ValueError(
             f"query heads {q.shape[2]} must be a multiple of the KV heads "
             f"{k.shape[2]}, and k and v alike (got {k.shape}, {v.shape})")
     if window is not None and window < 1:
         raise ValueError(f"window must be positive, got {window}")
-    t = q.shape[1]
-    block = min(int(block), t)
-    pad = -t % block
-    if pad:
-        # keys past the end lie in every real query's future; the rows of
-        # the queries past the end are cut off again
-        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                   for a in (q, k, v))
-    return _blocked(q, k, v, window, block)[:, :t]
+    loops = partial(_loops, window=window, block=block)
+    if q.shape[-1] % LANES:
+        _note_attention_call(False)
+        return loops(q, k, v)
+    # the gauge is set while the call is traced, when the platform of the
+    # lowering is not known yet: it goes by the process's backend
+    _note_attention_call(jax.default_backend() == "tpu")
+    return lax.platform_dependent(
+        q, k, v, tpu=partial(_fused, window=window), default=loops)

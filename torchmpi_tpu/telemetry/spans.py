@@ -97,6 +97,13 @@ MODEL_SCOPE_NAMES = (
     SCOPE_MOE_COMBINE,
 )
 
+# -- what a device trace calls the fused attention kernels of
+# parallel/ring_attention.py (jax's splash attention, one KV head with its
+# group of query heads): an event's name is the kernel's HLO instruction,
+# ``%splash_mqa_fwd_residuals.3 = ...`` (forward, with the log-sum-exp
+# saved or not) or ``%splash_mqa_dkv_no_residuals.7 = ...`` (backward)
+ATTN_KERNEL_EVENT = "splash_mqa_"
+
 
 class SpanRecord(NamedTuple):
     name: str
