@@ -13,7 +13,7 @@ It uses every local chip jax reports (1 or 4) and runs, in order:
 2. *Main path at full width*: ResNet-50 as published (3-4-6-3, 1000
    classes, 224 px, bf16 compute, SGD+momentum, learning rate scaled
    linearly from 0.1 at batch 256), 32 images per chip, through
-   ``AllReduceSGDEngine(mode="sync", model_state=batch_stats)`` fed by
+   ``AllReduceSGDEngine(model_state=batch_stats)`` fed by
    ``data.InputPipeline`` via ``engine.train()``. Checked: every step's
    loss finite, the last epoch's mean loss below the first's, replicas
    bitwise equal and passing ``mpinn.check_with_allreduce``, no
@@ -150,7 +150,6 @@ def phase_resnet(mpi, rehearse: bool, ledger: CompileLedger) -> dict:
         params,
         # the published recipe's 0.1 is for batch 256: scale it linearly
         optimizer=optax.sgd(0.1 * global_batch / 256, momentum=0.9),
-        mode="sync",
         model_state=stats,
         hooks={"on_start": on_start, "on_update": on_update},
     )

@@ -69,7 +69,7 @@ def main():
     model = MLP6(features=64)
     params = init_params(model, (1, 28, 28))
     engine = AllReduceSGDEngine(
-        make_loss_fn(model), params, optimizer=optax.sgd(0.05), mode="sync"
+        make_loss_fn(model), params, optimizer=optax.sgd(0.05)
     )
 
     start_epoch = 0

@@ -3,8 +3,8 @@
 batch 336 split over ranks, 5 epochs; distributed loss must match the
 sequential baseline and replicas must stay consistent.
 
-Run:  python examples/mnist_allreduce.py [--mode async] [--model lenet]
-      [--epochs 5] [--cpu-mesh N]
+Run:  python examples/mnist_allreduce.py [--model lenet] [--epochs 5]
+      [--cpu-mesh N]
 """
 
 from __future__ import annotations
@@ -18,7 +18,6 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--mode", default="sync", choices=["sync", "async"])
     ap.add_argument("--model", default="logreg", choices=["logreg", "lenet"])
     ap.add_argument("--epochs", type=int, default=5)
     ap.add_argument("--lr", type=float, default=0.2)
@@ -77,7 +76,6 @@ def main():
         params,
         optimizer=optax.sgd(args.lr),
         comm=comm,
-        mode=args.mode,
         hooks={
             "on_end_epoch": lambda s: print(
                 f"epoch {s['epoch']}: loss={s['losses'][-1]:.4f}"

@@ -33,7 +33,6 @@ def main(argv=None):
     ap.add_argument("--epochs", type=int, default=2)
     ap.add_argument("--lr", type=float, default=0.1)
     ap.add_argument("--momentum", type=float, default=0.9)
-    ap.add_argument("--mode", default="sync", choices=["sync", "async"])
     ap.add_argument("--bf16", action="store_true", help="bfloat16 compute")
     ap.add_argument(
         "--fsdp",
@@ -129,7 +128,6 @@ def main(argv=None):
         make_stateful_loss_fn(model),
         params,
         optimizer=optax.sgd(args.lr, momentum=args.momentum),
-        mode=args.mode,
         model_state=batch_stats,
         param_sharding="fsdp" if args.fsdp else "replicated",
         accum_steps=args.accum_steps,

@@ -29,7 +29,7 @@ run_fast() {
     echo "=== fast tier (unit + interpret p<=3 + single-process; lock monitor armed) ==="
     TORCHMPI_TPU_LOCK_MONITOR=1 python -m pytest tests/ -q -m "not slow"
     run_sim_smoke
-    run_perf_smoke
+    run_proc_smoke
 }
 
 run_sim_smoke() {
@@ -38,11 +38,11 @@ run_sim_smoke() {
     # survive a death wave and a partition, with telemetry.analyze
     # reaching the verdict each scenario file names (hang naming the
     # dead ranks; resize-incomplete naming the partitioned ones) —
-    # deterministically per seed. Then the coordinator-scalability
-    # curve (256 -> 10k ranks) gates resize commit, control-payload
-    # growth and chain re-formation fan-out. Pure host path — no jax
-    # backend.
-    echo "=== sim-smoke (1k-rank fault scenarios + 10k coordinator curve) ==="
+    # deterministically per seed. Pure host path — no jax backend. (The
+    # simulator's own gates — the coordinator curve, supervised
+    # death-wave recovery, synthesized-plan pricing — run at test-sized
+    # worlds in tests/test_sim.py.)
+    echo "=== sim-smoke (1k-rank fault scenarios) ==="
     simdir="$(mktemp -d)"
     # the EXIT trap survives set -eu: a failing scenario must not
     # strand ~2k telemetry dumps per retry in /tmp on the CI box
@@ -53,8 +53,8 @@ run_sim_smoke() {
     # partition SUPERVISED at 1024 ranks: the recovery ladder (verdict
     # -> evict the wave -> committed shrink -> training resumed) per
     # the scenario's expected.recovery contract. death_wave's
-    # supervised 1024-rank coverage lives in bench.py --sim --check
-    # below (check_supervised_recovery: bounded action count +
+    # supervised coverage is tests/test_sim.py's
+    # (check_supervised_recovery: bounded action count +
     # byte-identical journal replay), so it is not repeated here.
     JAX_PLATFORMS=cpu python -m torchmpi_tpu.sim --supervise \
         partition --ranks 1024 --out "$simdir"
@@ -69,55 +69,13 @@ run_sim_smoke() {
     JAX_PLATFORMS=cpu python -m torchmpi_tpu.sim --supervise \
         traffic_surge --ranks 1024 --out "$simdir"
     rm -rf "$simdir"
-    python bench.py --sim --check
 }
 
-run_perf_smoke() {
-    # perf-smoke: the eager-dispatch microbench must run to completion on
-    # CPU and show fused dispatch <= unfused for the canonical LeNet
-    # bucket set (correctness-of-direction, not absolute timing), with
-    # zero collective compiles after precompile(). --check encodes both
-    # assertions in the exit code, plus the live-plane extensions: the
-    # recorder-overhead laps run with the live exporter ARMED (streaming
-    # real frames to a local aggregator) under the same 150us/dispatch
-    # budget, and schedule.calibrate() fit from this run's dispatch
-    # samples must beat the hand-set plan_cost_* constants
-    # (calibrated error strictly smaller) — the calibration table is
-    # persisted to a temp cache as the CI artifact of the persistence
-    # path start() re-applies. The chunk-pipeline gate rides the same
-    # run: the depth>1 plan must beat its depth-1 twin in the
-    # stage-overlap cost model AND reproduce it bitwise, with the
-    # measured median inside an absolute regression budget (this box's
-    # virtual devices run sequentially, so the wall-clock win itself is
-    # an accelerator-only assertion).
-    echo "=== perf-smoke (eager dispatch microbench + live plane, CPU) ==="
-    calfile="$(mktemp -u).calibration.json"
-    XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=8" \
-        TORCHMPI_TPU_CALIBRATION_CACHE="$calfile" \
-        python bench.py --microbench --check
-    test -s "$calfile"  # the persisted calibrated cost model must exist
-    rm -f "$calfile"
-    # PS wire perf-smoke: int8 wire must move >= 2x the effective logical
-    # bytes/sec of fp32 on the LeNet parameter round trip over the paced
-    # (bandwidth-bound) link, with every decoded fetch inside its
-    # encoding's error bound. Pure host path — no jax backend.
-    echo "=== perf-smoke (parameter-server wire microbench, CPU) ==="
-    python bench.py --ps-microbench --check
-    # PS fabric fleet smoke: the event-multiplexed listener must serve a
-    # bounded synthetic downpour fleet (32 -> 256 clients, throughput
-    # within 2x; the 1024-client point proves >= 1000 concurrent clients
-    # on O(pools) server threads) with ZERO lost or double-applied
-    # updates — the scalability-curve JSON is the CI-captured evidence.
-    echo "=== perf-smoke (parameter-server fleet scalability, CPU) ==="
-    python bench.py --ps-fleet --check
-    # PS read-path smoke: replica-spread fetch routing must reach >= 2x
-    # the owner-only fetch throughput at 256 clients under the same
-    # reader/writer mix and per-member capacity (with a replica killed
-    # mid-window), the shm lane p50 must beat the loopback socket p50,
-    # and the self-describing audits must hold everywhere: zero torn
-    # reads, zero read-your-writes violations.
-    echo "=== perf-smoke (parameter-server read path: routing/RYW/shm, CPU) ==="
-    python bench.py --ps-fleet --read-mix 0.9 --check
+run_proc_smoke() {
+    # the multi-process smokes: each launches real worker processes on
+    # the CPU and checks what they leave behind. (What one process can
+    # assert — zero compiles after precompile(), bitwise twins,
+    # exactly-once audits — is in tier-1, tests/.)
     # flight-recorder/analyzer smoke: a short 2-proc job with telemetry on
     # must yield a merged per-rank Perfetto trace and a clean
     # `desync: none` analyzer report.
@@ -183,10 +141,10 @@ case "$tier" in
     lint) run_lint ;;
     fast) run_fast ;;
     sim-smoke) run_sim_smoke ;;
-    perf-smoke) run_perf_smoke ;;
+    proc-smoke) run_proc_smoke ;;
     slow-a) run_slow_a ;;
     slow-b) run_slow_b ;;
     all) run_fast; run_slow_a; run_slow_b ;;
-    *) echo "usage: scripts/ci.sh [lint|fast|sim-smoke|perf-smoke|slow-a|slow-b|all]" >&2; exit 2 ;;
+    *) echo "usage: scripts/ci.sh [lint|fast|sim-smoke|proc-smoke|slow-a|slow-b|all]" >&2; exit 2 ;;
 esac
 echo "Success"

@@ -17,7 +17,6 @@ done
 echo "=== examples (mesh 8) ==="
 for cmd in \
   "examples/mnist_allreduce.py --cpu-mesh 8 --epochs 2" \
-  "examples/mnist_allreduce.py --cpu-mesh 8 --epochs 2 --mode async" \
   "examples/mnist_parameterserver.py --cpu-mesh 8 --epochs 1 --variant downpour" \
   "examples/mnist_parameterserver.py --cpu-mesh 8 --epochs 1 --variant easgd" \
   "examples/mnist_parameterserver.py --cpu-mesh 8 --epochs 1 --variant easgd --dataparallel" \

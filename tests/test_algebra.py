@@ -234,6 +234,23 @@ def test_candidates_gated_by_knob():
     assert xla_synth and not any(c.feasible for c in xla_synth)
 
 
+def test_live_mesh_prices_synth_within_the_models_band(_started):
+    """On the 8-rank mesh the race prices the algebra's candidates beside
+    the legacy families, and the best of them is selected or within the
+    cost model's own error band (1.25x) of the best legacy plan; the
+    strict win is a matter of scale (``sim.bench.check_synth_pricing``)."""
+    constants.set("use_plan_synthesis", True)
+    topo = Topology.from_communicator(mpi.current_communicator())
+    priced = [c for c in candidate_plans(
+        "allreduce", 1 << 20, 4, topo, "ring", wire="int8",
+        route_small=True) if c.feasible and c.cost_us is not None]
+    synth = [c.cost_us for c in priced if is_synthesized(c.plan.generator)]
+    legacy = [c.cost_us for c in priced
+              if not is_synthesized(c.plan.generator)]
+    assert synth and legacy
+    assert min(synth) <= 1.25 * min(legacy)
+
+
 def test_synth_ring_phases_earn_pipeline_twins():
     """The ``_pipeline_eligible`` fix: synthesized plans whose phases
     are rings (stripe, torus) spawn depth twins like the legacy ring

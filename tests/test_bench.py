@@ -1,10 +1,9 @@
-"""The measurement entry points' contract: ``chip_smoke.py`` and
-``bench.py`` find a TPU or exit non-zero with the reason and no metric;
-nothing hides the device (no CPU fallback, no guessed peak, no unknown
-platform taking another's table); the compile cache can be placed from
-outside; and the rehearsal — the only chip-less mode — marks itself."""
+"""The start-up check's contract: ``chip_smoke.py`` finds a TPU or exits
+non-zero with the reason and no result; nothing hides the device (no CPU
+fallback, no guessed peak, no unknown platform taking another's table);
+the compile cache can be placed from outside; and the rehearsal — the
+only chip-less mode — marks itself."""
 
-import importlib.util
 import json
 import os
 import re
@@ -29,14 +28,6 @@ def _run(script, *args, timeout=600):
     )
 
 
-@pytest.fixture
-def bench():
-    spec = importlib.util.spec_from_file_location("_bench", REPO / "bench.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def _fake_tpus(n=4, kind="TPU v5 lite"):
     return [
         SimpleNamespace(platform="tpu", device_kind=kind, id=i)
@@ -55,67 +46,6 @@ def test_chip_smoke_without_tpu_exits_nonzero_before_any_work():
     assert r.stdout == ""  # no result line, no progress line
     assert "no TPU" in r.stderr and "'cpu'" in r.stderr
     assert len(r.stderr.strip().splitlines()) == 1  # a one-line reason
-
-
-def test_bench_without_tpu_exits_nonzero_and_prints_no_metric():
-    r = _run("bench.py")
-    assert r.returncode != 0
-    assert "metric" not in r.stdout and r.stdout.strip() == ""
-    assert "TPU" in r.stderr and "'cpu'" in r.stderr
-
-
-def test_bench_failed_worker_fails_the_run_and_prints_no_metric(
-    bench, monkeypatch, capsys
-):
-    """One process, workers in sequence: a worker that raises takes the
-    run down, and the lines of the workers before it are not printed."""
-    import torchmpi_tpu as mpi
-
-    monkeypatch.setattr(bench, "_require_tpu", lambda: _fake_tpus(1))
-    monkeypatch.setattr(mpi, "start", lambda **kw: None)
-    monkeypatch.setattr(mpi, "stop", lambda: None)
-
-    def boom(devices):
-        raise RuntimeError("worker died")
-
-    monkeypatch.setitem(
-        bench._WORKERS, "resnet50", lambda devices: {"metric": "a", "value": 1}
-    )
-    monkeypatch.setitem(bench._WORKERS, "lm", boom)
-    with pytest.raises(RuntimeError, match="worker died"):
-        bench._run_models(("resnet50", "lm"))
-    assert capsys.readouterr().out == ""
-
-
-def test_bench_lines_name_platform_kind_and_count(bench, monkeypatch, capsys):
-    import torchmpi_tpu as mpi
-
-    monkeypatch.setattr(bench, "_require_tpu", lambda: _fake_tpus(4))
-    monkeypatch.setattr(mpi, "start", lambda **kw: None)
-    monkeypatch.setattr(mpi, "stop", lambda: None)
-    for model in bench.MODELS:
-        monkeypatch.setitem(
-            bench._WORKERS, model,
-            lambda devices, m=model: {
-                "metric": bench._metric_name(m), "value": 1.0,
-                **bench._device_fields(devices),
-            },
-        )
-    assert bench._run_models(bench.MODELS) == 0
-    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()]
-    assert [l["metric"] for l in lines] == [
-        bench._metric_name(m) for m in bench.MODELS
-    ]
-    assert bench.MODELS[-1] == "mnist"  # the driver reads the last line
-    for line in lines:
-        assert line["platform"] == "tpu"
-        assert line["device_kind"] == "TPU v5 lite"
-        assert line["device_count"] == 4
-
-
-def test_bench_require_tpu_refuses_the_cpu(bench):
-    with pytest.raises(bench.NoTPUError, match="'cpu'"):
-        bench._require_tpu()
 
 
 # --------------------------------------------------------------------------

@@ -1,8 +1,7 @@
-"""Engine tests: distributed-vs-sequential loss parity + async numerics.
+"""Engine tests: distributed-vs-sequential loss parity.
 
 Mirrors the reference's e2e strategy: ``mnist_sequential.lua`` is the
-baseline, distributed runs must match its loss (mnist_allreduce.lua:87-113),
-and ``test/async.lua`` compares sync vs async gradients on an MLP.
+baseline, distributed runs must match its loss (mnist_allreduce.lua:87-113).
 """
 
 import jax
@@ -60,9 +59,8 @@ def _sequential_baseline(model, params, xtr, ytr, batch, epochs, lr, seed):
     return params, losses
 
 
-@pytest.mark.parametrize("mode", ["sync", "async"])
 @pytest.mark.slow
-def test_engine_matches_sequential(mode):
+def test_engine_matches_sequential():
     """Distributed AllReduceSGD must track the sequential baseline loss
     step-for-step (averaged grads over rank-shards == full-batch grad)."""
     p = mpi.size()
@@ -79,7 +77,6 @@ def test_engine_matches_sequential(mode):
         make_loss_fn(model),
         params,
         optimizer=optax.sgd(lr),
-        mode=mode,
         average_gradients=True,
     )
     it = DistributedIterator(
@@ -136,33 +133,6 @@ def test_engine_hooks_fire_in_order():
     assert calls.count("on_sample") == len(it)
     i = calls.index("on_sample")
     assert calls[i : i + 4] == ["on_sample", "on_forward", "on_backward", "on_update"]
-
-
-@pytest.mark.slow
-def test_engine_async_mlp_convergence():
-    """test/async.lua analog: async (bucketed) training on the 6-layer MLP
-    reaches the same loss region as sync."""
-    p = mpi.size()
-    (xtr, ytr), _ = synthetic_mnist(num_train=512, num_test=1)
-    model = MLP6(features=64)
-    params = init_params(model, (1, 28, 28))
-
-    finals = {}
-    for mode in ("sync", "async"):
-        engine = AllReduceSGDEngine(
-            make_loss_fn(model),
-            params,
-            optimizer=optax.sgd(0.1),
-            mode=mode,
-            num_buckets=3,
-        )
-        it = DistributedIterator(
-            xtr, ytr, 8 * p, p, seed=3, sharding=engine.batch_sharding
-        )
-        state = engine.train(lambda: iter(it), max_epochs=2)
-        finals[mode] = state["losses"][-1]
-    # bucketed psum is numerically the same collective: tight agreement
-    np.testing.assert_allclose(finals["async"], finals["sync"], rtol=1e-4)
 
 
 def test_engine_does_not_donate_caller_params():
@@ -439,20 +409,14 @@ def test_engine_fsdp_checkpoint_roundtrip(tmp_path):
     np.testing.assert_allclose(st_b["losses"], st_a["losses"], rtol=1e-5)
 
 
-def test_engine_fsdp_rejects_async():
+def test_engine_fsdp_rejects_summed_gradients():
     model = LogisticRegression()
     params = init_params(model, (1, 28, 28))
     with pytest.raises(ValueError, match="fsdp"):
         AllReduceSGDEngine(
-            make_loss_fn(model), params, mode="async", param_sharding="fsdp"
+            make_loss_fn(model), params, average_gradients=False,
+            param_sharding="fsdp",
         )
-
-
-def test_engine_rejects_bad_mode():
-    model = LogisticRegression()
-    params = init_params(model, (1, 28, 28))
-    with pytest.raises(ValueError):
-        AllReduceSGDEngine(make_loss_fn(model), params, mode="turbo")
 
 
 def test_iterator_partitioning():
@@ -592,41 +556,3 @@ def test_engine_evaluate_observes_single_element_mutation():
     assert engine.evaluate(apply_fn, xte, yte, mean_logit) == v1
     engine.invalidate_eval_cache()
     assert not engine._eval_data
-
-
-@pytest.mark.slow
-def test_engine_async_walltime_not_pathological():
-    """Wall-time sync-vs-async comparison, the reference's discipline
-    (test/async.lua:63-148 timed both and printed the ratio): async mode
-    (bucketed, overlap left to XLA's async collective scheduler) must not
-    be dramatically SLOWER than sync on identical resident training.
-    On the 1-CPU test box no speedup is expected — this guards against
-    the overlap machinery costing wall-clock, and prints the measured
-    ratio for the record."""
-    import time
-
-    # MLP, like the reference's async.lua harness: dense-only compiles
-    # and runs fast enough to time on the 1-CPU box
-    (xtr, ytr), _ = synthetic_mnist(num_train=2048, num_test=1)
-    model = MLP6(features=128)
-    params = init_params(model, (1, 28, 28))
-
-    def timed(mode):
-        eng = AllReduceSGDEngine(
-            make_loss_fn(model), params, optimizer=optax.sgd(0.05),
-            mode=mode,
-        )
-        # warmup epoch compiles; timed epochs measure steady state
-        eng.train_resident(xtr, ytr, 128, max_epochs=1, seed=1)
-        t0 = time.perf_counter()
-        eng.train_resident(xtr, ytr, 128, max_epochs=3, seed=1)
-        return time.perf_counter() - t0
-
-    t_sync = timed("sync")
-    t_async = timed("async")
-    ratio = t_async / t_sync
-    print(f"sync={t_sync:.2f}s async={t_async:.2f}s ratio={ratio:.2f}")
-    assert ratio < 2.0, (
-        f"async mode pathologically slower than sync: {t_async:.2f}s vs "
-        f"{t_sync:.2f}s"
-    )

@@ -60,12 +60,10 @@ def _data(steps, per_rank=2):
 
 def _engine(layout="sync", **kw):
     """An engine with the gradient sync or parameter layout ``layout``."""
-    if layout == "bucketed":
-        kw.update(mode="async", num_buckets=2)
-    elif layout == "bucketed_int8":
-        # one bucket, and a cutoff low enough for the wire to engage
+    if layout in ("int8", "bf16"):
+        # a cutoff low enough for the wire to engage
         constants.set("wire_quant_min_elements", 1)
-        kw.update(wire_dtype="int8")
+        kw.update(wire_dtype=layout)
     elif layout in ("fsdp", "zero1"):
         kw.update(param_sharding=layout)
     return AllReduceSGDEngine(
@@ -80,12 +78,12 @@ def _step_text(engine, per_rank=2):
     ).as_text(debug_info=True)
 
 
+WIRE_SCOPES = ("tm.grad_sync/pack", "tm.grad_sync/reduce",
+               "tm.grad_sync/unpack")
 SYNC_SCOPES = {
     "sync": ("tm.grad_sync/reduce", "tm.grad_sync/unpack"),
-    "bucketed": ("tm.grad_sync/b0/reduce", "tm.grad_sync/b1/reduce",
-                 "tm.grad_sync/b1/unpack"),
-    "bucketed_int8": ("tm.grad_sync/b0/pack", "tm.grad_sync/b0/reduce",
-                      "tm.grad_sync/b0/unpack"),
+    "int8": WIRE_SCOPES,
+    "bf16": WIRE_SCOPES,
     "fsdp": (),
     "zero1": (),
 }
@@ -116,6 +114,21 @@ def test_scope_names_in_lowered_step(layout):
     if layout in ("fsdp", "zero1"):
         # no sync call of the engine's own: GSPMD inserts the collectives
         assert names.SCOPE_GRAD_SYNC not in text
+
+
+@pytest.mark.parametrize("layout", sorted(SYNC_SCOPES))
+def test_one_gradient_sync_and_no_bucket_level_in_its_scopes(layout):
+    """The sync's phases lie directly under ``tm.grad_sync``, whatever
+    the layout or the wire: what reads a device trace by these names
+    finds no ``b<k>`` level between them."""
+    text = _step_text(_engine(layout))
+    assert not re.search(r"%s/b\d+/" % names.SCOPE_GRAD_SYNC, text)
+
+
+@pytest.mark.parametrize("gone,value", [("mode", "async"), ("num_buckets", 2)])
+def test_engine_takes_no_mode_and_no_bucket_count(gone, value):
+    with pytest.raises(TypeError, match=gone):
+        _engine(**{gone: value})
 
 
 @pytest.mark.parametrize("layout", ["sync", "fsdp"])
@@ -163,7 +176,7 @@ def test_names_are_pinned():
 
 
 # -- (b) telemetry changes neither the program nor where the host waits --
-@pytest.mark.parametrize("layout", ["sync", "bucketed", "fsdp"])
+@pytest.mark.parametrize("layout", ["sync", "int8", "fsdp"])
 def test_telemetry_leaves_the_lowered_step_unchanged(layout):
     texts = []
     for switch in (telemetry.disable, telemetry.enable):
@@ -357,7 +370,7 @@ def test_new_shape_in_mid_run_is_one_program_build():
 
 # -- (e) what the in-graph sync reduces ---------------------------------
 @pytest.mark.parametrize("layout,calls", [
-    ("sync", 3), ("bucketed", 3), ("bucketed_int8", 1)])
+    ("sync", 3), ("int8", 1), ("bf16", 1)])
 def test_sync_counters_equal_the_tree(layout, calls):
     """One collective per leaf where the leaves are reduced as they lie,
     one per flat buffer where a wire format packs them; the same bytes
@@ -370,9 +383,8 @@ def test_sync_counters_equal_the_tree(layout, calls):
 
 
 # -- (f) a flat buffer only where a wire format needs one ---------------
-@pytest.mark.parametrize("layout", ["sync", "bucketed"])
-def test_full_wire_step_holds_no_flat_buffer(layout):
-    text = _step_text(_engine(layout))
+def test_full_wire_step_holds_no_flat_buffer():
+    text = _step_text(_engine())
     ops = _sync_ops(text)
     # jax binds one psum for each leaf of a list: XLA's combiner groups them
     assert sum(op.endswith("/psum") for op in ops) == 3
@@ -380,13 +392,14 @@ def test_full_wire_step_holds_no_flat_buffer(layout):
     assert f"/{names.SCOPE_PACK}/" not in text
 
 
-def test_int8_wire_step_still_packs():
-    ops = _sync_ops(_step_text(_engine("bucketed_int8")))
-    assert "tm.grad_sync/b0/pack/concatenate" in ops
+@pytest.mark.parametrize("wire", ["int8", "bf16"])
+def test_compressed_wire_step_still_packs(wire):
+    ops = _sync_ops(_step_text(_engine(wire)))
+    assert "tm.grad_sync/pack/concatenate" in ops
     assert not [op for op in ops if op.endswith("/psum")]
 
 
-@pytest.mark.parametrize("layout", ["sync", "bucketed"])
+@pytest.mark.parametrize("layout", ["sync", "int8"])
 def test_fusion_buffer_bytes_does_not_reach_the_lowered_step(layout):
     texts = []
     for size in (0, 4 << 20):

@@ -202,6 +202,56 @@ def test_request_reply_round_trip_over_the_wire():
         lst.close()
 
 
+def test_surge_over_the_wire_sheds_by_class_and_drops_nothing():
+    """A burst held behind a closed model answers every REQUEST exactly
+    once, right or as an explicit ``shed:<ms>``: the ladder engages, the
+    top class is still served through it, and one request at a time
+    afterwards sheds nothing. No clock: the burst is released only once
+    the listener has admitted all of it."""
+    import threading
+    import time
+
+    from torchmpi_tpu.parameterserver import transport as T
+
+    constants.set("serve_queue_budget", 8)
+    levels = int(constants.get("serve_qos_levels"))
+    gate = threading.Event()
+
+    def model(w, x):
+        gate.wait(60)
+        return x + w[0]
+
+    srv = InferenceServer(model, weights=np.array([7.0], np.float32))
+    lst = T._Listener(lambda i: None)
+    lst.request_handler = srv.handle
+    ch = T._PeerChannel({0: ("127.0.0.1", lst.port)}, 0)
+
+    def ask(i):
+        return ch.submit(
+            T._KIND_REQUEST, 0, i % levels, 0, rule="infer",
+            payload_raw=np.array([i], np.float32).tobytes())
+
+    try:
+        burst = [ask(i) for i in range(48)]
+        while lst._pending_frames < len(burst):
+            time.sleep(0.001)
+        gate.set()
+        replies = [ch.complete(w) for w in burst]
+        calm = [ch.complete(ask(i)) for i in range(len(burst), 60)]
+    finally:
+        gate.set()
+        ch.close()
+        lst.close()
+    shed = [i for i, (status, _) in enumerate(replies)
+            if status.startswith("shed:")]
+    for i, (status, y) in enumerate(replies + calm):
+        if i not in shed:  # neither dropped nor wrong
+            assert status == "ok" and float(y[0]) == i + 7.0, (i, status)
+    assert shed and all(i % levels < levels - 1 for i in shed)
+    assert srv.shed == len(shed) and srv.served == 60 - len(shed)
+    assert all(status == "ok" for status, _ in calm)
+
+
 def test_request_without_handler_is_a_loud_error():
     from torchmpi_tpu.parameterserver import transport as T
 

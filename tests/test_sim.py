@@ -392,7 +392,20 @@ def test_supervised_dry_run_decides_but_never_acts(tmp_path):
     assert not res["stats"].get("rollback")
 
 
-def test_supervised_recovery_bench_gate_passes():
-    from torchmpi_tpu.sim.bench import check_supervised_recovery
+@pytest.mark.parametrize("gate", [
+    "supervised_recovery", "curve", "synth_pricing"])
+def test_sim_bench_gate_passes(gate):
+    """The simulator's own lists of failures, at test-sized worlds:
+    bounded supervised recovery with a journal that replays; every world
+    of the curve resizes, its control payloads grow no faster than the
+    member list, the smallest point replays; synthesized plans generated
+    in O(candidates) and priced under every legacy family from 1k ranks."""
+    from torchmpi_tpu.sim import bench
 
-    assert check_supervised_recovery(ranks=128) == []
+    failures = {
+        "supervised_recovery":
+            lambda: bench.check_supervised_recovery(ranks=128),
+        "curve": lambda: bench.check_curve(bench.bench_curve((64, 256))),
+        "synth_pricing": bench.check_synth_pricing,
+    }[gate]()
+    assert failures == []

@@ -828,9 +828,9 @@ def precompile(specs, comm: Optional[Communicator] = None,
     present — is pinned against LRU eviction
     (``free_collective_resources`` still frees them — wholesale teardown
     outranks pins). After precompile, a training loop's dispatches hit
-    zero executable compiles AND zero plan-cache misses (the
-    ``bench.py --microbench --check`` gates). Returns the number of
-    specs warmed. Typically invoked via
+    zero executable compiles AND zero plan-cache misses (pinned by
+    ``tests/test_fusion.py`` and ``tests/test_schedule.py``). Returns
+    the number of specs warmed. Typically invoked via
     ``start(precompile_collectives=...)`` or
     ``AllReduceSGDEngine.precompile()``."""
     if comm is None:

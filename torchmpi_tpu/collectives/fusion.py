@@ -29,7 +29,7 @@ every submit dispatches immediately, the pre-fusion behavior.
 
 Telemetry (when enabled): tensors coalesced, flushes by reason
 (``bytes`` / ``wait`` / ``explicit``), and fused-vs-unfused dispatch
-latency histograms — the evidence stream ``bench.py --microbench`` reads.
+latency histograms.
 """
 
 from __future__ import annotations
@@ -70,8 +70,8 @@ def _metric_handles():
             ),
             m.histogram(
                 "tm_fusion_dispatch_seconds",
-                "host-side dispatch wall time per flush by op/path — the "
-                "fused-vs-unfused comparison bench.py --microbench reads",
+                "host-side dispatch wall time per flush by op/path (fused "
+                "against unfused)",
             ),
         )
     return _MET

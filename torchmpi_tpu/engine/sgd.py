@@ -7,13 +7,11 @@ Reference behaviors preserved, re-designed for XLA:
 
 - one-shot parameter broadcast before training (``sgdengine.lua:140-144``)
   → ``in_graph_synchronize_parameters`` on step 0, or eager broadcast.
-- sync mode: gradient sum-allreduce every step (``sgdengine.lua:126-131``)
-  → a single jitted train step over the communicator's mesh with in-graph
-  psum; XLA fuses and schedules it.
-- async mode: per-layer overlapped allreduce (``sgdengine.lua:91-124``)
-  → bucketed in-graph psums (one collective per bucket) that XLA's
-  async-collective scheduler overlaps with remaining compute; bucket count
-  ≙ BlockSequential's block count.
+- gradient sum-allreduce every step, the reference's sync mode
+  (``sgdengine.lua:126-131``) and its async mode's per-layer overlapped
+  allreduce (``sgdengine.lua:91-124``) alike → ONE jitted train step over
+  the communicator's mesh with the in-graph psum of the gradient leaves;
+  grouping the all-reduces and placing them against backward is XLA's.
 - hooks: ``on_start, on_start_epoch, on_sample, on_forward, on_backward,
   on_update, on_end_epoch, on_end`` (the torchnet hook names,
   ``sgdengine.lua:82-135``), each receiving the mutable ``state`` dict.
@@ -38,7 +36,6 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import nn as mpinn, telemetry as _telemetry
-from ..nn import GradientBuckets
 from ..runtime.communicator import Communicator
 from ..telemetry import flightrecorder as _flight
 from ..telemetry import tracecontext as _tracecontext
@@ -241,8 +238,6 @@ class AllReduceSGDEngine:
     params : initial parameter pytree (un-stacked; will be replicated).
     optimizer : an optax GradientTransformation (default: plain SGD).
     comm : communicator (default: current).
-    mode : 'sync' (fused allreduce) or 'async' (bucketed, overlapped).
-    num_buckets : gradient buckets for async mode (``BlockSequential`` N).
     average_gradients : divide the summed gradients by world size. The
         reference sums only (division left to the caller, nn.lua:40);
         True by default here because optax learning rates assume means.
@@ -254,8 +249,6 @@ class AllReduceSGDEngine:
         params,
         optimizer: Optional[optax.GradientTransformation] = None,
         comm: Optional[Communicator] = None,
-        mode: str = "sync",
-        num_buckets: int = 4,
         average_gradients: bool = True,
         broadcast_parameters: bool = True,
         profile_dir: Optional[str] = None,
@@ -283,10 +276,9 @@ class AllReduceSGDEngine:
         sharded — the memory win of sharded moments without per-layer
         parameter gathers; the update math runs sharded and the applied
         updates are gathered once per step). fsdp/zero1 require
-        mode='sync' and average_gradients=True (the loss is a
-        global-batch mean, so gradients are means by construction); both
-        are capability extensions — the reference has no sharded-optimizer
-        mode.
+        average_gradients=True (the loss is a global-batch mean, so
+        gradients are means by construction); both are capability
+        extensions — the reference has no sharded-optimizer mode.
 
         ``accum_steps``: gradient accumulation — each step's batch is cut
         into this many microbatches processed sequentially (a scan, so
@@ -307,9 +299,9 @@ class AllReduceSGDEngine:
 
         ``wire_dtype``: on-wire encoding for the gradient allreduce
         ('full' | 'bf16' | 'int8'; None = the autotuned constants
-        default). A compressed encoding routes the gradient sync through
-        the bucketed compressed-wire ring (block-quantized send, f32
-        accumulate) — sync mode gets a single bucket. Replicated
+        default). A compressed encoding sends the float32 gradients
+        through the compressed-wire ring as one flat buffer
+        (block-quantized send, f32 accumulate). Replicated
         param_sharding only: fsdp/zero1 leave the collectives to GSPMD,
         which has no wire-format hook.
 
@@ -331,8 +323,6 @@ class AllReduceSGDEngine:
         # so step N is ONE trace fleet-wide)
         self.epochs_run = 0
         self.steps_run = 0
-        if mode not in ("sync", "async"):
-            raise ValueError(f"mode must be 'sync' or 'async', got {mode!r}")
         if batch_format not in ("auto", "flat", "stacked"):
             raise ValueError(
                 f"batch_format must be auto/flat/stacked, got {batch_format!r}"
@@ -342,13 +332,11 @@ class AllReduceSGDEngine:
                 "param_sharding must be replicated/fsdp/zero1, got "
                 f"{param_sharding!r}"
             )
-        if param_sharding in ("fsdp", "zero1") and (
-            mode != "sync" or not average_gradients
-        ):
+        if param_sharding in ("fsdp", "zero1") and not average_gradients:
             raise ValueError(
-                f"param_sharding={param_sharding!r} requires mode='sync' and "
+                f"param_sharding={param_sharding!r} requires "
                 "average_gradients=True (the global-batch loss already "
-                "yields mean gradients; XLA schedules the overlap)"
+                "yields mean gradients)"
             )
         if not isinstance(accum_steps, int) or accum_steps < 1:
             raise ValueError(
@@ -387,25 +375,11 @@ class AllReduceSGDEngine:
         self.loss_fn = jax.checkpoint(loss_fn) if remat else loss_fn
         self.remat = remat
         self.optimizer = optimizer or optax.sgd(0.2)
-        self.mode = mode
         self.average_gradients = average_gradients
         self.broadcast_parameters = broadcast_parameters
         self.profile_dir = profile_dir
         self.profile_window = profile_window
         self.hooks = hooks or {}
-        # a compressed wire needs the bucketed sync path even in sync
-        # mode: quantization works on a flat buffer, not on leaf-shaped
-        # psums, and that path packs one where the wire engages — one
-        # bucket keeps sync-mode step economics (a single collective).
-        # At full wire the compiled step holds no flat buffer at all
-        # (fusion_buffer_bytes governs the eager FusionBuffer only): on
-        # the chip the packing cost twice the all-reduce it fed (PERF.md)
-        wire_bucketed = wire_dtype in ("bf16", "int8")
-        self.buckets = (
-            GradientBuckets(params, num_buckets if mode == "async" else 1)
-            if (mode == "async" or wire_bucketed)
-            else None
-        )
         _listen_for_builds(self)
         with _ring.span(_names.ENGINE_INIT, step=(0, 0)) as init:
             self._place_state(params, model_state)
@@ -591,17 +565,14 @@ class AllReduceSGDEngine:
                 new_state = jax.tree_util.tree_map(
                     lambda s: jax.lax.pmean(s, _AXIS), new_state
                 )
-        # each sync function opens tm.grad_sync and its phases itself
-        if self.buckets is not None:
-            grads = mpinn.in_graph_synchronize_gradients_bucketed(
-                grads, self.buckets, _AXIS,
-                average=self.average_gradients,
-                wire_dtype=self.wire_dtype,
-            )
-        else:
-            grads = mpinn.in_graph_synchronize_gradients(
-                grads, _AXIS, average=self.average_gradients
-            )
+        # opens tm.grad_sync and its phases itself. At full wire the
+        # compiled step holds no flat buffer (fusion_buffer_bytes governs
+        # the eager FusionBuffer only): on the chip the packing cost twice
+        # the all-reduce it fed (PERF.md)
+        grads = mpinn.in_graph_synchronize_gradients(
+            grads, _AXIS, average=self.average_gradients,
+            wire_dtype=self.wire_dtype,
+        )
         params, opt_state = self._apply_update(params, opt_state, grads)
         with jax.named_scope(_names.SCOPE_LOSS_SYNC):
             loss = jax.lax.pmean(loss, _AXIS)
@@ -710,8 +681,7 @@ class AllReduceSGDEngine:
             with _ring.span(_names.ENGINE_DISPATCH):
                 out = self._call_step(batch)
             if telemetry_on:
-                _engine_metrics().steps.inc(
-                    mode=self.mode, sharding=self.param_sharding)
+                _engine_metrics().steps.inc(sharding=self.param_sharding)
                 if _flight.enabled():
                     # step events join the comm's flight stream (wall-clock
                     # stamps): per-seq issue-time spread across ranks is
@@ -721,7 +691,6 @@ class AllReduceSGDEngine:
                         _flight.comm_key(self.comm), "engine.step",
                         t0, time.time(),
                         payload=f"examples={examples},steps=1",
-                        routing=self.mode,
                     )
         self.steps_run += 1
         return out
@@ -763,51 +732,33 @@ class AllReduceSGDEngine:
     def collective_specs(self):
         """Declared eager-collective specs derived from the params
         template — the EXACT executables the eager gradient-sync paths
-        for this model would compile. Bucketed engines emit one
-        ``(op, (p, total), dtype)`` spec per bucket (the packed buffer
-        ``GradientBuckets.allreduce_async`` dispatches through ``run``);
-        unbucketed ones emit one ``{"layout": per-leaf widths}`` dict per
-        dtype group (the coalesced plan ``nn.synchronize_gradients``
-        flushes through ``run_fused`` — a ``(p, total)`` spec would warm
-        a cache key nothing ever dispatches). Feed to
-        ``collectives.precompile`` (or
+        for this model would compile: one ``{"layout": per-leaf
+        widths}`` dict per dtype group (the coalesced plan
+        ``nn.synchronize_gradients`` flushes through ``run_fused`` — a
+        ``(p, total)`` spec would warm a cache key nothing ever
+        dispatches). Feed to ``collectives.precompile`` (or
         ``start(precompile_collectives=...)``) so the eager latency path
         never compiles at step time. Empty for fsdp/zero1 (GSPMD owns
         those collectives)."""
         if self.param_sharding != "replicated":
             return []
-        p = self.comm.size
         wire = self.wire_dtype if self.wire_dtype != "full" else None
-        specs = []
-        if self.buckets is not None:
-            for b in range(self.buckets.num_buckets):
-                total = sum(
-                    self.buckets.sizes[i] for i in self.buckets.buckets[b]
-                )
-                specs.append(
-                    (
-                        "allreduce", (p, total),
-                        self.buckets.bucket_dtype(b), None, wire,
-                    )
-                )
-        else:
-            # per dtype group, per-leaf widths in tree order — the fused
-            # group synchronize_gradients submits leaf-by-leaf
-            by_dtype: Dict = {}
-            for leaf in jax.tree_util.tree_leaves(self.params):
-                by_dtype.setdefault(jnp.result_type(leaf), []).append(
-                    int(np.prod(np.shape(leaf)))
-                )
-            for dt, widths in by_dtype.items():
-                specs.append(
-                    {
-                        "op": "allreduce",
-                        "layout": tuple(widths),
-                        "dtype": dt,
-                        "wire_dtype": wire,
-                    }
-                )
-        return specs
+        # per dtype group, per-leaf widths in tree order — the fused
+        # group synchronize_gradients submits leaf-by-leaf
+        by_dtype: Dict = {}
+        for leaf in jax.tree_util.tree_leaves(self.params):
+            by_dtype.setdefault(jnp.result_type(leaf), []).append(
+                int(np.prod(np.shape(leaf)))
+            )
+        return [
+            {
+                "op": "allreduce",
+                "layout": tuple(widths),
+                "dtype": dt,
+                "wire_dtype": wire,
+            }
+            for dt, widths in by_dtype.items()
+        ]
 
     def _aot_key(self, batch) -> tuple:
         return tuple(
@@ -1472,14 +1423,13 @@ class AllReduceSGDEngine:
                 # a resident epoch is one dispatch: its steps are counted
                 # and its flight event stamped here, at the epoch's end
                 _engine_metrics().steps.inc(
-                    nb, mode=self.mode, sharding=self.param_sharding)
+                    nb, sharding=self.param_sharding)
                 if _flight.enabled():
                     wall_t1 = time.time()
                     _flight.recorder.record_complete(
                         _flight.comm_key(self.comm), "engine.epoch",
                         wall_t1 - whole.seconds, wall_t1,
                         payload=f"examples={examples},steps={nb}",
-                        routing=self.mode,
                     )
             with _ring.span(_names.ENGINE_EPOCH_END):
                 losses_h = np.asarray(jax.device_get(losses))

@@ -11,9 +11,9 @@ TPU-native analog of ``torchmpi/nn.lua``:
   ``backward`` to launch an async allreduce per layer on a fenced stream
   (``nn.lua:112-213``); on TPU the latency-hiding belongs to XLA's
   async-collective scheduler, so the REAL backward-compute overlap lives in
-  the **in-graph bucketed path** (``in_graph_synchronize_gradients_bucketed``,
-  compiled by the engine): XLA schedules each bucket's psum concurrently
-  with remaining compute. The *eager* :class:`GradientBuckets` API
+  the **in-graph path** (``in_graph_synchronize_gradients``, compiled by
+  the engine): XLA groups the leaves' psums and places them against the
+  remaining compute. The *eager* :class:`GradientBuckets` API
   (≙ ``BlockSequential``'s equal-parameter-count partitioning,
   ``BlockSequential.lua:29-89``) launches only after the full gradient tree
   exists — its buckets overlap with EACH OTHER and with whatever host/device
@@ -36,7 +36,7 @@ import numpy as np
 from jax import lax, tree_util
 
 from .. import collectives, telemetry as _telemetry
-from ..collectives import eager
+from ..collectives import eager, primitives as _prim
 from ..runtime.communicator import Communicator
 from ..runtime.handles import SyncHandle
 from ..telemetry import names as _names
@@ -290,7 +290,6 @@ class GradientBuckets:
         wire would not engage (non-f32 bucket, below the cutoff,
         'full'). ``buf`` is donated; callers use the returned array."""
         from .. import constants as _constants
-        from ..collectives import primitives as _prim
 
         p, n = int(buf.shape[0]), int(buf.shape[1])
         wire = eager.resolve_wire_dtype(
@@ -501,29 +500,18 @@ def _sync_leaves(leaves, idxs, n, axis):
     return sent
 
 
-def in_graph_synchronize_gradients(grads, axis: str = "mpi", average: bool = True):
-    """psum every leaf over the mesh axis — the compiled analog of
-    synchronizeGradients, fused and scheduled by XLA. No flat buffer:
-    integer leaves stay exact and no leaf is promoted."""
-    leaves, treedef = tree_util.tree_flatten(grads)
-    n = lax.psum(1, axis) if average else None
-    with jax.named_scope(_names.SCOPE_GRAD_SYNC):
-        _note_sync(_sync_leaves(leaves, range(len(leaves)), n, axis))
-    return tree_util.tree_unflatten(treedef, leaves)
-
-
-def _sync_flat_group(leaves, idxs, dtype, n, reduce_one):
+def _sync_flat_group(leaves, idxs, dtype, n, axis, wire_dtype):
     """Pack the leaves ``idxs`` of one dtype into a flat buffer, reduce it
-    with ``reduce_one`` and cut it back into ``leaves``, each phase under
-    its own scope (``n``: what to divide the sum by, None for a plain
-    sum); returns the buffer that went to the collective. Only a wire
-    format that quantizes flat buffers needs this."""
+    on the compressed-wire ring and cut it back into ``leaves``, each
+    phase under its own scope (``n``: what to divide the sum by, None for
+    a plain sum); returns the buffer that went to the collective. Only a
+    wire format that quantizes flat buffers needs this."""
     with jax.named_scope(_names.SCOPE_PACK):
         flats = [jnp.reshape(leaves[i], (-1,)) for i in idxs]
         splits = np.cumsum([f.shape[0] for f in flats])[:-1]
         cat = jnp.concatenate(flats)
     with jax.named_scope(_names.SCOPE_REDUCE):
-        buf = reduce_one(cat)
+        buf = _prim.ring_allreduce(cat, axis, wire_dtype=wire_dtype)
     with jax.named_scope(_names.SCOPE_UNPACK):
         if n is not None:
             buf = (buf / n).astype(dtype)
@@ -532,44 +520,39 @@ def _sync_flat_group(leaves, idxs, dtype, n, reduce_one):
     return [cat]
 
 
-def in_graph_synchronize_gradients_bucketed(
-    grads, buckets: GradientBuckets, axis: str = "mpi", average: bool = True,
+def in_graph_synchronize_gradients(
+    grads, axis: str = "mpi", average: bool = True,
     wire_dtype: Optional[str] = None,
 ):
-    """Bucketed psum: the leaves of one bucket reduced together (per
-    dtype), bucket by bucket, so XLA's async-collective scheduler can
-    overlap buckets with remaining compute — the in-graph analog of
-    registerAsyncMPIBackward's per-layer overlap. At full precision a
-    bucket's leaves go to ``lax.psum`` as they lie, dtypes kept exactly.
+    """Reduce every leaf over the mesh axis: the compiled analog of
+    synchronizeGradients and of registerAsyncMPIBackward's per-layer
+    overlap, which here is XLA's to schedule. The leaves go to one
+    ``lax.psum`` as they lie: no flat buffer, integer leaves stay exact
+    and no leaf is promoted.
 
     ``wire_dtype`` ('bf16' | 'int8') replaces the psum with the
-    compressed-wire ppermute ring for f32 groups above the tuned cutoff
-    (block-quantized send, f32 accumulate) — the in-graph path of the
-    EQuARX-style wire format. Quantization works on a flat buffer, so
-    such a group, and only such a group, is packed into one."""
-    from ..collectives import primitives as _prim
-
-    leaves = list(tree_util.tree_leaves(grads))
+    compressed-wire ppermute ring (block-quantized send, f32 accumulate)
+    for a dtype group the wire engages for: float32 leaves that together
+    reach the tuned cutoff. Quantization works on a flat buffer, so such
+    a group, and only such a group, is packed into one."""
+    leaves, treedef = tree_util.tree_flatten(grads)
     n = lax.psum(1, axis) if average else None
-    reduced = []
-    for b in range(buckets.num_buckets):
-        by_dtype: Dict = {}
-        for i in buckets.buckets[b]:
-            by_dtype.setdefault(jnp.result_type(leaves[i]), []).append(i)
-        # the bucket's index in the name: tm.grad_sync/b<b>/reduce, ...
-        with jax.named_scope(_names.SCOPE_GRAD_SYNC), \
-                jax.named_scope(f"b{b}"):
-            for dtype, idxs in by_dtype.items():
-                nelem = sum(int(np.prod(leaves[i].shape)) for i in idxs)
-                if _prim.wire_engages(wire_dtype, dtype, nelem):
-                    reduced += _sync_flat_group(
-                        leaves, idxs, dtype, n,
-                        lambda c: _prim.ring_allreduce(
-                            c, axis, wire_dtype=wire_dtype))
-                else:
-                    reduced += _sync_leaves(leaves, idxs, n, axis)
+    by_dtype: Dict = {}
+    for i, leaf in enumerate(leaves):
+        by_dtype.setdefault(jnp.result_type(leaf), []).append(i)
+    lie, reduced = [], []
+    with jax.named_scope(_names.SCOPE_GRAD_SYNC):
+        for dtype, idxs in by_dtype.items():
+            nelem = sum(int(np.prod(leaves[i].shape)) for i in idxs)
+            if _prim.wire_engages(wire_dtype, dtype, nelem):
+                reduced += _sync_flat_group(
+                    leaves, idxs, dtype, n, axis, wire_dtype)
+            else:
+                lie += idxs
+        if lie:
+            reduced += _sync_leaves(leaves, sorted(lie), n, axis)
     _note_sync(reduced)
-    return tree_util.tree_unflatten(buckets.treedef, leaves)
+    return tree_util.tree_unflatten(treedef, leaves)
 
 
 def in_graph_synchronize_parameters(params, axis: str = "mpi", root: int = 0):

@@ -31,7 +31,8 @@ spanning dispatch -> wait. PR 18's overlap ledger
 (:func:`~torchmpi_tpu.telemetry.criticalpath.overlap_ledger`) then
 *measures* the realized overlap fraction per schedule: disjoint spans
 ('none') read ~0, overlapped spans ('reverse') read toward
-``1 - 1/num_buckets`` — the bench.py microbench gate.
+``1 - 1/num_buckets`` (``scripts/overlap_smoke.py`` checks the two rows
+on two processes; ``tests/test_nn.py`` that the bits do not move).
 """
 
 from __future__ import annotations

@@ -40,7 +40,8 @@ included); a different seed changes event timing but never the
 analyzer's verdict. Fault scenarios (:mod:`.faults`) are JSON files —
 rank-death waves, stragglers, partitions, BUSY storms, torn resizes —
 each naming the verdict ``telemetry.analyze`` must reach, asserted in
-CI (``scripts/ci.sh`` sim-smoke) and benched (``bench.py --sim``).
+CI (``scripts/ci.sh`` sim-smoke; the coordinator curve of
+:mod:`.bench` is gated in ``tests/test_sim.py``).
 """
 
 import importlib

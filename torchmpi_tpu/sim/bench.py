@@ -108,7 +108,8 @@ def bench_curve(worlds=DEFAULT_WORLDS, seed: int = 17
 
 def check_curve(points: List[Dict[str, Any]], seed: int = 17
                 ) -> List[str]:
-    """CI gates over the curve; failures as strings (empty = pass)."""
+    """Gates over the curve (``tests/test_sim.py``); failures as
+    strings (empty = pass)."""
     failures: List[str] = []
     by_world = {p["world"]: p for p in points}
     for p in points:
@@ -157,7 +158,7 @@ def check_curve(points: List[Dict[str, Any]], seed: int = 17
 
 def check_synth_pricing(worlds=(1024, 4096),
                         payload_elems: int = 1 << 20) -> List[str]:
-    """CI gate (``bench.py --sim --check``): the composition algebra's
+    """Gate (``tests/test_sim.py``): the composition algebra's
     synthesized plans must be generated and sim-priced at fleet scale,
     and must WIN there — at every checked world (>= 1k ranks) the best
     synthesized candidate prices strictly cheaper under the calibrated
@@ -261,7 +262,7 @@ MAX_RECOVERY_ACTIONS = 4
 
 
 def check_supervised_recovery(ranks: int = 1024) -> List[str]:
-    """CI gate (``bench.py --sim --check``): supervised death-wave
+    """Gate (``tests/test_sim.py``): supervised death-wave
     recovery at ``ranks`` must CONVERGE — the supervisor evicts the
     wave, a shrink commits, training resumes, no rollback — within
     :data:`MAX_RECOVERY_ACTIONS` actions, and the journal must replay

@@ -205,9 +205,8 @@ class SampleStore:
 def samples_from_entries(entries: List[dict],
                          store: Optional[SampleStore] = None) -> SampleStore:
     """Build (or extend) a :class:`SampleStore` from flight-recorder
-    entry dicts — the in-process path ``bench.py --microbench`` uses,
-    mirroring what the fleet aggregator accumulates from streamed
-    tails."""
+    entry dicts: the in-process mirror of what the fleet aggregator
+    accumulates from streamed tails (``tests/test_live.py``)."""
     store = store if store is not None else SampleStore()
     for e in entries:
         store.add_entry(e)

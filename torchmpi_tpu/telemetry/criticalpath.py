@@ -396,8 +396,9 @@ def overlap_ledger(ranks: Dict[int, dict]) -> dict:
     measured = 1 - span/serial, clamped to [0, 1]
 
     Judged against :func:`modeled_overlap_fraction` of the SAME plan's
-    PR 15 stage costs by callers that hold the plan (bench.py's
-    microbench gate; this module never imports the schedule IR)."""
+    PR 15 stage costs by callers that hold the plan (this module never
+    imports the schedule IR; ``tests/test_tracecontext.py`` pins both
+    fractions' arithmetic)."""
     per_plan: Dict[str, List[Tuple[float, float]]] = {}
     for data in ranks.values():
         for e in _entries_of(data):

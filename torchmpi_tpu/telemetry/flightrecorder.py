@@ -29,8 +29,9 @@ place on completion — completion of an already-evicted entry is harmless.
 
 Gating: the recorder follows the telemetry master switch
 (``TORCHMPI_TPU_TELEMETRY`` / ``telemetry.enable()``) but can also be
-enabled **alone** (:func:`enable`), which is how ``bench.py --microbench``
-isolates recorder+watchdog overhead from the metrics/span machinery.
+enabled **alone** (:func:`enable`): the recorder and the watchdog without
+the metrics/span machinery, which is how the watchdog and the live
+exporter arm it.
 Stdlib-only, like the rest of the package.
 """
 
@@ -241,8 +242,7 @@ def enabled() -> bool:
 
 
 def enable() -> None:
-    """Force the recorder on independently of the telemetry switch (the
-    overhead-isolation mode of ``bench.py --microbench``)."""
+    """Force the recorder on independently of the telemetry switch."""
     global _forced, _enabled
     _forced = True
     _enabled = True

@@ -77,7 +77,7 @@ _TUNABLE = (
 
 #: canonical LeNet gradient leaf element counts (conv1 w/b, conv2 w/b,
 #: fc1-3 w/b) — the latency-bound north-star's actual small-tensor set,
-#: shared by :func:`tune_fusion_threshold` and ``bench.py --microbench``
+#: :func:`tune_fusion_threshold`'s default
 LENET_LEAF_SIZES = (150, 6, 2400, 16, 48000, 120, 10080, 84, 840, 10)
 
 

@@ -102,7 +102,6 @@ def _layout_meta(engine, step: int, extra: Optional[Dict],
                  state: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
     return {
         "step": int(step),
-        "mode": engine.mode,
         "world": int(engine.comm.size),
         "sharding": engine.param_sharding,
         "fingerprint": _tree_fingerprint(

@@ -35,17 +35,6 @@ def dense_flops(cin: int, cout: int) -> int:
     return 2 * cin * cout
 
 
-def lenet_forward_flops(image: int = 28) -> int:
-    """Per-sample forward FLOPs of ``models.mnist.LeNet`` (28x28x1 input)."""
-    f1, h, w = conv2d_flops(image, image, 1, 32, 5, 5)
-    h, w = h // 2, w // 2  # max_pool 2x2 s2
-    f2, h, w = conv2d_flops(h, w, 32, 64, 5, 5)
-    h, w = h // 2, w // 2
-    f3 = dense_flops(h * w * 64, 256)
-    f4 = dense_flops(256, 10)
-    return f1 + f2 + f3 + f4
-
-
 def resnet_forward_flops(image: int = 224, stage_sizes=(3, 4, 6, 3),
                          bottleneck: bool = True, num_classes: int = 1000,
                          num_filters: int = 64) -> int:
