@@ -148,7 +148,8 @@ def test_the_cells_traced_rehearsal_reports_the_routing_counters(capsys):
     line = json.loads(out.strip().splitlines()[-1])
     assert rc == 0 and line["correct"] is True, out
     assert {"moe_grouped_rows_per_step", "moe_max_over_mean_load",
-            "engine_dispatch_ms"} <= set(line["rehearsed"])
+            "moe_compact_share", "engine_dispatch_ms"} <= set(
+                line["rehearsed"])
 
 
 @pytest.fixture(scope="module")

@@ -104,6 +104,56 @@ def test_the_cells_step_fits_the_chip_and_holds_no_dispatch_tensor(one_chip):
     capacity = 2 * -(-cfg["moe_num_active_primary_experts"] * tokens_a_step
                      // experts)
     assert largest < tokens_a_step * experts * capacity / 10
+    # the expert layers' two tiers: a conditional a layer forward and one
+    # in backward (the recomputed forward's is dead: the layer's own
+    # derivative rule saves its inputs alone), and in each the compact
+    # branch holds no array of all the routes' rows, [98304, 2560] or
+    # [98304, 768], while the worst case's branch does
+    routes = tokens_a_step * cfg["moe_num_active_primary_experts"]
+    wide = re.compile(r"\[%d,(?:%d|%d)\]" % (
+        routes, cfg["hidden_size"], cfg["moe_ffn_hidden_size"]))
+    branches = conditional_branches(text)
+    assert len(branches) == 2 * cfg["num_hidden_layers"]
+    for pair in branches:
+        assert sorted(bool(wide.search(body)) for body in pair) == [
+            False, True]
+
+
+def conditional_branches(text):
+    """For each ``conditional`` of a compiled module's text, the text of
+    each of its branches with every computation the branch calls."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        header = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if header:
+            name = header.group(1)
+            bodies[name] = []
+        elif line.rstrip() == "}":
+            name = None
+        elif name:
+            bodies[name].append(line)
+
+    def reached(name, seen):
+        if name in bodies and name not in seen:
+            seen.add(name)
+            for line in bodies[name]:
+                for called in re.findall(
+                        r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)",
+                        line):
+                    reached(called, seen)
+        return seen
+
+    found = []
+    for lines in bodies.values():
+        for line in lines:
+            named = re.search(
+                r" conditional\(.*branch_computations=\{([^}]*)\}", line)
+            if named:
+                found.append(["\n".join(
+                    "\n".join(bodies[c]) for c in reached(
+                        b.strip().lstrip("%"), set()))
+                    for b in named.group(1).split(",")])
+    return found
 
 
 @pytest.mark.parametrize("head_dim,fused", [(128, True), (256, True),
@@ -144,7 +194,8 @@ def test_the_configuration_is_a_cell_of_the_benchmark():
     assert sorted(m["name"] for m in new) == [
         "attn_full_ms_per_step", "attn_kernel_ms_per_step",
         "attn_kernel_share", "attn_window_ms_per_step",
-        "moe_experts_ms_per_step", "moe_grouped_rows_per_step",
+        "moe_compact_share", "moe_experts_ms_per_step",
+        "moe_grouped_rows_per_step",
         "moe_held_route_share", "moe_max_over_mean_load",
         "moe_route_ms_per_step"]
     for m in new:

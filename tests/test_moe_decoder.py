@@ -182,24 +182,28 @@ def test_blocked_attention_rejects_bad_shapes():
 
 # -- the dropless expert layer against a per-token loop --------------------
 T, D, F, E, K = 48, 16, 12, 8, 3
+# sizes at which a share under half has a compact tier of rows (it takes
+# more routes than one tile of 512): 2 of 16 experts held, 1,536 routes,
+# a tier of 512
+BIG_T, BIG_E = 512, 16
 
 
-def expert_inputs(skew=True):
+def expert_inputs(skew=True, t=T, e=E):
     ks = jax.random.split(jax.random.PRNGKey(1), 5)
-    x = jax.random.normal(ks[0], (T, D))
-    logits = jax.random.normal(ks[1], (T, E))
+    x = jax.random.normal(ks[0], (t, D))
+    logits = jax.random.normal(ks[1], (t, e))
     if skew:  # expert 2 is on every token's list, expert 5 on none
         logits = logits.at[:, 2].add(6.0).at[:, 5].add(-60.0)
     w = [0.3 * jax.random.normal(k, s) for k, s in zip(
-        ks[2:], [(E, D, F), (E, D, F), (E, F, D)])]
+        ks[2:], [(e, D, F), (e, D, F), (e, F, D)])]
     return x, logits, w
 
 
 def token_loop(x, logits, w, held):
     x, lg = np.asarray(x, np.float64), np.asarray(logits, np.float64)
     wg, wu, wd = (np.asarray(a, np.float64) for a in w)
-    y, load = np.zeros((T, D)), np.zeros(len(held))
-    for t in range(T):
+    y, load = np.zeros(x.shape), np.zeros(len(held))
+    for t in range(len(x)):
         top = np.argsort(-lg[t], kind="stable")[:K]
         gate = np.exp(lg[t][top] - lg[t][top].max())
         gate /= gate.sum()
@@ -211,10 +215,13 @@ def token_loop(x, logits, w, held):
     return y, load
 
 
-def held_layer(x, logits, w, held):
+def held_layer(x, logits, w, held, rows=False):
+    """``(y, load)`` of the layer over the experts ``held`` of ``w``'s;
+    with ``rows`` also the rows its grouped products ran over."""
     sel = jnp.asarray(held)
-    return moe_local_experts(
+    out = moe_local_experts(
         x, logits, K, w[0][sel], w[1][sel], w[2][sel], held)
+    return out if rows else out[:2]
 
 
 @pytest.mark.parametrize("held", [
@@ -232,6 +239,245 @@ def test_expert_layer_matches_a_token_loop_under_skewed_routing(held):
         assert load[held.index(2)] == T
     if 5 in held:
         assert load[held.index(5)] == 0
+
+
+@pytest.mark.parametrize("held,more,tier", [
+    ([5, 7], 0, "compact"),    # a tenth of the tier's rows in use
+    ([2, 5], 0, "compact"),    # every token on expert 2: the tier full
+    ([2, 5], 1, "all rows"),   # ... and one route more than it holds
+    ([2, 7], 0, "all rows"),   # the overloaded expert and another's
+    ([2, 5], 300, "all rows"),
+])
+def test_nothing_is_dropped_on_either_side_of_the_compact_tier(
+        held, more, tier):
+    """2 of 16 experts held: a tier of 512 rows for 1,536 routes. A step
+    takes it while its held routes fit, ``sum(sizes) <= 512``, and the
+    execution over all rows from the first route past it; either way every
+    route to a held expert is computed, as the token loop computes it."""
+    from torchmpi_tpu.parallel import ep
+
+    x, logits, w = expert_inputs(t=BIG_T, e=BIG_E)
+    # ``more`` tokens put expert 5, on no token's list, first on theirs
+    logits = logits.at[:more, 5].set(60.0)
+    tier_rows = ep.compact_rows(BIG_T * K, len(held), BIG_E)
+    assert tier_rows == 512
+    y, load, rows = jax.jit(
+        lambda *a: held_layer(*a, held, rows=True))(x, logits, w)
+    want, want_load = token_loop(x, logits, w, held)
+    np.testing.assert_array_equal(load, want_load)
+    np.testing.assert_allclose(y, want, atol=1e-5)
+    if held == [2, 5]:
+        assert load.sum() == tier_rows + more
+    assert rows == {"compact": tier_rows, "all rows": BIG_T * K}[tier]
+    assert (load.sum() <= tier_rows) == (tier == "compact")
+
+
+def sorted_routes(logits, held):
+    """``(weight, slot, order)`` as the layer makes them: each token's
+    softmax over its top ``K``, each route's place among the ``held`` (or
+    their count), the routes' stable order by that."""
+    top, chosen = jax.lax.top_k(logits, K)
+    slot_of = np.full((logits.shape[1],), len(held), np.int32)
+    slot_of[held] = np.arange(len(held))
+    slot = jnp.asarray(slot_of)[chosen].reshape(-1)
+    return (jax.nn.softmax(top, axis=-1), slot,
+            jnp.argsort(slot, stable=True).astype(jnp.int32))
+
+
+def both_executions(x, logits, w, held, through):
+    """The layer's ``(y, load)`` by its compact execution and by the one
+    over all rows: ``through`` "direct" calls the two, "cond" the layer
+    itself, which takes the compact one here, and the layer with no
+    compact tier to take."""
+    from torchmpi_tpu.parallel import ep
+
+    if through == "cond":
+        got = held_layer(x, logits, w, held)
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(ep, "compact_rows", lambda routes, *_: routes)
+            return got, held_layer(x, logits, w, held)
+    weight, slot, order = sorted_routes(logits, held)
+    mine = [a[jnp.asarray(held)] for a in w]
+    want, sizes = ep._all_rows(
+        jax.nn.relu, x, weight, slot, order, *mine)
+    got = ep._first_rows(
+        ep.compact_rows(slot.size, len(held), logits.shape[1]), jax.nn.relu,
+        x, weight, order, sizes, *mine)
+    load = sizes.astype(jnp.float32)
+    return (got, load), (want, load)
+
+
+@pytest.mark.parametrize("through", ["direct", "cond"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_compact_execution_equals_the_one_over_all_rows(dtype, through):
+    """Value, load and all five gradients, 2 of 16 held under the skewed
+    routing (expert 2's 512 rows fill the tier to its last row). The same
+    products on the same rows: only the order of the float32 sum over a
+    token's routes differs, so float32 agrees to rounding and bfloat16 to
+    a rounding of the result."""
+    x, logits, w = expert_inputs(t=BIG_T, e=BIG_E)
+    x = x.astype(dtype)
+    mix = jax.random.normal(jax.random.PRNGKey(7), x.shape)
+
+    def run(which):
+        def loss(x, lg, wg, wu, wd):
+            y, load = both_executions(
+                x, lg, [wg, wu, wd], [2, 5], through)[which]
+            return jnp.sum(y.astype(jnp.float32) * mix), (y, load)
+
+        return jax.jit(jax.value_and_grad(
+            jax.checkpoint(loss), argnums=(0, 1, 2, 3, 4), has_aux=True))(
+                x, logits, *w)
+
+    ((_, (y, load)), grads), ((_, (want, want_load)), want_g) = run(0), run(1)
+    np.testing.assert_array_equal(load, want_load)
+    assert load.sum() == 512
+    tol = 1e-6 if dtype == jnp.float32 else 2.0 ** -8
+    close = lambda a, b: np.testing.assert_allclose(  # noqa: E731
+        np.asarray(a, np.float32), np.asarray(b, np.float32),
+        atol=tol * float(jnp.max(jnp.abs(b.astype(jnp.float32)))))
+    close(y, want)
+    for a, b in zip(grads, want_g):
+        assert a.dtype == b.dtype and np.all(np.isfinite(a))
+        close(a, b)
+
+
+@pytest.mark.parametrize("rows,t,k", [
+    (64, 40, 3), (512, 100, 6), (16, 300, 1), (8, 3, 6)])
+def test_rows_are_summed_into_their_tokens_as_np_add_at_sums_them(
+        rows, t, k):
+    """The compact execution's combine (and its gather's transpose): a sum
+    by token made of a sort, gathers and shifted adds, against
+    ``np.add.at``, with tokens of no row, of one and of up to ``k``."""
+    from torchmpi_tpu.parallel import ep
+
+    rng = np.random.default_rng(rows)
+    token = rng.permutation(np.repeat(np.arange(t), k))[:rows]
+    values = rng.normal(size=(rows, 5)).astype(np.float32)
+    want = np.zeros((t, 5), np.float32)
+    np.add.at(want, token, values)
+    got = jax.jit(ep._sum_by_token, static_argnums=(2, 3))(
+        jnp.asarray(values), jnp.asarray(token, jnp.int32), t, k)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    assert 1 <= np.bincount(token, minlength=t).max() <= k
+
+
+def equations_in(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax.tree_util.tree_leaves(
+                list(eqn.params.values()),
+                is_leaf=lambda p: hasattr(p, "eqns") or hasattr(p, "jaxpr")):
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns"):
+                yield from equations_in(inner)
+
+
+def arrays_in(jaxpr):
+    return [v.aval for eqn in equations_in(jaxpr) for v in eqn.outvars]
+
+
+def conds_in(jaxpr):
+    return [e for e in equations_in(jaxpr) if e.primitive.name == "cond"]
+
+
+def test_compact_execution_holds_no_array_of_all_the_routes_rows():
+    """The shape audit. Differentiated, the compact execution holds no
+    array of ``R x d`` or ``R x f`` elements, and the layer's ``cond``s,
+    forward and backward, hand out nothing with ``R`` rows (the derivative
+    of a ``lax.cond`` as jax makes it hands every branch's residuals out of
+    every branch: the layer's own rule is there so that it does not)."""
+    from torchmpi_tpu.parallel import ep
+
+    x, logits, w = expert_inputs(t=BIG_T, e=BIG_E)
+    routes = BIG_T * K
+
+    def compact(x, lg, wg, wu, wd):
+        weight, slot, order = sorted_routes(lg, [2, 9])
+        return jnp.sum(ep._first_rows(
+            512, jax.nn.relu, x, weight, order, ep._group_sizes(slot, 2),
+            wg[:2], wu[:2], wd[:2]))
+
+    def layer(x, lg, wg, wu, wd):
+        return jnp.sum(held_layer(x, lg, [wg, wu, wd], [2, 9])[0])
+
+    compact, layer = (
+        jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2, 3, 4)))(
+            x, logits, *w).jaxpr for f in (compact, layer))
+    sizes = {a.size for a in arrays_in(compact)}
+    assert 512 * D in sizes and 512 * F in sizes  # the tier's own arrays
+    assert max(sizes) < routes * min(D, F), sorted(sizes)[-3:]
+    # ... while the layer as a whole does hold them, in its other branch
+    assert routes * D in {a.size for a in arrays_in(layer)}
+    conds = conds_in(layer)
+    assert len(conds) == 2  # forward, and the rule's own in backward
+    for eqn in conds:
+        shapes = [v.aval.shape for v in eqn.outvars]
+        assert shapes and all(s[:1] != (routes,) for s in shapes), shapes
+    assert [v.aval.shape for v in conds[0].outvars] == [(BIG_T, D)]
+
+
+def parents_layer(x, router_logits, top_k, w_gate, w_up, w_down, held,
+                  activation=jax.nn.relu):
+    """``moe_local_experts`` as it stood before the layer had a compact
+    tier (commit 705a2ff), its checks left out: the text a layer with half
+    its experts or more held must still lower to."""
+    from torchmpi_tpu.parallel.ep import _permute_rows
+
+    lax, T, d = jax.lax, *x.shape
+    E = router_logits.shape[-1]
+    k, n = int(top_k), len(held)
+    R = T * k
+    top, chosen = lax.top_k(router_logits.astype(jnp.float32), k)
+    weight = jax.nn.softmax(top, axis=-1)
+    slot_of = np.full((E,), n, np.int32)
+    slot_of[list(held)] = np.arange(n, dtype=np.int32)
+    slot = jnp.asarray(slot_of)[chosen].reshape(R)
+    order = jnp.argsort(slot, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    sizes = jnp.sum(
+        slot[:, None] == jnp.arange(n, dtype=jnp.int32)[None, :],
+        axis=0, dtype=jnp.int32)
+    held_row = _permute_rows((slot < n).reshape(R, 1), order, inverse)
+    gate_row = _permute_rows(weight.reshape(R, 1), order, inverse)
+    rows = _permute_rows(jnp.repeat(x, k, axis=0), order, inverse)
+    own = lambda a: jnp.where(held_row, a, 0)  # noqa: E731
+    dt = x.dtype
+    rows = own(rows)
+    hidden = own(
+        activation(lax.ragged_dot(rows, w_gate.astype(dt), sizes))
+        * lax.ragged_dot(rows, w_up.astype(dt), sizes))
+    hidden = own(hidden * gate_row.astype(dt))
+    rows = own(lax.ragged_dot(hidden, w_down.astype(dt), sizes))
+    routes = _permute_rows(rows, inverse, order).reshape(T, k, d)
+    y = jnp.sum(routes, axis=1, dtype=jnp.float32).astype(dt)
+    return y, sizes.astype(jnp.float32)
+
+
+@pytest.mark.parametrize("held,t,e", [
+    (list(range(8)), T, E),            # a device that holds them all
+    ([2, 3, 4, 5], T, E),              # ... or half
+    (list(range(8)), BIG_T, BIG_E),    # half of 16, at the larger size
+    ([0, 1], T, E),                    # a quarter, of too few routes
+])
+def test_a_layer_with_no_compact_tier_lowers_to_the_parents_text(
+        held, t, e):
+    x, logits, w = expert_inputs(t=t, e=e)
+    x = x.astype(jnp.bfloat16)
+    sel = jnp.asarray(held)
+
+    def text(layer):
+        def loss(x, lg, *w):
+            y, load = layer(x, lg, K, *[a[sel] for a in w], held)[:2]
+            return jnp.sum(y.astype(jnp.float32) ** 2), load
+
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(
+                x, logits, *w).as_text()
+
+    assert text(moe_local_experts) == text(parents_layer)
 
 
 def test_expert_layer_gradients_are_finite_and_match_a_dense_mixture():
@@ -423,6 +669,49 @@ def test_three_engine_steps_match_the_reference(plain, devices):
     assert value("tm_moe_experts_held") == 2
 
 
+@pytest.mark.parametrize("crowded", [False, True],
+                         ids=["compact", "past_the_tier"])
+def test_gauges_say_which_rows_each_layers_products_ran_over(
+        monkeypatch, crowded):
+    """2 of 8 experts held and 768 routes a layer: a compact tier of 512
+    rows. The tier each layer took rides the model state beside the load,
+    and becomes gauges where the epoch's loss is read. ``crowded``: a tier
+    of 8 rows, which no layer's held routes fit, so every layer takes the
+    execution over all its routes."""
+    from torchmpi_tpu.parallel import ep
+
+    seq, routes = 128, 2 * 128 * 3
+    assert ep.compact_rows(routes, 2, 8) == 512
+    if crowded:
+        monkeypatch.setattr(ep, "compact_rows", lambda *_: 8)
+    cfg = tiny_cfg()
+    cfg["model"]["experts_held"] = [0, 1]
+    model = tiny_model(cfg)
+    mpi.start(devices=jax.devices()[:1])
+    engine = AllReduceSGDEngine(
+        make_moe_lm_loss_fn(model), seeded_params(model, seq),
+        optimizer=optax.sgd(0.01), model_state=init_moe_state(model))
+    gauges = lambda: {  # noqa: E731
+        k: v["series"].get("") for k, v in
+        telemetry.metrics.snapshot().items() if k.startswith("tm_moe_")}
+    telemetry.metrics.gauge("tm_moe_compact_layers_last_step").set(-1.0)
+    batch = tokens(2, seq, cfg["vocab_size"])
+    engine.step(batch)
+    # traced, never read: the worst case, every route
+    assert gauges()["tm_moe_grouped_rows_per_step"] == 4 * routes
+    assert gauges()["tm_moe_compact_layers_last_step"] == -1.0
+    engine.train(lambda: iter([batch]), max_epochs=1)
+    load = np.asarray(engine.model_state["moe_load"])
+    rows = np.asarray(engine.model_state["moe_rows"])
+    fits = load.sum(axis=1) <= (8 if crowded else 512)
+    assert fits.tolist() == [not crowded] * 4, load.sum(axis=1)
+    np.testing.assert_array_equal(rows, np.where(fits, 512, routes))
+    assert gauges()["tm_moe_grouped_rows_per_step"] == rows.sum()
+    assert gauges()["tm_moe_compact_layers_last_step"] == fits.sum()
+    assert gauges()["tm_moe_routes_per_step"] == 4 * routes
+    assert gauges()["tm_moe_held_routes_last_step"] == load.sum()
+
+
 def test_model_scopes_nest_under_fwd_bwd_in_the_lowered_step():
     assert names.MODEL_SCOPE_NAMES == (
         "tm.attn.full", "tm.attn.window", "tm.moe.route", "tm.moe.experts",
@@ -467,12 +756,20 @@ def test_observe_state_is_called_only_at_an_epochs_loss_read():
     assert calls == [3.0, 6.0]
 
 
-def test_rows_of_no_group_may_hold_anything(monkeypatch):
+@pytest.mark.parametrize("t,e,held,boost,rows", [
+    (T, E, [0, 2, 5, 6, 7], [], T * K),          # one tier
+    (BIG_T, BIG_E, [0, 2, 5], [], 1024),         # the compact tier, half idle
+    (BIG_T, BIG_E, [0, 2, 7], [0], BIG_T * K),   # some routes past it
+])
+def test_rows_of_no_group_may_hold_anything(monkeypatch, t, e, held, boost,
+                                            rows):
     """On the chip a grouped product leaves the rows that belong to no
     group as it found them, in its result and in the gradient it hands
     back (NaN, in the first run of this layer there). Stand-in: a
     ``ragged_dot`` that poisons exactly those rows, both ways. The layer's
-    result and every gradient must come out as with the clean one."""
+    result and every gradient must come out as with the clean one, in the
+    execution over all rows (alone, and as the branch a crowded step
+    takes) and in the compact one, whose tier is never full."""
     from torchmpi_tpu.parallel import ep
 
     real = jax.lax.ragged_dot
@@ -495,15 +792,17 @@ def test_rows_of_no_group_may_hold_anything(monkeypatch):
         return poison(d_lhs, sizes), d_rhs, None
 
     poisoned.defvjp(fwd, bwd)
-    x, logits, w = expert_inputs()
-    held = [0, 2, 5]
+    x, logits, w = expert_inputs(t=t, e=e)
+    logits = logits.at[:, boost].add(6.0)  # a second expert on every list
 
     def run():
         return jax.value_and_grad(
-            lambda x, lg, w: jnp.sum(held_layer(x, lg, w, held)[0] ** 2),
-            argnums=(0, 1, 2))(x, logits, w)
+            lambda x, lg, w: (lambda y, load, rows: (jnp.sum(y ** 2), rows))(
+                *held_layer(x, lg, w, held, rows=True)),
+            argnums=(0, 1, 2), has_aux=True)(x, logits, w)
 
     want = run()
+    assert want[0][1] == rows
     monkeypatch.setattr(
         ep.lax, "ragged_dot",
         lambda lhs, rhs, sizes, **kw: poisoned(lhs, rhs, sizes))

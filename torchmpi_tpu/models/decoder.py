@@ -74,7 +74,8 @@ class MoEDecoderBlock(fnn.Module):
 
     @fnn.compact
     def __call__(self, x):
-        # x: [B, T, D] -> (x, the tokens each held expert received)
+        # x: [B, T, D] -> (x, (the tokens each held expert received, the
+        # rows the grouped products ran over))
         b, t, d = x.shape
         dense = lambda n, name: fnn.Dense(  # noqa: E731
             n, use_bias=False, dtype=self.dtype, name=name)
@@ -104,7 +105,7 @@ class MoEDecoderBlock(fnn.Module):
             epsilon=self.norm_eps, dtype=jnp.float32, name="norm_moe")(x)
         n, f = len(self.held), self.expert_width
         init = fnn.initializers.lecun_normal(in_axis=-2, out_axis=-1)
-        y, load = moe_local_experts(
+        y, load, rows = moe_local_experts(
             h.astype(self.dtype).reshape(b * t, d),
             logits.reshape(b * t, self.num_experts),
             self.top_k,
@@ -114,12 +115,13 @@ class MoEDecoderBlock(fnn.Module):
             tuple(self.held),
             activation=self.activation,
         )
-        return x + y.reshape(b, t, d), load
+        return x + y.reshape(b, t, d), (load, rows)
 
 
 class MoEDecoder(fnn.Module):
     """Decoder-only LM over ``MoEDecoderBlock``s. Returns ``(logits [B, T,
-    vocab] float32, load [layers, held] float32)``."""
+    vocab] float32, {"moe_load": [layers, held], "moe_rows": [layers]}
+    float32)``: what each layer measured of its routing."""
 
     vocab_size: int = 256
     num_layers: int = 4
@@ -150,11 +152,11 @@ class MoEDecoder(fnn.Module):
         )(tokens)
         block_cls = fnn.remat(MoEDecoderBlock) if self.remat \
             else MoEDecoderBlock
-        loads = []
+        routing = []
         for i in range(self.num_layers):
             windowed = self.window_layout[i % len(self.window_layout)]
             rotated = self.rope_layout[i % len(self.rope_layout)]
-            x, load = block_cls(
+            x, measured = block_cls(
                 num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
                 head_dim=self.head_dim, expert_width=self.expert_width,
                 num_experts=self.num_experts, top_k=self.top_k,
@@ -165,20 +167,25 @@ class MoEDecoder(fnn.Module):
                 dtype=self.dtype,
                 name=f"MoEDecoderBlock_{i}",  # the same with and without remat
             )(x)
-            loads.append(load)
+            routing.append(measured)
         x = fnn.RMSNorm(
             epsilon=self.norm_eps, dtype=jnp.float32, name="norm")(x)
         logits = fnn.Dense(
             self.vocab_size, use_bias=False, dtype=jnp.float32, name="head"
         )(x)
-        return logits, jnp.stack(loads)
+        load, rows = (jnp.stack(a) for a in zip(*routing))
+        return logits, {"moe_load": load, "moe_rows": rows}
 
 
 def init_moe_state(model: MoEDecoder):
-    """The model state the engine carries for ``make_moe_lm_loss_fn``: the
-    tokens each held expert received in the last step, by layer."""
-    return {"moe_load": jnp.zeros(
-        (model.num_layers, len(model.held)), jnp.float32)}
+    """The model state the engine carries for ``make_moe_lm_loss_fn``: by
+    layer, the tokens each held expert received in the last step, and the
+    rows the layer's grouped products ran over."""
+    return {
+        "moe_load": jnp.zeros(
+            (model.num_layers, len(model.held)), jnp.float32),
+        "moe_rows": jnp.zeros((model.num_layers,), jnp.float32),
+    }
 
 
 def make_moe_lm_loss_fn(model: MoEDecoder):
@@ -188,14 +195,15 @@ def make_moe_lm_loss_fn(model: MoEDecoder):
     rides the path batch norm's statistics take: no further output of the
     step). No auxiliary load-balancing loss. Where the engine reads an
     epoch's loss it hands the state to ``loss_fn.observe_state``, which
-    sets ``tm_moe_held_routes_last_step`` and
-    ``tm_moe_max_over_mean_load``."""
+    sets ``tm_moe_held_routes_last_step``, ``tm_moe_max_over_mean_load``,
+    ``tm_moe_grouped_rows_per_step`` and
+    ``tm_moe_compact_layers_last_step``."""
 
     def loss_fn(params, state, batch):
         tokens, targets = batch
-        logits, load = model.apply({"params": params}, tokens)
-        return lm_cross_entropy(logits, targets), {"moe_load": load}
+        logits, routing = model.apply({"params": params}, tokens)
+        return lm_cross_entropy(logits, targets), routing
 
     loss_fn.observe_state = lambda state: note_expert_load(
-        state["moe_load"])
+        state["moe_load"], state["moe_rows"])
     return loss_fn

@@ -33,6 +33,7 @@ Two expert layers, for two layouts:
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Sequence
 
 import jax
@@ -172,6 +173,194 @@ _permute_rows.defvjp(
 )
 
 
+def compact_rows(routes: int, held: int, experts: int) -> int:
+    """The rows of an expert layer's compact tier, from its shapes alone:
+    twice the share of the ``routes`` that ``held`` of ``experts`` are
+    expected to receive, rounded up to 512 rows (the tile of rows XLA's
+    grouped product works through on the TPU). Where that is no fewer
+    than the routes (half the experts held or more, or a few hundred
+    routes in all) there is no compact tier: the layer has one execution,
+    sized for every route."""
+    return min(routes, -(-2 * routes * held // (experts * 512)) * 512)
+
+
+def _own(held_row, a):
+    """``a`` with the rows of no group set to zero, forward and (the
+    select's transpose) backward. A grouped product writes its groups'
+    rows and leaves the others as it found them, in its result and in
+    the gradient it hands back alike: on the chip that is whatever the
+    buffer held, NaN included, and 0 x NaN is NaN. So such rows are
+    selected out wherever they would meet a product or a sum."""
+    return jnp.where(held_row, a, 0)
+
+
+def _grouped_experts(rows, gate_row, held_row, sizes, w_gate, w_up, w_down,
+                     activation):
+    """``(activation(rows W_g) * (rows W_u)) W_d`` weighted by ``gate_row``,
+    by grouped products over ``sizes`` rows for each held expert; the rows
+    behind the groups come out zero."""
+    with jax.named_scope(_names.SCOPE_MOE_EXPERTS):
+        dt = rows.dtype
+        rows = _own(held_row, rows)
+        hidden = _own(
+            held_row,
+            activation(lax.ragged_dot(rows, w_gate.astype(dt), sizes))
+            * lax.ragged_dot(rows, w_up.astype(dt), sizes))
+        # the route's weight goes onto the narrow side of the down
+        # projection (w (h W_d) = (w h) W_d): f columns a row, not d
+        hidden = _own(held_row, hidden * gate_row.astype(dt))
+        return _own(
+            held_row, lax.ragged_dot(hidden, w_down.astype(dt), sizes))
+
+
+def _group_sizes(slot, n):
+    return jnp.sum(
+        slot[:, None] == jnp.arange(n, dtype=jnp.int32)[None, :],
+        axis=0, dtype=jnp.int32)  # [held] rows of each group
+
+
+def _all_rows(activation, x, weight, slot, order, w_gate, w_up, w_down):
+    """The layer over all ``R = T * top_k`` sorted rows, the worst case
+    (every route landing here): ``(y, sizes)``. The one execution where
+    half the experts or more are held; elsewhere the branch a step takes
+    when more routes arrive than the compact tier holds, and what the
+    compact execution is tested against."""
+    (T, d), (_, k), n = x.shape, weight.shape, w_gate.shape[0]
+    R = T * k
+    with jax.named_scope(_names.SCOPE_MOE_ROUTE):
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        sizes = _group_sizes(slot, n)
+        # sorted as the rows are: which of them belong to a group at all,
+        # and each row's route's weight
+        held_row = _permute_rows(
+            (slot < n).reshape(R, 1), order, inverse)
+        gate_row = _permute_rows(weight.reshape(R, 1), order, inverse)
+        rows = _permute_rows(jnp.repeat(x, k, axis=0), order, inverse)
+    rows = _grouped_experts(
+        rows, gate_row, held_row, sizes, w_gate, w_up, w_down, activation)
+    with jax.named_scope(_names.SCOPE_MOE_COMBINE):
+        routes = _permute_rows(rows, inverse, order).reshape(T, k, d)
+        y = jnp.sum(routes, axis=1, dtype=jnp.float32).astype(x.dtype)
+    return y, sizes
+
+
+def _sum_by_token(rows, token, T, k):
+    """``[T, d]``: each of ``rows`` ``[C, d]`` added into row ``token[i]``,
+    in float32, where no token has more than ``k`` rows. By gathers: on
+    the chip XLA's scatter-add of the cell's 24,576 rows of 2,560 takes
+    8.4 ms, sorted beforehand or not, and this 4.7 (``PERF.md``, PR 29).
+    The rows are put in their tokens' order, so that a token's lie
+    together; each token's first row takes in the up to ``k - 1`` behind
+    it; each token reads its first row, if it has one."""
+    C = rows.shape[0]
+    by_token = jnp.argsort(token).astype(jnp.int32)
+    tok, rows = token[by_token], rows[by_token]
+    at = jnp.arange(C, dtype=jnp.int32)
+    is_first = jnp.pad(tok[1:] != tok[:-1], (1, 0), constant_values=True)
+    # how far behind its token's first row each row lies: under k
+    behind = at - lax.cummax(jnp.where(is_first, at, 0))
+    total = rows.astype(jnp.float32)
+    for j in range(1, min(k, C)):
+        follows = jnp.pad(behind[j:] == j, (0, j))[:, None]
+        total += jnp.where(
+            follows, jnp.pad(rows[j:], ((0, j), (0, 0))), 0
+        ).astype(jnp.float32)
+    # where each token's first row is: C integers scattered, not C rows
+    first = jnp.full((T,), C, jnp.int32).at[jnp.where(is_first, tok, T)].set(
+        at, mode="drop", unique_indices=True)
+    return jnp.where(
+        (first < C)[:, None], total[jnp.minimum(first, C - 1)], 0
+    ).astype(rows.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _take_rows(x, token, T, k):
+    """``x[token]`` (``T = len(x)``); backward is ``_add_rows``."""
+    return x[token]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _add_rows(rows, token, T, k):
+    """``_sum_by_token``; backward is ``_take_rows``: each is the other's
+    transpose, and neither way is a scatter of rows (jax's own transpose
+    of a gather is)."""
+    return _sum_by_token(rows, token, T, k)
+
+
+_take_rows.defvjp(
+    lambda x, token, T, k: (x[token], token),
+    lambda T, k, token, g: (_add_rows(g, token, T, k), None))
+_add_rows.defvjp(
+    lambda rows, token, T, k: (_sum_by_token(rows, token, T, k), token),
+    lambda T, k, token, g: (_take_rows(g, token, T, k), None))
+
+
+def _first_rows(C, activation, x, weight, order, sizes, w_gate, w_up,
+                w_down):
+    """The same layer over the first ``C`` sorted rows alone, for a step
+    whose held routes all lie among them (``sum(sizes) <= C``: the stable
+    sort puts them first). No array of ``R`` rows of ``d`` or ``f``
+    columns exists, forward or backward: the rows are gathered from their
+    tokens, and the weighted results added into their tokens in float32
+    (the one's transpose is the other)."""
+    (T, d), k = x.shape, weight.shape[1]
+    with jax.named_scope(_names.SCOPE_MOE_ROUTE):
+        first = order[:C]
+        token = first // k
+        held_row = (jnp.arange(C, dtype=jnp.int32) < jnp.sum(sizes))[:, None]
+        gate_row = weight.reshape(T * k)[first][:, None]
+        rows = _take_rows(x, token, T, k)
+    rows = _grouped_experts(
+        rows, gate_row, held_row, sizes, w_gate, w_up, w_down, activation)
+    with jax.named_scope(_names.SCOPE_MOE_COMBINE):
+        return _add_rows(rows, token, T, k)
+
+
+def _tiers(C, activation, slot, order, sizes):
+    """(whether the compact tier holds this step's held routes, the
+    compact execution, the one over all rows): the two as functions of the
+    layer's differentiable inputs ``x, weight, w_gate, w_up, w_down``."""
+
+    def compact(x, weight, *w):
+        return _first_rows(C, activation, x, weight, order, sizes, *w)
+
+    def all_rows(x, weight, *w):
+        return _all_rows(activation, x, weight, slot, order, *w)[0]
+
+    return jnp.sum(sizes) <= C, compact, all_rows
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _tiered(C, activation, slot, order, sizes, *inputs):
+    """The layer's result ``y`` by the execution the count of held routes
+    selects; ``inputs``: ``x, weight, w_gate, w_up, w_down``.
+
+    The derivative is the taken branch's alone. Differentiated as it
+    stands, a ``lax.cond`` hands back every branch's residuals from every
+    branch, zeros where they are not its own: the compact branch would
+    write the worst case's ``[R, d]`` and ``[R, f]`` arrays after all. So
+    forward saves the inputs, and backward makes the same choice again and
+    pulls back through the one branch (whose forward it runs once more; a
+    block recomputed in backward then computes this layer's result once,
+    not twice: the recomputed one feeds nothing)."""
+    return lax.cond(*_tiers(C, activation, slot, order, sizes), *inputs)
+
+
+def _tiered_fwd(C, activation, slot, order, sizes, *inputs):
+    return (lax.cond(*_tiers(C, activation, slot, order, sizes), *inputs),
+            (slot, order, sizes, inputs))
+
+
+def _tiered_bwd(C, activation, saved, g):
+    slot, order, sizes, inputs = saved
+    fits, *tiers = _tiers(C, activation, slot, order, sizes)
+    pulls = [lambda g, *a, f=f: jax.vjp(f, *a)[1](g) for f in tiers]
+    return (None, None, None) + lax.cond(fits, *pulls, g, *inputs)
+
+
+_tiered.defvjp(_tiered_fwd, _tiered_bwd)
+
+
 def moe_local_experts(
     x,
     router_logits,
@@ -198,15 +387,26 @@ def moe_local_experts(
     activation : the gate's (ReLU: ReGLU).
 
     Every route to a held expert is kept: the routes are ordered by
-    expert, the three products of ``activation(x W_g) * (x W_u)) W_d`` run
-    as grouped products over the held experts' row groups
-    (``lax.ragged_dot``), and the weighted rows are added back to their
-    tokens. Shapes are static and sized for the worst case, all ``T *
-    top_k`` routes landing here; rows of routes to experts held elsewhere
-    sort behind every group, belong to none, and count for nothing (they
-    are set to zero on either side of every grouped product).
-    Bookkeeping is int32. Returns ``(y [T, d], load [held] float32)``:
-    the tokens each held expert received.
+    expert (a stable sort: the held experts' groups first, the routes to
+    experts held elsewhere behind every group), the three products of
+    ``activation(x W_g) * (x W_u)) W_d`` run as grouped products over the
+    held experts' row groups (``lax.ragged_dot``), and the weighted rows
+    are added back to their tokens. Rows of no group count for nothing
+    (they are set to zero on either side of every grouped product).
+
+    Shapes are static, in two tiers. The worst case is all ``R = T *
+    top_k`` routes landing here; the expected case is ``held / E`` of
+    them. Where fewer than half the experts are held, a step whose held
+    routes fit ``compact_rows(R, held, E)`` rows (twice the expected
+    share) does all its row work (gathering, masking, the products, the
+    sum) over that many rows, and a step with more takes the worst case's
+    execution: one ``lax.cond`` on the count, both branches the same
+    mathematics, nothing dropped in either. Where half or more are held
+    there is the worst case's execution alone. Bookkeeping is int32.
+
+    Returns ``(y [T, d], load [held] float32, rows [] float32)``: the
+    tokens each held expert received, and the rows this call's grouped
+    products ran over (``R`` or the compact tier's).
     """
     T, d = x.shape
     E = router_logits.shape[-1]
@@ -223,6 +423,7 @@ def moe_local_experts(
             f"expected [{n}, {d}, f], [{n}, {d}, f], [{n}, f, {d}]; got "
             f"{w_gate.shape}, {w_up.shape}, {w_down.shape}")
     R = T * k
+    C = compact_rows(R, n, E)
     with jax.named_scope(_names.SCOPE_MOE_ROUTE):
         top, chosen = lax.top_k(router_logits.astype(jnp.float32), k)
         weight = jax.nn.softmax(top, axis=-1)  # [T, k] float32
@@ -231,40 +432,16 @@ def moe_local_experts(
         slot_of[list(held)] = np.arange(n, dtype=np.int32)
         slot = jnp.asarray(slot_of)[chosen].reshape(R)
         order = jnp.argsort(slot, stable=True).astype(jnp.int32)
-        inverse = jnp.argsort(order).astype(jnp.int32)
-        sizes = jnp.sum(
-            slot[:, None] == jnp.arange(n, dtype=jnp.int32)[None, :],
-            axis=0, dtype=jnp.int32)  # [held] rows of each group
-        # sorted as the rows are: which of them belong to a group at all,
-        # and each row's route's weight
-        held_row = _permute_rows(
-            (slot < n).reshape(R, 1), order, inverse)
-        gate_row = _permute_rows(weight.reshape(R, 1), order, inverse)
-        rows = _permute_rows(jnp.repeat(x, k, axis=0), order, inverse)
-
-    def own(a):
-        """``a`` with the rows of no group set to zero, forward and (the
-        select's transpose) backward. A grouped product writes its groups'
-        rows and leaves the others as it found them, in its result and in
-        the gradient it hands back alike: on the chip that is whatever the
-        buffer held, NaN included, and 0 x NaN is NaN. So such rows are
-        selected out wherever they would meet a product or a sum."""
-        return jnp.where(held_row, a, 0)
-
-    with jax.named_scope(_names.SCOPE_MOE_EXPERTS):
-        dt = x.dtype
-        rows = own(rows)
-        hidden = own(
-            activation(lax.ragged_dot(rows, w_gate.astype(dt), sizes))
-            * lax.ragged_dot(rows, w_up.astype(dt), sizes))
-        # the route's weight goes onto the narrow side of the down
-        # projection (w (h W_d) = (w h) W_d): f columns a row, not d
-        hidden = own(hidden * gate_row.astype(dt))
-        rows = own(lax.ragged_dot(hidden, w_down.astype(dt), sizes))
-    with jax.named_scope(_names.SCOPE_MOE_COMBINE):
-        routes = _permute_rows(rows, inverse, order).reshape(T, k, d)
-        y = jnp.sum(routes, axis=1, dtype=jnp.float32).astype(dt)
-    return y, sizes.astype(jnp.float32)
+    if C == R:
+        y, sizes = _all_rows(
+            activation, x, weight, slot, order, w_gate, w_up, w_down)
+        return y, sizes.astype(jnp.float32), jnp.float32(R)
+    with jax.named_scope(_names.SCOPE_MOE_ROUTE):
+        sizes = _group_sizes(slot, n)
+    y = _tiered(
+        C, activation, slot, order, sizes, x, weight, w_gate, w_up, w_down)
+    rows = jnp.where(jnp.sum(sizes) <= C, C, R).astype(jnp.float32)
+    return y, sizes.astype(jnp.float32), rows
 
 
 def note_expert_layers(tokens: int, top_k: int, layers: int,
@@ -272,8 +449,10 @@ def note_expert_layers(tokens: int, top_k: int, layers: int,
     """Publish what one step's expert layers route, from static shapes:
     called by a model while its forward pass is traced, so the gauges
     describe the step most recently traced (as ``nn._note_sync``'s do).
-    ``tokens`` are this rank's; every route is sized for, so the rows of
-    the grouped products are the routes."""
+    ``tokens`` are this rank's. The rows of the grouped products are set
+    to the worst case here, every route, so that a step whose state was
+    never read still has a value; ``note_expert_load`` sets them to what
+    a step's layers took."""
     m = _telemetry.metrics
     routes = int(tokens) * int(top_k) * int(layers)
     m.gauge(
@@ -281,22 +460,32 @@ def note_expert_layers(tokens: int, top_k: int, layers: int,
         "routes each rank makes per step: tokens x top_k x expert layers "
         "(static shapes of the step most recently traced)",
     ).set(routes)
-    m.gauge(
-        "tm_moe_grouped_rows_per_step",
-        "rows each rank's grouped expert products are sized for per step: "
-        "every route, wherever its expert is held",
-    ).set(routes)
+    _grouped_rows_gauge().set(routes)
     m.gauge(
         "tm_moe_experts_held",
         "experts this rank holds in each expert layer",
     ).set(int(held))
 
 
-def note_expert_load(load) -> None:
-    """Publish a step's measured routing from ``load`` (host array,
-    ``[layers, held]``: the tokens each held expert received): the routes
-    kept here, and the worst layer's largest load over its mean load."""
+def _grouped_rows_gauge():
+    return _telemetry.metrics.gauge(
+        "tm_moe_grouped_rows_per_step",
+        "rows each rank's grouped expert products ran over, summed over "
+        "the layers, in the last step read: a layer's compact tier or "
+        "all its routes (all of them until a step is read)",
+    )
+
+
+def note_expert_load(load, rows) -> None:
+    """Publish a step's measured routing (host arrays). ``load``
+    ``[layers, held]``, the tokens each held expert received: the routes
+    kept here, and the worst layer's largest load over its mean load.
+    ``rows`` ``[layers]``, the rows each layer's grouped products ran
+    over: their sum, and the layers that took the compact tier (fewer
+    rows than the layer's routes, as ``note_expert_layers`` published
+    them when the step was traced)."""
     load = np.asarray(load, np.float64)
+    rows = np.asarray(rows, np.float64)
     m = _telemetry.metrics
     m.gauge(
         "tm_moe_held_routes_last_step",
@@ -309,3 +498,10 @@ def note_expert_load(load) -> None:
         "largest held expert's tokens over the mean held expert's, of "
         "the layer where that is worst, in the last step read",
     ).set(float(np.max(load.max(axis=-1) / np.maximum(mean, 1e-9))))
+    _grouped_rows_gauge().set(float(rows.sum()))
+    routes = m.gauge("tm_moe_routes_per_step").value() or 0.0
+    m.gauge(
+        "tm_moe_compact_layers_last_step",
+        "expert layers whose held routes fit the compact tier of rows in "
+        "the last step read, so that no row work was sized for all routes",
+    ).set(float(np.sum(rows < routes / len(rows))))
