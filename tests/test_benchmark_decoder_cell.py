@@ -1,8 +1,10 @@
-"""The benchmark's pieces for the configuration ``smallthinker-21b-a3b``
-(the sparse decoder of ``tests/test_moe_decoder.py`` at the published
-widths): its file, its operation count, its data, the reader of its inner
-scopes, and its cell's whole run at the rehearsal's sizes, sound, broken
-and with the fp8 control in the program's place."""
+"""The benchmark's pieces for the two decoder configurations,
+``smallthinker-21b-a3b`` and ``keye-vl-2-30b-a3b`` (the sparse decoders of
+``tests/test_moe_decoder.py`` and ``tests/test_selected_attention.py`` at
+the published widths): each one's file, its operation count, its data, the
+reader of its inner scopes, and its cell's whole run at the rehearsal's
+sizes, sound, broken and with the fp8 control in the program's place. What
+is the same for both is one test with a case for each."""
 
 import importlib.util
 import json
@@ -15,6 +17,8 @@ import pytest
 ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 CONFIG = "smallthinker-21b-a3b"
+KEYE = "keye-vl-2-30b-a3b"
+CONFIGS = [CONFIG, KEYE]
 CELL = CONFIG + ".stream.x1"
 
 
@@ -42,6 +46,14 @@ def test_inner_scope_reader_sees_through_wrappers():
          "tm.moe.route"),
         (pre + "jvp(MoEDecoder)/tm.moe.combine/reduce_sum",
          "tm.moe.combine"),
+        (pre + "jvp(MoEDecoder)/MoEDecoderBlock_0/tm.attn.index/index_q/"
+         "dot_general", "tm.attn.index"),
+        (pre + "jvp(MoEDecoder)/checkpoint/MoEDecoderBlock_2/cond/"
+         "branch_0_fun/tm.attn.select/tm_attn_select_kth/pallas_call",
+         "tm.attn.select"),
+        (pre + "transpose(jvp(MoEDecoder))/MoEDecoderBlock_1/cond/"
+         "branch_0_fun/tm.attn.sparse/vmap(splash_mqa_dkv_no_residuals)/"
+         "pallas_call", "tm.attn.sparse"),
         (pre + "jvp(MoEDecoder)/MoEDecoderBlock_3/q/dot_general", None),
         ("jit(tm_step)/shard_map/tm.optimizer/mul", None), ("", None),
     ]:
@@ -109,11 +121,12 @@ def test_configuration_file_keeps_the_published_widths():
     assert tiny["model"]["router_outputs"] == 8
 
 
-def test_zipf_token_ids_are_seeded_and_skewed():
+@pytest.mark.parametrize("config", CONFIGS)
+def test_zipf_token_ids_are_seeded_and_skewed(config):
     from benchmark import configs
 
-    cfg = configs.load(CONFIG, rehearse=True)
-    built = configs.build(CONFIG, cfg)
+    cfg = configs.load(config, rehearse=True)
+    built = configs.build(config, cfg)
     big = 2**31 + 12345  # the driver's seeds pass 32 signed bits
     x, y = built.make_data(big, 64)
     x2, _ = built.make_data(big, 64)
@@ -126,12 +139,14 @@ def test_zipf_token_ids_are_seeded_and_skewed():
     assert counts[0] > counts[1] > counts[3] > counts[9] > counts[40]
 
 
-def test_the_cells_rehearsal_is_correct(capsys):
-    """The new cell's whole run at the rehearsal's sizes, as
+@pytest.mark.parametrize("config", CONFIGS)
+def test_the_cells_rehearsal_is_correct(capsys, config):
+    """The cell's whole run at the rehearsal's sizes, as
     ``benchmark/tests`` drives the other cells."""
     from benchmark import run as bench
 
-    rc = bench.main(["--workload", CELL, "--seed", str(2**31 + 7),
+    rc = bench.main(["--workload", config + ".stream.x1", "--seed",
+                     str(2**31 + 7),
                      "--seconds", "1", "--trace", "0", "--rehearse"])
     out = capsys.readouterr().out
     line = json.loads(out.strip().splitlines()[-1])
@@ -139,17 +154,25 @@ def test_the_cells_rehearsal_is_correct(capsys):
     assert line["metrics"] == {} and line["attempted"] >= 32
 
 
-def test_the_cells_traced_rehearsal_reports_the_routing_counters(capsys):
+@pytest.mark.parametrize("config,more", [
+    (CONFIG, set()),
+    (KEYE, {"attn_selected_pair_share", "attn_index_loss",
+            "attn_kernel_share"})], ids=CONFIGS)
+def test_the_cells_traced_rehearsal_reports_the_routing_counters(
+        capsys, config, more):
+    """... and, for the configuration that selects its keys, what the
+    selection measured (the scope metrics need a TPU's trace)."""
     from benchmark import run as bench
 
-    rc = bench.main(["--workload", CELL, "--seed", "11", "--trace", "1",
-                     "--rehearse"])
+    rc = bench.main(["--workload", config + ".stream.x1", "--seed", "11",
+                     "--trace", "1", "--rehearse"])
     out = capsys.readouterr().out
     line = json.loads(out.strip().splitlines()[-1])
     assert rc == 0 and line["correct"] is True, out
     assert {"moe_grouped_rows_per_step", "moe_max_over_mean_load",
-            "moe_compact_share", "engine_dispatch_ms"} <= set(
+            "moe_compact_share", "engine_dispatch_ms"} | more <= set(
                 line["rehearsed"])
+    assert ("attn_selected_pair_share" in line["rehearsed"]) == bool(more)
 
 
 @pytest.fixture(scope="module")
@@ -158,11 +181,120 @@ def shown():
     return _load(ROOT / "benchmark" / "tests" / "test_correct.py")
 
 
+@pytest.mark.parametrize("config", CONFIGS)
 def test_a_step_that_changes_nothing_is_not_correct_in_the_cell(
-        shown, capsys, monkeypatch):
+        shown, capsys, monkeypatch, config):
     shown.test_step_that_returns_its_state_unchanged_is_not_correct(
-        capsys, monkeypatch, CELL)
+        capsys, monkeypatch, config + ".stream.x1")
 
 
-def test_the_fp8_control_is_not_correct_in_the_cell(shown):
-    shown.test_fp8_control_is_not_correct(CELL)
+@pytest.mark.parametrize("config", CONFIGS)
+def test_the_fp8_control_is_not_correct_in_the_cell(shown, config):
+    shown.test_fp8_control_is_not_correct(config + ".stream.x1")
+
+
+# -- the configuration that selects its keys -------------------------------
+def test_flops_of_the_selecting_configuration_are_the_issues_arithmetic():
+    from benchmark import configs, decoder_flops, selected_decoder_flops
+
+    assert selected_decoder_flops.selected_pairs(16384, 2048) == 31_458_304
+    assert decoder_flops.visible_pairs(16384) == 134_225_920
+    forward = selected_decoder_flops.selected_decoder_forward_flops(
+        16384, 2048, 32, 4, 128, 768, 128, 8, 16, 18992, 4, 16, 64, 2048)
+    assert 7.85e12 < forward < 7.87e12         # ISSUE 30: 7.86 T forward
+    built = configs.build(KEYE, configs.load(KEYE))
+    assert built.flops_per_sample == 3 * forward  # 23.6 T a training step
+    # attention is counted over the selected pairs, the indexer over all
+    fewer = selected_decoder_flops.selected_decoder_forward_flops(
+        16384, 2048, 32, 4, 128, 768, 128, 8, 16, 18992, 4, 16, 64, 1024)
+    assert forward - fewer == 4 * 4 * 32 * 128 * (
+        31_458_304 - selected_decoder_flops.selected_pairs(16384, 1024))
+
+
+def test_the_selecting_configurations_file_keeps_the_published_widths():
+    """Every number of the catalog's entry under its own key, but the four
+    that are cut, which ``reduced`` and ``published`` name."""
+    cfg = json.loads(
+        (ROOT / "benchmark" / "configs" / f"{KEYE}.json").read_text())
+    catalog = {
+        "attention_bias": False, "decoder_sparse_step": 1, "head_dim": 128,
+        "hidden_act": "silu", "hidden_size": 2048,
+        "intermediate_size": 6144, "max_position_embeddings": 262144,
+        "max_window_layers": 48, "mlp_only_layers": [],
+        "model_type": "KeyeVL2", "moe_intermediate_size": 768,
+        "norm_topk_prob": True, "num_attention_heads": 32,
+        "num_experts_per_tok": 8, "num_key_value_heads": 4,
+        "rms_norm_eps": 1e-06, "rope_theta": 10000000,
+        "rope_scaling": {"mrope_section": [16, 24, 24],
+                         "rope_type": "default", "type": "default"},
+        "sa_config": {"indexer_head_dim": 64, "indexer_num_heads": 16,
+                      "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+                      "q_chunk_size": 512, "topk": 2048},
+        "sliding_window": None, "tie_word_embeddings": False,
+        "use_sliding_window": False,
+    }
+    for key, value in catalog.items():
+        assert cfg[key] == value and key not in cfg["reduced"], key
+    cut = {"num_hidden_layers": (4, 48), "num_experts": (16, 128),
+           "num_local_experts": (16, 128), "vocab_size": (18992, 151936)}
+    assert sorted(cut) == sorted(cfg["reduced"])
+    for key, (here, published) in cut.items():
+        assert cfg[key] == here and cfg["published"][key] == published
+    assert cfg["model"]["router_outputs"] == 128
+    assert cfg["model"]["experts_held"] == list(range(16))
+    assert cfg["vocab_size"] * 8 == 151936
+    assert cfg["sequence_length"] == 16384 and cfg["per_chip_batch"] == 1
+    assert "8 chips" in cfg["deployment"] and "precision highest" in cfg[
+        "indexer_precision"]
+    assert {"vision_tower", "auxiliary_loss", "indexer_bits"} == set(
+        cfg["departures"])
+    assert {"rotary", "qk_norm", "indexer", "selection",
+            "indexer_loss"} <= set(cfg["assumed"])
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    entry = next(c for c in spec["configs"] if c["name"] == KEYE)
+    assert entry["reduced"] == cfg["reduced"]
+    assert entry["source"] == cfg["source"]
+    tiny = cfg["rehearsal"]
+    assert tiny["sa_config"]["topk"] < tiny["sequence_length"]
+    assert tiny["num_key_value_heads"] == 2
+
+
+def broken_run(capsys):
+    from benchmark import run as bench
+
+    rc = bench.main(["--workload", KEYE + ".stream.x1", "--seed", "41",
+                     "--seconds", "1", "--trace", "0", "--rehearse"])
+    out = capsys.readouterr().out
+    return rc, json.loads(out.strip().splitlines()[-1]), out
+
+
+def test_one_key_too_few_selected_is_not_correct(capsys, monkeypatch):
+    """The program selecting ``topk - 1`` keys a query where the model
+    states ``topk``: the reference selects ``topk``, and the comparison
+    says so."""
+    from torchmpi_tpu.parallel import selected_attention as sa
+
+    real = sa._threshold_rows
+    monkeypatch.setattr(
+        sa, "_threshold_rows",
+        lambda scores, rows, top_k: real(scores, rows, top_k - 1))
+    rc, line, out = broken_run(capsys)
+    assert rc == 0 and line["correct"] is False, out
+    assert "OUTSIDE" in out
+
+
+def test_an_indexer_fed_bfloat16_is_not_correct(capsys, monkeypatch):
+    """The indexer reading its input rounded to bfloat16: its scores move
+    in the third digit, other keys are selected than the reference's, and
+    the comparison says so."""
+    import jax.numpy as jnp
+
+    from torchmpi_tpu.models.decoder import MoEDecoderBlock
+
+    real = MoEDecoderBlock._indexer
+    monkeypatch.setattr(
+        MoEDecoderBlock, "_indexer",
+        lambda self, h: real(self, h.astype(jnp.bfloat16)))
+    rc, line, out = broken_run(capsys)
+    assert rc == 0 and line["correct"] is False, out
+    assert "OUTSIDE" in out

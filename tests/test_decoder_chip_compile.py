@@ -8,6 +8,7 @@ import json
 import math
 import re
 import sys
+from collections import Counter
 from pathlib import Path
 
 import jax
@@ -18,6 +19,7 @@ import pytest
 ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 CONFIG = "smallthinker-21b-a3b"
+KEYE = "keye-vl-2-30b-a3b"
 
 
 @pytest.fixture(scope="module")
@@ -31,6 +33,75 @@ def one_chip():
     except Exception as e:  # noqa: BLE001 - whatever keeps it from describing
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
+
+
+def compiled_step(config, one_chip):
+    """(cfg, the parameters' shapes, the cell's training step compiled for
+    the described chip)."""
+    from benchmark import configs
+
+    cfg = configs.load(config)
+    built = configs.build(config, cfg)
+    batch, seq = cfg["per_chip_batch"], cfg["sequence_length"]
+
+    def step(params, opt_state, state, tokens):
+        (loss, state), grads = jax.value_and_grad(
+            built.loss_fn, has_aux=True)(params, state, tokens)
+        updates, opt_state = built.optimizer.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, state, loss
+
+    params, state = jax.eval_shape(built.state_at, jax.random.PRNGKey(0))
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one_chip)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+            place(params), place(jax.eval_shape(built.optimizer.init, params)),
+            place(state), (tokens, tokens)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    return cfg, params, compiled
+
+
+def test_the_selecting_cells_step_fits_the_chip_in_its_kernels(one_chip):
+    """``keye-vl-2-30b-a3b.stream.x1``'s step at the published widths: it
+    fits, every piece of the selected attention is a kernel, the selection
+    and the forward attention kernel run once a step (the layer's
+    recomputation keeps what they made), and no ``t x t`` array is held:
+    a panel of 4,096 queries at a time."""
+    from torchmpi_tpu.parallel import selected_attention as sa
+
+    cfg, params, compiled = compiled_step(KEYE, one_chip)
+    count = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    assert 465e6 < count < 466e6  # 4 layers of 96.9 M + 77.8 M of vocabulary
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    # 12 B a parameter of state and 7.0 GiB of temporaries measured here
+    # (12.2 GiB): inside the chip's 15.75 GiB
+    assert memory.argument_size_in_bytes > 12 * count
+    assert held < 13 * 2**30, memory
+    text = compiled.as_text()
+    kernels = Counter(
+        re.findall(r"%([A-Za-z_]+?)[.\d]* = [^\n]*tpu_custom_call", text))
+    layers, seq = cfg["num_hidden_layers"], cfg["sequence_length"]
+    panels = seq // sa._panel_of(seq)
+    assert panels == 4
+    once, twice = layers * panels, 2 * layers * panels
+    assert kernels == {
+        "tm_attn_index_scores": twice,       # forward, and again in backward
+        "tm_attn_select_kth": once,
+        "splash_mqa_fwd_residuals": once,
+        "tm_attn_sparse_mean_probabilities": twice,
+        "splash_mqa_dkv_no_residuals": once,
+        "tm_attn_index_grad_queries": once,
+        "tm_attn_index_grad_keys": once}, kernels
+    assert all(k.startswith(sa.SPARSE_KERNEL_EVENTS) == (
+        "index" not in k and "select" not in k) for k in kernels)
+    assert "ragged-dot" in text
+    assert f"[{seq},{seq}]" not in text  # no t x t array, of any type
 
 
 def test_the_cells_step_fits_the_chip_and_holds_no_dispatch_tensor(one_chip):
@@ -184,20 +255,38 @@ def test_a_tpu_lowering_takes_the_kernels_where_the_heads_allow(
     assert ("while(" in text) != fused
 
 
-def test_the_configuration_is_a_cell_of_the_benchmark():
-    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
-    cell = next(c for c in spec["workloads"] if c["config"] == CONFIG)
-    assert cell == {**cell, "name": CONFIG + ".stream.x1",
-                    "traffic": "stream", "chips": 1}
-    assert len(cell["why"]) <= 200
-    new = [m for m in spec["per_layer"] if m["workloads"] == [cell["name"]]]
-    assert sorted(m["name"] for m in new) == [
+@pytest.mark.parametrize("config,own", [
+    (CONFIG, [
         "attn_full_ms_per_step", "attn_kernel_ms_per_step",
         "attn_kernel_share", "attn_window_ms_per_step",
         "moe_compact_share", "moe_experts_ms_per_step",
         "moe_grouped_rows_per_step",
         "moe_held_route_share", "moe_max_over_mean_load",
-        "moe_route_ms_per_step"]
+        "moe_route_ms_per_step"]),
+    (KEYE, [
+        "attn_index_loss", "attn_index_ms_per_step",
+        "attn_select_ms_per_step", "attn_selected_pair_share",
+        "attn_sparse_kernel_roofline", "attn_sparse_ms_per_step"]),
+], ids=[CONFIG, KEYE])
+def test_the_configuration_is_a_cell_of_the_benchmark(config, own):
+    """The configuration's one cell, and the per-layer metrics that came
+    with it: those whose list of cells begins with it."""
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    cell = next(c for c in spec["workloads"] if c["config"] == config)
+    assert cell == {**cell, "name": config + ".stream.x1",
+                    "traffic": "stream", "chips": 1}
+    assert len(cell["why"]) <= 200
+    new = [m for m in spec["per_layer"] if m["workloads"][0] == cell["name"]]
+    assert sorted(m["name"] for m in new) == own
     for m in new:
         assert (ROOT / "benchmark" / "layer_metrics"
                 / f"{m['name']}.py").is_file()
+    # the two decoders share the expert layer's and the kernels' metrics,
+    # not the scopes of each other's attention
+    shared = {m["name"] for m in spec["per_layer"]
+              if {CONFIG + ".stream.x1", KEYE + ".stream.x1"} <= set(
+                  m["workloads"])}
+    assert {"moe_route_ms_per_step", "moe_compact_share",
+            "attn_kernel_share", "attn_kernel_ms_per_step"} <= shared
+    assert not shared & {"attn_full_ms_per_step", "attn_window_ms_per_step",
+                         "attn_sparse_ms_per_step"}

@@ -215,12 +215,13 @@ def token_loop(x, logits, w, held):
     return y, load
 
 
-def held_layer(x, logits, w, held, rows=False):
+def held_layer(x, logits, w, held, rows=False, activation=jax.nn.relu):
     """``(y, load)`` of the layer over the experts ``held`` of ``w``'s;
     with ``rows`` also the rows its grouped products ran over."""
     sel = jnp.asarray(held)
     out = moe_local_experts(
-        x, logits, K, w[0][sel], w[1][sel], w[2][sel], held)
+        x, logits, K, w[0][sel], w[1][sel], w[2][sel], held,
+        activation=activation)
     return out if rows else out[:2]
 
 
@@ -518,24 +519,34 @@ def test_expert_layer_rejects_bad_arguments():
         moe_local_experts(x, logits[:, :2], K, *[a[:2] for a in w], [0, 1])
 
 
+@pytest.mark.parametrize("config,activation", [
+    (CONFIG, jax.nn.relu),              # ReGLU experts
+    ("keye-vl-2-30b-a3b", jax.nn.silu),  # SwiGLU experts
+], ids=["smallthinker-21b-a3b", "keye-vl-2-30b-a3b"])
 @pytest.mark.parametrize("shares", [
     [[e] for e in range(8)],          # 8 shares of 1 expert
     [[0, 1, 2, 3], [4, 5, 6, 7]],     # 2 shares of 4
     [[6, 1], [0, 7, 3], [2], [5, 4]],  # uneven shares, out of order
 ])
-def test_shares_add_up_to_the_uncut_reference_layer(plain, shares):
+def test_shares_add_up_to_the_uncut_reference_layer(
+        shares, config, activation):
     """The share test: what every share computes of the expert layer,
-    added, is what the plain reference gives for the layer with all the
-    experts (nothing is computed by every share alike here: there is no
-    shared expert)."""
+    added, is what the configuration's plain reference gives for the layer
+    with all the experts (nothing is computed by every share alike here:
+    neither model has a shared expert). Each reference is handed the
+    router's logits as its layer would read them (before attention, or
+    after the second norm): the layer under test begins at the logits."""
     x, logits, w = expert_inputs(skew=False)
     cfg = tiny_cfg()
     cfg["model"]["experts_held"] = list(range(E))
-    whole = plain.experts(
+    whole = _load(ROOT / "benchmark" / "reference" / f"{config}.py").experts(
         x, logits, {"experts_gate": w[0], "experts_up": w[1],
                     "experts_down": w[2]},
-        {**cfg, "moe_num_active_primary_experts": K}, "float32")
-    parts, loads = zip(*(held_layer(x, logits, w, held) for held in shares))
+        {**cfg, "moe_num_active_primary_experts": K,
+         "num_experts_per_tok": K}, "float32")
+    parts, loads = zip(*(held_layer(x, logits, w, held,
+                                    activation=activation)
+                         for held in shares))
     np.testing.assert_allclose(sum(parts), whole, atol=1e-5)
     assert sum(float(l.sum()) for l in loads) == T * K  # every route, once
 
@@ -712,12 +723,24 @@ def test_gauges_say_which_rows_each_layers_products_ran_over(
     assert gauges()["tm_moe_held_routes_last_step"] == load.sum()
 
 
-def test_model_scopes_nest_under_fwd_bwd_in_the_lowered_step():
+@pytest.mark.parametrize("selecting", [False, True],
+                         ids=["full-and-window", "selected"])
+def test_model_scopes_nest_under_fwd_bwd_in_the_lowered_step(selecting):
+    """The scopes each decoder configuration opens: full and window
+    attention, or the indexer, the selection and the attention over it;
+    the expert layer's three in both."""
     assert names.MODEL_SCOPE_NAMES == (
         "tm.attn.full", "tm.attn.window", "tm.moe.route", "tm.moe.experts",
-        "tm.moe.combine")
+        "tm.moe.combine", "tm.attn.index", "tm.attn.select",
+        "tm.attn.sparse")
     cfg = tiny_cfg()
     model = tiny_model(cfg)
+    opened = set(names.MODEL_SCOPE_NAMES[:5])
+    if selecting:
+        model = model.clone(
+            window_layout=(0,), rope_layout=(1,), selected_layout=(1,),
+            index_top_k=9, index_heads=3, index_dim=8)
+        opened = set(names.MODEL_SCOPE_NAMES[2:])
     mpi.start(devices=jax.devices()[:1])
     engine = AllReduceSGDEngine(
         make_moe_lm_loss_fn(model), seeded_params(model, SEQ),
@@ -736,9 +759,17 @@ def test_model_scopes_nest_under_fwd_bwd_in_the_lowered_step():
             # the first tm. component is the engine's: fwd_bwd stays whole
             assert scopes.scope_of(op) == "tm.fwd_bwd", op
             seen.setdefault(inner, set()).add("transpose(" in op)
-    assert set(seen) == set(names.MODEL_SCOPE_NAMES), seen
-    # forward and backward alike, seen through jax's wrappers
-    assert all(kinds == {False, True} for kinds in seen.values()), seen
+    if selecting and "tm.attn.select" not in seen:
+        # what the function opens inside its own derivative rule lies in a
+        # nested location of the lowered text, a fragment of its own
+        assert '"tm.attn.select/' in text
+        seen["tm.attn.select"] = {False}
+    assert set(seen) == opened, seen
+    # forward and backward alike, seen through jax's wrappers (backward
+    # selects nothing: it makes the mask again from the saved thresholds,
+    # inside the attention's own scope)
+    assert all(kinds == {False, True} for scope, kinds in seen.items()
+               if scope != "tm.attn.select"), seen
 
 
 def test_observe_state_is_called_only_at_an_epochs_loss_read():

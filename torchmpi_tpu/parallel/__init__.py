@@ -10,6 +10,7 @@ from .ring_attention import (
     full_self_attention,
     ring_self_attention,
 )
+from .selected_attention import selected_self_attention
 from .tp import MPLinear, MPLinearOutputSplit, shard_input_features
 
 __all__ = [
@@ -23,6 +24,7 @@ __all__ = [
     "ring_self_attention",
     "full_self_attention",
     "blocked_self_attention",
+    "selected_self_attention",
     "MPLinear",
     "MPLinearOutputSplit",
     "shard_input_features",

@@ -91,10 +91,17 @@ SCOPE_ATTN_WINDOW = "tm.attn.window"  # the same within a sliding window
 SCOPE_MOE_ROUTE = "tm.moe.route"      # top-k, softmax, ordering, dispatch
 SCOPE_MOE_EXPERTS = "tm.moe.experts"  # the grouped products
 SCOPE_MOE_COMBINE = "tm.moe.combine"  # weighted rows back to their tokens
+# parallel/selected_attention.py: attention over the keys an indexer selects
+SCOPE_ATTN_INDEX = "tm.attn.index"    # the indexer: projections, norm,
+#                                       rotary, the index scores
+SCOPE_ATTN_SELECT = "tm.attn.select"  # the k-th largest a query, the mask
+SCOPE_ATTN_SPARSE = "tm.attn.sparse"  # attention over the selection, and
+#                                       the indexer's loss and its gradient
 
 MODEL_SCOPE_NAMES = (
     SCOPE_ATTN_FULL, SCOPE_ATTN_WINDOW, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
-    SCOPE_MOE_COMBINE,
+    SCOPE_MOE_COMBINE, SCOPE_ATTN_INDEX, SCOPE_ATTN_SELECT,
+    SCOPE_ATTN_SPARSE,
 )
 
 # -- what a device trace calls the fused attention kernels of
