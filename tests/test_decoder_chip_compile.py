@@ -68,11 +68,14 @@ def compiled_step(config, one_chip):
 
 def test_the_selecting_cells_step_fits_the_chip_in_its_kernels(one_chip):
     """``keye-vl-2-30b-a3b.stream.x1``'s step at the published widths: it
-    fits, every piece of the selected attention is a kernel, the selection
-    and the forward attention kernel run once a step (the layer's
-    recomputation keeps what they made), and no ``t x t`` array is held:
-    a panel of 4,096 queries at a time."""
+    fits, every piece of the selected attention is a kernel of the repo's
+    own (none of jax's splash kernels is left), the selection and both
+    forward kernels run once a step (the layer's recomputation keeps what
+    they made), the selection is nowhere an array (the kernels make it in
+    VMEM from a tile of scores), and no ``t x t`` array is held: a panel
+    of 4,096 queries at a time."""
     from torchmpi_tpu.parallel import selected_attention as sa
+    from torchmpi_tpu.telemetry import names
 
     cfg, params, compiled = compiled_step(KEYE, one_chip)
     count = sum(a.size for a in jax.tree_util.tree_leaves(params))
@@ -87,19 +90,34 @@ def test_the_selecting_cells_step_fits_the_chip_in_its_kernels(one_chip):
     kernels = Counter(
         re.findall(r"%([A-Za-z_]+?)[.\d]* = [^\n]*tpu_custom_call", text))
     layers, seq = cfg["num_hidden_layers"], cfg["sequence_length"]
-    panels = seq // sa._panel_of(seq)
+    panel = sa._panel_of(seq)
+    panels = seq // panel
     assert panels == 4
     once, twice = layers * panels, 2 * layers * panels
     assert kernels == {
         "tm_attn_index_scores": twice,       # forward, and again in backward
         "tm_attn_select_kth": once,
-        "splash_mqa_fwd_residuals": once,
-        "tm_attn_sparse_mean_probabilities": twice,
-        "splash_mqa_dkv_no_residuals": once,
+        "tm_attn_sparse_fwd": once,
+        "tm_attn_sparse_mean_probabilities": once,
+        "tm_attn_sparse_bwd": once,
         "tm_attn_index_grad_queries": once,
         "tm_attn_index_grad_keys": once}, kernels
     assert all(k.startswith(sa.SPARSE_KERNEL_EVENTS) == (
         "index" not in k and "select" not in k) for k in kernels)
+    # what the benchmark's attn_kernel_ms_per_step reads here: the forward
+    # and the backward attention kernel, no other
+    assert {k for k in kernels if k.startswith(names.ATTN_KERNEL_EVENT)} == {
+        "tm_attn_sparse_fwd", "tm_attn_sparse_bwd"}
+    # the selection is no array: no int8 mask of a panel anywhere, and no
+    # int32 table or boolean mask of a panel's [4096, keys] is an
+    # instruction's result outside a fusion (inside one, a comparison of
+    # the scores is counted where it is made); float32 panels are left:
+    # the scores, and in backward the indexer's gradient of them
+    assert not re.findall(r"(?:s8|u8)\[%d,\d+\]" % panel, text)
+    whole = re.compile(r"= \(?(s32|pred|f32)\[%d,\d{4,}\]" % panel)
+    held = Counter(m.group(1) for m in map(whole.search, outside_fusions(
+        text)) if m)
+    assert set(held) == {"f32"}, held
     assert "ragged-dot" in text
     assert f"[{seq},{seq}]" not in text  # no t x t array, of any type
 
@@ -188,6 +206,19 @@ def test_the_cells_step_fits_the_chip_and_holds_no_dispatch_tensor(one_chip):
     for pair in branches:
         assert sorted(bool(wide.search(body)) for body in pair) == [
             False, True]
+
+
+def outside_fusions(text):
+    """The instructions of a compiled module's text that are no part of a
+    fused computation: each one's result is an array in memory."""
+    inside = False
+    for line in text.splitlines():
+        if re.match(r"%?fused_computation[\w.\-]* \(.*\{$", line):
+            inside = True
+        elif line.rstrip() == "}":
+            inside = False
+        elif not inside:
+            yield line
 
 
 def conditional_branches(text):

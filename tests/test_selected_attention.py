@@ -151,9 +151,13 @@ def test_ties_go_to_the_lower_key():
     np.testing.assert_array_equal(selected[:, 0], np.minimum(at[:, 0] + 1, 5))
 
 
-@pytest.mark.parametrize("t,top_k", [(256, 40), (384, 500)])
-def test_the_bisection_kernel_finds_the_kth_largest_exactly(t, top_k):
+@pytest.mark.parametrize("t,top_k,period", [(256, 40, 0), (384, 500, 0),
+                                            (384, 90, 6)])
+def test_the_bisection_kernel_finds_the_kth_largest_exactly(
+        t, top_k, period):
     _, _, _, iq, ik, iw = inputs(3, 1, t, 2, 1, 8, 3, 64)
+    if period:  # tied scores, and rows of exact zeros
+        ik, iw = ik[:, jnp.arange(t) % period], iw.at[:, ::5].set(0.0)
     scores = sa._index_scores(
         jnp.moveaxis(iq[0], 1, 0), ik[0], iw[0], 0, True)
     want_scores = jnp.where(jnp.tril(jnp.ones((t, t), bool)),
@@ -243,6 +247,84 @@ def test_the_kernels_are_the_loops_mathematics():
         for g, w in zip(g_got, g_want):
             np.testing.assert_allclose(
                 g, w, atol=2e-5 * float(jnp.max(jnp.abs(w))))
+
+
+def interpreted(top_k, *args):
+    """The TPU's execution of one sequence, interpreted on the CPU, padded
+    as ``selected_self_attention`` pads it."""
+    t = args[0].shape[1]
+    return sa._one_sequence(
+        lambda *p: sa._kernels(*p[:-1], top_k, p[-1], True),
+        sa._ring._fused_tile(t), [x[0] for x in args])
+
+
+def tied(args, period):
+    """The same inputs with index keys that repeat with ``period`` and a
+    few queries whose every score is exactly zero: a row's scores take
+    ``period`` values, so its threshold is tied many times over, on both
+    sides of every tile edge."""
+    q, k, v, iq, ik, iw = args
+    t = ik.shape[1]
+    ik = ik[:, jnp.arange(t) % period]
+    iw = iw.at[:, ::5].set(0.0)
+    return q, k, v, iq, ik, iw
+
+
+@pytest.mark.parametrize("t,hq,hkv,top_k,period", [
+    (512, 1, 1, 70, 0),       # one tile; one KV head, a group of one
+    (300, 4, 4, 40, 0),       # shorter than a tile; 4 KV heads, groups of 1
+    (1100, 8, 1, 70, 0),      # a padded tail; a group of 8; top_k < a tile
+    (2500, 32, 4, 1500, 0),   # 3 panels; 4 groups of 8; top_k over a panel
+    (1100, 8, 1, 700, 7),     # ties at the threshold across a tile's edge
+    (2500, 2, 2, 600, 5),     # ... and across a panel's
+], ids=["one_tile", "short", "padded_group8", "panels_4x8", "ties_tile",
+        "ties_panels"])
+def test_the_kernels_are_the_loops_on_what_a_kernel_can_get_wrong(
+        t, hq, hkv, top_k, period):
+    """Outputs, ``L_I``, the pairs counted and all six gradients of the
+    hand-written kernels against the loops. A key wrongly in or out of one
+    row's selection moves that row's output by 1 / top_k of a value, far
+    over the tolerance: with tied scores this holds the tie rule as the
+    kernels evaluate it (``_chosen`` on a tile in VMEM) to the loops'."""
+    args = inputs(t, 1, t, hq, hkv, 128, 2, 64)
+    if period:
+        args = tied(args, period)
+        thr, cut, _ = sa._threshold_rows(
+            jnp.where(jnp.tril(jnp.ones((t, t), bool)), index_scores(
+                args[3][0], args[4][0], args[5][0]), -jnp.inf),
+            jnp.arange(t)[:, None], top_k)
+        assert int(jnp.sum(cut < t)) > t // 4  # rows whose ties are cut
+    kernels = partial(interpreted, top_k)
+    loops = lambda *a: selected_self_attention(  # noqa: E731
+        *a, top_k=top_k, block=512)
+    got, want = kernels(*args), loops(*args)
+    np.testing.assert_allclose(got[0], want[0][0], atol=2e-5)
+    np.testing.assert_allclose(got[1], want[1], rtol=2e-5)
+    assert float(got[2]) == float(want[2]) == sum(
+        min(i + 1, top_k) for i in range(t))
+    g_got = jax.grad(weighed(kernels), argnums=range(6))(*args)
+    g_want = jax.grad(weighed(loops), argnums=range(6))(*args)
+    for g, w in zip(g_got, g_want):
+        np.testing.assert_allclose(
+            g, w, atol=2e-5 * float(jnp.max(jnp.abs(w))))
+
+
+@pytest.mark.parametrize("t,top_k", [(700, 700), (1100, 5000)])
+def test_the_kernels_selecting_every_key_are_blocked_attention(t, top_k):
+    """``top_k >= t`` through the kernels: the output and ``dq``, ``dk``,
+    ``dv`` are ``blocked_self_attention``'s over the causal prefix."""
+    args = inputs(5, 1, t, 4, 2, 128, 2, 64)
+    weight = jnp.sin(jnp.arange(args[0].size).reshape(args[0].shape))
+    through = lambda f: jax.value_and_grad(  # noqa: E731
+        lambda q, k, v: jnp.sum(f(q, k, v) * weight),
+        argnums=(0, 1, 2))(*args[:3])
+    got = through(lambda q, k, v: interpreted(
+        top_k, q, k, v, *args[3:])[0][None])
+    want = through(lambda q, k, v: blocked_self_attention(
+        q, k, v, block=256))
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for g, w in zip(got[1], want[1]):
+        np.testing.assert_allclose(g, w, atol=2e-5)
 
 
 def test_selected_attention_rejects_bad_shapes():

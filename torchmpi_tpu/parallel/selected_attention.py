@@ -23,10 +23,14 @@ No ``t x t`` tensor a head is ever held; one sequence's float32 index scores
 
 Two executions of one mathematics, chosen as ``blocked_self_attention``
 chooses (the platform the program is lowered for, and the heads' width):
-on a TPU, Pallas kernels (the index scores, the k-th largest by bisection
-over the scores' bit pattern, jax's splash attention under the selection's
-mask, the head-summed probabilities, the indexer's gradient); elsewhere
-blocks of queries against all the keys in XLA operations.
+on a TPU, Pallas kernels of this module (the index scores, the k-th largest
+by bisection over the scores' bit pattern, attention over the selection
+forward and backward, the indexer's loss from the head-summed
+probabilities, the indexer's gradient); elsewhere blocks of queries against
+all the keys in XLA operations. In the kernels the selection is never an
+array: each takes a tile of index scores and its rows' two numbers
+(threshold and tie cut) and makes the tile's mask in VMEM, once for all the
+query heads that share the tile.
 """
 
 from __future__ import annotations
@@ -47,6 +51,7 @@ HIGHEST = lax.Precision.HIGHEST
 NEG_INF = _ring.NEG_INF
 _INT_MIN = np.int32(-2**31)
 _INT_MAX = np.int32(2**31 - 1)
+_TINY = float(np.finfo(np.float32).tiny)
 
 
 def _index_scale(heads: int, dim: int) -> float:
@@ -66,12 +71,11 @@ def _cut_of_ties(scores, thr, want):
     threshold that is still selected, so that ``want`` keys are; the count
     selected. ``scores`` ``[rows, t]``, ``thr``, ``want`` ``[rows, 1]``."""
     over = jnp.sum(scores > thr, axis=-1, keepdims=True, dtype=jnp.int32)
-    tie = scores == thr
-    ties = jnp.sum(tie, axis=-1, keepdims=True, dtype=jnp.int32)
+    ties = jnp.sum(scores == thr, axis=-1, keepdims=True, dtype=jnp.int32)
     room = want - over  # ties to take: at least one
 
     def counted():
-        rank = jnp.cumsum(tie.astype(jnp.int32), axis=-1)
+        rank = jnp.cumsum((scores == thr).astype(jnp.int32), axis=-1)
         return jnp.sum(rank <= room, axis=-1, keepdims=True,
                        dtype=jnp.int32) - 1
 
@@ -215,16 +219,25 @@ _loops.defvjp(_loops_fwd, _loops_bwd)
 SAVED = "tm_attn_selected"  # checkpoint_name of what a forward pass keeps
 TILE = 512        # the kernels' tile of queries and of keys
 SELECT_ROWS = 64  # rows of scores whose k-th largest one kernel step finds
-PANEL = 4096      # queries whose scores, masks and probabilities are held
-#                   at once: a panel of rows against the keys up to its end
+PANEL = 4096      # queries whose scores (and, in backward, the indexer's
+#                   gradient of them) are held at once: a panel of rows
+#                   against the keys up to its end
 # what a device trace calls the kernels of the attention over the selection
-# (an event's name is the kernel's HLO instruction): jax's splash kernels,
-# and the head-summed probabilities for the indexer's loss
-SPARSE_KERNEL_EVENTS = ("splash_mqa_", "tm_attn_sparse_")
+# (an event's name is the kernel's HLO instruction): forward, the indexer's
+# loss from the head-summed probabilities, backward
+SPARSE_KERNEL_EVENTS = ("tm_attn_sparse_",)
 
 
 def _tile_of(t: int) -> int:
     return next(s for s in (TILE, 256, 128) if t % s == 0)
+
+
+def _wide_tile_of(t: int) -> int:
+    """The forward attention kernel's tile of keys: two tiles of queries
+    wide where a panel allows (a row's maximum crosses the lanes once a
+    tile of keys, so a wider tile is cheaper a key: 34.3 ms a layer at 512,
+    23.0 at 1,024, 23.5 at 2,048 on a v5e; PERF.md, PR 31)."""
+    return 2 * TILE if t % (2 * TILE) == 0 else _tile_of(t)
 
 
 def _panel_of(t: int) -> int:
@@ -377,52 +390,328 @@ def _select(scores, first, top_k, interpret):
     )(scores)
 
 
-def _mean_probabilities_kernel(q_ref, k_ref, lse_ref, mask_ref, out_ref, *,
-                               first, groups):
+def _chosen_tile(scores_ref, thr_ref, cut_ref, kj, at=0, width=None):
+    """``_chosen`` on tile ``kj`` of keys (its ``width`` columns from ``at``
+    on), from the tile's scores and its rows' two numbers, all in VMEM."""
+    size = scores_ref.shape[1]
+    scores = scores_ref[:, at:at + (width or size)]
+    cols = kj * size + at + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    return scores, _chosen(scores, thr_ref[...], cut_ref[...], cols)
+
+
+def _attend_kernel(q_ref, k_ref, v_ref, scores_ref, thr_ref, cut_ref,
+                   out_ref, lse_ref, m_ref, l_ref, acc_ref, *, first):
+    """Causal attention of one KV head's query heads over the selection: a
+    tile of queries against the tiles of keys up to its diagonal (the
+    grid's inner axis), streaming softmax. The selection is made here, from
+    the tile's index scores, once for the heads that share the tile. A
+    tile of keys is several tiles of queries wide (a row's maximum crosses
+    the lanes once a tile); on the diagonal only the pieces that hold a key
+    of the tile's causal prefix are visited."""
+    from jax.experimental import pallas as pl
+
+    qi, kj = pl.program_id(1), pl.program_id(2)
+    g, bq, _ = q_ref.shape
+    bk = k_ref.shape[0]
+    last_row = first + qi * bq + bq - 1
+    last = last_row // bk
+
+    @pl.when(kj == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def visit(at, width):
+        _, chosen = _chosen_tile(scores_ref, thr_ref, cut_ref, kj, at, width)
+        # a piece with no selected key yet leaves a row's maximum at
+        # NEG_INF and its sums at what exp(0) gives; the first selected
+        # key's alpha, exp(NEG_INF - s) = 0, wipes them
+        off = jnp.where(chosen, 0.0, NEG_INF)
+        k, v = k_ref[at:at + width], v_ref[at:at + width]
+
+        def head(h, _):
+            s = _nt(q_ref[h], k) + off
+            m_prev = m_ref[h]
+            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.exp(s - m_next)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[h] = m_next
+            acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            return 0
+
+        lax.fori_loop(0, g, head, 0)
+
+    @pl.when(kj < last)
+    def _():
+        visit(0, bk)
+
+    for piece in range(bk // bq):  # the diagonal's tile, a piece at a time
+        @pl.when((kj == last) & (kj * bk + piece * bq <= last_row))
+        def _():
+            visit(piece * bq, bq)
+
+    @pl.when(kj == last)
+    def _():
+        lane = lax.broadcasted_iota(jnp.int32, lse_ref.shape, 1)
+        lse = jnp.zeros(lse_ref.shape, jnp.float32)
+        for h in range(g):
+            out_ref[h] = (acc_ref[h] / l_ref[h]).astype(out_ref.dtype)
+            lse = jnp.where(lane == h, m_ref[h] + jnp.log(l_ref[h]), lse)
+        lse_ref[...] = lse
+
+
+def _attend(q, k, v, scores, thr, cut, first, interpret):
+    """Attention over the selection, a panel of queries: ``q`` ``[hkv, g,
+    t, d]`` (scaled; the panel's rows are taken from ``first`` on), ``k``,
+    ``v`` ``[hkv, t, d]`` (the keys up to the panel's end are read), the
+    panel's ``scores`` ``[rows, keys]`` and ``thr``, ``cut`` ``[rows, 1]``
+    -> (out ``[hkv, g, rows, d]``, the log-sum-exps ``[rows, hkv * g]``
+    float32)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    hkv, g, _, d = q.shape
+    rows, keys = scores.shape
+    bq, bk = _tile_of(rows), _wide_tile_of(rows)
+    lanes = -(-g // _ring.LANES) * _ring.LANES
+    key_tile = lambda qi, kj: jnp.minimum(  # noqa: E731
+        kj, _last_tile(first, qi, bq, bk))
+    of_rows = lambda h, qi, kj: (qi, 0)  # noqa: E731
+    out, lse = pl.pallas_call(
+        partial(_attend_kernel, first=first),
+        grid=(hkv, rows // bq, keys // bk),
+        in_specs=[
+            pl.BlockSpec((None, g, bq, d),
+                         lambda h, qi, kj: (h, 0, first // bq + qi, 0)),
+            pl.BlockSpec((None, bk, d),
+                         lambda h, qi, kj: (h, key_tile(qi, kj), 0)),
+            pl.BlockSpec((None, bk, d),
+                         lambda h, qi, kj: (h, key_tile(qi, kj), 0)),
+            pl.BlockSpec((bq, bk), lambda h, qi, kj: (qi, key_tile(qi, kj))),
+            pl.BlockSpec((bq, 1), of_rows), pl.BlockSpec((bq, 1), of_rows)],
+        out_specs=[
+            pl.BlockSpec((None, g, bq, d), lambda h, qi, kj: (h, 0, qi, 0)),
+            pl.BlockSpec((None, bq, lanes), lambda h, qi, kj: (h, qi, 0))],
+        out_shape=[jax.ShapeDtypeStruct((hkv, g, rows, d), q.dtype),
+                   jax.ShapeDtypeStruct((hkv, rows, lanes), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((g, bq, 1), jnp.float32),
+                        pltpu.VMEM((g, bq, 1), jnp.float32),
+                        pltpu.VMEM((g, bq, d), jnp.float32)],
+        name="tm_attn_sparse_fwd",
+        **_params(interpret, "parallel", "parallel", "arbitrary"),
+    )(q, k, v, scores, thr, cut)
+    return out, jnp.moveaxis(lse[:, :, :g], 0, 1).reshape(rows, hkv * g)
+
+
+def _mean_probabilities_kernel(q_ref, k_ref, lse_ref, scores_ref, thr_ref,
+                               cut_ref, loss_ref, log_z_ref, m_ref, l_ref,
+                               a_ref, c_ref, *, first, groups, real):
+    """A tile of queries' rows of ``L_I``, summed over the tiles of keys
+    (the grid's inner axis): the attention's probabilities summed over the
+    heads and divided by their number stay in VMEM; what leaves is a row's
+    ``sum_S p log p - sum_S p I + log_z sum_S p`` and its ``log_z``, the
+    log-sum-exp of its selected index scores."""
     from jax.experimental import pallas as pl
 
     qi, kj = pl.program_id(0), pl.program_id(1)
-    bq, bk = out_ref.shape
-    heads = q_ref.shape[0]
-    inside = kj <= _last_tile(first, qi, bq, bk)
+    heads, bq, _ = q_ref.shape
+    bk = k_ref.shape[1]
+    last = _last_tile(first, qi, bq, bk)
 
-    @pl.when(inside)
+    @pl.when(kj == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        for ref in (l_ref, a_ref, c_ref):
+            ref[...] = jnp.zeros(ref.shape, jnp.float32)
+
+    @pl.when(kj <= last)
     def _():
         lse = lse_ref[...]
         acc = jnp.zeros((bq, bk), jnp.float32)
         for h in range(heads):
             s = _nt(q_ref[h], k_ref[h // groups])
             acc = acc + jnp.exp(s - lse[:, h:h + 1])
-        out_ref[...] = jnp.where(mask_ref[...] != 0, acc * (1.0 / heads), 0.0)
+        scores, chosen = _chosen_tile(scores_ref, thr_ref, cut_ref, kj)
+        p = jnp.where(chosen, acc * (1.0 / heads), 0.0)
+        held = jnp.where(chosen, scores, 0.0)
+        # p log p - p I; where p is 0 the logarithm's floor keeps it 0
+        a_ref[...] += jnp.sum(
+            p * (jnp.log(jnp.maximum(p, _TINY)) - held), axis=1,
+            keepdims=True)
+        c_ref[...] += jnp.sum(p, axis=1, keepdims=True)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, jnp.max(
+            jnp.where(chosen, scores, NEG_INF), axis=1, keepdims=True))
+        l_ref[...] = l_ref[...] * jnp.exp(m_prev - m_next) + jnp.sum(
+            jnp.where(chosen, jnp.exp(held - m_next), 0.0), axis=1,
+            keepdims=True)
+        m_ref[...] = m_next
 
-    @pl.when(jnp.logical_not(inside))
+    @pl.when(kj == last)
     def _():
-        out_ref[...] = jnp.zeros((bq, bk), jnp.float32)
+        log_z = m_ref[...] + jnp.log(l_ref[...])
+        rows = first + qi * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+        log_z_ref[...] = log_z
+        loss_ref[...] = jnp.where(
+            rows < real, a_ref[...] + c_ref[...] * log_z, 0.0)
 
 
-def _mean_probabilities(q, k, lse, mask, first, interpret):
-    """The attention's probabilities summed over the heads and divided by
-    their number, a panel ``[rows, keys]`` float32, zero off the selection:
-    ``q`` ``[hq, rows, d]`` (scaled), ``k`` ``[hkv, keys, d]``, ``lse``
-    ``[rows, hq]``, ``mask`` ``[rows, keys]`` int8."""
+def _mean_probabilities(q, k, lse, scores, thr, cut, first, real, interpret):
+    """``L_I`` of a panel's rows, before the mean: (each row's term ``[rows,
+    1]`` float32, zero past the ``real`` queries; each row's ``log_z``).
+    ``q`` ``[hq, t, d]`` (scaled), ``k`` ``[hkv, t, d]``, the panel's ``lse``
+    ``[rows, hq]``, ``scores`` ``[rows, keys]``, ``thr``, ``cut`` ``[rows,
+    1]``."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    hq, rows, d = q.shape
-    hkv, keys, _ = k.shape
+    hq, _, d = q.shape
+    hkv = k.shape[0]
+    rows, keys = scores.shape
     b = _tile_of(rows)
+    key_tile = lambda qi, kj: jnp.minimum(  # noqa: E731
+        kj, _last_tile(first, qi, b, b))
+    of_rows = lambda qi, kj: (qi, 0)  # noqa: E731
+    column = jax.ShapeDtypeStruct((rows, 1), jnp.float32)
     return pl.pallas_call(
-        partial(_mean_probabilities_kernel, first=first, groups=hq // hkv),
+        partial(_mean_probabilities_kernel, first=first, groups=hq // hkv,
+                real=real),
         grid=(rows // b, keys // b),
-        in_specs=[pl.BlockSpec((hq, b, d), lambda qi, kj: (0, qi, 0)),
-                  pl.BlockSpec((hkv, b, d), lambda qi, kj: (
-                      0, jnp.minimum(kj, _last_tile(first, qi, b, b)), 0)),
-                  pl.BlockSpec((b, hq), lambda qi, kj: (qi, 0)),
-                  pl.BlockSpec((b, b), lambda qi, kj: (qi, kj))],
-        out_specs=pl.BlockSpec((b, b), lambda qi, kj: (qi, kj)),
-        out_shape=jax.ShapeDtypeStruct((rows, keys), jnp.float32),
+        in_specs=[
+            pl.BlockSpec((hq, b, d), lambda qi, kj: (0, first // b + qi, 0)),
+            pl.BlockSpec((hkv, b, d),
+                         lambda qi, kj: (0, key_tile(qi, kj), 0)),
+            pl.BlockSpec((b, hq), of_rows),
+            pl.BlockSpec((b, b), lambda qi, kj: (qi, key_tile(qi, kj))),
+            pl.BlockSpec((b, 1), of_rows), pl.BlockSpec((b, 1), of_rows)],
+        out_specs=[pl.BlockSpec((b, 1), of_rows),
+                   pl.BlockSpec((b, 1), of_rows)],
+        out_shape=[column, column],
+        scratch_shapes=[pltpu.VMEM((b, 1), jnp.float32)] * 4,
         name="tm_attn_sparse_mean_probabilities",
         **_params(interpret, "parallel", "arbitrary"),
-    )(q, k, lse, mask)
+    )(q, k, lse, scores, thr, cut)
+
+
+def _attend_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
+                       scores_ref, thr_ref, cut_ref, log_z_ref, dq_ref,
+                       dk_ref, dv_ref, g_ref, dq_acc, sum_ref, *, first,
+                       groups, real, scale):
+    """Backward of ``_attend_kernel`` for every head at once, a tile of
+    queries against the tiles of keys up to its diagonal: the tile's
+    probabilities are made again keys-major (S and dP transposed, as jax's
+    fused backward kernel makes them, so that dV and dK are plain products
+    and only dQ wants a transpose), dQ gathers over the keys' tiles in
+    VMEM, dK and dV leave as this tile of queries' part. The heads'
+    probabilities are also summed: what leaves with them is ``dL_I / dI``
+    of the tile before the loss's own factor, ``softmax_S(I) - p`` on the
+    selection."""
+    from jax.experimental import pallas as pl
+
+    qi, kj = pl.program_id(0), pl.program_id(1)
+    heads, bq, _ = q_ref.shape
+    hkv, bk, _ = k_ref.shape
+    last = _last_tile(first, qi, bq, bk)
+
+    @pl.when(kj == 0)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+
+    dk_ref[...] = jnp.zeros(dk_ref.shape, jnp.float32)
+    dv_ref[...] = jnp.zeros(dv_ref.shape, jnp.float32)
+
+    @pl.when(kj <= last)
+    def _():
+        scores, chosen = _chosen_tile(scores_ref, thr_ref, cut_ref, kj)
+        off = jnp.where(chosen, 0.0, NEG_INF).T
+        sum_ref[...] = jnp.zeros(sum_ref.shape, jnp.float32)
+        for n in range(hkv):
+            k, v = k_ref[n], v_ref[n]
+
+            def head(j, _):
+                h = n * groups + j
+                q, do = q_ref[h], do_ref[h]
+                p = jnp.exp(_nt(k, q) + off - lse_ref[h])
+                ds = p * (_nt(v, do) - di_ref[h])
+                sum_ref[...] += p
+                dv_ref[n] += jnp.dot(
+                    p.astype(do.dtype), do,
+                    preferred_element_type=jnp.float32)
+                dk_ref[n] += jnp.dot(
+                    ds.astype(q.dtype), q,
+                    preferred_element_type=jnp.float32)
+                dq_acc[h] += jnp.dot(
+                    ds.T.astype(k.dtype), k,
+                    preferred_element_type=jnp.float32)
+                return 0
+
+            lax.fori_loop(0, groups, head, 0)
+        rows = first + qi * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+        g_ref[...] = jnp.where(
+            chosen & (rows < real),
+            jnp.exp(scores - log_z_ref[...])
+            - sum_ref[...].T * (1.0 / heads), 0.0)
+
+    @pl.when(kj == last)
+    def _():
+        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+def _attend_bwd(q, k, v, do, lse, di, scores, thr, cut, log_z, first, real,
+                scale, interpret):
+    """Pull ``do`` back through a panel's attention: ``q``, ``do`` ``[hq, t,
+    d]``, ``k``, ``v`` ``[hkv, t, d]``, ``lse`` and ``di = sum(out * do)``
+    ``[hq, 1, t]``; the panel's ``scores`` and ``thr``, ``cut``, ``log_z``
+    ``[rows, 1]`` -> (dq ``[hq, rows, d]``, dk and dv ``[hkv, keys, d]``
+    float32, and ``dL_I / dI`` ``[rows, keys]`` before the loss's own
+    factor; its tiles past a tile of queries' diagonal are not written,
+    and ``_index_grads`` does not read them)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    hq, _, d = q.shape
+    hkv = k.shape[0]
+    rows, keys = scores.shape
+    bq = bk = _tile_of(rows)
+    key_tile = lambda qi, kj: jnp.minimum(  # noqa: E731
+        kj, _last_tile(first, qi, bq, bk))
+    of_rows = lambda qi, kj: (qi, 0)  # noqa: E731
+    own_rows = lambda qi, kj: (0, first // bq + qi, 0)  # noqa: E731
+    own_lanes = lambda qi, kj: (0, 0, first // bq + qi)  # noqa: E731
+    own_keys = lambda qi, kj: (0, key_tile(qi, kj), 0)  # noqa: E731
+    own_tile = lambda qi, kj: (qi, key_tile(qi, kj))  # noqa: E731
+    parts = jax.ShapeDtypeStruct((rows // bq, hkv, keys, d), jnp.float32)
+    dq, dk, dv, g = pl.pallas_call(
+        partial(_attend_bwd_kernel, first=first, groups=hq // hkv,
+                real=real, scale=scale),
+        grid=(rows // bq, keys // bk),
+        in_specs=[
+            pl.BlockSpec((hq, bq, d), own_rows),
+            pl.BlockSpec((hkv, bk, d), own_keys),
+            pl.BlockSpec((hkv, bk, d), own_keys),
+            pl.BlockSpec((hq, bq, d), own_rows),
+            pl.BlockSpec((hq, 1, bq), own_lanes),
+            pl.BlockSpec((hq, 1, bq), own_lanes),
+            pl.BlockSpec((bq, bk), own_tile),
+            pl.BlockSpec((bq, 1), of_rows), pl.BlockSpec((bq, 1), of_rows),
+            pl.BlockSpec((bq, 1), of_rows)],
+        out_specs=[
+            pl.BlockSpec((hq, bq, d), lambda qi, kj: (0, qi, 0)),
+            pl.BlockSpec((None, hkv, bk, d), lambda qi, kj: (qi, 0, kj, 0)),
+            pl.BlockSpec((None, hkv, bk, d), lambda qi, kj: (qi, 0, kj, 0)),
+            pl.BlockSpec((bq, bk), own_tile)],
+        out_shape=[
+            jax.ShapeDtypeStruct((hq, rows, d), q.dtype), parts, parts,
+            jax.ShapeDtypeStruct((rows, keys), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hq, bq, d), jnp.float32),
+                        pltpu.VMEM((bk, bq), jnp.float32)],
+        name="tm_attn_sparse_bwd",
+        **_params(interpret, "parallel", "arbitrary"),
+    )(q, k, v, do, lse, di, scores, thr, cut, log_z)
+    return dq, jnp.sum(dk, axis=0), jnp.sum(dv, axis=0), g
 
 
 def _index_grad_queries_kernel(g_ref, iq_ref, ik_ref, iw_ref, diq_ref,
@@ -529,49 +818,6 @@ def _index_grads(g, iq, ik, iw, first, interpret):
     return diq, dik, diw
 
 
-def _splash(keys: int, interpret: bool):
-    """What jax's splash-attention kernels are called with here: the key
-    tile of ``ring_attention._fused_kernel`` and its one backward kernel,
-    but 512 queries a tile: a mixed tile's mask reaches the kernel as int32,
-    and 1,024 x 1,024 of them, double-buffered, pass the 16 MiB of VMEM a
-    kernel may take."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as kernel,
-    )
-
-    tile = _ring._fused_tile(keys)
-    piece = 512 if tile % 512 == 0 else tile
-    sizes = kernel.BlockSizes(
-        block_q=piece, block_kv=tile, block_kv_compute=piece,
-        block_q_dkv=piece, block_kv_dkv=tile, block_kv_dkv_compute=piece,
-        use_fused_bwd_kernel=True)
-    return kernel, sizes, dict(
-        mask_value=kernel.DEFAULT_MASK_VALUE, is_mqa=True, block_sizes=sizes,
-        residual_checkpoint_name=None, mask_function=None,
-        attn_logits_soft_cap=None, interpret=interpret)
-
-
-def _mask_tables(mask, groups: int, tile, backward: bool):
-    """splash attention's tables for a mask known only when the step runs:
-    one head's tables (which tiles are empty, whole or mixed, and the mixed
-    tiles' masks) shared by the ``groups`` query heads of a KV head."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_mask_info as info,
-    )
-
-    tables, _ = info._process_dynamic_mask(
-        mask[None], tile, is_dkv=backward)
-
-    def every(a):
-        return jnp.broadcast_to(a, (groups,) + a.shape[1:])
-
-    return tables._replace(
-        data_next=every(tables.data_next), mask_next=every(tables.mask_next),
-        block_mask=every(tables.block_mask),
-        partial_mask_blocks=tables.partial_mask_blocks.reshape(
-            (-1,) + tables.partial_mask_blocks.shape[-2:]))
-
-
 def _head_major(x):
     return jnp.moveaxis(x, 1, 0)
 
@@ -583,69 +829,36 @@ def _panels(t: int):
     return [(first, first + size) for first in range(0, t, size)]
 
 
-def _selection_of(iq, ik, iw, first, top_k, interpret, saved=None):
-    """A panel's (index scores ``[rows, keys]``, mask, thr, cut, selected a
-    row); ``iq`` head-major. With ``saved`` (thr, cut), the mask is made
-    from them again and nothing is selected anew."""
-    rows, keys = iq.shape[1], ik.shape[0]
+def _panel_scores(iq, ik, iw, first, end, interpret):
+    """A panel's index scores ``[rows, keys]``; ``iq`` head-major."""
+    iq, ik, iw = iq[:, first:end], ik[:end], iw[first:end]
     with jax.named_scope(_names.SCOPE_ATTN_INDEX):
-        scores = _index_scores(iq, ik, iw, first, interpret)
-    with jax.named_scope(_names.SCOPE_ATTN_SELECT):
-        selected = None
-        if saved is None:
-            thr = _select(scores, first, top_k, interpret)
-            want = jnp.minimum(
-                first + jnp.arange(rows)[:, None] + 1, int(top_k))
-            cut, selected = _cut_of_ties(scores, thr, want)
-        else:
-            thr, cut = saved
-        mask = _chosen(scores, thr, cut, jnp.arange(keys)[None, :])
-    return scores, mask, thr, cut, selected
-
-
-def _panel_probabilities(qh, kh, lse, mask, first, end, interpret):
-    """``_mean_probabilities`` of the panel ``[first, end)``: ``qh`` ``[hkv,
-    g, t, d]`` (scaled), ``kh`` ``[hkv, t, d]``, the panel's ``lse`` and
-    mask."""
-    hkv, g, _, d = qh.shape
-    return _mean_probabilities(
-        qh[:, :, first:end].reshape(hkv * g, end - first, d), kh[:, :end],
-        lse, mask.astype(jnp.int8), first, interpret)
+        return _index_scores(iq, ik, iw, first, interpret)
 
 
 def _kernels_forward(q, k, v, iq, ik, iw, top_k, real, interpret):
     t, hq, d = q.shape
     hkv = k.shape[1]
-    g = hq // hkv
     qs = (q * (1.0 / math.sqrt(d))).astype(q.dtype)
-    qh = _head_major(qs).reshape(hkv, g, t, d)
-    kh, vh, iqh = _head_major(k), _head_major(v), _head_major(iq)
+    qh, kh, vh, iqh = (_head_major(a) for a in (qs, k, v, iq))
+    grouped = qh.reshape(hkv, hq // hkv, t, d)
     outs, small, loss, pairs = [], [], 0.0, 0.0
     for first, end in _panels(t):
-        scores, mask, thr, cut, selected = _selection_of(
-            iqh[:, first:end], ik[:end], iw[first:end], first, top_k,
-            interpret)
+        rows = first + jnp.arange(end - first)[:, None]
+        scores = _panel_scores(iqh, ik, iw, first, end, interpret)
+        with jax.named_scope(_names.SCOPE_ATTN_SELECT):
+            thr = _select(scores, first, top_k, interpret)
+            cut, selected = _cut_of_ties(
+                scores, thr, jnp.minimum(rows + 1, int(top_k)))
         with jax.named_scope(_names.SCOPE_ATTN_SPARSE):
-            kernel, sizes, how = _splash(end, interpret)
-            tables = _mask_tables(
-                mask, g, (sizes.block_q, sizes.block_kv), backward=False)
-            out, (lse,) = jax.vmap(
-                lambda q, k, v: kernel._splash_attention_forward(
-                    tables, q, k, v, None, None, save_residuals=True, **how)
-            )(qh[:, :, first:end], kh[:, :end], vh[:, :end])
-            lse = lse.reshape(hq, end - first).T
-            mean_p = _panel_probabilities(
-                qh, kh, lse, mask, first, end, interpret)
-            log_z = jax.nn.logsumexp(
-                jnp.where(mask, scores, -jnp.inf), axis=-1, keepdims=True)
-            counts = first + jnp.arange(end - first)[:, None] < real
-            kl = jnp.where(
-                mask & counts, jax.scipy.special.xlogy(mean_p, mean_p)
-                - mean_p * (jnp.where(mask, scores, 0.0) - log_z), 0.0)
-            loss = loss + jnp.sum(kl)
-            pairs = pairs + jnp.sum(jnp.where(counts, selected, 0))
+            out, lse = _attend(
+                grouped, kh, vh, scores, thr, cut, first, interpret)
+            terms, log_z = _mean_probabilities(
+                qh, kh, lse, scores, thr, cut, first, real, interpret)
+            loss = loss + jnp.sum(terms)
+            pairs = pairs + jnp.sum(jnp.where(rows < real, selected, 0))
         outs.append(out.reshape(hq, end - first, d))
-        small.append((lse, thr, cut))
+        small.append((lse, thr, cut, log_z))
     out = jnp.moveaxis(jnp.concatenate(outs, axis=1), 0, 1)
     return (out, loss / real, jnp.asarray(pairs, jnp.float32),
             tuple(jnp.concatenate(a) for a in zip(*small)))
@@ -662,76 +875,57 @@ def _kernels_fwd(q, k, v, iq, ik, iw, top_k, real, interpret):
     out, loss, pairs, small = _kernels_forward(
         q, k, v, iq, ik, iw, top_k, real, interpret)
     # named, so that a caller that recomputes its layer in backward can keep
-    # these four (a policy of ``save_only_these_names(SAVED)``) and has the
-    # scores, the k-th largest and the forward kernel made once a step
-    out, lse, thr, cut = (checkpoint_name(a, SAVED) for a in (out, *small))
-    return (out, loss, pairs), (q, k, v, iq, ik, iw, out, lse, thr, cut)
+    # these five (a policy of ``save_only_these_names(SAVED)``) and has the
+    # k-th largest and both forward kernels run once a step
+    out, *small = (checkpoint_name(a, SAVED) for a in (out, *small))
+    return (out, loss, pairs), (q, k, v, iq, ik, iw, out, *small)
 
 
 def _kernels_bwd(top_k, real, interpret, saved, cot):
-    q, k, v, iq, ik, iw, out, lse, thr, cut = saved
+    q, k, v, iq, ik, iw, out, lse, thr, cut, log_z = saved
     dout, dloss, _ = cot
     t, hq, d = q.shape
-    hkv = k.shape[1]
-    g = hq // hkv
     f32 = jnp.float32
-    qs = (q * (1.0 / math.sqrt(d))).astype(q.dtype)
+    scale = 1.0 / math.sqrt(d)
+    dout = dout.astype(out.dtype)
+    qh, kh, vh, iqh, doh = (_head_major(a) for a in (
+        (q * scale).astype(q.dtype), k, v, iq, dout))
 
-    def heads(a):
-        return _head_major(a).reshape(hkv, g, t, d)
-
-    qh, oh, doh = heads(qs), heads(out), heads(dout.astype(out.dtype))
-    kh, vh, iqh = _head_major(k), _head_major(v), _head_major(iq)
-    lse_h = lse.T.reshape(hkv, g, t)
+    with jax.named_scope(_names.SCOPE_ATTN_SPARSE):
+        # [t, hq] -> [hq, 1, t]: a head's row along the lanes
+        lse, di = (a.T[:, None, :] for a in (lse, jnp.sum(
+            out.astype(f32) * dout.astype(f32), axis=-1)))
     dqs, diqs, diws = [], [], []
     dk, dv = jnp.zeros(kh.shape, f32), jnp.zeros(vh.shape, f32)
     dik = jnp.zeros(ik.shape, f32)
 
     def ahead(a, end, axis):
         """A panel's gradient of the keys up to ``end``, among all ``t``."""
-        return jnp.pad(a.astype(f32), [(0, t - end if i == axis else 0)
-                                       for i in range(a.ndim)])
+        return jnp.pad(a, [(0, t - end if i == axis else 0)
+                           for i in range(a.ndim)])
 
     for first, end in _panels(t):
         rows = slice(first, end)
-        scores, mask, *_ = _selection_of(
-            iqh[:, rows], ik[:end], iw[rows], first, top_k, interpret,
-            saved=(thr[rows], cut[rows]))
+        scores = _panel_scores(iqh, ik, iw, first, end, interpret)
         with jax.named_scope(_names.SCOPE_ATTN_SPARSE):
-            kernel, sizes, how = _splash(end, interpret)
-            tables = _mask_tables(
-                mask, g, (sizes.block_q_dkv, sizes.block_kv_dkv),
-                backward=True)
-
-            def pull(q, k, v, out, lse, do):
-                res = (q, k, v, None, None, out, lse, None, tables)
-                return kernel._splash_attention_bwd(
-                    False, how["mask_value"], True, sizes, None, None, None,
-                    interpret, res, do)[3:6]
-
-            dq, dkp, dvp = jax.vmap(pull)(
-                qh[:, :, rows], kh[:, :end], vh[:, :end], oh[:, :, rows],
-                lse_h[:, :, rows], doh[:, :, rows])
-            dqs.append(dq.reshape(hq, end - first, d))
+            dq, dkp, dvp, pulled = _attend_bwd(
+                qh, kh, vh, doh, lse, di, scores, thr[rows],
+                cut[rows], log_z[rows], first, real, scale, interpret)
+            dqs.append(dq)
             dk, dv = dk + ahead(dkp, end, 1), dv + ahead(dvp, end, 1)
-            mean_p = _panel_probabilities(
-                qh, kh, lse[rows], mask, first, end, interpret)
-            counts = first + jnp.arange(end - first)[:, None] < real
-            pulled = jnp.where(
-                mask & counts,
-                (jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
-                 - mean_p) * (dloss / real), 0.0)
             diq, dikp, diw = _index_grads(
                 pulled, iqh[:, rows], ik[:end], iw[rows], first, interpret)
             dik = dik + ahead(dikp, end, 0)
             diqs.append(diq)
             diws.append(diw)
-    dq = jnp.moveaxis(jnp.concatenate(dqs, axis=1), 0, 1) \
-        * (1.0 / math.sqrt(d))
+    dq = jnp.moveaxis(jnp.concatenate(dqs, axis=1), 0, 1)
+    of_loss = dloss / real  # the index gradients are linear in ``pulled``
     return (dq.astype(q.dtype), jnp.moveaxis(dk, 0, 1).astype(k.dtype),
             jnp.moveaxis(dv, 0, 1).astype(v.dtype),
-            _head_major(jnp.concatenate(diqs, axis=1)).astype(iq.dtype),
-            dik.astype(ik.dtype), jnp.concatenate(diws).astype(iw.dtype))
+            (_head_major(jnp.concatenate(diqs, axis=1)) * of_loss).astype(
+                iq.dtype),
+            (dik * of_loss).astype(ik.dtype),
+            (jnp.concatenate(diws) * of_loss).astype(iw.dtype))
 
 
 _kernels.defvjp(_kernels_fwd, _kernels_bwd)
@@ -850,8 +1044,8 @@ def selected_self_attention(q, k, v, index_q, index_k, index_w, top_k: int,
             block, a), args)
 
     def kernels(*args):
-        # a sequence at a time, unrolled: the tables of a sequence's mask
-        # are the kernels' scalar arguments
+        # a sequence at a time, unrolled (under ``lax.map`` the interpreted
+        # kernels gave wrong index gradients once)
         each = [_one_sequence(
             lambda *p: _kernels(*p[:-1], int(top_k), p[-1], False),
             _ring._fused_tile(t), [a[i] for a in args]) for i in range(b)]

@@ -104,12 +104,14 @@ MODEL_SCOPE_NAMES = (
     SCOPE_ATTN_SPARSE,
 )
 
-# -- what a device trace calls the fused attention kernels of
-# parallel/ring_attention.py (jax's splash attention, one KV head with its
-# group of query heads): an event's name is the kernel's HLO instruction,
+# -- what a device trace calls the attention kernels (an event's name is
+# the kernel's HLO instruction): jax's splash attention in
+# parallel/ring_attention.py, one KV head with its group of query heads,
 # ``%splash_mqa_fwd_residuals.3 = ...`` (forward, with the log-sum-exp
-# saved or not) or ``%splash_mqa_dkv_no_residuals.7 = ...`` (backward)
-ATTN_KERNEL_EVENT = "splash_mqa_"
+# saved or not) or ``%splash_mqa_dkv_no_residuals.7 = ...`` (backward); and
+# the repo's own forward and backward kernels of the attention over a
+# selection in parallel/selected_attention.py. Read with ``str.startswith``
+ATTN_KERNEL_EVENT = ("splash_mqa_", "tm_attn_sparse_fwd", "tm_attn_sparse_bwd")
 
 
 class SpanRecord(NamedTuple):
