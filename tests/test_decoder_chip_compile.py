@@ -20,6 +20,7 @@ ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 CONFIG = "smallthinker-21b-a3b"
 KEYE = "keye-vl-2-30b-a3b"
+LAGUNA = "laguna-s-2-1"
 
 
 @pytest.fixture(scope="module")
@@ -120,6 +121,47 @@ def test_the_selecting_cells_step_fits_the_chip_in_its_kernels(one_chip):
     assert set(held) == {"f32"}, held
     assert "ragged-dot" in text
     assert f"[{seq},{seq}]" not in text  # no t x t array, of any type
+
+
+def test_the_shared_cells_step_fits_the_chip_with_its_heads_by_layer(
+        one_chip):
+    """``laguna-s-2-1.stream.x1``'s step at the published widths: it fits
+    (1 x 16,384, not the fallback of 8,192), every layer's attention takes
+    the fused kernels with 6 or 9 query heads to the one KV head and a
+    window of 512 under tiles of 1,024, and the four expert layers have
+    their two tiers with no array of all 163,840 routes' rows in a compact
+    branch."""
+    from torchmpi_tpu.parallel import ep
+    from torchmpi_tpu.telemetry import names
+
+    cfg, params, compiled = compiled_step(LAGUNA, one_chip)
+    count = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    assert 468.8e6 < count < 469.0e6  # 19.7 + 3 x 93.6 + 91.3 + 77.1 M
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    # 12 B a parameter of state and 4.9 GiB of temporaries measured here
+    # (10.2 GiB): inside the chip's 15.75 GiB
+    assert memory.argument_size_in_bytes > 12 * count
+    assert held < 11 * 2**30, memory
+    text = compiled.as_text()
+    kernels = Counter(
+        re.findall(r"%([A-Za-z_]+?)[.\d]* = [^\n]*tpu_custom_call", text))
+    layers = cfg["num_hidden_layers"]
+    # forward and the block's recomputed forward, and one backward
+    assert kernels == {"splash_mqa_fwd_residuals": 2 * layers,
+                       "splash_mqa_dkv_no_residuals": layers}, kernels
+    assert all(k.startswith(names.ATTN_KERNEL_EVENT) for k in kernels)
+    seq = cfg["sequence_length"]
+    assert f"[{seq},{seq}]" not in text  # no t x t array, of any type
+    routes = seq * cfg["num_experts_per_tok"]
+    assert ep.compact_rows(routes, 8, 256) == 10240
+    wide = re.compile(r"\[%d,(?:%d|%d)\]" % (
+        routes, cfg["hidden_size"], cfg["moe_intermediate_size"]))
+    branches = conditional_branches(text)
+    assert len(branches) == 2 * (layers - len(cfg["mlp_only_layers"]))
+    for pair in branches:
+        assert sorted(bool(wide.search(body)) for body in pair) == [
+            False, True]
 
 
 def test_the_cells_step_fits_the_chip_and_holds_no_dispatch_tensor(one_chip):
@@ -298,7 +340,10 @@ def test_a_tpu_lowering_takes_the_kernels_where_the_heads_allow(
         "attn_index_loss", "attn_index_ms_per_step",
         "attn_select_ms_per_step", "attn_selected_pair_share",
         "attn_sparse_kernel_roofline", "attn_sparse_ms_per_step"]),
-], ids=[CONFIG, KEYE])
+    (LAGUNA, [
+        "attn_gate_ms_per_step", "attn_heads_held_share",
+        "mlp_dense_ms_per_step", "moe_shared_ms_per_step"]),
+], ids=[CONFIG, KEYE, LAGUNA])
 def test_the_configuration_is_a_cell_of_the_benchmark(config, own):
     """The configuration's one cell, and the per-layer metrics that came
     with it: those whose list of cells begins with it."""
@@ -321,3 +366,14 @@ def test_the_configuration_is_a_cell_of_the_benchmark(config, own):
             "attn_kernel_share", "attn_kernel_ms_per_step"} <= shared
     assert not shared & {"attn_full_ms_per_step", "attn_window_ms_per_step",
                          "attn_sparse_ms_per_step"}
+    # the third decoder reads what the first reads (full and window
+    # attention, the expert layer, the kernels), and nothing of the second
+    # alone; but not ``moe_compact_share``, whose reader divides by every
+    # layer where this configuration's layer 0 has no experts
+    third = {m["name"] for m in spec["per_layer"]
+             if LAGUNA + ".stream.x1" in m["workloads"]}
+    assert {m["name"] for m in spec["per_layer"]
+            if CONFIG + ".stream.x1" in m["workloads"]} - third == {
+                "moe_compact_share"}
+    assert not third & {"attn_sparse_ms_per_step", "attn_index_ms_per_step",
+                        "attn_select_ms_per_step"}

@@ -732,7 +732,7 @@ def test_model_scopes_nest_under_fwd_bwd_in_the_lowered_step(selecting):
     assert names.MODEL_SCOPE_NAMES == (
         "tm.attn.full", "tm.attn.window", "tm.moe.route", "tm.moe.experts",
         "tm.moe.combine", "tm.attn.index", "tm.attn.select",
-        "tm.attn.sparse")
+        "tm.attn.sparse", "tm.attn.gate", "tm.moe.shared", "tm.moe.dense")
     cfg = tiny_cfg()
     model = tiny_model(cfg)
     opened = set(names.MODEL_SCOPE_NAMES[:5])
@@ -740,7 +740,7 @@ def test_model_scopes_nest_under_fwd_bwd_in_the_lowered_step(selecting):
         model = model.clone(
             window_layout=(0,), rope_layout=(1,), selected_layout=(1,),
             index_top_k=9, index_heads=3, index_dim=8)
-        opened = set(names.MODEL_SCOPE_NAMES[2:])
+        opened = set(names.MODEL_SCOPE_NAMES[2:8])
     mpi.start(devices=jax.devices()[:1])
     engine = AllReduceSGDEngine(
         make_moe_lm_loss_fn(model), seeded_params(model, SEQ),

@@ -1,6 +1,7 @@
 from .decoder import (
     MoEDecoder,
     MoEDecoderBlock,
+    Rotary,
     init_moe_state,
     make_moe_lm_loss_fn,
 )
@@ -38,6 +39,7 @@ __all__ = [
     "RingAttentionBlock",
     "MoEDecoder",
     "MoEDecoderBlock",
+    "Rotary",
     "make_moe_lm_loss_fn",
     "init_moe_state",
     "cross_entropy_loss",

@@ -1,14 +1,18 @@
 """A present-day sparse decoder: RMSNorm, grouped KV heads, full,
-sliding-window or selected attention by layer (rotary position or none by
-layer), the router read before attention or after the second norm, a gated
-feed-forward of routed experts of which this device holds some.
+sliding-window or selected attention by layer (rotary position over the
+whole head, over a part of it under YaRN's frequencies, or none, by layer),
+a count of query and KV heads by layer, a sigmoid gate on each head, the
+router read before attention or after the second norm, a gated feed-forward
+of routed experts of which this device holds some, a shared expert beside
+them, and leading layers whose feed-forward is dense.
 
 Built from a layer pattern: ``window_layout[l % period]`` says whether layer
 ``l`` attends within ``window`` (else over the whole causal prefix),
 ``selected_layout[l % period]`` whether it attends to the ``index_top_k``
 keys its own indexer selects for each query, and ``rope_layout[l %
 period]`` whether its queries and keys are rotated (else the layer has no
-positional encoding at all). Attention is
+positional encoding at all); ``num_heads`` and ``num_kv_heads`` are one
+number or, as the layouts, a pattern by layer. Attention is
 ``parallel.ring_attention.blocked_self_attention`` or
 ``parallel.selected_attention.selected_self_attention`` (no ``t x t``
 tensor a head; on a TPU with heads of a multiple of 128 in fused kernels,
@@ -16,14 +20,31 @@ else in loops of XLA operations: the call decides, the model sets nothing);
 the experts are ``parallel.ep.moe_local_experts`` (dropless, told which of
 all the experts it holds: what the others would add is left out, the part
 an exchange across devices would bring). Parameters are float32, the
-matrix products run in ``dtype``, the router's product, top-k and softmax
-in float32.
+matrix products run in ``dtype``, the router's product and its rule
+(``route_weights``: top-k and softmax, or sigmoid scores normalised and
+scaled) in float32.
 
 One layer, input ``h``: ``r = h W_r`` (before the norm, before attention;
 with ``router_after_norm``, ``r = m W_r``); ``a = RMSNorm(h)``; ``h' = h +
 Attn(a) W_o`` (with ``qk_norm``, each query and key head through an RMSNorm
-of its own before the rotation); ``m = RMSNorm(h')``; ``out = h' + sum_{e in
-top_k(r), e held} softmax(r[top_k])_e (act(m W_g^e) * (m W_u^e)) W_d^e``.
+of its own before the rotation; with ``head_gate``, head ``n``'s output
+times ``sigmoid(a W_g)_n``, one number a token and head, before ``W_o``);
+``m = RMSNorm(h')``; ``out = h' + sum_{e in chosen(r), e held} w_e (act(m
+W_g^e) * (m W_u^e)) W_d^e`` with ``chosen`` and ``w`` the router's rule's
+(by default the ``top_k`` largest ``r`` and the softmax over them), plus,
+with ``shared_width``, ``(act(m S_g) * (m S_u)) S_d``, an expert every token
+takes at weight 1. The first ``dense_layers`` layers have no router and no
+experts: ``out = h' + (act(m D_g) * (m D_u)) D_d``.
+
+**A layer held by share.** The heads a layer is built with are the heads
+this device holds (a KV head with its group of query heads),
+``dense_width`` the columns of the dense feed-forward it holds, ``held``
+its experts. Each is a slice of ``W_q``, ``W_k``, ``W_v``, ``W_g``
+by column and of ``W_o`` (or ``D_d``) by row, so what the layer adds to the
+residual stream is this device's part of a sum; the parts held elsewhere
+are left out, as the absent experts' are, and the partial result goes on.
+The sum over the devices (a ``psum`` of the ``o`` and ``down`` partials) is
+not made here.
 
 A selected layer's indexer reads ``stop_gradient(a)`` in float32 at
 precision highest: ``qI = a W_qI`` (``index_heads`` of ``index_dim``), ``kI =
@@ -34,17 +55,21 @@ reaches the indexer's parameters alone.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional, Sequence
+import math
+from typing import Any, Callable, NamedTuple, Optional, Sequence, Union
 
 import flax.linen as fnn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
+from .. import telemetry as _telemetry
 from ..parallel.ep import (
     moe_local_experts,
     note_expert_layers,
     note_expert_load,
+    softmax_route_weights,
 )
 from ..parallel.ring_attention import (
     blocked_self_attention,
@@ -73,8 +98,67 @@ def rotary(x, theta: float):
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
+class Rotary(NamedTuple):
+    """A layer kind's rotation where it is not the whole head at one
+    ``theta``: the first ``width`` of each head rotated (its halves against
+    each other), the rest passed on as it is; the frequencies YaRN's
+    (arXiv:2309.00071), stretched by ``factor`` from ``original_positions``
+    where a frequency turns fewer than ``beta_slow`` times over them, left
+    alone where it turns more than ``beta_fast`` times, blended between;
+    cos and sin times ``attention_factor``."""
+
+    theta: float
+    width: int
+    factor: float
+    original_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+def yarn_inv_freq(rope: Rotary) -> np.ndarray:
+    """The ``width / 2`` frequencies of ``rope``, float32, from its numbers
+    alone: ``f_i = theta^(-2i / width)``; ``r_i = clip((i - low) / (high -
+    low), 0, 1)`` with ``low`` (``high``) the index whose frequency turns
+    ``beta_fast`` (``beta_slow``) times over the original positions, rounded
+    down (up) and kept in ``[0, width - 1]``; ``r_i f_i / factor + (1 - r_i)
+    f_i``."""
+    dim = rope.width
+    freq = rope.theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def turns_at(turns):
+        return dim * math.log(rope.original_positions / (
+            turns * 2 * math.pi)) / (2 * math.log(rope.theta))
+
+    low = min(max(math.floor(turns_at(rope.beta_fast)), 0), dim - 1)
+    high = min(max(math.ceil(turns_at(rope.beta_slow)), 0), dim - 1)
+    ramp = np.clip(
+        (np.arange(dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (ramp * freq / rope.factor + (1 - ramp) * freq).astype(np.float32)
+
+
+def rotary_part(x, rope: Rotary):
+    """``rope``'s rotation of ``x`` ``[b, t, h, d]``, positions ``0 .. t -
+    1``; float32 inside, ``x``'s dtype out."""
+    inv = jnp.asarray(yarn_inv_freq(rope))
+    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = (rope.attention_factor * jnp.cos(angle))[:, None, :]
+    sin = (rope.attention_factor * jnp.sin(angle))[:, None, :]
+    turned, passed = jnp.split(x.astype(jnp.float32), [rope.width], axis=-1)
+    x1, x2 = jnp.split(turned, 2, axis=-1)
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, passed],
+        axis=-1).astype(x.dtype)
+
+
+def _of_layer(value, i: int):
+    """``value`` of layer ``i``: one number for every layer, or a pattern
+    by layer as the layouts are."""
+    return value if isinstance(value, int) else value[i % len(value)]
+
+
 class MoEDecoderBlock(fnn.Module):
-    num_heads: int
+    num_heads: int              # query and KV heads this layer holds here
     num_kv_heads: int
     head_dim: int
     expert_width: int
@@ -83,6 +167,7 @@ class MoEDecoderBlock(fnn.Module):
     held: Sequence[int]         # ids of the experts this device holds
     window: Optional[int] = None       # None: the whole causal prefix
     rope_theta: Optional[float] = None  # None: no positional encoding
+    rope: Optional[Rotary] = None      # not None: in place of rope_theta
     norm_eps: float = 1e-6
     attn_block: int = 1024
     activation: Callable = jax.nn.relu
@@ -91,6 +176,11 @@ class MoEDecoderBlock(fnn.Module):
     index_top_k: Optional[int] = None  # not None: selected attention
     index_heads: int = 16
     index_dim: int = 64
+    head_gate: bool = False          # a sigmoid gate on each head's output
+    route_weights: Callable = softmax_route_weights  # the router's rule
+    shared_width: Optional[int] = None  # not None: a shared expert
+    dense_width: Optional[int] = None   # not None: a dense feed-forward of
+    #                                     these columns, no router or expert
     dtype: Any = jnp.float32
 
     def _indexer(self, h):
@@ -120,10 +210,19 @@ class MoEDecoderBlock(fnn.Module):
         b, t, d = x.shape
         dense = lambda n, name: fnn.Dense(  # noqa: E731
             n, use_bias=False, dtype=self.dtype, name=name)
-        router = fnn.Dense(
-            self.num_experts, use_bias=False, dtype=jnp.float32,
-            precision=lax.Precision.HIGHEST, name="router")
-        if not self.router_after_norm:
+
+        def gated(m, width, name):
+            """``(act(m W_gate) * (m W_up)) W_down``, ``width`` columns."""
+            return dense(d, name + "_down")(
+                self.activation(dense(width, name + "_gate")(m))
+                * dense(width, name + "_up")(m))
+
+        sparse = self.dense_width is None
+        if sparse:
+            router = fnn.Dense(
+                self.num_experts, use_bias=False, dtype=jnp.float32,
+                precision=lax.Precision.HIGHEST, name="router")
+        if sparse and not self.router_after_norm:
             logits = router(x.astype(jnp.float32))
 
         h = fnn.RMSNorm(
@@ -139,6 +238,10 @@ class MoEDecoderBlock(fnn.Module):
                 epsilon=self.norm_eps, dtype=jnp.float32, name=name)
             q = head_norm("q_norm")(q).astype(self.dtype)
             k = head_norm("k_norm")(k).astype(self.dtype)
+        if self.head_gate:
+            with jax.named_scope(_names.SCOPE_ATTN_GATE):
+                gate = jax.nn.sigmoid(
+                    dense(self.num_heads, "head_gate")(h).astype(jnp.float32))
         index_loss = pairs = jnp.float32(0.0)
         selected = self.index_top_k is not None
         if selected:
@@ -148,7 +251,9 @@ class MoEDecoderBlock(fnn.Module):
                 _names.SCOPE_ATTN_SPARSE if selected
                 else _names.SCOPE_ATTN_FULL if self.window is None
                 else _names.SCOPE_ATTN_WINDOW):
-            if self.rope_theta is not None:
+            if self.rope is not None:
+                q, k = rotary_part(q, self.rope), rotary_part(k, self.rope)
+            elif self.rope_theta is not None:
                 q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
             if selected:  # the function opens the indexer's scopes itself
                 attn, index_loss, pairs = selected_self_attention(
@@ -156,13 +261,25 @@ class MoEDecoderBlock(fnn.Module):
             else:
                 attn = blocked_self_attention(
                     q, k, v, window=self.window, block=self.attn_block)
+        if self.head_gate:
+            with jax.named_scope(_names.SCOPE_ATTN_GATE):
+                attn = (attn * gate[..., None]).astype(attn.dtype)
         x = x + dense(d, "o")(attn.reshape(b, t, -1))
 
         h = fnn.RMSNorm(
             epsilon=self.norm_eps, dtype=jnp.float32, name="norm_moe")(x)
+        n, f = len(self.held), self.expert_width
+        if not sparse:
+            with jax.named_scope(_names.SCOPE_MOE_DENSE):
+                x = x + gated(h.astype(self.dtype), self.dense_width, "mlp")
+            return x, (jnp.zeros((n,), jnp.float32), jnp.float32(0.0),
+                       index_loss, pairs)
         if self.router_after_norm:
             logits = router(h.astype(jnp.float32))
-        n, f = len(self.held), self.expert_width
+        if self.shared_width is not None:
+            with jax.named_scope(_names.SCOPE_MOE_SHARED):
+                x = x + gated(
+                    h.astype(self.dtype), self.shared_width, "shared")
         init = fnn.initializers.lecun_normal(in_axis=-2, out_axis=-1)
         y, load, rows = moe_local_experts(
             h.astype(self.dtype).reshape(b * t, d),
@@ -173,22 +290,34 @@ class MoEDecoderBlock(fnn.Module):
             self.param("experts_down", init, (n, f, d), jnp.float32),
             tuple(self.held),
             activation=self.activation,
+            route_weights=self.route_weights,
         )
         return x + y.reshape(b, t, d), (load, rows, index_loss, pairs)
 
 
 class MoEDecoder(fnn.Module):
     """Decoder-only LM over ``MoEDecoderBlock``s. Returns ``(logits [B, T,
-    vocab] float32, {"moe_load": [layers, held], "moe_rows": [layers]}
-    float32)``: what each layer measured of its routing; a model with
-    selected layers adds ``"attn_index_loss"`` and ``"attn_selected_pairs"``
-    ``[layers]``: each layer's ``L_I`` and the pairs it selected."""
+    vocab] float32, {"moe_load": [expert layers, held], "moe_rows": [expert
+    layers]} float32)``: what each layer with experts measured of its
+    routing; a model with selected layers adds ``"attn_index_loss"`` and
+    ``"attn_selected_pairs"`` ``[layers]``: each layer's ``L_I`` and the
+    pairs it selected.
+
+    What differs by layer is given as a pattern, repeated over the depth:
+    ``window_layout``, ``rope_layout``, ``selected_layout``, and
+    ``num_heads`` / ``num_kv_heads`` where they are sequences (one number:
+    every layer's). ``rope_full`` is the rotation of the layers that attend
+    over the whole prefix where it is not the window layers' (``rope_theta``
+    over the whole head). The first ``dense_layers`` layers have a dense
+    feed-forward of ``dense_width`` columns in place of the experts. The
+    heads, the columns and the experts given are the ones this device holds
+    (the module's docstring: a layer held by share)."""
 
     vocab_size: int = 256
     num_layers: int = 4
     d_model: int = 128
-    num_heads: int = 4
-    num_kv_heads: int = 2
+    num_heads: Union[int, Sequence[int]] = 4
+    num_kv_heads: Union[int, Sequence[int]] = 2
     head_dim: int = 32
     expert_width: int = 64
     num_experts: int = 8
@@ -207,6 +336,12 @@ class MoEDecoder(fnn.Module):
     index_top_k: int = 2048
     index_heads: int = 16
     index_dim: int = 64
+    head_gate: bool = False
+    rope_full: Optional[Rotary] = None
+    route_weights: Callable = softmax_route_weights
+    shared_width: Optional[int] = None
+    dense_layers: int = 0
+    dense_width: int = 0
     remat: bool = False  # recompute each block in backward
     dtype: Any = jnp.float32
 
@@ -217,10 +352,23 @@ class MoEDecoder(fnn.Module):
     def selected_layers(self) -> int:
         return sum(self.selects(i) for i in range(self.num_layers))
 
+    @property
+    def expert_layers(self) -> int:
+        return self.num_layers - self.dense_layers
+
     @fnn.compact
     def __call__(self, tokens):
+        if not 0 <= self.dense_layers < self.num_layers:
+            raise ValueError(
+                f"dense_layers must leave a layer with experts, got "
+                f"{self.dense_layers} of {self.num_layers}")
         note_expert_layers(
-            tokens.size, self.top_k, self.num_layers, len(self.held))
+            tokens.size, self.top_k, self.expert_layers, len(self.held))
+        _telemetry.metrics.gauge(
+            _names.GAUGE_ATTN_HEADS_HELD,
+            "query heads this rank holds, summed over the layers of the "
+            "step most recently traced").set(sum(
+                _of_layer(self.num_heads, i) for i in range(self.num_layers)))
         note_attention_step()  # each layer's call below counts itself
         note_selected_layers(
             tokens.shape[0], tokens.shape[1], self.selected_layers)
@@ -240,18 +388,24 @@ class MoEDecoder(fnn.Module):
             windowed = self.window_layout[i % len(self.window_layout)]
             rotated = self.rope_layout[i % len(self.rope_layout)]
             x, measured = block_cls(
-                num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
+                num_heads=_of_layer(self.num_heads, i),
+                num_kv_heads=_of_layer(self.num_kv_heads, i),
                 head_dim=self.head_dim, expert_width=self.expert_width,
                 num_experts=self.num_experts, top_k=self.top_k,
                 held=tuple(self.held),
                 window=self.window if windowed else None,
                 rope_theta=self.rope_theta if rotated else None,
+                rope=self.rope_full if rotated and not windowed else None,
                 norm_eps=self.norm_eps, attn_block=self.attn_block,
                 activation=self.activation,
                 router_after_norm=self.router_after_norm,
                 qk_norm=self.qk_norm,
                 index_top_k=self.index_top_k if self.selects(i) else None,
                 index_heads=self.index_heads, index_dim=self.index_dim,
+                head_gate=self.head_gate, route_weights=self.route_weights,
+                shared_width=self.shared_width,
+                dense_width=(
+                    self.dense_width if i < self.dense_layers else None),
                 dtype=self.dtype,
                 name=f"MoEDecoderBlock_{i}",  # the same with and without remat
             )(x)
@@ -263,7 +417,9 @@ class MoEDecoder(fnn.Module):
         )(x)
         load, rows, index_loss, pairs = (
             jnp.stack(a) for a in zip(*routing))
-        measured = {"moe_load": load, "moe_rows": rows}
+        # a dense layer routes nothing: its zeros are no expert layer's
+        measured = {"moe_load": load[self.dense_layers:],
+                    "moe_rows": rows[self.dense_layers:]}
         if self.selected_layers:
             measured.update(
                 attn_index_loss=index_loss, attn_selected_pairs=pairs)
@@ -272,14 +428,14 @@ class MoEDecoder(fnn.Module):
 
 def init_moe_state(model: MoEDecoder):
     """The model state the engine carries for ``make_moe_lm_loss_fn``: by
-    layer, the tokens each held expert received in the last step, and the
-    rows the layer's grouped products ran over; with selected layers,
-    each layer's indexer loss and the pairs it selected."""
+    layer with experts, the tokens each held expert received in the last
+    step, and the rows the layer's grouped products ran over; with selected
+    layers, each layer's indexer loss and the pairs it selected."""
     layers = jnp.zeros((model.num_layers,), jnp.float32)
     state = {
         "moe_load": jnp.zeros(
-            (model.num_layers, len(model.held)), jnp.float32),
-        "moe_rows": layers,
+            (model.expert_layers, len(model.held)), jnp.float32),
+        "moe_rows": jnp.zeros((model.expert_layers,), jnp.float32),
     }
     if model.selected_layers:
         state.update(attn_index_loss=layers, attn_selected_pairs=layers)

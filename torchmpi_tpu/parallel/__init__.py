@@ -1,4 +1,10 @@
-from .ep import moe_dispatch_combine, moe_load_stats, moe_local_experts
+from .ep import (
+    moe_dispatch_combine,
+    moe_load_stats,
+    moe_local_experts,
+    sigmoid_route_weights,
+    softmax_route_weights,
+)
 from .mesh import make_parallel_mesh
 from .pp import (
     pipeline_1f1b_value_and_grad,
@@ -18,6 +24,8 @@ __all__ = [
     "moe_dispatch_combine",
     "moe_load_stats",
     "moe_local_experts",
+    "sigmoid_route_weights",
+    "softmax_route_weights",
     "pipeline_1f1b_value_and_grad",
     "pipeline_forward",
     "pipeline_loss_fn",

@@ -361,6 +361,29 @@ def _tiered_bwd(C, activation, saved, g):
 _tiered.defvjp(_tiered_fwd, _tiered_bwd)
 
 
+def softmax_route_weights(router_logits, top_k: int):
+    """A token's ``top_k`` largest logits and the softmax over them (a
+    softmax over all ``E`` renormalised over the chosen is the same
+    numbers): ``(weight [T, top_k], chosen [T, top_k])``."""
+    top, chosen = lax.top_k(router_logits, top_k)
+    return jax.nn.softmax(top, axis=-1), chosen
+
+
+def sigmoid_route_weights(scale: float = 1.0):
+    """The rule of a router that scores each expert by itself: ``s =
+    sigmoid(logit)``, a token's ``top_k`` largest ``s`` (the sigmoid is
+    monotone: the largest logits, but ranked as the scores round), each
+    over the chosen ones' sum, times ``scale`` (DeepSeek-V3's
+    ``norm_topk_prob`` with a ``routed_scaling_factor``): the weights of a
+    token sum to ``scale``."""
+
+    def rule(router_logits, top_k: int):
+        top, chosen = lax.top_k(jax.nn.sigmoid(router_logits), top_k)
+        return scale * top / jnp.sum(top, axis=-1, keepdims=True), chosen
+
+    return rule
+
+
 def moe_local_experts(
     x,
     router_logits,
@@ -370,6 +393,7 @@ def moe_local_experts(
     w_down,
     held: Sequence[int],
     activation: Callable = jax.nn.relu,
+    route_weights: Callable = softmax_route_weights,
 ):
     """This device's experts' part of a gated-feed-forward expert layer,
     with no route dropped.
@@ -378,13 +402,18 @@ def moe_local_experts(
     ----------
     x : ``[T, d]`` the tokens (any float dtype; the products run in it).
     router_logits : ``[T, E]`` float32 scores over ALL ``E`` experts.
-    top_k : experts a token; its weights are the softmax over its ``top_k``
-        largest logits (a softmax over all ``E`` renormalised over the
-        chosen is the same numbers).
+    top_k : experts a token, chosen and weighted by ``route_weights``.
     w_gate, w_up : ``[held, d, f]``; w_down : ``[held, f, d]``: the stacked
         parameters of the experts held here, expert ``held[i]`` at ``i``.
     held : the ids, among the ``E``, of the experts held here (static).
     activation : the gate's (ReLU: ReGLU).
+    route_weights : the router's rule, ``(logits [T, E] float32, top_k) ->
+        (weight, chosen)``, both ``[T, top_k]``: which experts a token
+        takes and what each one's result counts for. The default,
+        :func:`softmax_route_weights`, is the softmax over the token's
+        ``top_k`` largest logits; :func:`sigmoid_route_weights` scores by
+        sigmoid, normalises over the chosen and scales. Everything after
+        it (ordering, the tiers, the products, the sum) is the same.
 
     Every route to a held expert is kept: the routes are ordered by
     expert (a stable sort: the held experts' groups first, the routes to
@@ -425,8 +454,8 @@ def moe_local_experts(
     R = T * k
     C = compact_rows(R, n, E)
     with jax.named_scope(_names.SCOPE_MOE_ROUTE):
-        top, chosen = lax.top_k(router_logits.astype(jnp.float32), k)
-        weight = jax.nn.softmax(top, axis=-1)  # [T, k] float32
+        # [T, k] float32 weights, [T, k] expert ids
+        weight, chosen = route_weights(router_logits.astype(jnp.float32), k)
         # slot of each route: its expert's place among the held, or n
         slot_of = np.full((E,), n, np.int32)
         slot_of[list(held)] = np.arange(n, dtype=np.int32)

@@ -98,11 +98,24 @@ SCOPE_ATTN_SELECT = "tm.attn.select"  # the k-th largest a query, the mask
 SCOPE_ATTN_SPARSE = "tm.attn.sparse"  # attention over the selection, and
 #                                       the indexer's loss and its gradient
 
+# models/decoder.py: what a layer held by share may add to the two above
+SCOPE_ATTN_GATE = "tm.attn.gate"      # the gate on each head: its product,
+#                                       the sigmoid and the multiply
+SCOPE_MOE_SHARED = "tm.moe.shared"    # the shared expert's three products
+SCOPE_MOE_DENSE = "tm.moe.dense"      # a leading layer's dense feed-forward,
+#                                       in the slot the experts have elsewhere
+
 MODEL_SCOPE_NAMES = (
     SCOPE_ATTN_FULL, SCOPE_ATTN_WINDOW, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
     SCOPE_MOE_COMBINE, SCOPE_ATTN_INDEX, SCOPE_ATTN_SELECT,
-    SCOPE_ATTN_SPARSE,
+    SCOPE_ATTN_SPARSE, SCOPE_ATTN_GATE, SCOPE_MOE_SHARED, SCOPE_MOE_DENSE,
 )
+
+# -- the gauge models/decoder.py sets from static shapes while its step is
+# traced (as ``ep.note_expert_layers`` does): the query heads this device
+# holds. The benchmark's ``attn_heads_held_share`` reads it against the
+# heads of the same layers whole, which the configuration's file gives.
+GAUGE_ATTN_HEADS_HELD = "tm_attn_query_heads_held_per_step"
 
 # -- what a device trace calls the attention kernels (an event's name is
 # the kernel's HLO instruction): jax's splash attention in
