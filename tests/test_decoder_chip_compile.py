@@ -70,11 +70,12 @@ def compiled_step(config, one_chip):
 def test_the_selecting_cells_step_fits_the_chip_in_its_kernels(one_chip):
     """``keye-vl-2-30b-a3b.stream.x1``'s step at the published widths: it
     fits, every piece of the selected attention is a kernel of the repo's
-    own (none of jax's splash kernels is left), the selection and both
-    forward kernels run once a step (the layer's recomputation keeps what
-    they made), the selection is nowhere an array (the kernels make it in
-    VMEM from a tile of scores), and no ``t x t`` array is held: a panel
-    of 4,096 queries at a time."""
+    own (none of jax's splash kernels is left), the index scores, the
+    selection and both forward kernels run once a step (the layer's
+    recomputation keeps what they made, the sixteen panels of float32
+    scores among it), the selection is nowhere an array (the kernels make
+    it in VMEM from a tile of scores), and no ``t x t`` array is held: a
+    panel of 4,096 queries at a time."""
     from torchmpi_tpu.parallel import selected_attention as sa
     from torchmpi_tpu.telemetry import names
 
@@ -83,10 +84,12 @@ def test_the_selecting_cells_step_fits_the_chip_in_its_kernels(one_chip):
     assert 465e6 < count < 466e6  # 4 layers of 96.9 M + 77.8 M of vocabulary
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
-    # 12 B a parameter of state and 7.0 GiB of temporaries measured here
-    # (12.2 GiB): inside the chip's 15.75 GiB
+    # 12 B a parameter of state (5.20 GiB) and 7.13 GiB of temporaries
+    # measured here, 12.33 GiB: 3.4 GiB inside the chip's 15.75. Of the
+    # temporaries 2.12 GiB are the four layers' kept panels of index
+    # scores (5.01 GiB without them, with a second run of their kernel)
     assert memory.argument_size_in_bytes > 12 * count
-    assert held < 13 * 2**30, memory
+    assert held < 12.6 * 2**30, memory
     text = compiled.as_text()
     kernels = Counter(
         re.findall(r"%([A-Za-z_]+?)[.\d]* = [^\n]*tpu_custom_call", text))
@@ -94,9 +97,9 @@ def test_the_selecting_cells_step_fits_the_chip_in_its_kernels(one_chip):
     panel = sa._panel_of(seq)
     panels = seq // panel
     assert panels == 4
-    once, twice = layers * panels, 2 * layers * panels
+    once = layers * panels
     assert kernels == {
-        "tm_attn_index_scores": twice,       # forward, and again in backward
+        "tm_attn_index_scores": once,
         "tm_attn_select_kth": once,
         "tm_attn_sparse_fwd": once,
         "tm_attn_sparse_mean_probabilities": once,
@@ -337,9 +340,10 @@ def test_a_tpu_lowering_takes_the_kernels_where_the_heads_allow(
         "moe_held_route_share", "moe_max_over_mean_load",
         "moe_route_ms_per_step"]),
     (KEYE, [
-        "attn_index_loss", "attn_index_ms_per_step",
-        "attn_select_ms_per_step", "attn_selected_pair_share",
-        "attn_sparse_kernel_roofline", "attn_sparse_ms_per_step"]),
+        "attn_index_kernel_ms_per_step", "attn_index_loss",
+        "attn_index_ms_per_step", "attn_select_ms_per_step",
+        "attn_selected_pair_share", "attn_sparse_kernel_roofline",
+        "attn_sparse_ms_per_step"]),
     (LAGUNA, [
         "attn_gate_ms_per_step", "attn_heads_held_share",
         "mlp_dense_ms_per_step", "moe_shared_ms_per_step"]),

@@ -234,7 +234,7 @@ def test_the_rule_is_all_that_differs_in_the_expert_layer():
     assert text() != text(route_weights=rule)
 
 
-# The two older decoder cells' whole steps (loss, gradients, AdamW) at
+# The decoder cells' whole steps (loss, gradients, AdamW) at
 # their rehearsals' sizes, as lowered for the CPU: the characters and the
 # first 16 of the text's sha256, as the parent of the PR that added the
 # route rule, the heads by layer, the gate, the shared expert and the
@@ -243,11 +243,14 @@ def test_the_rule_is_all_that_differs_in_the_expert_layer():
 LOWERED = {
     "smallthinker-21b-a3b": (697739, "440c14fb6dcae597"),
     "keye-vl-2-30b-a3b": (1205758, "55eca190dcff1715"),
+    # as PR 32 left it; the loops name nothing a recomputation could keep,
+    # so the selecting layers' policy (PR 36) moved none of the three
+    "laguna-s-2-1": (934255, "9e6a660275528747"),
 }
 
 
 @pytest.mark.parametrize("config", sorted(LOWERED))
-def test_the_older_decoders_steps_lower_to_the_text_they_had(config):
+def test_the_decoders_steps_lower_to_the_text_they_had(config):
     from benchmark import configs
 
     cfg = configs.load(config, rehearse=True)

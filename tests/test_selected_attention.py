@@ -11,7 +11,9 @@ leaf. Tiny sizes that keep what matters: 4 query to 2 KV heads, 3 index
 heads, a selection far smaller than the sequence."""
 
 import importlib.util
+import re
 import sys
+from collections import Counter
 from functools import partial
 from pathlib import Path
 
@@ -487,7 +489,7 @@ def test_one_block_builds_both_decoder_configurations():
     assert set(init_moe_state(MoEDecoder())) == {"moe_load", "moe_rows"}
 
 
-def test_remat_keeps_the_selection_and_changes_no_number():
+def test_the_decoders_recomputation_changes_no_number():
     cfg = tiny_cfg()
     x, y = tokens(2, SEQ, cfg["vocab_size"])
     batch = (jnp.asarray(x), jnp.asarray(y))
@@ -502,6 +504,101 @@ def test_remat_keeps_the_selection_and_changes_no_number():
     np.testing.assert_allclose(got[0][0], got[1][0], rtol=1e-6)
     for a, b in zip(*(jax.tree_util.tree_leaves(g) for _, g in got)):
         np.testing.assert_allclose(a, b, atol=1e-6)
+
+
+# -- what a recomputing caller keeps ----------------------------------------
+_names_kept = jax.checkpoint_policies.save_only_these_names
+KEPT = {
+    "no_recomputation": jax.checkpoint_policies.everything_saveable,
+    "saved": _names_kept(sa.SAVED),
+}
+REMAT_T, REMAT_TOP_K = 2500, 600  # 3 panels; ties across their edges
+
+
+def test_remat_keeps_the_selection_and_changes_no_number():
+    """A caller that recomputes the layer in backward and keeps nothing
+    but ``SAVED`` (the panels of index scores among it: backward then
+    masks with the very bits forward selected from), against one that
+    keeps everything: the value and every gradient are EQUAL, not close,
+    on the interpreted kernels. The inputs' scores tie across tile and
+    panel edges."""
+    args = tied(inputs(11, 1, REMAT_T, 2, 1, 128, 2, 64), 5)
+    assert len(sa._panels(3072)) == 3
+    (got, grads), (want, want_grads) = (
+        jax.jit(jax.value_and_grad(jax.checkpoint(
+            weighed(partial(interpreted, REMAT_TOP_K)),
+            policy=KEPT[kept]), argnums=range(6)))(*args)
+        for kept in ("saved", "no_recomputation"))
+    np.testing.assert_array_equal(got, want)
+    for g, w in zip(grads, want_grads):
+        assert np.any(np.asarray(w))
+        np.testing.assert_array_equal(g, w)
+
+
+def kernel_calls(lowered_text):
+    """How often each of the module's kernels is called in a program's
+    text as lowered for a TPU (a ``tpu_custom_call`` bears its name)."""
+    return Counter(re.findall(r'kernel_name = "(tm_attn_\w+)"', lowered_text))
+
+
+def lowered_for_tpu(fn, *args):
+    """``fn``'s text as jax hands it to the TPU's compiler: the lowering
+    takes the kernels though this process's backend is the CPU."""
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+def benchmark_file(*parts):
+    from benchmark import configs
+
+    return configs.load_module(ROOT.joinpath("benchmark", *parts))
+
+
+def index_kernel_name():
+    """The name the benchmark's ``attn_index_kernel_ms_per_step`` looks
+    for in a device trace (the reader holds it itself)."""
+    return benchmark_file(
+        "layer_metrics", "attn_index_kernel_ms_per_step.py").KERNEL
+
+
+@pytest.mark.parametrize("kept", sorted(KEPT))
+def test_the_index_scores_are_made_once(kept):
+    """A selecting layer's forward and backward, lowered for a TPU:
+    backward holds no ``tm_attn_index_scores`` call beyond the forward's
+    (one a panel), whether the caller recomputes the layer and keeps
+    ``SAVED`` or recomputes nothing. Every other kernel runs once too."""
+    t, panels = 3072, 3
+    shapes = [(1, t, 2, 128), (1, t, 1, 128), (1, t, 1, 128), (1, t, 2, 64),
+              (1, t, 64), (1, t, 2)]
+    layer = jax.checkpoint(
+        weighed(partial(selected_self_attention, top_k=70)),
+        policy=KEPT[kept])
+    calls = kernel_calls(lowered_for_tpu(
+        jax.grad(layer, argnums=range(6)),
+        *[jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]))
+    # the reader's name is the lowered program's, or this finds none
+    assert calls.pop(index_kernel_name()) == panels
+    assert calls == dict.fromkeys((
+        "tm_attn_select_kth", "tm_attn_sparse_fwd",
+        "tm_attn_sparse_mean_probabilities", "tm_attn_sparse_bwd",
+        "tm_attn_index_grad_queries", "tm_attn_index_grad_keys"), panels)
+
+
+def test_the_decoders_policy_keeps_the_index_scores():
+    """The decoder's own recomputation (``remat=True``) names ``SAVED``:
+    its step lowered for a TPU makes each selecting layer's index scores once
+    a panel, forward and backward together."""
+    t, panels, layers = 3072, 3, 2
+    cfg = tiny_cfg(head_dim=128, num_attention_heads=2,
+                   num_key_value_heads=1)
+    model = tiny_model(cfg)
+    params = jax.eval_shape(lambda: init_lm_params(model, t))
+    ids = jax.ShapeDtypeStruct((1, t), jnp.int32)
+    calls = kernel_calls(lowered_for_tpu(
+        jax.grad(lambda p, x, y: make_moe_lm_loss_fn(model)(
+            p, init_moe_state(model), (x, y))[0]), params, ids, ids))
+    assert set(calls.values()) == {layers * panels}
+    assert len(calls) == 7 and index_kernel_name() in calls
 
 
 def test_three_engine_steps_match_the_reference_and_set_the_gauges(plain):

@@ -377,9 +377,10 @@ class MoEDecoder(fnn.Module):
         )(tokens)
         block_cls = MoEDecoderBlock
         if self.remat:
-            # a selected layer's output, log-sum-exps and thresholds are
-            # kept: 130 MiB a layer at 16,384 positions buys the selection
-            # and the forward attention kernel once a step, not twice
+            # a selected layer's output, log-sum-exps, thresholds and its
+            # panels of float32 index scores are kept: 130 + 640 MiB a
+            # layer at 16,384 positions buy the index scores, the selection
+            # and the forward attention kernels once a step, not twice
             block_cls = fnn.remat(MoEDecoderBlock, policy=(
                 jax.checkpoint_policies.save_only_these_names(_ATTN_SAVED)
                 if self.selected_layers else None))

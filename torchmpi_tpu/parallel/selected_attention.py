@@ -19,7 +19,16 @@ over the selection::
 Its gradient reaches the indexer's inputs alone, the output's gradient
 reaches ``q``, ``k`` and ``v`` alone: the function carries both rules itself.
 No ``t x t`` tensor a head is ever held; one sequence's float32 index scores
-(``t x t``) do pass through memory, and are made again in backward.
+do pass through memory, a panel of ``PANEL`` queries against the keys up to
+the panel's end at a time, and the kernels' forward pass hands its panels to
+backward as residuals: the scores are made once, and backward masks with
+the bits forward selected from. They bear the name ``SAVED`` with the small
+residuals, for a caller that recomputes the layer: ``4 * t * (t + PANEL) / 2``
+bytes a sequence (640 MiB at 16,384 positions, 2.25 GiB at 32,768) beside
+the 130 MiB of the other five. One name, because scores made again from a
+recomputed indexer are not forward's bits on the chip (XLA rounds the
+recomputed block elsewhere: up to 0.038 apart, measured, PR 36), and
+backward would then mask with another selection than forward attended.
 
 Two executions of one mathematics, chosen as ``blocked_self_attention``
 chooses (the platform the program is lowered for, and the heads' width):
@@ -842,7 +851,7 @@ def _kernels_forward(q, k, v, iq, ik, iw, top_k, real, interpret):
     qs = (q * (1.0 / math.sqrt(d))).astype(q.dtype)
     qh, kh, vh, iqh = (_head_major(a) for a in (qs, k, v, iq))
     grouped = qh.reshape(hkv, hq // hkv, t, d)
-    outs, small, loss, pairs = [], [], 0.0, 0.0
+    outs, small, panels, loss, pairs = [], [], [], 0.0, 0.0
     for first, end in _panels(t):
         rows = first + jnp.arange(end - first)[:, None]
         scores = _panel_scores(iqh, ik, iw, first, end, interpret)
@@ -859,9 +868,10 @@ def _kernels_forward(q, k, v, iq, ik, iw, top_k, real, interpret):
             pairs = pairs + jnp.sum(jnp.where(rows < real, selected, 0))
         outs.append(out.reshape(hq, end - first, d))
         small.append((lse, thr, cut, log_z))
+        panels.append(scores)
     out = jnp.moveaxis(jnp.concatenate(outs, axis=1), 0, 1)
     return (out, loss / real, jnp.asarray(pairs, jnp.float32),
-            tuple(jnp.concatenate(a) for a in zip(*small)))
+            tuple(jnp.concatenate(a) for a in zip(*small)), tuple(panels))
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
@@ -872,17 +882,19 @@ def _kernels(q, k, v, iq, ik, iw, top_k, real, interpret):
 def _kernels_fwd(q, k, v, iq, ik, iw, top_k, real, interpret):
     from jax.ad_checkpoint import checkpoint_name
 
-    out, loss, pairs, small = _kernels_forward(
+    out, loss, pairs, small, panels = _kernels_forward(
         q, k, v, iq, ik, iw, top_k, real, interpret)
     # named, so that a caller that recomputes its layer in backward can keep
-    # these five (a policy of ``save_only_these_names(SAVED)``) and has the
-    # k-th largest and both forward kernels run once a step
+    # these (a policy of ``save_only_these_names(SAVED)``) and has the index
+    # scores, the k-th largest and both forward kernels run once a step;
+    # backward masks with the bits forward selected from
     out, *small = (checkpoint_name(a, SAVED) for a in (out, *small))
-    return (out, loss, pairs), (q, k, v, iq, ik, iw, out, *small)
+    panels = tuple(checkpoint_name(a, SAVED) for a in panels)
+    return (out, loss, pairs), (q, k, v, iq, ik, iw, out, *small, panels)
 
 
 def _kernels_bwd(top_k, real, interpret, saved, cot):
-    q, k, v, iq, ik, iw, out, lse, thr, cut, log_z = saved
+    q, k, v, iq, ik, iw, out, lse, thr, cut, log_z, panels = saved
     dout, dloss, _ = cot
     t, hq, d = q.shape
     f32 = jnp.float32
@@ -904,9 +916,8 @@ def _kernels_bwd(top_k, real, interpret, saved, cot):
         return jnp.pad(a, [(0, t - end if i == axis else 0)
                            for i in range(a.ndim)])
 
-    for first, end in _panels(t):
+    for (first, end), scores in zip(_panels(t), panels):
         rows = slice(first, end)
-        scores = _panel_scores(iqh, ik, iw, first, end, interpret)
         with jax.named_scope(_names.SCOPE_ATTN_SPARSE):
             dq, dkp, dvp, pulled = _attend_bwd(
                 qh, kh, vh, doh, lse, di, scores, thr[rows],
@@ -1019,7 +1030,16 @@ def selected_self_attention(q, k, v, index_q, index_k, index_w, top_k: int,
     Lowered for a TPU with ``head_dim`` a multiple of 128: kernels (see the
     module); elsewhere blocks of ``block`` queries against all the keys in
     XLA operations. No flag: the call decides, as
-    ``blocked_self_attention`` does."""
+    ``blocked_self_attention`` does.
+
+    What the kernels' forward pass keeps for backward bears the
+    ``checkpoint_name`` ``SAVED``, for a caller's ``jax.checkpoint`` policy:
+    the output, the log-sum-exps and each row's threshold, tie cut and
+    ``log_z`` (130 MiB a sequence of 16,384 with 32 heads of 128), and the
+    float32 index scores, a panel's ``[PANEL, keys up to its end]`` each
+    (640 MiB at 16,384 positions, 2.25 GiB at 32,768). A caller that
+    recomputes nothing holds them as any residual; one that does not name
+    ``SAVED`` recomputes the whole layer."""
     if q.shape[2] % k.shape[2] or k.shape != v.shape:
         raise ValueError(
             f"query heads {q.shape[2]} must be a multiple of the KV heads "
