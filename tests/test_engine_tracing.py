@@ -105,7 +105,7 @@ def _sync_ops(text):
 @pytest.mark.parametrize("layout", sorted(SYNC_SCOPES))
 def test_scope_names_in_lowered_step(layout):
     text = _step_text(_engine(layout))
-    assert "jit(tm_step)" in text
+    assert "jit(tm_train_step)" in text
     expect = (names.SCOPE_FWD_BWD, names.SCOPE_OPTIMIZER) + SYNC_SCOPES[layout]
     if layout not in ("fsdp", "zero1"):
         expect += (names.SCOPE_LOSS_SYNC,)
@@ -283,7 +283,7 @@ def test_train_spans():
         assert r.step == parent.step
     # the step's program was built inside the first dispatch, at step 0
     builds = [r for r in got[names.ENGINE_PROGRAM_BUILD]
-              if r.attrs["program"] == "jit(tm_step)"]
+              if r.attrs["program"] == "jit(tm_train_step)"]
     assert [(r.parent, r.step) for r in builds] == [(steps[0].id, (0, 0))]
     _check_clock_and_order(records, t0, t1)
 
@@ -355,7 +355,7 @@ def test_new_shape_in_mid_run_is_one_program_build():
     seconds = met.counter("tm_engine_program_build_seconds_total").total()
     steps = [r for r in ring.records()
              if r.name == names.ENGINE_PROGRAM_BUILD
-             and r.attrs["program"] == "jit(tm_step)"]
+             and r.attrs["program"] == "jit(tm_train_step)"]
     assert [r.step for r in steps] == [(0, 0), (0, 2)]
     assert steps[1].attrs["seconds"] > 0
     assert steps[1].attrs["event"].endswith("backend_compile_duration")
@@ -363,7 +363,7 @@ def test_new_shape_in_mid_run_is_one_program_build():
     engine.train(batches, max_epochs=1)  # both shapes are built: none now
     assert not [r for r in ring.records()
                 if r.name == names.ENGINE_PROGRAM_BUILD
-                and r.attrs["program"] == "jit(tm_step)"]
+                and r.attrs["program"] == "jit(tm_train_step)"]
     assert built >= 2 and seconds > 0
     assert met.gauge("tm_engine_init_seconds").value() > 0
 

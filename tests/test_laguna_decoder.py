@@ -488,7 +488,7 @@ def test_the_new_scopes_nest_under_fwd_bwd_in_the_lowered_step():
     text = engine._step_fn.lower(
         engine.params, engine.opt_state, engine.model_state,
         engine._prepare_batch((x, y))).as_text(debug_info=True)
-    ops = set(re.findall(r'"(jit\(tm_step\)[^"]*)"', text))
+    ops = set(re.findall(r'"(jit\(tm_train_step\)[^"]*)"', text))
     seen = {}
     for op in ops:
         for scope in ("tm.attn.gate", "tm.moe.shared", "tm.moe.dense"):

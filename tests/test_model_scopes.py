@@ -1,0 +1,238 @@
+"""Forward and backward name their parts: the scopes a language model opens
+for its embedding, norms, projections, feed-forward, router, head and loss
+(``telemetry.names``, ``models/decoder.py``, ``models/transformer.py``) and
+the benchmark's reader that divides ``tm.fwd_bwd`` among every inner scope
+(``benchmark/model_scopes.py``). The scopes are metadata of the jitted step:
+they reach every phase of it, move no operation of an older scope, and
+change no byte of the compiled program."""
+
+import contextlib
+import re
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+import torchmpi_tpu as mpi
+from torchmpi_tpu.engine import AllReduceSGDEngine
+from torchmpi_tpu.models import (
+    LongContextTransformer,
+    MoEDecoder,
+    Rotary,
+    init_lm_params,
+    init_moe_state,
+    make_lm_loss_fn,
+    make_moe_lm_loss_fn,
+)
+from torchmpi_tpu.parallel import sigmoid_route_weights
+from torchmpi_tpu.telemetry import names
+
+ROOT = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT))
+
+SEQ, VOCAB = 24, 61
+OLD = names.MODEL_SCOPE_NAMES[:11]   # what the benchmark's metrics read
+NEW = names.MODEL_SCOPE_NAMES[11:]   # what this file is about
+EVERY_LM = {"tm.lm.embed", "tm.lm.norm", "tm.attn.proj", "tm.lm.head",
+            "tm.lm.loss"}
+# the scopes opened inside a block are recomputed with it; the embedding,
+# the last norm's model-level call, the head and the loss are not
+IN_BLOCKS = {"tm.lm.norm", "tm.attn.proj", "tm.lm.mlp", "tm.moe.router"}
+
+
+def _decoder(**over):
+    kw = dict(
+        vocab_size=VOCAB, num_layers=4, d_model=32, num_heads=4,
+        num_kv_heads=2, head_dim=8, expert_width=16, num_experts=8, top_k=3,
+        held=tuple(range(8)), window=12, window_layout=(0, 1, 1, 1),
+        rope_layout=(0, 1, 1, 1), attn_block=8, remat=True)
+    kw.update(over)
+    return MoEDecoder(**kw)
+
+
+FAMILIES = {
+    # GPT-2's: LayerNorm, one qkv product, T x T attention, a GELU
+    # feed-forward, each block recomputed
+    "gpt2": lambda: LongContextTransformer(
+        vocab_size=VOCAB, num_layers=2, num_heads=2, head_dim=8, d_model=16,
+        max_len=32, remat=True),
+    # smallthinker-21b-a3b's: the router read before attention
+    "smallthinker": _decoder,
+    # laguna-s-2-1's: heads by layer, a gate a head, the router after the
+    # second norm, a shared expert, a dense leading layer
+    "laguna": lambda: _decoder(
+        num_layers=5, num_heads=(2, 3, 3, 3), num_kv_heads=1,
+        rope_layout=(1,), rope_theta=1e4,
+        rope_full=Rotary(5e5, 4, 128.0, 8192, 32.0, 1.0, 1.4852),
+        activation=jax.nn.silu, router_after_norm=True, head_gate=True,
+        route_weights=sigmoid_route_weights(2.5), shared_width=16,
+        dense_layers=1, dense_width=24),
+    # keye-vl-2-30b-a3b's: every layer selects, with a norm on each query
+    # and key head; the indexer's projections stay under tm.attn.index
+    "selected": lambda: _decoder(
+        window_layout=(0,), rope_layout=(1,), selected_layout=(1,),
+        index_top_k=9, index_heads=3, index_dim=8, router_after_norm=True,
+        qk_norm=True),
+}
+
+
+@pytest.fixture(autouse=True)
+def _one_device():
+    mpi.start(devices=jax.devices()[:1])
+
+
+def _engine(family):
+    model = FAMILIES[family]()
+    params = init_lm_params(model, SEQ)
+    if isinstance(model, MoEDecoder):
+        return AllReduceSGDEngine(
+            make_moe_lm_loss_fn(model), params, optimizer=optax.sgd(0.1),
+            model_state=init_moe_state(model))
+    return AllReduceSGDEngine(
+        make_lm_loss_fn(model), params, optimizer=optax.sgd(0.1))
+
+
+def _lowered(engine):
+    toks = np.random.default_rng(0).integers(
+        0, VOCAB, size=(2, SEQ + 1), dtype=np.int32)
+    return engine._step_fn.lower(
+        engine.params, engine.opt_state, engine.model_state,
+        engine._prepare_batch((toks[:, :-1], toks[:, 1:])))
+
+
+def _op_names(engine):
+    text = _lowered(engine).as_text(debug_info=True)
+    return set(re.findall(r'"(jit\(tm_train_step\)[^"]*)"', text))
+
+
+@contextlib.contextmanager
+def _without(monkeypatch, dropped):
+    """``jax.named_scope`` opens nothing for the names in ``dropped`` (every
+    name where it is None): the step as a model without them traces it."""
+    real = jax.named_scope
+    with monkeypatch.context() as patch:
+        patch.setattr(jax, "named_scope", lambda name: (
+            contextlib.nullcontext() if dropped is None or name in dropped
+            else real(name)))
+        yield
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_new_scopes_reach_every_phase_under_fwd_bwd(family):
+    from benchmark import model_scopes, scopes
+
+    assert NEW == ("tm.lm.embed", "tm.lm.norm", "tm.attn.proj", "tm.lm.mlp",
+                   "tm.moe.router", "tm.lm.head", "tm.lm.loss")
+    seen = {}
+    for op in _op_names(_engine(family)):
+        bucket = model_scopes.bucket_of(op)
+        if bucket in NEW:
+            assert scopes.scope_of(op) == "tm.fwd_bwd", op
+            seen.setdefault(bucket, set()).add(model_scopes.phase_of(op))
+    own = "tm.lm.mlp" if family == "gpt2" else "tm.moe.router"
+    assert set(seen) == EVERY_LM | {own}, seen
+    for scope, phases in seen.items():
+        want = {"forward", "backward"}
+        if scope in IN_BLOCKS:
+            want.add("recompute")
+        assert phases == want, (scope, phases)
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_older_inner_scopes_read_what_they_read(family, monkeypatch):
+    """No new scope encloses or lies inside an older one: every operation
+    ``inner_scopes.inner_scope_of`` gives to an older scope has the
+    ``op_name`` and the scope it has in a step traced without the new
+    scopes, and no other operation joins them."""
+    from benchmark import inner_scopes
+
+    def older(ops):
+        pairs = {(op, inner_scopes.inner_scope_of(op)) for op in ops}
+        return {(op, scope) for op, scope in pairs if scope in OLD}
+
+    with _without(monkeypatch, NEW):
+        before = older(_op_names(_engine(family)))
+    after = older(_op_names(_engine(family)))
+    assert before and after == before
+    if family == "gpt2":  # its attention bears the decoders' name now
+        assert {scope for _, scope in after} == {"tm.attn.full"}
+    if family == "selected":
+        assert any(op.endswith("_indexer/index_q/dot_general")
+                   and scope == "tm.attn.index" for op, scope in after)
+        assert not [op for op, _ in after if "tm.attn.proj" in op]
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_scopes_leave_the_compiled_step_unchanged(family, monkeypatch):
+    """The compiled step with every ``metadata={...}`` stripped is the
+    same bytes with the scopes and with ``jax.named_scope`` opening nothing
+    while the step is traced."""
+    def strip(text):
+        return re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+
+    texts = []
+    for dropped in ((), None):  # nothing dropped, then every name
+        with _without(monkeypatch, dropped):
+            # one call site for both: the text holds source lines
+            texts.append(_lowered(_engine(family)).compile().as_text())
+    with_scopes, without = texts
+    assert "tm.lm.norm" in with_scopes and "tm." not in without
+    assert "tm." not in strip(with_scopes)  # all of it was metadata
+    assert strip(with_scopes) == strip(without)
+
+
+def test_bucket_and_phase_of_hand_written_op_names():
+    """The reader's two rules on the ``op_name``s of ``inner_scopes.py``'s
+    docstring and its self-test, and on the new scopes'."""
+    from benchmark import model_scopes
+
+    pre = "jit(tm_train_step)/shard_map/tm.fwd_bwd/"
+    back = pre + "transpose(jvp(MoEDecoder))/tm.fwd_bwd/jvp(MoEDecoder)/"
+    for op, bucket, phase in [
+        (pre + "jvp(MoEDecoder)/MoEDecoderBlock_0/tm.attn.full/while/body/"
+         "dot_general", "tm.attn.full", "forward"),
+        (back + "checkpoint/rematted_computation/MoEDecoderBlock_1/"
+         "tm.attn.window/while/body/dot_general", "tm.attn.window",
+         "recompute"),
+        (back + "checkpoint/MoEDecoderBlock_2/tm.moe.experts/ragged_dot",
+         "tm.moe.experts", "backward"),
+        (pre + "transpose(jvp(MoEDecoder/MoEDecoderBlock_3/tm.moe.route))"
+         "/gather", "tm.moe.route", "backward"),
+        (back + "checkpoint/rematted_computation/MoEDecoderBlock_1/"
+         "tm.lm.norm/norm_attn/mul", "tm.lm.norm", "recompute"),
+        (pre + "transpose(jvp(tm.lm.loss))/jit(log_softmax)/sub",
+         "tm.lm.loss", "backward"),
+        (pre + "jvp(MoEDecoder)/tm.lm.head/head/dot_general", "tm.lm.head",
+         "forward"),
+        # the program's older name, and a scope nested in another: the
+        # innermost name takes the operation
+        ("jit(tm_step)/shard_map/tm.fwd_bwd/jvp(M)/tm.attn.sparse/"
+         "tm.attn.select/pallas_call", "tm.attn.select", "forward"),
+        (pre + "jvp(MoEDecoder)/MoEDecoderBlock_3/add", "unnamed",
+         "forward"),
+        # two operations XLA merged into one copy: the attention's reshape
+        # and the model's beside it, which stands under no scope so that
+        # the copy stays the attention's
+        (back + "checkpoint/MoEDecoderBlock_1/tm.attn.window/cond/"
+         "branch_0_fun/reshape;" + back + "checkpoint/MoEDecoderBlock_1/"
+         "reshape", "tm.attn.window", "backward"),
+        ("ragged-dot-none:", "tm.moe.experts", "forward"),
+        ("jit(tm_train_step)/shard_map/tm.optimizer/mul", None, None),
+        ("jit(tm_train_step)/shard_map/tm.grad_sync/reduce/psum", None,
+         None),
+        ("", None, None),
+    ]:
+        assert model_scopes.bucket_of(op) == bucket, op
+        if bucket is not None:
+            assert model_scopes.phase_of(op) == phase, op
+
+
+@pytest.mark.parametrize("case", ["hand_written", "recorded"])
+def test_the_readers_self_test(case):
+    from benchmark import model_scopes_selftest
+
+    getattr(model_scopes_selftest, f"test_{case}")()

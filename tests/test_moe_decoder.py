@@ -732,7 +732,10 @@ def test_model_scopes_nest_under_fwd_bwd_in_the_lowered_step(selecting):
     assert names.MODEL_SCOPE_NAMES == (
         "tm.attn.full", "tm.attn.window", "tm.moe.route", "tm.moe.experts",
         "tm.moe.combine", "tm.attn.index", "tm.attn.select",
-        "tm.attn.sparse", "tm.attn.gate", "tm.moe.shared", "tm.moe.dense")
+        "tm.attn.sparse", "tm.attn.gate", "tm.moe.shared", "tm.moe.dense",
+        # every language model's parts (tests/test_model_scopes.py)
+        "tm.lm.embed", "tm.lm.norm", "tm.attn.proj", "tm.lm.mlp",
+        "tm.moe.router", "tm.lm.head", "tm.lm.loss")
     cfg = tiny_cfg()
     model = tiny_model(cfg)
     opened = set(names.MODEL_SCOPE_NAMES[:5])
@@ -751,11 +754,11 @@ def test_model_scopes_nest_under_fwd_bwd_in_the_lowered_step(selecting):
         engine._prepare_batch((x, y))).as_text(debug_info=True)
     from benchmark import inner_scopes, scopes
 
-    op_names = set(re.findall(r'"(jit\(tm_step\)[^"]*)"', text))
+    op_names = set(re.findall(r'"(jit\(tm_train_step\)[^"]*)"', text))
     seen = {}
     for op in op_names:
         inner = inner_scopes.inner_scope_of(op)
-        if inner is not None:
+        if inner in names.MODEL_SCOPE_NAMES[:11]:
             # the first tm. component is the engine's: fwd_bwd stays whole
             assert scopes.scope_of(op) == "tm.fwd_bwd", op
             seen.setdefault(inner, set()).add("transpose(" in op)

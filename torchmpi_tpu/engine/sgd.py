@@ -610,19 +610,20 @@ class AllReduceSGDEngine:
         return params, opt_state, new_state, loss
 
     def _build_step(self):
-        # The step's program is named ``tm_step`` (the resident epoch's
-        # ``tm_epoch``): a stable name in a device trace's module line.
-        # It is also part of the persistent compilation cache's key, which
-        # scope names are not (jax strips metadata from the key): a change
-        # to the scopes alone must change this name too, or a warm cache
-        # hands back the old executable with the old scopes.
+        # The step's program is named ``tm_train_step`` (the resident
+        # epoch's ``tm_epoch``): a stable name in a device trace's module
+        # line. It is also part of the persistent compilation cache's key,
+        # which scope names are not (jax strips metadata from the key): a
+        # change to the scopes alone must change this name too, or a warm
+        # cache hands back the old executable with the old scopes (the
+        # name was ``tm_step`` until the language models named their parts).
         if self.param_sharding in ("fsdp", "zero1"):
-            def tm_step(params, opt_state, model_state, batch):
+            def tm_train_step(params, opt_state, model_state, batch):
                 return self._fsdp_step_core(
                     params, opt_state, model_state, batch)
 
             return jax.jit(
-                tm_step,
+                tm_train_step,
                 donate_argnums=(0, 1, 2),
                 out_shardings=self._out_shardings,
             )
@@ -634,10 +635,10 @@ class AllReduceSGDEngine:
             check_vma=False,
         )
 
-        def tm_step(params, opt_state, model_state, batch):
+        def tm_train_step(params, opt_state, model_state, batch):
             return shmapped(params, opt_state, model_state, batch)
 
-        return jax.jit(tm_step, donate_argnums=(0, 1, 2))
+        return jax.jit(tm_train_step, donate_argnums=(0, 1, 2))
 
     def _build_broadcast(self):
         if self.param_sharding in ("fsdp", "zero1"):

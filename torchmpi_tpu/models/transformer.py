@@ -16,14 +16,16 @@ import jax
 import jax.numpy as jnp
 
 from ..parallel.ring_attention import full_self_attention, ring_self_attention
+from ..telemetry import names as _names
 
 
 def lm_cross_entropy(logits, targets):
     """Mean next-token cross-entropy over every position, the log-softmax
     in float32: the one loss of every language model here."""
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    picked = jnp.take_along_axis(logp, targets[..., None], axis=-1)
-    return -jnp.mean(picked)
+    with jax.named_scope(_names.SCOPE_LM_LOSS):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+        picked = jnp.take_along_axis(logp, targets[..., None], axis=-1)
+        return -jnp.mean(picked)
 
 
 def make_lm_loss_fn(model: fnn.Module):
@@ -59,25 +61,36 @@ class RingAttentionBlock(fnn.Module):
     def __call__(self, x):
         # x: [B, T_local, D]
         d_model = x.shape[-1]
-        h = fnn.LayerNorm(dtype=jnp.float32)(x)
-        qkv = fnn.Dense(3 * self.num_heads * self.head_dim, dtype=self.dtype)(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        with jax.named_scope(_names.SCOPE_LM_NORM):
+            h = fnn.LayerNorm(dtype=jnp.float32)(x)
+        with jax.named_scope(_names.SCOPE_ATTN_PROJ):
+            qkv = fnn.Dense(
+                3 * self.num_heads * self.head_dim, dtype=self.dtype)(h)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+        # the reshapes on either side of the attention stand under no scope,
+        # as in models/decoder.py
         shape = x.shape[:2] + (self.num_heads, self.head_dim)
         q, k, v = (a.reshape(shape) for a in (q, k, v))
-        if self.sp_axis is not None:
-            attn = ring_self_attention(
-                q, k, v, axis=self.sp_axis, causal=True,
-                backend=self.sp_backend,
-            )
-        else:
-            attn = full_self_attention(q, k, v, causal=True)
+        # the decoders' name for the same thing: attention over the whole
+        # causal prefix, no projection
+        with jax.named_scope(_names.SCOPE_ATTN_FULL):
+            if self.sp_axis is not None:
+                attn = ring_self_attention(
+                    q, k, v, axis=self.sp_axis, causal=True,
+                    backend=self.sp_backend,
+                )
+            else:
+                attn = full_self_attention(q, k, v, causal=True)
         attn = attn.reshape(x.shape[:2] + (-1,))
-        x = x + fnn.Dense(d_model, dtype=self.dtype)(attn)
+        with jax.named_scope(_names.SCOPE_ATTN_PROJ):
+            x = x + fnn.Dense(d_model, dtype=self.dtype)(attn)
 
-        h = fnn.LayerNorm(dtype=jnp.float32)(x)
-        h = fnn.Dense(self.mlp_ratio * d_model, dtype=self.dtype)(h)
-        h = fnn.gelu(h)
-        x = x + fnn.Dense(d_model, dtype=self.dtype)(h)
+        with jax.named_scope(_names.SCOPE_LM_NORM):
+            h = fnn.LayerNorm(dtype=jnp.float32)(x)
+        with jax.named_scope(_names.SCOPE_LM_MLP):
+            h = fnn.Dense(self.mlp_ratio * d_model, dtype=self.dtype)(h)
+            h = fnn.gelu(h)
+            x = x + fnn.Dense(d_model, dtype=self.dtype)(h)
         return x
 
 
@@ -106,8 +119,11 @@ class LongContextTransformer(fnn.Module):
             pos = r * t_local + jnp.arange(t_local)
         else:
             pos = jnp.arange(t_local)
-        x = fnn.Embed(self.vocab_size, self.d_model, dtype=self.dtype)(tokens)
-        x = x + fnn.Embed(self.max_len, self.d_model, dtype=self.dtype)(pos)[None]
+        with jax.named_scope(_names.SCOPE_LM_EMBED):
+            x = fnn.Embed(
+                self.vocab_size, self.d_model, dtype=self.dtype)(tokens)
+            x = x + fnn.Embed(
+                self.max_len, self.d_model, dtype=self.dtype)(pos)[None]
         # remat: drop each block's activations and recompute them during
         # backward — long-context HBM is dominated by per-layer
         # activations ([B, T, D] x layers), so this trades one extra
@@ -126,5 +142,7 @@ class LongContextTransformer(fnn.Module):
                 dtype=self.dtype,
                 name=f"RingAttentionBlock_{i}",
             )(x)
-        x = fnn.LayerNorm(dtype=jnp.float32)(x)
-        return fnn.Dense(self.vocab_size, dtype=jnp.float32)(x)
+        with jax.named_scope(_names.SCOPE_LM_NORM):
+            x = fnn.LayerNorm(dtype=jnp.float32)(x)
+        with jax.named_scope(_names.SCOPE_LM_HEAD):
+            return fnn.Dense(self.vocab_size, dtype=jnp.float32)(x)

@@ -105,10 +105,31 @@ SCOPE_MOE_SHARED = "tm.moe.shared"    # the shared expert's three products
 SCOPE_MOE_DENSE = "tm.moe.dense"      # a leading layer's dense feed-forward,
 #                                       in the slot the experts have elsewhere
 
+# models/decoder.py, models/transformer.py: the parts of a language model's
+# step that are no attention and no expert layer. None encloses or lies
+# inside a scope above. What XLA fuses across a boundary bears its root's
+# scope, so a fusion goes to one side whole.
+SCOPE_LM_EMBED = "tm.lm.embed"        # the embedding's gather (GPT-2: and
+#                                       the positions'); backward: scatter-add
+SCOPE_LM_NORM = "tm.lm.norm"          # a block's norms, the model's last,
+#                                       the query and key heads' norms
+SCOPE_ATTN_PROJ = "tm.attn.proj"      # the q, k, v and o products; not the
+#                                       indexer's, not the reshapes beside
+#                                       the attention (under no scope)
+SCOPE_LM_MLP = "tm.lm.mlp"            # GPT-2's feed-forward: two products
+#                                       and the GELU
+SCOPE_MOE_ROUTER = "tm.moe.router"    # the router's product, precision
+#                                       highest
+SCOPE_LM_HEAD = "tm.lm.head"          # the product with the vocabulary
+SCOPE_LM_LOSS = "tm.lm.loss"          # the float32 log-softmax, the pick,
+#                                       the mean
+
 MODEL_SCOPE_NAMES = (
     SCOPE_ATTN_FULL, SCOPE_ATTN_WINDOW, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
     SCOPE_MOE_COMBINE, SCOPE_ATTN_INDEX, SCOPE_ATTN_SELECT,
     SCOPE_ATTN_SPARSE, SCOPE_ATTN_GATE, SCOPE_MOE_SHARED, SCOPE_MOE_DENSE,
+    SCOPE_LM_EMBED, SCOPE_LM_NORM, SCOPE_ATTN_PROJ, SCOPE_LM_MLP,
+    SCOPE_MOE_ROUTER, SCOPE_LM_HEAD, SCOPE_LM_LOSS,
 )
 
 # -- the gauge models/decoder.py sets from static shapes while its step is
