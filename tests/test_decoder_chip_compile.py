@@ -4,6 +4,7 @@ installed here): what the chip's compiler would refuse, it refuses here.
 One file, and the topology is described inside a fixture, so that only the
 worker that runs this file loads the TPU's library."""
 
+import hashlib
 import json
 import math
 import re
@@ -19,6 +20,7 @@ import pytest
 ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 CONFIG = "smallthinker-21b-a3b"
+GPT2 = "gpt2-medium"
 KEYE = "keye-vl-2-30b-a3b"
 LAGUNA = "laguna-s-2-1"
 
@@ -36,8 +38,8 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def compiled_step(config, one_chip):
-    """(cfg, the parameters' shapes, the cell's training step compiled for
+def lowered_step(config, one_chip):
+    """(cfg, the parameters' shapes, the cell's training step lowered for
     the described chip)."""
     from benchmark import configs
 
@@ -46,8 +48,11 @@ def compiled_step(config, one_chip):
     batch, seq = cfg["per_chip_batch"], cfg["sequence_length"]
 
     def step(params, opt_state, state, tokens):
-        (loss, state), grads = jax.value_and_grad(
-            built.loss_fn, has_aux=True)(params, state, tokens)
+        if state is None:  # a model that keeps no state: loss_fn(params, batch)
+            loss, grads = jax.value_and_grad(built.loss_fn)(params, tokens)
+        else:
+            (loss, state), grads = jax.value_and_grad(
+                built.loss_fn, has_aux=True)(params, state, tokens)
         updates, opt_state = built.optimizer.update(grads, opt_state, params)
         return optax.apply_updates(params, updates), opt_state, state, loss
 
@@ -56,15 +61,56 @@ def compiled_step(config, one_chip):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         tree)
     tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one_chip)
+    return cfg, params, jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+        place(params), place(jax.eval_shape(built.optimizer.init, params)),
+        place(state), (tokens, tokens))
+
+
+def compiled_step(config, one_chip):
+    """(cfg, the parameters' shapes, that step compiled)."""
+    cfg, params, lowered = lowered_step(config, one_chip)
     cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
-            place(params), place(jax.eval_shape(built.optimizer.init, params)),
-            place(state), (tokens, tokens)).compile()
+        return cfg, params, lowered.compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
-    return cfg, params, compiled
+
+
+def test_gpt2s_step_holds_its_attention_in_the_kernels(one_chip):
+    """``gpt2-medium``'s step at its real size (both of its cells run it):
+    each of the 24 blocks' attention is the fused kernels, forward, the
+    block's recomputed forward and one backward, with heads of 64 and one
+    tile of 1,024; no ``[b, h, t, t]`` array of any type is left, and the step's
+    temporaries are no larger than with the ``t x t`` attention."""
+    from torchmpi_tpu.telemetry import names
+
+    cfg, params, compiled = compiled_step(GPT2, one_chip)
+    count = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    assert 406e6 < count < 407e6
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 12 * count
+    # 3.392 GiB measured here; the parent's step, every block's scores an
+    # array, 3.416 GiB (3,667,638,272 B: PERF.md, PR 38)
+    assert memory.temp_size_in_bytes <= 3_667_638_272, memory
+    text = compiled.as_text()
+    kernels = Counter(
+        re.findall(r"%([A-Za-z_]+?)[.\d]* = [^\n]*tpu_custom_call", text))
+    layers = cfg["model"]["n_layer"]
+    assert kernels == {"splash_mqa_fwd_residuals": 2 * layers,
+                       "splash_mqa_dkv_no_residuals": layers}, kernels
+    assert all(k.startswith(names.ATTN_KERNEL_EVENT) for k in kernels)
+    batch, seq = cfg["per_chip_batch"], cfg["sequence_length"]
+    heads = cfg["model"]["n_head"]
+    assert (batch, heads, seq) == (8, 16, 1024)
+    for scores in (f"[{batch},{heads},{seq},{seq}]", f"[{heads},{seq},{seq}]",
+                   f"[{batch * heads},{seq},{seq}]"):
+        assert scores not in text  # no [b, h, t, t] array, of any type
+    # ... nor any array of four or more axes whose last two are both 512
+    # or more: a tile's scores stay in VMEM
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"\w+\[([\d,]+)\]", text)}
+    assert not [s for s in shapes if len(s) >= 4 and min(s[-2:]) >= 512]
 
 
 def test_the_selecting_cells_step_fits_the_chip_in_its_kernels(one_chip):
@@ -303,13 +349,38 @@ def conditional_branches(text):
     return found
 
 
+# The three decoder cells' whole steps at their real sizes as LOWERED for the
+# described chip (not compiled): characters and the first 16 of the sha256
+# of the text without the kernels' serialized bodies (they carry the
+# checkout's path). As the parent of PR 38 lowered them: heads of 128 and
+# sequences of 8,192 and 16,384 take the branch and the tiles they took. A
+# PR that means to change those steps changes these; one that does not,
+# must not.
+TPU_LOWERED = {
+    CONFIG: (955645, "c6e7fca3cf766c29"),
+    KEYE: (1248452, "47bf5842f8ac3442"),
+    LAGUNA: (1492140, "fc51f3fc3cfee276"),
+}
+
+
+@pytest.mark.parametrize("config", sorted(TPU_LOWERED))
+def test_the_decoder_cells_lower_for_the_chip_to_the_text_they_had(
+        one_chip, config):
+    text = lowered_step(config, one_chip)[2].as_text()
+    text = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""', text)
+    text = re.sub(r"backend_config = \{[^\n]*", "", text)
+    assert (len(text), hashlib.sha256(text.encode()).hexdigest()[:16]) == (
+        TPU_LOWERED[config])
+
+
 @pytest.mark.parametrize("head_dim,fused", [(128, True), (256, True),
-                                            (64, False)])
+                                            (64, True), (32, False)])
 def test_a_tpu_lowering_takes_the_kernels_where_the_heads_allow(
         one_chip, head_dim, fused):
     """The choice is the lowering's: from this CPU process, a program
     lowered for the described chip holds the kernels, forward and
-    backward, for heads of a multiple of 128, and the loops otherwise."""
+    backward, for heads of 64 or of a multiple of 128, and the loops
+    otherwise."""
     from torchmpi_tpu.parallel import blocked_self_attention
 
     def grads(q, k, v):

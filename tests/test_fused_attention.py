@@ -19,11 +19,12 @@ import pytest
 
 from torchmpi_tpu import telemetry
 from torchmpi_tpu.models import MoEDecoder
-from torchmpi_tpu.parallel import blocked_self_attention
+from torchmpi_tpu.parallel import blocked_self_attention, full_self_attention
 from torchmpi_tpu.parallel.ring_attention import (
     LANES,
     _fused,
     _fused_tile,
+    _kernels_take,
     _loops,
     note_attention_step,
 )
@@ -31,7 +32,8 @@ from torchmpi_tpu.telemetry import names
 
 ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
-HEAD = LANES  # the narrowest head the kernels take
+HEAD = LANES  # the head of the decoders' cells
+NARROW = LANES // 2  # GPT-2's, the narrowest head the kernels take
 
 
 def dense_attention(q, k, v, window):
@@ -56,25 +58,32 @@ def gauges():
 
 # the tile is 1,024 from 1,024 positions on; a band's edge is worth a case
 # wherever it meets a tile's edge
-@pytest.mark.parametrize("t,window,heads,kv_heads,dtype", [
-    (2048, None, 2, 2, jnp.float32),   # no window, equal heads, two tiles
-    (2048, None, 7, 1, jnp.bfloat16),  # no window, seven heads to a KV head
-    (2048, 100, 7, 1, jnp.float32),    # a window smaller than a tile
-    (3072, 2100, 1, 1, jnp.float32),   # a window of several tiles
-    (1300, 2000, 7, 1, jnp.float32),   # window >= t; t padded to two tiles
-    (2100, 1200, 2, 2, jnp.bfloat16),  # padded, the band across tile edges
-    (300, None, 2, 1, jnp.float32),    # shorter than a tile: one of 384
-    (2048, 1024, 2, 1, jnp.float32),   # the window a tile exactly
-    (2048, 1025, 2, 1, jnp.float32),   # ... and one key more
-    (2048, 1, 2, 1, jnp.float32),      # a window of the token itself
+@pytest.mark.parametrize("t,window,heads,kv_heads,dtype,head", [
+    (2048, None, 2, 2, jnp.float32, HEAD),   # no window, equal heads, 2 tiles
+    (2048, None, 7, 1, jnp.bfloat16, HEAD),  # seven heads to a KV head
+    (2048, 100, 7, 1, jnp.float32, HEAD),    # a window smaller than a tile
+    (3072, 2100, 1, 1, jnp.float32, HEAD),   # a window of several tiles
+    (1300, 2000, 7, 1, jnp.float32, HEAD),   # window >= t; padded to 2 tiles
+    (2100, 1200, 2, 2, jnp.bfloat16, HEAD),  # padded, the band across tiles
+    (300, None, 2, 1, jnp.float32, HEAD),    # shorter than a tile: one of 384
+    (2048, 1024, 2, 1, jnp.float32, HEAD),   # the window a tile exactly
+    (2048, 1025, 2, 1, jnp.float32, HEAD),   # ... and one key more
+    (2048, 1, 2, 1, jnp.float32, HEAD),      # a window of the token itself
+    # heads of 64 (GPT-2's: one KV head a query head), as they come: one
+    # tile, a sequence padded to one tile, one padded to two
+    (256, None, 3, 3, jnp.float32, NARROW),
+    (256, None, 3, 3, jnp.bfloat16, NARROW),
+    (700, None, 3, 3, jnp.float32, NARROW),
+    (700, None, 3, 3, jnp.bfloat16, NARROW),
+    (1100, None, 3, 3, jnp.bfloat16, NARROW),  # two tiles, the last padded
 ])
 def test_fused_attention_matches_the_loops_and_a_dense_masked_softmax(
-        t, window, heads, kv_heads, dtype):
+        t, window, heads, kv_heads, dtype, head):
     ks = jax.random.split(jax.random.PRNGKey(t + heads), 4)
-    q = jax.random.normal(ks[0], (1, t, heads, HEAD), dtype)
-    k = jax.random.normal(ks[1], (1, t, kv_heads, HEAD), dtype)
-    v = jax.random.normal(ks[2], (1, t, kv_heads, HEAD), dtype)
-    w = jax.random.normal(ks[3], (1, t, heads, HEAD), jnp.float32)
+    q = jax.random.normal(ks[0], (1, t, heads, head), dtype)
+    k = jax.random.normal(ks[1], (1, t, kv_heads, head), dtype)
+    v = jax.random.normal(ks[2], (1, t, kv_heads, head), dtype)
+    w = jax.random.normal(ks[3], (1, t, heads, head), jnp.float32)
     f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
 
     def through(fn):
@@ -105,30 +114,49 @@ def test_fused_attention_matches_the_loops_and_a_dense_masked_softmax(
     np.testing.assert_allclose(f32(got), f32(want), **tol)
     np.testing.assert_allclose(f32(got), f32(loops), **tol)
     for mine, theirs, exact in zip(got_g, loops_g, want_g):
+        assert mine.shape == exact.shape and mine.dtype == dtype
         # (a window of one key leaves q and k no gradient at all)
         scale = max(np.max(np.abs(f32(exact))), 0.1)
         assert np.max(np.abs(f32(mine) - f32(exact))) <= grad_tol * scale
         assert np.max(np.abs(f32(mine) - f32(theirs))) <= grad_tol * scale
+    if window is None and heads == kv_heads:
+        # the library's own t x t reference, which GPT-2's block called
+        (_, full), full_g = through(
+            lambda q, k, v: full_self_attention(q, k, v, causal=True))(
+                *(a.astype(jnp.float32) for a in (q, k, v)))
+        np.testing.assert_allclose(f32(full), f32(want), rtol=1e-5, atol=1e-5)
+        for mine, exact in zip(got_g, full_g):
+            scale = np.max(np.abs(f32(exact)))
+            assert np.max(np.abs(f32(mine) - f32(exact))) <= grad_tol * scale
 
 
 def test_the_tile_follows_the_sequence():
     assert [_fused_tile(t) for t in (1, 128, 129, 300, 1024, 1025, 8192)] == [
         128, 128, 256, 384, 1024, 1024, 1024]
+    # every decoder cell's length, and every other from 2,048 on: PR 27's
+    assert {_fused_tile(t) for t in (
+        2048, 2049, 4096, 8192, 16384, 16385, 10**6)} == {1024}
 
 
-@pytest.mark.parametrize("head_dim", [32, HEAD])
+def test_the_kernels_take_heads_of_64_and_of_the_lanes_multiples():
+    assert [d for d in range(1, 513) if _kernels_take(d)] == [
+        64, 128, 256, 384, 512]
+
+
+@pytest.mark.parametrize("head_dim", [32, NARROW, HEAD])
 def test_the_cpu_and_narrow_heads_take_the_loops_and_the_gauges_say_so(
         head_dim):
     """On a CPU lowering no kernel is left in the program, whatever the
-    heads; with heads of the lanes' width the kernels are offered (the
-    traced program holds both executions) and the lowering drops them."""
+    heads; with heads the kernels take (64, the lanes' multiples) they are
+    offered (the traced program holds both executions) and the lowering
+    drops them."""
     q = jax.ShapeDtypeStruct((1, 256, 4, head_dim), jnp.float32)
     k = jax.ShapeDtypeStruct((1, 256, 2, head_dim), jnp.float32)
     fn = lambda q, k, v: blocked_self_attention(q, k, v, 64, 64)  # noqa: E731
     note_attention_step()
     assert gauges() == (0, 0)
     traced = str(jax.make_jaxpr(fn)(q, k, k))
-    assert ("pallas_call" in traced) == (head_dim == HEAD)
+    assert ("pallas_call" in traced) == (head_dim in (NARROW, HEAD))
     assert gauges() == (1, 0)
     lowered = jax.jit(fn).lower(q, k, k).as_text()
     assert "tpu_custom_call" not in lowered and "while" in lowered
@@ -151,6 +179,23 @@ def test_a_models_forward_pass_counts_its_attention_calls():
     jax.eval_shape(
         jax.grad(lambda p: jnp.sum(model.apply(p, toks)[0])), params)
     assert gauges() == (5, 0)  # a layer's call, not each of its traces
+
+
+def test_gpt2s_forward_pass_counts_its_attention_calls():
+    """``gpt2-medium`` at its real size, traced and not run: one call a
+    block, none of them the kernels' on the CPU (24 of 24 on a TPU)."""
+    from benchmark import configs
+
+    cfg = configs.load("gpt2-medium")
+    built = configs.build("gpt2-medium", cfg)
+    tokens = jax.ShapeDtypeStruct(
+        (cfg["per_chip_batch"], cfg["sequence_length"]), jnp.int32)
+    params, _ = jax.eval_shape(built.state_at, jax.random.PRNGKey(0))
+    telemetry.metrics.gauge("tm_attn_calls_per_step").set(99)
+    jax.eval_shape(built.loss_fn, params, (tokens, tokens))
+    assert gauges() == (cfg["model"]["n_layer"], 0) == (24, 0)
+    jax.eval_shape(jax.grad(built.loss_fn), params, (tokens, tokens))
+    assert gauges() == (24, 0)  # a block's call, not each of its traces
 
 
 def test_the_kernels_event_names_are_the_kernels_own():

@@ -240,12 +240,21 @@ def test_the_rule_is_all_that_differs_in_the_expert_layer():
 # route rule, the heads by layer, the gate, the shared expert and the
 # dense layer lowered them (PERF.md section 6, PR 31 and PR 32). A PR that
 # means to change those steps changes these; one that does not, must not.
+# PR 38 meant one change and made it: ``blocked_self_attention``'s loops are
+# traced once a shape (``jax.jit(..., inline=True)``), so the lowering
+# writes ONE function for the loops' bodies that the layers share where it
+# wrote one a layer: the same operations in fewer characters (697,739 ->
+# 658,943 and 934,255 -> 877,075; 56 -> 54 and 70 -> 67 functions), and
+# ``keye-vl-2-30b-a3b``, whose layers all select, as it was. Lowered for the
+# TPU at the cells' own sizes, where the loops are dropped, all three are
+# the parent's text letter for letter:
+# ``test_decoder_chip_compile.py`` pins those.
 LOWERED = {
-    "smallthinker-21b-a3b": (697739, "440c14fb6dcae597"),
+    "smallthinker-21b-a3b": (658943, "9b0c6898b47d4450"),
     "keye-vl-2-30b-a3b": (1205758, "55eca190dcff1715"),
-    # as PR 32 left it; the loops name nothing a recomputation could keep,
-    # so the selecting layers' policy (PR 36) moved none of the three
-    "laguna-s-2-1": (934255, "9e6a660275528747"),
+    # the loops name nothing a recomputation could keep, so the selecting
+    # layers' policy (PR 36) moved none of the three
+    "laguna-s-2-1": (877075, "9b3ffce1528a44a1"),
 }
 
 

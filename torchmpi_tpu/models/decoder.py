@@ -15,8 +15,9 @@ positional encoding at all); ``num_heads`` and ``num_kv_heads`` are one
 number or, as the layouts, a pattern by layer. Attention is
 ``parallel.ring_attention.blocked_self_attention`` or
 ``parallel.selected_attention.selected_self_attention`` (no ``t x t``
-tensor a head; on a TPU with heads of a multiple of 128 in fused kernels,
-else in loops of XLA operations: the call decides, the model sets nothing);
+tensor a head; on a TPU with heads of a width the kernels take in fused
+kernels, else in loops of XLA operations: the call decides, the model sets
+nothing);
 the experts are ``parallel.ep.moe_local_experts`` (dropless, told which of
 all the experts it holds: what the others would add is left out, the part
 an exchange across devices would bring). Parameters are float32, the

@@ -5,6 +5,10 @@ absent there): a decoder-only block stack whose attention runs over a
 sequence axis sharded across devices via :func:`ring_self_attention` — the
 sequence dimension never materialises on one chip, so context length scales
 with the sp-axis size. MXU-friendly dims (multiples of 128 for model width).
+On one device (``sp_axis=None``) the attention is
+:func:`blocked_self_attention` over the whole causal prefix: no ``T x T``
+tensor; the call chooses its execution (fused kernels or loops), the model
+sets nothing.
 """
 
 from __future__ import annotations
@@ -15,7 +19,11 @@ import flax.linen as fnn
 import jax
 import jax.numpy as jnp
 
-from ..parallel.ring_attention import full_self_attention, ring_self_attention
+from ..parallel.ring_attention import (
+    blocked_self_attention,
+    note_attention_step,
+    ring_self_attention,
+)
 from ..telemetry import names as _names
 
 
@@ -53,7 +61,7 @@ class RingAttentionBlock(fnn.Module):
     num_heads: int
     head_dim: int
     mlp_ratio: int = 4
-    sp_axis: Optional[str] = None  # None = full attention (single shard)
+    sp_axis: Optional[str] = None  # None = one shard: blocked attention
     sp_backend: str = "xla"  # 'xla' | 'pallas[_interpret][_bidir][_full]'
     dtype: Any = jnp.float32
 
@@ -80,7 +88,7 @@ class RingAttentionBlock(fnn.Module):
                     backend=self.sp_backend,
                 )
             else:
-                attn = full_self_attention(q, k, v, causal=True)
+                attn = blocked_self_attention(q, k, v)
         attn = attn.reshape(x.shape[:2] + (-1,))
         with jax.named_scope(_names.SCOPE_ATTN_PROJ):
             x = x + fnn.Dense(d_model, dtype=self.dtype)(attn)
@@ -113,6 +121,7 @@ class LongContextTransformer(fnn.Module):
     @fnn.compact
     def __call__(self, tokens):
         # tokens: [B, T_local] int32
+        note_attention_step()
         t_local = tokens.shape[1]
         if self.sp_axis is not None:
             r = jax.lax.axis_index(self.sp_axis)

@@ -21,8 +21,9 @@ its own.
 On ONE device ``blocked_self_attention`` is the same streaming softmax over
 blocks of one sequence (causal, optionally within a window, grouped KV
 heads), in two executions it chooses between itself: on a TPU, with heads
-of a multiple of 128, jax's fused splash-attention kernels, in which a
-tile's scores stay in VMEM; elsewhere loops of XLA operations.
+of 64 or of a multiple of 128 (``_kernels_take``), jax's fused
+splash-attention kernels, in which a tile's scores stay in VMEM; elsewhere
+loops of XLA operations.
 
 Derived from the ring-attention pattern in the public pallas guide and the
 scaling-book recipe: shift-K/V ring + online softmax.
@@ -211,6 +212,13 @@ def _rows(x, i, block: int):
     return lax.dynamic_slice_in_dim(x, i * block, block, axis=1)
 
 
+# Traced once a shape and laid into the caller's program as it is (``inline``:
+# no call is left there), as ``_blocked_backward`` and ``_either`` are: a
+# model of 24 unrolled blocks would else trace the loops 24 times forward and
+# again wherever a derivative rule transposes the branch they stand in, 13 s
+# of every start; with ``_either`` alone decorated still 6.6 s, 14 % of a
+# warm start, so all three stay (PERF.md, PR 38).
+@partial(jax.jit, static_argnums=(3, 4), inline=True)
 def _blocked_forward(q, k, v, window, block):
     """(output ``[b, t, hq, d]`` in ``q``'s dtype; the log-sum-exp of each
     query's scores, ``[blocks, b, hkv, g * block]``). ``t`` is a multiple
@@ -251,10 +259,14 @@ def _blocked_fwd(q, k, v, window, block):
 
 
 def _blocked_bwd(window, block, saved, dout):
+    return _blocked_backward(*saved, dout, window, block)
+
+
+@partial(jax.jit, static_argnums=(6, 7), inline=True)
+def _blocked_backward(q, k, v, out, lses, dout, window, block):
     """Backward from the saved output and log-sum-exps: each block pair's
     probabilities are made again from its scores, so nothing of size
     ``t x t`` is ever kept."""
-    q, k, v, out, lses = saved
     f32 = jnp.float32
     g = q.shape[2] // k.shape[2]
     scale = 1.0 / math.sqrt(q.shape[-1])
@@ -307,15 +319,26 @@ _blocked.defvjp(_blocked_fwd, _blocked_bwd)
 # the same attention in fused kernels: a tile's scores never leave VMEM
 # ---------------------------------------------------------------------------
 
-LANES = 128  # the TPU's vector lanes: what the kernels' heads and tiles
-#              must be multiples of
+LANES = 128  # the TPU's vector lanes: what the kernels' tiles must be
+#              multiples of, and their heads but for half of it
+
+
+def _kernels_take(head_dim: int) -> bool:
+    """Whether the fused kernels take heads of this width: the multiples
+    of the lanes, and half the lanes (GPT-2's 64, which the kernels run as
+    it comes: PERF.md, PR 38). No other width was measured on a chip, and
+    those keep the loops. The one rule of the call and of its gauge."""
+    return head_dim % LANES == 0 or head_dim == LANES // 2
 
 
 def _fused_tile(t: int) -> int:
     """The kernels' tile of queries and of keys for a sequence of ``t``:
     1,024 (on a v5e the tile that ran fastest forward and backward at 8,192
     positions: PERF.md, PR 27; 2,048 queries do not fit VMEM), and for a
-    shorter sequence the multiple of the lanes that holds it."""
+    shorter sequence the multiple of the lanes that holds it (GPT-2's
+    1,024 positions are one tile, the whole square under its mask: its
+    step ran 1.6 % faster so than with four of 512, of which three are
+    visited: PERF.md, PR 38)."""
     return min(1024, -(-t // LANES) * LANES)
 
 
@@ -365,11 +388,14 @@ def _pad_sequence(q, k, v, multiple: int):
 
 
 def _fused(q, k, v, window: Optional[int], interpret: bool = False):
-    """``blocked_self_attention`` in the fused kernels; ``head_dim`` is a
-    multiple of the lanes here. The kernels apply no scale: ``q`` is
+    """``blocked_self_attention`` in the fused kernels; ``head_dim`` is one
+    ``_kernels_take`` here, and the kernels run it as it comes (a head of
+    64 fills half the lanes of its tiles; padded with zeros to 128 it ran
+    slower: PERF.md, PR 38). The kernels apply no scale: ``q`` is
     multiplied by ``1 / sqrt(head_dim)`` in its own dtype on the way in
     (one more rounding of a bfloat16 ``q``, where the loops scale the
-    float32 scores). The products take ``q``, ``k``, ``v`` as they come
+    float32 scores; exact where the width is a power of four, as 64 is).
+    The products take ``q``, ``k``, ``v`` as they come
     (bfloat16 into the MXU), the running maximum, normaliser and
     accumulator are float32. ``interpret``: on the CPU, for the tests."""
     b, t, hq, d = q.shape
@@ -405,8 +431,8 @@ def _attention_gauges():
         m.gauge(
             "tm_attn_kernel_calls_per_step",
             "those of tm_attn_calls_per_step that take the fused kernels: "
-            "head_dim a multiple of 128, traced where jax's backend is a "
-            "TPU"),
+            "head_dim a width the kernels take (_kernels_take), traced "
+            "where jax's backend is a TPU"),
     )
 
 
@@ -438,11 +464,12 @@ def blocked_self_attention(q, k, v, window: Optional[int] = None,
     wholly outside that band are skipped, not masked; ``t`` need not be a
     multiple of a block (it is padded to one).
 
-    Where the step is lowered for a TPU and ``head_dim`` is a multiple of
-    the 128 lanes: fused kernels (``_fused``), forward and backward, in
-    which a tile's scores, probabilities and their gradients live in VMEM
-    and never reach HBM; the tiles are chosen from ``t``. Everywhere else
-    (the CPU, narrower heads): XLA operations (``_loops``), blocks of
+    Where the step is lowered for a TPU and ``head_dim`` is a width the
+    kernels take (``_kernels_take``): fused kernels
+    (``_fused``), forward and backward, in which a tile's scores,
+    probabilities and their gradients live in VMEM and never reach HBM; the
+    tiles are chosen from ``t`` (``_fused_tile``). Everywhere else (the
+    CPU, other widths): XLA operations (``_loops``), blocks of
     ``block`` queries against blocks of keys through ``_block_attn`` and
     the streaming-softmax merge of the ring, each block pair's float32
     scores passing through memory. Both keep the log-sum-exp and make each
@@ -457,12 +484,19 @@ def blocked_self_attention(q, k, v, window: Optional[int] = None,
             f"{k.shape[2]}, and k and v alike (got {k.shape}, {v.shape})")
     if window is not None and window < 1:
         raise ValueError(f"window must be positive, got {window}")
-    loops = partial(_loops, window=window, block=block)
-    if q.shape[-1] % LANES:
-        _note_attention_call(False)
-        return loops(q, k, v)
     # the gauge is set while the call is traced, when the platform of the
     # lowering is not known yet: it goes by the process's backend
-    _note_attention_call(jax.default_backend() == "tpu")
+    _note_attention_call(
+        _kernels_take(q.shape[-1]) and jax.default_backend() == "tpu")
+    return _either(q, k, v, window=window, block=int(block))
+
+
+@partial(jax.jit, static_argnames=("window", "block"), inline=True)
+def _either(q, k, v, window, block):
+    """The two executions under the choice between them; see
+    ``_blocked_forward`` for the decorator."""
+    loops = partial(_loops, window=window, block=block)
+    if not _kernels_take(q.shape[-1]):
+        return loops(q, k, v)
     return lax.platform_dependent(
         q, k, v, tpu=partial(_fused, window=window), default=loops)
