@@ -1,8 +1,9 @@
-"""The benchmark's pieces for the three decoder configurations,
-``smallthinker-21b-a3b``, ``keye-vl-2-30b-a3b`` and ``laguna-s-2-1`` (the
-sparse decoders of ``tests/test_moe_decoder.py``,
+"""The benchmark's pieces for the four decoder configurations,
+``smallthinker-21b-a3b``, ``keye-vl-2-30b-a3b``, ``laguna-s-2-1`` and
+``falcon-h1-34b`` (the sparse decoders of ``tests/test_moe_decoder.py``,
 ``tests/test_selected_attention.py`` and ``tests/test_laguna_decoder.py``
-at the published widths): each one's file, its operation count, its data, the
+and the hybrid one of ``tests/test_hybrid_decoder.py`` at the published
+widths): each one's file, its operation count, its data, the
 reader of its inner scopes, and its cell's whole run at the rehearsal's
 sizes, sound, broken and with the fp8 control in the program's place. What
 is the same for both is one test with a case for each."""
@@ -20,7 +21,8 @@ sys.path.insert(0, str(ROOT))
 CONFIG = "smallthinker-21b-a3b"
 KEYE = "keye-vl-2-30b-a3b"
 LAGUNA = "laguna-s-2-1"
-CONFIGS = [CONFIG, KEYE, LAGUNA]
+FALCON = "falcon-h1-34b"  # no experts: a state-space mixer beside attention
+CONFIGS = [CONFIG, KEYE, LAGUNA, FALCON]
 CELL = CONFIG + ".stream.x1"
 
 
@@ -166,12 +168,14 @@ def test_the_cells_rehearsal_is_correct(capsys, config):
     (CONFIG, set()),
     (KEYE, {"attn_selected_pair_share", "attn_index_loss",
             "attn_kernel_share"}),
-    (LAGUNA, {"attn_heads_held_share", "attn_kernel_share"})], ids=CONFIGS)
+    (LAGUNA, {"attn_heads_held_share", "attn_kernel_share"}),
+    (FALCON, {"ssm_heads_held_share", "attn_kernel_share"})], ids=CONFIGS)
 def test_the_cells_traced_rehearsal_reports_the_routing_counters(
         capsys, config, more):
     """... and, for the configuration that selects its keys, what the
-    selection measured; for the one held by share, the share of the heads
-    (the scope metrics need a TPU's trace)."""
+    selection measured; for the one held by share, the share of the heads;
+    for the hybrid one, which routes nothing, the share of the mixer's
+    heads (the scope metrics need a TPU's trace)."""
     from benchmark import run as bench
 
     rc = bench.main(["--workload", config + ".stream.x1", "--seed", "11",
@@ -179,10 +183,14 @@ def test_the_cells_traced_rehearsal_reports_the_routing_counters(
     out = capsys.readouterr().out
     line = json.loads(out.strip().splitlines()[-1])
     assert rc == 0 and line["correct"] is True, out
-    assert {"moe_grouped_rows_per_step", "moe_max_over_mean_load",
-            "engine_dispatch_ms"} | more <= set(line["rehearsed"])
+    routed = set() if config == FALCON else {
+        "moe_grouped_rows_per_step", "moe_max_over_mean_load"}
+    assert routed | {"engine_dispatch_ms"} | more <= set(line["rehearsed"])
+    assert (config == FALCON) == (not [
+        m for m in line["rehearsed"] if m.startswith("moe_")])
     # its reader divides by every layer; the third's layer 0 has no experts
-    assert ("moe_compact_share" in line["rehearsed"]) == (config != LAGUNA)
+    assert ("moe_compact_share" in line["rehearsed"]) == (
+        config in (CONFIG, KEYE))
     assert ("attn_selected_pair_share" in line["rehearsed"]) == (
         config == KEYE)
     assert ("attn_heads_held_share" in line["rehearsed"]) == (

@@ -23,6 +23,7 @@ CONFIG = "smallthinker-21b-a3b"
 GPT2 = "gpt2-medium"
 KEYE = "keye-vl-2-30b-a3b"
 LAGUNA = "laguna-s-2-1"
+FALCON = "falcon-h1-34b"
 
 
 @pytest.fixture(scope="module")
@@ -213,6 +214,46 @@ def test_the_shared_cells_step_fits_the_chip_with_its_heads_by_layer(
             False, True]
 
 
+def test_the_hybrid_cells_step_fits_the_chip_with_its_scan_in_chunks(
+        one_chip):
+    """``falcon-h1-34b.stream.x1``'s step at the published widths: it fits
+    at 1 x 16,384 (not the fallback of 8,192) under the 15.0 GiB ISSUE 39
+    set, every layer's attention takes the fused kernels with 5 query
+    heads to the one KV head, the scan holds no array of all the positions
+    squared (its masked products are ``[128, 128]`` a chunk) and no state a
+    position, and the carried state is one loop over the 128 chunks,
+    forward and backward."""
+    from torchmpi_tpu.telemetry import names
+
+    cfg, params, compiled = compiled_step(FALCON, one_chip)
+    count = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    assert count == 572_935_216  # 4 layers of 59.68 M + 334.2 M of vocabulary
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    # 12 B a parameter of state (6.40 GiB) and 8.27 GiB of temporaries
+    # measured here, 14.68 GiB, and the same on the chip (PERF.md, PR 39):
+    # the float32 logits of 16,384 x 32,640 are 1.99 GiB an array
+    assert memory.argument_size_in_bytes > 12 * count
+    assert held < 15.0 * 2**30, memory
+    text = compiled.as_text()
+    kernels = Counter(
+        re.findall(r"%([A-Za-z_]+?)[.\d]* = [^\n]*tpu_custom_call", text))
+    layers, seq = cfg["num_hidden_layers"], cfg["sequence_length"]
+    assert kernels == {"splash_mqa_fwd_residuals": 2 * layers,
+                       "splash_mqa_dkv_no_residuals": layers}, kernels
+    assert all(k.startswith(names.ATTN_KERNEL_EVENT) for k in kernels)
+    assert (seq, cfg["mamba_chunk_size"]) == (16384, 128)
+    assert f"[{seq},{seq}]" not in text  # no t x t array, of any type
+    # the state is [heads, P, N] = [4, 128, 256] a CHUNK (128 of them),
+    # never a position: no array holds 16,384 states
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text)}
+    states = [s for s in shapes if s[-2:] == (128, 256) and len(s) >= 3]
+    assert states and max(math.prod(s) for s in states) == 128 * 4 * 128 * 256
+    # forward, the recomputed forward and backward carry the state a layer
+    assert text.count(" while(") >= 3 * layers
+
+
 def test_the_cells_step_fits_the_chip_and_holds_no_dispatch_tensor(one_chip):
     from benchmark import configs
     from torchmpi_tpu.telemetry import names
@@ -349,17 +390,18 @@ def conditional_branches(text):
     return found
 
 
-# The three decoder cells' whole steps at their real sizes as LOWERED for the
+# The four decoder cells' whole steps at their real sizes as LOWERED for the
 # described chip (not compiled): characters and the first 16 of the sha256
 # of the text without the kernels' serialized bodies (they carry the
-# checkout's path). As the parent of PR 38 lowered them: heads of 128 and
-# sequences of 8,192 and 16,384 take the branch and the tiles they took. A
-# PR that means to change those steps changes these; one that does not,
-# must not.
+# checkout's path). The first three as the parent of PR 38 lowered them:
+# heads of 128 and sequences of 8,192 and 16,384 take the branch and the
+# tiles they took; the hybrid one as PR 39 brought it. A PR that means to
+# change those steps changes these; one that does not, must not.
 TPU_LOWERED = {
     CONFIG: (955645, "c6e7fca3cf766c29"),
     KEYE: (1248452, "47bf5842f8ac3442"),
     LAGUNA: (1492140, "fc51f3fc3cfee276"),
+    FALCON: (1113784, "12847685d7cdf152"),
 }
 
 
@@ -420,7 +462,11 @@ def test_a_tpu_lowering_takes_the_kernels_where_the_heads_allow(
     (LAGUNA, [
         "attn_gate_ms_per_step", "attn_heads_held_share",
         "mlp_dense_ms_per_step", "moe_shared_ms_per_step"]),
-], ids=[CONFIG, KEYE, LAGUNA])
+    (FALCON, [
+        "ssm_conv_ms_per_step", "ssm_gate_ms_per_step",
+        "ssm_heads_held_share", "ssm_proj_ms_per_step",
+        "ssm_scan_ms_per_step"]),
+], ids=[CONFIG, KEYE, LAGUNA, FALCON])
 def test_the_configuration_is_a_cell_of_the_benchmark(config, own):
     """The configuration's one cell, and the per-layer metrics that came
     with it: those whose list of cells begins with it."""
@@ -454,3 +500,11 @@ def test_the_configuration_is_a_cell_of_the_benchmark(config, own):
                 "moe_compact_share"}
     assert not third & {"attn_sparse_ms_per_step", "attn_index_ms_per_step",
                         "attn_select_ms_per_step"}
+    # the hybrid one reads GPT-2's feed-forward scope and the decoders'
+    # full attention and kernels, and nothing of an expert layer
+    fourth = {m["name"] for m in spec["per_layer"]
+              if FALCON + ".stream.x1" in m["workloads"]}
+    assert {"mlp_ms_per_step", "attn_full_ms_per_step", "attn_kernel_share",
+            "attn_kernel_ms_per_step", "fwd_bwd_unnamed_share"} <= fourth
+    assert not [m for m in fourth if m.startswith("moe_")]
+    assert not fourth & {"attn_window_ms_per_step", "mlp_dense_ms_per_step"}

@@ -20,6 +20,7 @@ import pytest
 import torchmpi_tpu as mpi
 from torchmpi_tpu.engine import AllReduceSGDEngine
 from torchmpi_tpu.models import (
+    HybridDecoder,
     LongContextTransformer,
     MoEDecoder,
     Rotary,
@@ -37,11 +38,13 @@ sys.path.insert(0, str(ROOT))
 SEQ, VOCAB = 24, 61
 OLD = names.MODEL_SCOPE_NAMES[:11]   # what the benchmark's metrics read
 NEW = names.MODEL_SCOPE_NAMES[11:]   # what this file is about
+SSM = names.MODEL_SCOPE_NAMES[18:]   # the state-space mixer's (PR 39)
 EVERY_LM = {"tm.lm.embed", "tm.lm.norm", "tm.attn.proj", "tm.lm.head",
             "tm.lm.loss"}
 # the scopes opened inside a block are recomputed with it; the embedding,
 # the last norm's model-level call, the head and the loss are not
-IN_BLOCKS = {"tm.lm.norm", "tm.attn.proj", "tm.lm.mlp", "tm.moe.router"}
+IN_BLOCKS = {"tm.lm.norm", "tm.attn.proj", "tm.lm.mlp", "tm.moe.router",
+             *SSM}
 
 
 def _decoder(**over):
@@ -71,6 +74,13 @@ FAMILIES = {
         activation=jax.nn.silu, router_after_norm=True, head_gate=True,
         route_weights=sigmoid_route_weights(2.5), shared_width=16,
         dense_layers=1, dense_width=24),
+    # falcon-h1-34b's: a state-space mixer beside attention in every block,
+    # a gated feed-forward under GPT-2's scope, no router
+    "hybrid": lambda: HybridDecoder(
+        vocab_size=VOCAB, num_layers=2, d_model=32, num_heads=2,
+        num_kv_heads=1, head_dim=8, ssm_heads=2, ssm_head_dim=8,
+        ssm_groups=1, ssm_state=6, mlp_width=24, chunk=8, attn_block=8,
+        remat=True),
     # keye-vl-2-30b-a3b's: every layer selects, with a norm on each query
     # and key head; the indexer's projections stay under tm.attn.index
     "selected": lambda: _decoder(
@@ -126,15 +136,18 @@ def test_new_scopes_reach_every_phase_under_fwd_bwd(family):
     from benchmark import model_scopes, scopes
 
     assert NEW == ("tm.lm.embed", "tm.lm.norm", "tm.attn.proj", "tm.lm.mlp",
-                   "tm.moe.router", "tm.lm.head", "tm.lm.loss")
+                   "tm.moe.router", "tm.lm.head", "tm.lm.loss",
+                   "tm.lm.ssm_proj", "tm.lm.ssm_conv", "tm.lm.ssm_scan",
+                   "tm.lm.ssm_gate")
     seen = {}
     for op in _op_names(_engine(family)):
         bucket = model_scopes.bucket_of(op)
         if bucket in NEW:
             assert scopes.scope_of(op) == "tm.fwd_bwd", op
             seen.setdefault(bucket, set()).add(model_scopes.phase_of(op))
-    own = "tm.lm.mlp" if family == "gpt2" else "tm.moe.router"
-    assert set(seen) == EVERY_LM | {own}, seen
+    own = {"gpt2": {"tm.lm.mlp"}, "hybrid": {"tm.lm.mlp", *SSM}}.get(
+        family, {"tm.moe.router"})
+    assert set(seen) == EVERY_LM | own, seen
     for scope, phases in seen.items():
         want = {"forward", "backward"}
         if scope in IN_BLOCKS:

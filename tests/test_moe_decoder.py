@@ -735,7 +735,10 @@ def test_model_scopes_nest_under_fwd_bwd_in_the_lowered_step(selecting):
         "tm.attn.sparse", "tm.attn.gate", "tm.moe.shared", "tm.moe.dense",
         # every language model's parts (tests/test_model_scopes.py)
         "tm.lm.embed", "tm.lm.norm", "tm.attn.proj", "tm.lm.mlp",
-        "tm.moe.router", "tm.lm.head", "tm.lm.loss")
+        "tm.moe.router", "tm.lm.head", "tm.lm.loss",
+        # the state-space mixer's (tests/test_hybrid_decoder.py)
+        "tm.lm.ssm_proj", "tm.lm.ssm_conv", "tm.lm.ssm_scan",
+        "tm.lm.ssm_gate")
     cfg = tiny_cfg()
     model = tiny_model(cfg)
     opened = set(names.MODEL_SCOPE_NAMES[:5])

@@ -5,6 +5,7 @@ from .decoder import (
     init_moe_state,
     make_moe_lm_loss_fn,
 )
+from .hybrid import HybridDecoder, HybridDecoderBlock, Multipliers
 from .mlp import MLP6
 from .mnist import (
     LeNet,
@@ -40,6 +41,9 @@ __all__ = [
     "MoEDecoder",
     "MoEDecoderBlock",
     "Rotary",
+    "HybridDecoder",
+    "HybridDecoderBlock",
+    "Multipliers",
     "make_moe_lm_loss_fn",
     "init_moe_state",
     "cross_entropy_loss",

@@ -17,6 +17,7 @@ from .ring_attention import (
     ring_self_attention,
 )
 from .selected_attention import selected_self_attention
+from .ssm import causal_conv1d, gated_group_norm, ssd_chunked_scan
 from .tp import MPLinear, MPLinearOutputSplit, shard_input_features
 
 __all__ = [
@@ -33,6 +34,9 @@ __all__ = [
     "full_self_attention",
     "blocked_self_attention",
     "selected_self_attention",
+    "causal_conv1d",
+    "ssd_chunked_scan",
+    "gated_group_norm",
     "MPLinear",
     "MPLinearOutputSplit",
     "shard_input_features",

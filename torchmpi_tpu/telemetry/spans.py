@@ -124,12 +124,24 @@ SCOPE_LM_HEAD = "tm.lm.head"          # the product with the vocabulary
 SCOPE_LM_LOSS = "tm.lm.loss"          # the float32 log-softmax, the pick,
 #                                       the mean
 
+# models/hybrid.py: a state-space mixer beside the attention of a block
+# (parallel/ssm.py). Named ``tm.lm.*`` so that the benchmark's reader, which
+# knows ``tm.(lm|attn|moe).*``, gives each a bucket of its own.
+SCOPE_SSM_PROJ = "tm.lm.ssm_proj"     # the mixer's in and out products and
+#                                       the multipliers on their parts
+SCOPE_SSM_CONV = "tm.lm.ssm_conv"     # the causal depthwise convolution, its
+#                                       bias and the SiLU
+SCOPE_SSM_SCAN = "tm.lm.ssm_scan"     # delta, the decays, the chunked dual,
+#                                       the carried state, D x
+SCOPE_SSM_GATE = "tm.lm.ssm_gate"     # the gate and the norm by groups
+
 MODEL_SCOPE_NAMES = (
     SCOPE_ATTN_FULL, SCOPE_ATTN_WINDOW, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
     SCOPE_MOE_COMBINE, SCOPE_ATTN_INDEX, SCOPE_ATTN_SELECT,
     SCOPE_ATTN_SPARSE, SCOPE_ATTN_GATE, SCOPE_MOE_SHARED, SCOPE_MOE_DENSE,
     SCOPE_LM_EMBED, SCOPE_LM_NORM, SCOPE_ATTN_PROJ, SCOPE_LM_MLP,
     SCOPE_MOE_ROUTER, SCOPE_LM_HEAD, SCOPE_LM_LOSS,
+    SCOPE_SSM_PROJ, SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_SSM_GATE,
 )
 
 # -- the gauge models/decoder.py sets from static shapes while its step is
@@ -137,6 +149,11 @@ MODEL_SCOPE_NAMES = (
 # holds. The benchmark's ``attn_heads_held_share`` reads it against the
 # heads of the same layers whole, which the configuration's file gives.
 GAUGE_ATTN_HEADS_HELD = "tm_attn_query_heads_held_per_step"
+# -- the gauges parallel/ssm.py ``note_ssm_step`` sets the same way for
+# models/hybrid.py: the mixer heads this device holds, summed over the
+# layers, and the chunks its scans run over (layers x sequences x chunks)
+GAUGE_SSM_HEADS_HELD = "tm_ssm_heads_held_per_step"
+GAUGE_SSM_CHUNKS = "tm_ssm_chunks_per_step"
 
 # -- what a device trace calls the attention kernels (an event's name is
 # the kernel's HLO instruction): jax's splash attention in
