@@ -80,10 +80,12 @@ def compiled_step(config, one_chip):
 
 def test_gpt2s_step_holds_its_attention_in_the_kernels(one_chip):
     """``gpt2-medium``'s step at its real size (both of its cells run it):
-    each of the 24 blocks' attention is the fused kernels, forward, the
-    block's recomputed forward and one backward, with heads of 64 and one
-    tile of 1,024; no ``[b, h, t, t]`` array of any type is left, and the step's
-    temporaries are no larger than with the ``t x t`` attention."""
+    each of the 24 blocks' attention is the fused kernels, one forward and
+    one backward (the block's recomputation keeps the forward kernel's
+    output and log-sum-exp, ``ring_attention.SAVED``, and does not run it
+    again), with heads of 64 and one tile of 1,024; no ``[b, h, t, t]``
+    array of any type is left, and the step's temporaries, the kept
+    0.39 GiB among them, are no larger than when nothing was kept."""
     from torchmpi_tpu.telemetry import names
 
     cfg, params, compiled = compiled_step(GPT2, one_chip)
@@ -91,14 +93,20 @@ def test_gpt2s_step_holds_its_attention_in_the_kernels(one_chip):
     assert 406e6 < count < 407e6
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 12 * count
-    # 3.392 GiB measured here; the parent's step, every block's scores an
-    # array, 3.416 GiB (3,667,638,272 B: PERF.md, PR 38)
-    assert memory.temp_size_in_bytes <= 3_667_638_272, memory
+    # 3.359 GiB measured here (3,606,996,992 B) WITH the 24 layers' kept
+    # outputs and log-sum-exps, 24 x (16 + 0.5 MiB) = 0.39 GiB: the bound
+    # is the parent's step, which kept nothing and ran the forward kernel
+    # twice, 3.392 GiB (3,641,704,448 B: PERF.md, PR 40). The peak stands
+    # in backward, where a block's recomputed activations are live: the
+    # kept arrays are live there in either program (made again or kept),
+    # and keeping them spares the second kernel's own temporaries
+    assert memory.temp_size_in_bytes <= 3_641_704_448, memory
     text = compiled.as_text()
     kernels = Counter(
         re.findall(r"%([A-Za-z_]+?)[.\d]* = [^\n]*tpu_custom_call", text))
     layers = cfg["model"]["n_layer"]
-    assert kernels == {"splash_mqa_fwd_residuals": 2 * layers,
+    # one forward kernel a layer: what it hands to backward is kept
+    assert kernels == {"splash_mqa_fwd_residuals": layers,
                        "splash_mqa_dkv_no_residuals": layers}, kernels
     assert all(k.startswith(names.ATTN_KERNEL_EVENT) for k in kernels)
     batch, seq = cfg["per_chip_batch"], cfg["sequence_length"]
@@ -189,16 +197,19 @@ def test_the_shared_cells_step_fits_the_chip_with_its_heads_by_layer(
     assert 468.8e6 < count < 469.0e6  # 19.7 + 3 x 93.6 + 91.3 + 77.1 M
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
-    # 12 B a parameter of state and 4.9 GiB of temporaries measured here
-    # (10.2 GiB): inside the chip's 15.75 GiB
+    # 12 B a parameter of state and 5.04 GiB of temporaries measured here
+    # (10.29 GiB; 10.16 before the five layers' attention outputs and
+    # log-sum-exps were kept): inside the chip's 15.75 GiB
     assert memory.argument_size_in_bytes > 12 * count
     assert held < 11 * 2**30, memory
     text = compiled.as_text()
     kernels = Counter(
         re.findall(r"%([A-Za-z_]+?)[.\d]* = [^\n]*tpu_custom_call", text))
     layers = cfg["num_hidden_layers"]
-    # forward and the block's recomputed forward, and one backward
-    assert kernels == {"splash_mqa_fwd_residuals": 2 * layers,
+    # one forward and one backward a layer: the block's recomputation
+    # keeps the forward kernel's output and log-sum-exp (``SAVED``) and
+    # does not run it a second time
+    assert kernels == {"splash_mqa_fwd_residuals": layers,
                        "splash_mqa_dkv_no_residuals": layers}, kernels
     assert all(k.startswith(names.ATTN_KERNEL_EVENT) for k in kernels)
     seq = cfg["sequence_length"]
@@ -230,16 +241,24 @@ def test_the_hybrid_cells_step_fits_the_chip_with_its_scan_in_chunks(
     assert count == 572_935_216  # 4 layers of 59.68 M + 334.2 M of vocabulary
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
-    # 12 B a parameter of state (6.40 GiB) and 8.27 GiB of temporaries
-    # measured here, 14.68 GiB, and the same on the chip (PERF.md, PR 39):
-    # the float32 logits of 16,384 x 32,640 are 1.99 GiB an array
+    # 12 B a parameter of state (6.40 GiB) and 8.35 GiB of temporaries
+    # measured here, 14.75 GiB (14.68 before the four layers' attention
+    # outputs and log-sum-exps, 81 MiB, were kept, and the same on the
+    # chip: PERF.md, PR 39): the float32 logits of 16,384 x 32,640 are
+    # 1.99 GiB an array. With the kernels' lane-wide log-sum-exps alive
+    # from forward to backward (240 MiB kept, 14.83 GiB) XLA fitted the
+    # step by making the head's product twice, 36 ms a step on the chip
+    # (PERF.md, PR 40): ``ring_attention._held_by_forward`` has the slices
+    # made in forward, and nothing here is XLA's own rematerialization
     assert memory.argument_size_in_bytes > 12 * count
     assert held < 15.0 * 2**30, memory
     text = compiled.as_text()
+    assert not re.findall(r"\.remat[.\d]* = ", text)
     kernels = Counter(
         re.findall(r"%([A-Za-z_]+?)[.\d]* = [^\n]*tpu_custom_call", text))
     layers, seq = cfg["num_hidden_layers"], cfg["sequence_length"]
-    assert kernels == {"splash_mqa_fwd_residuals": 2 * layers,
+    # one forward kernel a layer: its two results are kept for backward
+    assert kernels == {"splash_mqa_fwd_residuals": layers,
                        "splash_mqa_dkv_no_residuals": layers}, kernels
     assert all(k.startswith(names.ATTN_KERNEL_EVENT) for k in kernels)
     assert (seq, cfg["mamba_chunk_size"]) == (16384, 128)
@@ -288,18 +307,23 @@ def test_the_cells_step_fits_the_chip_and_holds_no_dispatch_tensor(one_chip):
     # parameters and AdamW's moments are 12 B each; the temporaries (the
     # float32 logits, the routed rows; attention's kernels keep their
     # scores in VMEM; their largest own array is dQ's 8 parts, 1.75 GiB)
-    # measured 3.45 GiB here: well inside the chip's 15.75 GiB
+    # measured 3.69 GiB here, 7.84 GiB in all (2.98 and 7.12 before the
+    # four layers' attention outputs, 112 MiB each, and log-sum-exps were
+    # kept): well inside the chip's 15.75 GiB
     assert memory.argument_size_in_bytes > 12 * count
     assert held < 9 * 2**30, memory
     text = compiled.as_text()
     # the grouped products are XLA's kernel, not a product an expert
     assert "ragged-dot" in text
     # a lowering for the TPU takes the fused attention kernels, though this
-    # process's backend is the CPU: in each of the 4 layers a forward, the
-    # block's recomputed forward and one backward, under the name the
-    # benchmark's reader looks for
+    # process's backend is the CPU: in each of the 4 layers one forward
+    # and one backward (the block's recomputation keeps what the forward
+    # kernel made, ``ring_attention.SAVED``, and does not run it again),
+    # under the name the benchmark's reader looks for
     kernels = re.findall(r"%(\w+?)[.\d]* = [^\n]*tpu_custom_call", text)
-    assert len(kernels) == 3 * cfg["num_hidden_layers"], kernels
+    assert Counter(re.sub(r"[.\d]+$", "", k) for k in kernels) == {
+        "splash_mqa_fwd_residuals": cfg["num_hidden_layers"],
+        "splash_mqa_dkv_no_residuals": cfg["num_hidden_layers"]}, kernels
     assert all(k.startswith(names.ATTN_KERNEL_EVENT) for k in kernels)
     # ... and no block pair's scores are left to cross HBM: neither the
     # loops' [batch, KV heads, group x block, block] nor any array of four
@@ -393,15 +417,22 @@ def conditional_branches(text):
 # The four decoder cells' whole steps at their real sizes as LOWERED for the
 # described chip (not compiled): characters and the first 16 of the sha256
 # of the text without the kernels' serialized bodies (they carry the
-# checkout's path). The first three as the parent of PR 38 lowered them:
-# heads of 128 and sequences of 8,192 and 16,384 take the branch and the
-# tiles they took; the hybrid one as PR 39 brought it. A PR that means to
-# change those steps changes these; one that does not, must not.
+# checkout's path). ``keye-vl-2-30b-a3b`` as the parent of PR 38 lowered
+# it, and as PR 40 left it: its layers all select, their kernels kept their
+# results under the policy already, and the one helper that now spells
+# every model's recomputation (``models.transformer.recomputed``) gives it
+# the text it had. The other three as PR 40 lowered them: a recomputed
+# block keeps the fused kernels' output and log-sum-exp and holds no
+# second forward kernel (955,645 c6e7fca3cf766c29, 1,492,140
+# fc51f3fc3cfee276 and 1,113,784 12847685d7cdf152 before; the text is
+# longer because the backward kernel's tile tables, constants, now stand
+# in forward's barrier too). A PR that means
+# to change those steps changes these; one that does not, must not.
 TPU_LOWERED = {
-    CONFIG: (955645, "c6e7fca3cf766c29"),
+    CONFIG: (1482928, "2e54c323e433ec0e"),
     KEYE: (1248452, "47bf5842f8ac3442"),
-    LAGUNA: (1492140, "fc51f3fc3cfee276"),
-    FALCON: (1113784, "12847685d7cdf152"),
+    LAGUNA: (2943293, "162f73ace4dfee71"),
+    FALCON: (2436298, "a49bae8c575c46cb"),
 }
 
 

@@ -9,9 +9,12 @@ readers the benchmark gained. What a lowering for a TPU takes is in
 compiler)."""
 
 import math
+import re
 import sys
 from pathlib import Path
+from typing import Optional
 
+import flax.linen as fnn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,9 +22,11 @@ import pytest
 
 from torchmpi_tpu import telemetry
 from torchmpi_tpu.models import MoEDecoder
+from torchmpi_tpu.models.transformer import recomputed
 from torchmpi_tpu.parallel import blocked_self_attention, full_self_attention
 from torchmpi_tpu.parallel.ring_attention import (
     LANES,
+    SAVED,
     _fused,
     _fused_tile,
     _kernels_take,
@@ -128,6 +133,136 @@ def test_fused_attention_matches_the_loops_and_a_dense_masked_softmax(
         for mine, exact in zip(got_g, full_g):
             scale = np.max(np.abs(f32(exact)))
             assert np.max(np.abs(f32(mine) - f32(exact))) <= grad_tol * scale
+
+
+# -- what a recomputed block keeps of its attention ---------------------------
+class Attention(fnn.Module):
+    """An attention call as a block of its own, for ``recomputed``."""
+
+    window: Optional[int] = None
+    interpret: Optional[bool] = None  # None: the call's own choice
+
+    @fnn.compact
+    def __call__(self, q, k, v):
+        if self.interpret is None:
+            return blocked_self_attention(q, k, v, self.window, 64)
+        return _fused(q, k, v, self.window, interpret=self.interpret)
+
+
+def loss_and_grads(block_cls, w, **fields):
+    """value_and_grad of a weighed sum of the block's output, by q, k, v."""
+    def weighed(q, k, v):
+        out = block_cls(**fields).apply({}, q, k, v)
+        return jnp.sum(out.astype(jnp.float32) * w)
+    return jax.value_and_grad(weighed, argnums=(0, 1, 2))
+
+
+def forward_kernels(jaxpr) -> int:
+    return len(re.findall(r"name=splash_mqa_fwd_residuals\b", str(jaxpr)))
+
+
+@pytest.mark.parametrize("window", [None, 300], ids=["full", "window"])
+@pytest.mark.parametrize("head", [NARROW, HEAD])
+def test_a_recomputed_block_keeps_the_kernels_output_and_log_sum_exp(
+        window, head):
+    """Under ``recomputed`` (``jax.checkpoint`` with the models' policy) the
+    forward kernel's two results are kept: backward holds no second forward
+    kernel, where a plain ``jax.checkpoint`` holds one; and what was kept is
+    what would have been made again, so the gradients are those of no
+    policy and of no checkpoint, bit for bit."""
+    t, heads, kv_heads = 1200, 4, 2  # two tiles, the last padded
+    ks = jax.random.split(jax.random.PRNGKey(head), 4)
+    q = jax.random.normal(ks[0], (1, t, heads, head), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, t, kv_heads, head), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, t, kv_heads, head), jnp.bfloat16)
+    w = jax.random.normal(ks[3], q.shape, jnp.float32)
+    fields = {"window": window, "interpret": True}
+    kept = loss_and_grads(recomputed(Attention), w, **fields)
+    again = loss_and_grads(fnn.remat(Attention), w, **fields)
+    plain = loss_and_grads(Attention, w, **fields)
+    # forward's kernel, then in backward: none, the recomputed one, none
+    assert [forward_kernels(jax.make_jaxpr(f)(q, k, v))
+            for f in (kept, again, plain)] == [1, 2, 1]
+    (loss, grads), *others = (jax.jit(f)(q, k, v)
+                              for f in (kept, again, plain))
+    assert np.isfinite(float(loss)) and all(
+        np.any(np.asarray(g, np.float32)) for g in grads)
+    for other_loss, other in others:
+        assert float(other_loss) == float(loss)
+        for mine, theirs in zip(grads, other):
+            assert mine.dtype == theirs.dtype == jnp.bfloat16
+            np.testing.assert_array_equal(
+                np.asarray(mine, np.float32), np.asarray(theirs, np.float32))
+
+
+def test_a_head_the_kernels_do_not_take_names_nothing_to_keep():
+    """Heads of 96 take the loops on every platform: nothing bears
+    ``SAVED``, and the block traces to the same program under the models'
+    policy and under none."""
+    assert not _kernels_take(96)
+    q = jax.ShapeDtypeStruct((1, 256, 4, 96), jnp.float32)
+    k = jax.ShapeDtypeStruct((1, 256, 2, 96), jnp.float32)
+    w = jnp.ones(q.shape, jnp.float32)
+    texts = [re.sub(r"policy=[^\n]*", "policy=", str(jax.make_jaxpr(
+        loss_and_grads(cls, w, window=100))(q, k, k)))
+        for cls in (recomputed(Attention), fnn.remat(Attention))]
+    assert texts[0] == texts[1]
+    assert "remat2" in texts[0] and SAVED not in texts[0]
+    assert "pallas_call" not in texts[0]
+    # ... where a head the kernels take has them offered, named
+    wide = jax.ShapeDtypeStruct((1, 256, 2, HEAD), jnp.float32)
+    assert SAVED in str(jax.make_jaxpr(loss_and_grads(
+        recomputed(Attention), jnp.ones(wide.shape), window=100))(
+            wide, wide, wide))
+
+
+@pytest.mark.parametrize("config", [
+    "gpt2-medium", "smallthinker-21b-a3b", "laguna-s-2-1", "falcon-h1-34b"])
+def test_the_models_recomputation_is_inert_where_the_loops_run(
+        config, monkeypatch):
+    """The three model families at their rehearsal sizes on the CPU (heads
+    of 16 and 32: the loops, nothing named): through ``recomputed`` the
+    loss and every leaf of the gradient are, bit for bit, those of a
+    recomputation with no policy (``fnn.remat`` alone, every model's
+    spelling before), and those of ``remat=False`` as closely as any
+    recomputation's are (XLA rounds a block it makes again at other places
+    than the one it ran forward: bits differ there with no policy too)."""
+    from benchmark import configs
+    from torchmpi_tpu.models import decoder, hybrid, transformer
+
+    cfg = configs.load(config, rehearse=True)
+    assert cfg["remat"] is True
+    ids = jax.random.randint(
+        jax.random.PRNGKey(3),
+        (cfg["per_chip_batch"], cfg["sequence_length"]), 0, 97)
+
+    def loss_and_gradient(remat):
+        built = configs.build(config, {**cfg, "remat": remat})
+        params, state = built.state_at(jax.random.PRNGKey(0))
+        if state is None:
+            return jax.jit(jax.value_and_grad(built.loss_fn))(
+                params, (ids, ids))
+        (loss, _), grads = jax.jit(jax.value_and_grad(
+            built.loss_fn, has_aux=True))(params, state, (ids, ids))
+        return loss, grads
+
+    loss, grads = loss_and_gradient(True)
+    want_loss, want = loss_and_gradient(False)
+    for module in (transformer, decoder, hybrid):
+        monkeypatch.setattr(module, "recomputed", fnn.remat)
+    plain_loss, plain = loss_and_gradient(True)
+    assert float(loss) == float(plain_loss) and np.isfinite(float(loss))
+    leaves, treedef = jax.tree_util.tree_flatten(grads)
+    assert treedef == jax.tree_util.tree_structure(plain)
+    assert treedef == jax.tree_util.tree_structure(want) and len(leaves) > 10
+    for mine, theirs, unrecomputed in zip(
+            leaves, jax.tree_util.tree_leaves(plain),
+            jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(mine), np.asarray(theirs))
+        scale = max(float(jnp.max(jnp.abs(unrecomputed))), 1e-3)
+        # bfloat16 products: at most 1.8 % of a leaf's largest read here
+        assert float(jnp.max(jnp.abs(mine - unrecomputed))) <= 5e-2 * scale
+    assert abs(float(loss) - float(want_loss)) <= 1e-4 * abs(float(want_loss))
 
 
 def test_the_tile_follows_the_sequence():

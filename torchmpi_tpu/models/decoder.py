@@ -77,13 +77,12 @@ from ..parallel.ring_attention import (
     note_attention_step,
 )
 from ..parallel.selected_attention import (
-    SAVED as _ATTN_SAVED,
     note_selected_layers,
     note_selection,
     selected_self_attention,
 )
 from ..telemetry import names as _names
-from .transformer import lm_cross_entropy
+from .transformer import lm_cross_entropy, recomputed
 
 
 def rotary(x, theta: float):
@@ -354,7 +353,8 @@ class MoEDecoder(fnn.Module):
     shared_width: Optional[int] = None
     dense_layers: int = 0
     dense_width: int = 0
-    remat: bool = False  # recompute each block in backward
+    remat: bool = False  # recompute each block in backward, but for what
+    #                      its attention's forward kernels kept: ``recomputed``
     dtype: Any = jnp.float32
 
     def selects(self, i: int) -> bool:
@@ -390,13 +390,14 @@ class MoEDecoder(fnn.Module):
             )(tokens)
         block_cls = MoEDecoderBlock
         if self.remat:
-            # a selected layer's output, log-sum-exps, thresholds and its
-            # panels of float32 index scores are kept: 130 + 640 MiB a
-            # layer at 16,384 positions buy the index scores, the selection
-            # and the forward attention kernels once a step, not twice
-            block_cls = fnn.remat(MoEDecoderBlock, policy=(
-                jax.checkpoint_policies.save_only_these_names(_ATTN_SAVED)
-                if self.selected_layers else None))
+            # every layer kind keeps what its attention's forward kernels
+            # made. A selected layer: the output, log-sum-exps, thresholds
+            # and its panels of float32 index scores, 130 + 640 MiB a layer
+            # at 16,384 positions, which buy the index scores, the selection
+            # and the forward attention kernels once a step, not twice. A
+            # full or windowed layer: the fused kernels' output and
+            # log-sum-exp (2 B x head_dim + 4 B a query and head)
+            block_cls = recomputed(MoEDecoderBlock)
         routing = []
         for i in range(self.num_layers):
             windowed = self.window_layout[i % len(self.window_layout)]

@@ -66,6 +66,7 @@ from ..parallel.ssm import (
 )
 from ..telemetry import names as _names
 from .decoder import rotary
+from .transformer import recomputed
 
 
 class Multipliers(NamedTuple):
@@ -228,7 +229,8 @@ class HybridDecoder(fnn.Module):
     norm_eps: float = 1e-5
     attn_block: int = 1024
     axis_name: Optional[str] = None
-    remat: bool = False  # recompute each block in backward
+    remat: bool = False  # recompute each block in backward, but for what
+    #                      its attention's forward kernel kept (``recomputed``)
     dtype: Any = jnp.float32
 
     @fnn.compact
@@ -244,7 +246,7 @@ class HybridDecoder(fnn.Module):
                 name="embed")(tokens)).astype(self.dtype)
         block_cls = HybridDecoderBlock
         if self.remat:
-            block_cls = fnn.remat(HybridDecoderBlock)
+            block_cls = recomputed(HybridDecoderBlock)
         for i in range(self.num_layers):
             x = block_cls(
                 num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ..parallel.ring_attention import (
+    SAVED as _ATTN_SAVED,
     blocked_self_attention,
     note_attention_step,
     ring_self_attention,
@@ -34,6 +35,21 @@ def lm_cross_entropy(logits, targets):
         logp = jax.nn.log_softmax(logits.astype(jnp.float32))
         picked = jnp.take_along_axis(logp, targets[..., None], axis=-1)
         return -jnp.mean(picked)
+
+
+def recomputed(block_cls):
+    """``block_cls`` recomputed in backward, but for what its attention
+    call's forward kernels hand to their backward kernels: the arrays that
+    bear the ``checkpoint_name`` ``ring_attention.SAVED``
+    (``blocked_self_attention``'s output and log-sum-exp where it takes the
+    fused kernels; a selecting layer's output, log-sum-exps, thresholds and
+    panels of index scores) are kept, so an attention kernel runs forward
+    once a step and not again with the block; the rest of the block is made
+    again. Where the call takes the loops nothing bears the name, and the
+    whole block is recomputed. The one spelling of every model's ``remat``."""
+    return fnn.remat(
+        block_cls,
+        policy=jax.checkpoint_policies.save_only_these_names(_ATTN_SAVED))
 
 
 def make_lm_loss_fn(model: fnn.Module):
@@ -115,7 +131,8 @@ class LongContextTransformer(fnn.Module):
     max_len: int = 4096
     sp_axis: Optional[str] = None
     sp_backend: str = "xla"  # ring-attention transport (see RingAttentionBlock)
-    remat: bool = False  # rematerialize each block on backward (HBM for FLOPs)
+    remat: bool = False  # recompute each block in backward (``recomputed``:
+    #                      all but the attention kernels' output and lse)
     dtype: Any = jnp.float32
 
     @fnn.compact
@@ -137,8 +154,12 @@ class LongContextTransformer(fnn.Module):
         # backward — long-context HBM is dominated by per-layer
         # activations ([B, T, D] x layers), so this trades one extra
         # forward per block for an O(num_layers) -> O(1) activation
-        # footprint (the standard long-sequence memory lever on TPU)
-        block_cls = fnn.remat(RingAttentionBlock) if self.remat else RingAttentionBlock
+        # footprint (the standard long-sequence memory lever on TPU); the
+        # fused attention kernels' output and log-sum-exp alone are kept
+        # (``recomputed``: 2 x [B, T, D] bytes + 4 a head and position a layer)
+        block_cls = RingAttentionBlock
+        if self.remat:
+            block_cls = recomputed(RingAttentionBlock)
         for i in range(self.num_layers):
             # explicit name: the remat wrapper would otherwise rename the
             # module path (Checkpoint...), making remat and non-remat

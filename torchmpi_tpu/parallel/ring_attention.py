@@ -321,6 +321,11 @@ _blocked.defvjp(_blocked_fwd, _blocked_bwd)
 
 LANES = 128  # the TPU's vector lanes: what the kernels' tiles must be
 #              multiples of, and their heads but for half of it
+SAVED = "tm_attn_saved"  # checkpoint_name of what an attention call's
+#                          forward kernels hand to its backward kernels: the
+#                          one name a recomputed block keeps (it is
+#                          ``selected_attention``'s too; the policy is
+#                          ``models.transformer.recomputed``)
 
 
 def _kernels_take(head_dim: int) -> bool:
@@ -347,14 +352,20 @@ def _fused_kernel(t: int, groups: int, window: Optional[int],
                   interpret: bool):
     """jax's fused attention kernels (``pallas.ops.tpu.splash_attention``)
     over the causal band of a ``t x t`` square (``t`` a multiple of its
-    tile), one KV head at a time with its ``groups`` query heads: a forward
-    kernel that saves the log-sum-exp and ONE backward kernel that makes
-    the probabilities again and gives dQ, dK and dV (a second kernel for dQ
-    alone would make them twice; it measured slower). Built once a shape.
-    Tiles wholly outside the band do no work, tiles wholly inside it run
-    without a mask, and the mask of the others is computed in the kernel
-    from the positions. A tile of keys is worked through in pieces of 512
-    where it divides so."""
+    tile) for ``[b, hkv, groups, t, d]`` queries and ``[b, hkv, t, d]`` keys
+    and values, one KV head at a time with its ``groups`` query heads: a
+    forward kernel that saves the log-sum-exp and ONE backward kernel that
+    makes the probabilities again and gives dQ, dK and dV (a second kernel
+    for dQ alone would make them twice; it measured slower). Built once a
+    shape.
+    What forward hands to backward beside ``q``, ``k`` and ``v``, the output
+    and the log-sum-exp, bears the ``checkpoint_name`` ``SAVED``: inert
+    without a policy, and under ``save_only_these_names(SAVED)`` a caller
+    that recomputes its block in backward keeps the two and does not run
+    the forward kernel a second time. Tiles wholly outside the band do no
+    work, tiles wholly inside it run without a mask, and the mask of the
+    others is computed in the kernel from the positions. A tile of keys is
+    worked through in pieces of 512 where it divides so."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel,
         splash_attention_mask as masks,
@@ -367,13 +378,36 @@ def _fused_kernel(t: int, groups: int, window: Optional[int],
     tile = _fused_tile(t)
     piece = 512 if tile % 512 == 0 else tile
     with jax.ensure_compile_time_eval():  # the tile tables: constants
-        return kernel.make_splash_mqa_single_device(
+        one_kv_head = kernel.make_splash_mqa_single_device(
             masks.MultiHeadMask([band] * groups),
             block_sizes=kernel.BlockSizes(
                 block_q=tile, block_kv=tile, block_kv_compute=piece,
                 block_q_dkv=tile, block_kv_dkv=tile,
                 block_kv_dkv_compute=piece, use_fused_bwd_kernel=True),
-            interpret=interpret)
+            residual_checkpoint_name=SAVED, interpret=interpret)
+    return _held_by_forward(jax.vmap(jax.vmap(one_kv_head)))
+
+
+def _held_by_forward(attend):
+    """``attend(q, k, v)`` with everything its derivative rule hands to
+    backward made where its output is made: the kernels write each row's
+    log-sum-exp a lane wide (128 float32 a row, twice the bytes of a
+    bfloat16 output of heads of 128) and their rule keeps one column of it,
+    a slice that XLA, left alone, makes where backward first reads it, so
+    that the wide array lives from forward to backward (240 MiB in place
+    of 81 over ``falcon-h1-34b``'s four layers, enough there for XLA to fit
+    the step by making the head's product twice: PERF.md, PR 40). A barrier
+    over the output and the rule's residuals together, in forward, has the
+    slice made before the output is read. Backward is the rule's own."""
+    @jax.custom_vjp
+    def held(q, k, v):
+        return attend(q, k, v)
+
+    def forward(q, k, v):
+        return lax.optimization_barrier(jax.vjp(attend, q, k, v))
+
+    held.defvjp(forward, lambda pullback, dout: pullback(dout))
+    return held
 
 
 def _pad_sequence(q, k, v, multiple: int):
@@ -408,8 +442,7 @@ def _fused(q, k, v, window: Optional[int], interpret: bool = False):
     # its K and V, which are not repeated
     q = q.reshape(b, padded, hkv, g, d).transpose(0, 2, 3, 1, 4)
     k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-    one_kv_head = _fused_kernel(padded, g, window, interpret)
-    out = jax.vmap(jax.vmap(one_kv_head))(q, k, v)
+    out = _fused_kernel(padded, g, window, interpret)(q, k, v)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, padded, hq, d)[:, :t]
 
 
@@ -473,11 +506,17 @@ def blocked_self_attention(q, k, v, window: Optional[int] = None,
     ``block`` queries against blocks of keys through ``_block_attn`` and
     the streaming-softmax merge of the ring, each block pair's float32
     scores passing through memory. Both keep the log-sum-exp and make each
-    pair's probabilities again in backward. The platform is the one the
-    program is lowered for (``lax.platform_dependent``), not the process's
-    default; both executions are traced wherever the heads allow the
-    kernels, and the kernels' results declare no varying mesh axes, so
-    inside a ``shard_map`` this wants ``check_vma=False`` (the engine's)."""
+    pair's probabilities again in backward. The kernels' output and
+    log-sum-exp bear the ``checkpoint_name`` ``SAVED``: a caller that
+    recomputes its block under ``jax.checkpoint`` with a policy of
+    ``save_only_these_names(SAVED)`` keeps them (2 B x ``head_dim`` + 4 B a
+    query and head) and runs the forward kernel once a step; the loops name
+    nothing, and without a policy the name does nothing. The platform is
+    the one the program is lowered for (``lax.platform_dependent``), not
+    the process's default; both executions are traced wherever the heads
+    allow the kernels, and the kernels' results declare no varying mesh
+    axes, so inside a ``shard_map`` this wants ``check_vma=False`` (the
+    engine's)."""
     if q.shape[2] % k.shape[2] or k.shape != v.shape:
         raise ValueError(
             f"query heads {q.shape[2]} must be a multiple of the KV heads "

@@ -22,7 +22,9 @@ No ``t x t`` tensor a head is ever held; one sequence's float32 index scores
 do pass through memory, a panel of ``PANEL`` queries against the keys up to
 the panel's end at a time, and the kernels' forward pass hands its panels to
 backward as residuals: the scores are made once, and backward masks with
-the bits forward selected from. They bear the name ``SAVED`` with the small
+the bits forward selected from. They bear the name ``SAVED``
+(``ring_attention.SAVED``: one name for what any attention call's forward
+kernels keep for backward) with the small
 residuals, for a caller that recomputes the layer: ``4 * t * (t + PANEL) / 2``
 bytes a sequence (640 MiB at 16,384 positions, 2.25 GiB at 32,768) beside
 the 130 MiB of the other five. One name, because scores made again from a
@@ -225,7 +227,7 @@ _loops.defvjp(_loops_fwd, _loops_bwd)
 # the same in kernels
 # ---------------------------------------------------------------------------
 
-SAVED = "tm_attn_selected"  # checkpoint_name of what a forward pass keeps
+SAVED = _ring.SAVED  # checkpoint_name of what a forward pass keeps
 TILE = 512        # the kernels' tile of queries and of keys
 SELECT_ROWS = 64  # rows of scores whose k-th largest one kernel step finds
 PANEL = 4096      # queries whose scores (and, in backward, the indexer's
