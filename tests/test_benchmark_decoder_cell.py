@@ -1,8 +1,10 @@
-"""The benchmark's pieces for the four decoder configurations,
-``smallthinker-21b-a3b``, ``keye-vl-2-30b-a3b``, ``laguna-s-2-1`` and
-``falcon-h1-34b`` (the sparse decoders of ``tests/test_moe_decoder.py``,
-``tests/test_selected_attention.py`` and ``tests/test_laguna_decoder.py``
-and the hybrid one of ``tests/test_hybrid_decoder.py`` at the published
+"""The benchmark's pieces for the five decoder configurations,
+``smallthinker-21b-a3b``, ``keye-vl-2-30b-a3b``, ``laguna-s-2-1``,
+``falcon-h1-34b`` and ``brumby-14b`` (the sparse decoders of
+``tests/test_moe_decoder.py``, ``tests/test_selected_attention.py`` and
+``tests/test_laguna_decoder.py``, the hybrid one of
+``tests/test_hybrid_decoder.py`` and the retentive one of
+``tests/test_retention_decoder.py`` at the published
 widths): each one's file, its operation count, its data, the
 reader of its inner scopes, and its cell's whole run at the rehearsal's
 sizes, sound, broken and with the fp8 control in the program's place. What
@@ -22,7 +24,9 @@ CONFIG = "smallthinker-21b-a3b"
 KEYE = "keye-vl-2-30b-a3b"
 LAGUNA = "laguna-s-2-1"
 FALCON = "falcon-h1-34b"  # no experts: a state-space mixer beside attention
-CONFIGS = [CONFIG, KEYE, LAGUNA, FALCON]
+BRUMBY = "brumby-14b"     # no experts, no attention: power retention
+CONFIGS = [CONFIG, KEYE, LAGUNA, FALCON, BRUMBY]
+UNROUTED = (FALCON, BRUMBY)
 CELL = CONFIG + ".stream.x1"
 
 
@@ -169,13 +173,15 @@ def test_the_cells_rehearsal_is_correct(capsys, config):
     (KEYE, {"attn_selected_pair_share", "attn_index_loss",
             "attn_kernel_share"}),
     (LAGUNA, {"attn_heads_held_share", "attn_kernel_share"}),
-    (FALCON, {"ssm_heads_held_share", "attn_kernel_share"})], ids=CONFIGS)
+    (FALCON, {"ssm_heads_held_share", "attn_kernel_share"}),
+    (BRUMBY, {"retention_heads_held_share"})], ids=CONFIGS)
 def test_the_cells_traced_rehearsal_reports_the_routing_counters(
         capsys, config, more):
     """... and, for the configuration that selects its keys, what the
     selection measured; for the one held by share, the share of the heads;
     for the hybrid one, which routes nothing, the share of the mixer's
-    heads (the scope metrics need a TPU's trace)."""
+    heads; for the retentive one, which neither routes nor attends, the
+    share of its KV heads (the scope metrics need a TPU's trace)."""
     from benchmark import run as bench
 
     rc = bench.main(["--workload", config + ".stream.x1", "--seed", "11",
@@ -183,11 +189,13 @@ def test_the_cells_traced_rehearsal_reports_the_routing_counters(
     out = capsys.readouterr().out
     line = json.loads(out.strip().splitlines()[-1])
     assert rc == 0 and line["correct"] is True, out
-    routed = set() if config == FALCON else {
+    routed = set() if config in UNROUTED else {
         "moe_grouped_rows_per_step", "moe_max_over_mean_load"}
     assert routed | {"engine_dispatch_ms"} | more <= set(line["rehearsed"])
-    assert (config == FALCON) == (not [
+    assert (config in UNROUTED) == (not [
         m for m in line["rehearsed"] if m.startswith("moe_")])
+    assert (config == BRUMBY) == (not [
+        m for m in line["rehearsed"] if m.startswith("attn_")])
     # its reader divides by every layer; the third's layer 0 has no experts
     assert ("moe_compact_share" in line["rehearsed"]) == (
         config in (CONFIG, KEYE))

@@ -24,6 +24,7 @@ GPT2 = "gpt2-medium"
 KEYE = "keye-vl-2-30b-a3b"
 LAGUNA = "laguna-s-2-1"
 FALCON = "falcon-h1-34b"
+BRUMBY = "brumby-14b"
 
 
 @pytest.fixture(scope="module")
@@ -273,6 +274,53 @@ def test_the_hybrid_cells_step_fits_the_chip_with_its_scan_in_chunks(
     assert text.count(" while(") >= 3 * layers
 
 
+def test_the_retentive_cells_step_fits_the_chip_with_its_state_by_chunks(
+        one_chip):
+    """``brumby-14b.stream.x1``'s step at the published widths: it fits at
+    1 x 32,768 (not the fallback of 16,384) under the 15.0 GiB ISSUE 41
+    set, without an instruction of XLA's own rematerialization; no array of
+    all the positions squared (the masked products are ``[chunk, chunk]``)
+    and none of all the positions times the features of the symmetric
+    square (9,216 in the program, 8,256 in the mathematics, 16,384 of the
+    whole outer product), kept or transient, forward or backward: the
+    widest with that axis is one chunk's five query heads, then the chunks'
+    states; no attention kernel at all; one loop over the chunks a layer,
+    forward, recomputed and backward."""
+    from torchmpi_tpu.parallel.retention import features
+
+    cfg, params, compiled = compiled_step(BRUMBY, one_chip)
+    count = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    # 4 layers of 41.30 M + 194.5 M of vocabulary (ISSUE 41: 359.7 M)
+    assert count == 4 * 41_303_297 + 2 * 18992 * 5120 + 5120
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    # 12 B a parameter of state (4.02 GiB) and 8.48 GiB of temporaries
+    # measured here, 12.50 GiB: the float32 logits of 32,768 x 18,992 are
+    # 2.32 GiB an array
+    assert memory.argument_size_in_bytes > 12 * count
+    assert held < 15.0 * 2**30, memory
+    text = compiled.as_text()
+    assert ".remat" not in text
+    assert "tpu_custom_call" not in text
+    layers, seq = cfg["num_hidden_layers"], cfg["sequence_length"]
+    chunk, heads = cfg["model"]["retention_chunk"], cfg["num_attention_heads"]
+    assert (seq, chunk, heads, features(128)) == (32768, 256, 5, 9216)
+    assert f"[{seq},{seq}]" not in text  # no t x t array, of any type
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text)}
+    wide = [s for s in shapes if features(128) in s]
+    assert wide and not [s for s in wide if seq in s]
+    # a chunk's five query heads, or the chunks' states: 608 MB a layer,
+    # while that layer's backward runs
+    assert max(math.prod(s) for s in wide) == max(
+        heads * chunk, seq // chunk * 129) * features(128)
+    assert (seq // chunk, 1, 1, 129, features(128)) in shapes
+    # nor the mathematics' 8,256 or the whole outer product's 16,384
+    assert not [s for s in shapes if seq in s and (
+        128 * 129 // 2 in s or 128 * 128 in s)]
+    assert text.count(" while(") == 3 * layers
+
+
 def test_the_cells_step_fits_the_chip_and_holds_no_dispatch_tensor(one_chip):
     from benchmark import configs
     from torchmpi_tpu.telemetry import names
@@ -414,7 +462,7 @@ def conditional_branches(text):
     return found
 
 
-# The four decoder cells' whole steps at their real sizes as LOWERED for the
+# The five decoder cells' whole steps at their real sizes as LOWERED for the
 # described chip (not compiled): characters and the first 16 of the sha256
 # of the text without the kernels' serialized bodies (they carry the
 # checkout's path). ``keye-vl-2-30b-a3b`` as the parent of PR 38 lowered
@@ -426,13 +474,16 @@ def conditional_branches(text):
 # second forward kernel (955,645 c6e7fca3cf766c29, 1,492,140
 # fc51f3fc3cfee276 and 1,113,784 12847685d7cdf152 before; the text is
 # longer because the backward kernel's tile tables, constants, now stand
-# in forward's barrier too). A PR that means
-# to change those steps changes these; one that does not, must not.
+# in forward's barrier too). The retentive one as PR 41 brought it (with
+# the normaliser's ``eps`` at 1e-12; 878,312 fe718a62df96b44e at 1e-6). A PR
+# that means to change those steps changes these; one that does not, must
+# not.
 TPU_LOWERED = {
     CONFIG: (1482928, "2e54c323e433ec0e"),
     KEYE: (1248452, "47bf5842f8ac3442"),
     LAGUNA: (2943293, "162f73ace4dfee71"),
     FALCON: (2436298, "a49bae8c575c46cb"),
+    BRUMBY: (878324, "ff7090aaee6aae19"),
 }
 
 
@@ -497,7 +548,11 @@ def test_a_tpu_lowering_takes_the_kernels_where_the_heads_allow(
         "ssm_conv_ms_per_step", "ssm_gate_ms_per_step",
         "ssm_heads_held_share", "ssm_proj_ms_per_step",
         "ssm_scan_ms_per_step"]),
-], ids=[CONFIG, KEYE, LAGUNA, FALCON])
+    (BRUMBY, [
+        "retention_chunk_ms_per_step", "retention_gate_ms_per_step",
+        "retention_heads_held_share", "retention_peak_share",
+        "retention_state_ms_per_step"]),
+], ids=[CONFIG, KEYE, LAGUNA, FALCON, BRUMBY])
 def test_the_configuration_is_a_cell_of_the_benchmark(config, own):
     """The configuration's one cell, and the per-layer metrics that came
     with it: those whose list of cells begins with it."""
@@ -539,3 +594,15 @@ def test_the_configuration_is_a_cell_of_the_benchmark(config, own):
             "attn_kernel_ms_per_step", "fwd_bwd_unnamed_share"} <= fourth
     assert not [m for m in fourth if m.startswith("moe_")]
     assert not fourth & {"attn_window_ms_per_step", "mlp_dense_ms_per_step"}
+    # the retentive one reads what the hybrid one reads but attention's
+    # and the mixer's: it has neither
+    fifth = {m["name"] for m in spec["per_layer"]
+             if BRUMBY + ".stream.x1" in m["workloads"]}
+    assert {m for m in fourth - fifth if not m.startswith("ssm_")} == {
+        "attn_full_ms_per_step", "attn_kernel_share",
+        "attn_kernel_ms_per_step"}
+    assert fifth - fourth == {
+        m["name"] for m in spec["per_layer"]
+        if m["workloads"] == [BRUMBY + ".stream.x1"]}
+    assert {m["layer"] for m in spec["per_layer"]
+            if m["name"].startswith("retention_")} == {"retention"}

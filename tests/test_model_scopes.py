@@ -23,6 +23,7 @@ from torchmpi_tpu.models import (
     HybridDecoder,
     LongContextTransformer,
     MoEDecoder,
+    RetentionDecoder,
     Rotary,
     init_lm_params,
     init_moe_state,
@@ -38,13 +39,14 @@ sys.path.insert(0, str(ROOT))
 SEQ, VOCAB = 24, 61
 OLD = names.MODEL_SCOPE_NAMES[:11]   # what the benchmark's metrics read
 NEW = names.MODEL_SCOPE_NAMES[11:]   # what this file is about
-SSM = names.MODEL_SCOPE_NAMES[18:]   # the state-space mixer's (PR 39)
+SSM = names.MODEL_SCOPE_NAMES[18:22]  # the state-space mixer's (PR 39)
+RET = names.MODEL_SCOPE_NAMES[22:]   # power retention's (PR 41)
 EVERY_LM = {"tm.lm.embed", "tm.lm.norm", "tm.attn.proj", "tm.lm.head",
             "tm.lm.loss"}
 # the scopes opened inside a block are recomputed with it; the embedding,
 # the last norm's model-level call, the head and the loss are not
 IN_BLOCKS = {"tm.lm.norm", "tm.attn.proj", "tm.lm.mlp", "tm.moe.router",
-             *SSM}
+             *SSM, *RET}
 
 
 def _decoder(**over):
@@ -81,6 +83,12 @@ FAMILIES = {
         num_kv_heads=1, head_dim=8, ssm_heads=2, ssm_head_dim=8,
         ssm_groups=1, ssm_state=6, mlp_width=24, chunk=8, attn_block=8,
         remat=True),
+    # brumby-14b's: power retention where attention stood in every block (no
+    # attention's scope at all), a norm on each query and key head, a gated
+    # feed-forward under GPT-2's scope, no router
+    "retentive": lambda: RetentionDecoder(
+        vocab_size=VOCAB, num_layers=2, d_model=32, num_heads=5,
+        num_kv_heads=1, head_dim=32, mlp_width=24, chunk=8, remat=True),
     # keye-vl-2-30b-a3b's: every layer selects, with a norm on each query
     # and key head; the indexer's projections stay under tm.attn.index
     "selected": lambda: _decoder(
@@ -114,9 +122,17 @@ def _lowered(engine):
         engine._prepare_batch((toks[:, :-1], toks[:, 1:])))
 
 
-def _op_names(engine):
-    text = _lowered(engine).as_text(debug_info=True)
-    return set(re.findall(r'"(jit\(tm_train_step\)[^"]*)"', text))
+def _op_names(family):
+    lowered = _lowered(_engine(family))
+    names_ = set(re.findall(
+        r'"(jit\(tm_train_step\)[^"]*)"', lowered.as_text(debug_info=True)))
+    if family == "retentive":
+        # an operation inside a scan's body is named from the body's own
+        # function on in the lowered text; the compiled step has its path
+        names_ |= set(re.findall(
+            r'op_name="(jit\(tm_train_step\)[^"]*)"',
+            lowered.compile().as_text()))
+    return names_
 
 
 @contextlib.contextmanager
@@ -138,15 +154,16 @@ def test_new_scopes_reach_every_phase_under_fwd_bwd(family):
     assert NEW == ("tm.lm.embed", "tm.lm.norm", "tm.attn.proj", "tm.lm.mlp",
                    "tm.moe.router", "tm.lm.head", "tm.lm.loss",
                    "tm.lm.ssm_proj", "tm.lm.ssm_conv", "tm.lm.ssm_scan",
-                   "tm.lm.ssm_gate")
+                   "tm.lm.ssm_gate", "tm.lm.ret_gate", "tm.lm.ret_chunk",
+                   "tm.lm.ret_state")
     seen = {}
-    for op in _op_names(_engine(family)):
+    for op in _op_names(family):
         bucket = model_scopes.bucket_of(op)
         if bucket in NEW:
             assert scopes.scope_of(op) == "tm.fwd_bwd", op
             seen.setdefault(bucket, set()).add(model_scopes.phase_of(op))
-    own = {"gpt2": {"tm.lm.mlp"}, "hybrid": {"tm.lm.mlp", *SSM}}.get(
-        family, {"tm.moe.router"})
+    own = {"gpt2": {"tm.lm.mlp"}, "hybrid": {"tm.lm.mlp", *SSM},
+           "retentive": {"tm.lm.mlp", *RET}}.get(family, {"tm.moe.router"})
     assert set(seen) == EVERY_LM | own, seen
     for scope, phases in seen.items():
         want = {"forward", "backward"}
@@ -168,9 +185,10 @@ def test_older_inner_scopes_read_what_they_read(family, monkeypatch):
         return {(op, scope) for op, scope in pairs if scope in OLD}
 
     with _without(monkeypatch, NEW):
-        before = older(_op_names(_engine(family)))
-    after = older(_op_names(_engine(family)))
-    assert before and after == before
+        before = older(_op_names(family))
+    after = older(_op_names(family))
+    # the retentive family opens none of the older scopes: no attention
+    assert after == before and bool(before) == (family != "retentive")
     if family == "gpt2":  # its attention bears the decoders' name now
         assert {scope for _, scope in after} == {"tm.attn.full"}
     if family == "selected":

@@ -738,7 +738,9 @@ def test_model_scopes_nest_under_fwd_bwd_in_the_lowered_step(selecting):
         "tm.moe.router", "tm.lm.head", "tm.lm.loss",
         # the state-space mixer's (tests/test_hybrid_decoder.py)
         "tm.lm.ssm_proj", "tm.lm.ssm_conv", "tm.lm.ssm_scan",
-        "tm.lm.ssm_gate")
+        "tm.lm.ssm_gate",
+        # power retention's (tests/test_retention_decoder.py)
+        "tm.lm.ret_gate", "tm.lm.ret_chunk", "tm.lm.ret_state")
     cfg = tiny_cfg()
     model = tiny_model(cfg)
     opened = set(names.MODEL_SCOPE_NAMES[:5])

@@ -22,6 +22,7 @@ from .resnet import (
     init_resnet,
     make_stateful_loss_fn,
 )
+from .retentive import RetentionDecoder, RetentionDecoderBlock
 from .transformer import (
     LongContextTransformer,
     RingAttentionBlock,
@@ -44,6 +45,8 @@ __all__ = [
     "HybridDecoder",
     "HybridDecoderBlock",
     "Multipliers",
+    "RetentionDecoder",
+    "RetentionDecoderBlock",
     "make_moe_lm_loss_fn",
     "init_moe_state",
     "cross_entropy_loss",

@@ -11,6 +11,7 @@ from .pp import (
     pipeline_forward,
     pipeline_loss_fn,
 )
+from .retention import power_retention, symmetric_square
 from .ring_attention import (
     blocked_self_attention,
     full_self_attention,
@@ -37,6 +38,8 @@ __all__ = [
     "causal_conv1d",
     "ssd_chunked_scan",
     "gated_group_norm",
+    "power_retention",
+    "symmetric_square",
     "MPLinear",
     "MPLinearOutputSplit",
     "shard_input_features",

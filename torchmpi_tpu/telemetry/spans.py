@@ -135,6 +135,21 @@ SCOPE_SSM_SCAN = "tm.lm.ssm_scan"     # delta, the decays, the chunked dual,
 #                                       the carried state, D x
 SCOPE_SSM_GATE = "tm.lm.ssm_gate"     # the gate and the norm by groups
 
+# models/retentive.py: power retention where attention stood
+# (parallel/retention.py). q, k, v and o stand under SCOPE_ATTN_PROJ.
+SCOPE_RET_GATE = "tm.lm.ret_gate"     # the gate's projection, log-sigmoid,
+#                                       the cumulative sums and the decays
+SCOPE_RET_CHUNK = "tm.lm.ret_chunk"   # rotary position and the scale, the
+#                                       masked products inside a chunk
+SCOPE_RET_STATE = "tm.lm.ret_state"   # the symmetric square, a chunk's own
+#                                       state, the carry, the state's read,
+#                                       the division. It holds the scan
+#                                       over the chunks, so the two above
+#                                       are opened INSIDE it in the loop's
+#                                       body: the one nesting among these
+#                                       names (the readers go by the
+#                                       innermost, the last, name)
+
 MODEL_SCOPE_NAMES = (
     SCOPE_ATTN_FULL, SCOPE_ATTN_WINDOW, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
     SCOPE_MOE_COMBINE, SCOPE_ATTN_INDEX, SCOPE_ATTN_SELECT,
@@ -142,6 +157,7 @@ MODEL_SCOPE_NAMES = (
     SCOPE_LM_EMBED, SCOPE_LM_NORM, SCOPE_ATTN_PROJ, SCOPE_LM_MLP,
     SCOPE_MOE_ROUTER, SCOPE_LM_HEAD, SCOPE_LM_LOSS,
     SCOPE_SSM_PROJ, SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_SSM_GATE,
+    SCOPE_RET_GATE, SCOPE_RET_CHUNK, SCOPE_RET_STATE,
 )
 
 # -- the gauge models/decoder.py sets from static shapes while its step is
@@ -154,6 +170,14 @@ GAUGE_ATTN_HEADS_HELD = "tm_attn_query_heads_held_per_step"
 # layers, and the chunks its scans run over (layers x sequences x chunks)
 GAUGE_SSM_HEADS_HELD = "tm_ssm_heads_held_per_step"
 GAUGE_SSM_CHUNKS = "tm_ssm_chunks_per_step"
+# -- the gauges parallel/retention.py ``note_retention_step`` sets the same
+# way for models/retentive.py: the KV heads of power retention this device
+# holds, summed over the layers; the chunks (layers x sequences x chunks);
+# the bytes of the float32 states its recurrences carry (layers x sequences
+# x KV heads held x D x (head_dim + 1) x 4)
+GAUGE_RETENTION_KV_HEADS_HELD = "tm_retention_kv_heads_held_per_step"
+GAUGE_RETENTION_CHUNKS = "tm_retention_chunks_per_step"
+GAUGE_RETENTION_STATE_BYTES = "tm_retention_state_bytes_per_step"
 
 # -- what a device trace calls the attention kernels (an event's name is
 # the kernel's HLO instruction): jax's splash attention in
