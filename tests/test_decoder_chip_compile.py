@@ -226,6 +226,18 @@ def test_the_shared_cells_step_fits_the_chip_with_its_heads_by_layer(
             False, True]
 
 
+def row_scatters(text, cfg):
+    """The ``scatter`` instructions of a compiled step's text whose operand
+    is the ``f32[V, D]`` embedding table: jax's transpose of the token
+    gather (64 ms a step of ``falcon-h1-34b`` on the chip, 42 of
+    ``brumby-14b``, at these tables' width: PERF.md, PR 42).
+    The lookup's own derivative rule (``models/embedding.py``) leaves none:
+    what it scatters is ``V`` integers."""
+    table = f"f32[{cfg['vocab_size']},{cfg['hidden_size']}]"
+    return [line for line in text.splitlines()
+            if re.search(r" scatter\(", line) and table in line]
+
+
 def test_the_hybrid_cells_step_fits_the_chip_with_its_scan_in_chunks(
         one_chip):
     """``falcon-h1-34b.stream.x1``'s step at the published widths: it fits
@@ -234,7 +246,8 @@ def test_the_hybrid_cells_step_fits_the_chip_with_its_scan_in_chunks(
     heads to the one KV head, the scan holds no array of all the positions
     squared (its masked products are ``[128, 128]`` a chunk) and no state a
     position, and the carried state is one loop over the 128 chunks,
-    forward and backward."""
+    forward and backward; the embedding's gradient is no scatter of rows
+    into the table (``row_scatters``)."""
     from torchmpi_tpu.telemetry import names
 
     cfg, params, compiled = compiled_step(FALCON, one_chip)
@@ -255,6 +268,7 @@ def test_the_hybrid_cells_step_fits_the_chip_with_its_scan_in_chunks(
     assert held < 15.0 * 2**30, memory
     text = compiled.as_text()
     assert not re.findall(r"\.remat[.\d]* = ", text)
+    assert not row_scatters(text, cfg)
     kernels = Counter(
         re.findall(r"%([A-Za-z_]+?)[.\d]* = [^\n]*tpu_custom_call", text))
     layers, seq = cfg["num_hidden_layers"], cfg["sequence_length"]
@@ -285,7 +299,8 @@ def test_the_retentive_cells_step_fits_the_chip_with_its_state_by_chunks(
     whole outer product), kept or transient, forward or backward: the
     widest with that axis is one chunk's five query heads, then the chunks'
     states; no attention kernel at all; one loop over the chunks a layer,
-    forward, recomputed and backward."""
+    forward, recomputed and backward; the embedding's gradient is no
+    scatter of rows into the table (``row_scatters``)."""
     from torchmpi_tpu.parallel.retention import features
 
     cfg, params, compiled = compiled_step(BRUMBY, one_chip)
@@ -301,6 +316,7 @@ def test_the_retentive_cells_step_fits_the_chip_with_its_state_by_chunks(
     assert held < 15.0 * 2**30, memory
     text = compiled.as_text()
     assert ".remat" not in text
+    assert not row_scatters(text, cfg)
     assert "tpu_custom_call" not in text
     layers, seq = cfg["num_hidden_layers"], cfg["sequence_length"]
     chunk, heads = cfg["model"]["retention_chunk"], cfg["num_attention_heads"]
@@ -318,7 +334,8 @@ def test_the_retentive_cells_step_fits_the_chip_with_its_state_by_chunks(
     # nor the mathematics' 8,256 or the whole outer product's 16,384
     assert not [s for s in shapes if seq in s and (
         128 * 129 // 2 in s or 128 * 128 in s)]
-    assert text.count(" while(") == 3 * layers
+    # ... and one over the blocks of the embedding's sorted gradient rows
+    assert text.count(" while(") == 3 * layers + 1
 
 
 def test_the_cells_step_fits_the_chip_and_holds_no_dispatch_tensor(one_chip):
@@ -475,15 +492,23 @@ def conditional_branches(text):
 # fc51f3fc3cfee276 and 1,113,784 12847685d7cdf152 before; the text is
 # longer because the backward kernel's tile tables, constants, now stand
 # in forward's barrier too). The retentive one as PR 41 brought it (with
-# the normaliser's ``eps`` at 1e-12; 878,312 fe718a62df96b44e at 1e-6). A PR
-# that means to change those steps changes these; one that does not, must
-# not.
+# the normaliser's ``eps`` at 1e-12; 878,312 fe718a62df96b44e at 1e-6).
+# PR 42 meant to change three and did: the token lookup has a derivative
+# rule of its own (``models/embedding.py``), so ``smallthinker-21b-a3b``,
+# ``falcon-h1-34b`` and ``brumby-14b`` end their backward in a sort, a loop
+# of one-hot products and a gather where jax's scatter-add of the
+# embedding's rows stood (1,482,928 2e54c323e433ec0e, 2,436,298
+# a49bae8c575c46cb and 878,324 ff7090aaee6aae19 before); the rule
+# (``embedding.takes_sorted_sum``) keeps jax's transpose at the widths of
+# ``keye-vl-2-30b-a3b`` and ``laguna-s-2-1``, whose steps are the parent's
+# text letter for letter. A PR that means to change those steps changes
+# these; one that does not, must not.
 TPU_LOWERED = {
-    CONFIG: (1482928, "2e54c323e433ec0e"),
+    CONFIG: (1498767, "6eca475fac54c18e"),
     KEYE: (1248452, "47bf5842f8ac3442"),
     LAGUNA: (2943293, "162f73ace4dfee71"),
-    FALCON: (2436298, "a49bae8c575c46cb"),
-    BRUMBY: (878324, "ff7090aaee6aae19"),
+    FALCON: (2452301, "16a4c55366e3d917"),
+    BRUMBY: (894277, "8eae3c7ec1685bf8"),
 }
 
 

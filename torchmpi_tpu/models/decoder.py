@@ -82,6 +82,7 @@ from ..parallel.selected_attention import (
     selected_self_attention,
 )
 from ..telemetry import names as _names
+from .embedding import TokenEmbed
 from .transformer import lm_cross_entropy, recomputed
 
 
@@ -385,7 +386,7 @@ class MoEDecoder(fnn.Module):
         note_selected_layers(
             tokens.shape[0], tokens.shape[1], self.selected_layers)
         with jax.named_scope(_names.SCOPE_LM_EMBED):
-            x = fnn.Embed(
+            x = TokenEmbed(
                 self.vocab_size, self.d_model, dtype=self.dtype, name="embed"
             )(tokens)
         block_cls = MoEDecoderBlock

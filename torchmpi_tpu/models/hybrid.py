@@ -66,6 +66,7 @@ from ..parallel.ssm import (
 )
 from ..telemetry import names as _names
 from .decoder import rotary
+from .embedding import TokenEmbed
 from .transformer import recomputed
 
 
@@ -241,7 +242,7 @@ class HybridDecoder(fnn.Module):
         note_ssm_step(self.num_layers * self.ssm_heads,
                       self.num_layers * batch * -(-t // self.chunk))
         with jax.named_scope(_names.SCOPE_LM_EMBED):
-            x = (m.embedding * fnn.Embed(
+            x = (m.embedding * TokenEmbed(
                 self.vocab_size, self.d_model, dtype=jnp.float32,
                 name="embed")(tokens)).astype(self.dtype)
         block_cls = HybridDecoderBlock

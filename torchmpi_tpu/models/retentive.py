@@ -46,6 +46,7 @@ import jax.numpy as jnp
 from ..parallel.retention import note_retention_step, power_retention
 from ..telemetry import names as _names
 from .decoder import rotary
+from .embedding import TokenEmbed
 from .transformer import recomputed
 
 # what a seeded gate lets through of the state, a position: ``1 - 1 / n``
@@ -145,7 +146,7 @@ class RetentionDecoder(fnn.Module):
         note_retention_step(self.num_layers, batch, self.num_kv_heads,
                             self.head_dim, -(-t // self.chunk))
         with jax.named_scope(_names.SCOPE_LM_EMBED):
-            x = fnn.Embed(
+            x = TokenEmbed(
                 self.vocab_size, self.d_model, dtype=jnp.float32,
                 name="embed")(tokens).astype(self.dtype)
         block_cls = RetentionDecoderBlock

@@ -26,6 +26,7 @@ from ..parallel.ring_attention import (
     ring_self_attention,
 )
 from ..telemetry import names as _names
+from .embedding import TokenEmbed
 
 
 def lm_cross_entropy(logits, targets):
@@ -146,10 +147,15 @@ class LongContextTransformer(fnn.Module):
         else:
             pos = jnp.arange(t_local)
         with jax.named_scope(_names.SCOPE_LM_EMBED):
-            x = fnn.Embed(
-                self.vocab_size, self.d_model, dtype=self.dtype)(tokens)
+            # named as flax named them when both were ``fnn.Embed``
+            x = TokenEmbed(
+                self.vocab_size, self.d_model, dtype=self.dtype,
+                name="Embed_0")(tokens)
+            # the positions: ``t`` distinct sorted rows, summed over the
+            # batch before they reach the table
             x = x + fnn.Embed(
-                self.max_len, self.d_model, dtype=self.dtype)(pos)[None]
+                self.max_len, self.d_model, dtype=self.dtype,
+                name="Embed_1")(pos)[None]
         # remat: drop each block's activations and recompute them during
         # backward — long-context HBM is dominated by per-layer
         # activations ([B, T, D] x layers), so this trades one extra

@@ -110,7 +110,10 @@ SCOPE_MOE_DENSE = "tm.moe.dense"      # a leading layer's dense feed-forward,
 # inside a scope above. What XLA fuses across a boundary bears its root's
 # scope, so a fusion goes to one side whole.
 SCOPE_LM_EMBED = "tm.lm.embed"        # the embedding's gather (GPT-2: and
-#                                       the positions'); backward: scatter-add
+#                                       the positions'); backward: the rows
+#                                       sorted by id, summed by blocks, one
+#                                       gather into the table
+#                                       (models/embedding.py)
 SCOPE_LM_NORM = "tm.lm.norm"          # a block's norms, the model's last,
 #                                       the query and key heads' norms
 SCOPE_ATTN_PROJ = "tm.attn.proj"      # the q, k, v and o products; not the
@@ -178,6 +181,12 @@ GAUGE_SSM_CHUNKS = "tm_ssm_chunks_per_step"
 GAUGE_RETENTION_KV_HEADS_HELD = "tm_retention_kv_heads_held_per_step"
 GAUGE_RETENTION_CHUNKS = "tm_retention_chunks_per_step"
 GAUGE_RETENTION_STATE_BYTES = "tm_retention_state_bytes_per_step"
+# -- the gauge models/embedding.py ``TokenEmbed`` sets the same way: the
+# token rows of the step most recently traced whose embedding gradient is
+# summed by sorted ids before it touches the table (0 where the shapes keep
+# jax's scatter-add). The benchmark's ``embed_grad_sorted_share`` reads it
+# against the step's tokens
+GAUGE_EMBED_GRAD_SORTED_ROWS = "tm_embed_grad_sorted_rows_per_step"
 
 # -- what a device trace calls the attention kernels (an event's name is
 # the kernel's HLO instruction): jax's splash attention in
