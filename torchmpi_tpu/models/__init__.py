@@ -6,6 +6,7 @@ from .decoder import (
     make_moe_lm_loss_fn,
 )
 from .hybrid import HybridDecoder, HybridDecoderBlock, Multipliers
+from .lm_head import VocabHead
 from .mlp import MLP6
 from .mnist import (
     LeNet,
@@ -47,6 +48,7 @@ __all__ = [
     "Multipliers",
     "RetentionDecoder",
     "RetentionDecoderBlock",
+    "VocabHead",
     "make_moe_lm_loss_fn",
     "init_moe_state",
     "cross_entropy_loss",

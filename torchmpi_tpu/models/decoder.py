@@ -83,7 +83,8 @@ from ..parallel.selected_attention import (
 )
 from ..telemetry import names as _names
 from .embedding import TokenEmbed
-from .transformer import lm_cross_entropy, recomputed
+from .lm_head import VocabHead
+from .transformer import recomputed
 
 
 def rotary(x, theta: float):
@@ -313,7 +314,8 @@ class MoEDecoder(fnn.Module):
     layers]} float32)``: what each layer with experts measured of its
     routing; a model with selected layers adds ``"attn_index_loss"`` and
     ``"attn_selected_pairs"`` ``[layers]``: each layer's ``L_I`` and the
-    pairs it selected.
+    pairs it selected. With ``targets``, the mean next-token loss stands
+    where the logits do (``lm_head.VocabHead``).
 
     What differs by layer is given as a pattern, repeated over the depth:
     ``window_layout``, ``rope_layout``, ``selected_layout``, and
@@ -370,7 +372,7 @@ class MoEDecoder(fnn.Module):
         return self.num_layers - self.dense_layers
 
     @fnn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, targets=None):
         if not 0 <= self.dense_layers < self.num_layers:
             raise ValueError(
                 f"dense_layers must leave a layer with experts, got "
@@ -429,10 +431,10 @@ class MoEDecoder(fnn.Module):
         with jax.named_scope(_names.SCOPE_LM_NORM):
             x = fnn.RMSNorm(
                 epsilon=self.norm_eps, dtype=jnp.float32, name="norm")(x)
-        with jax.named_scope(_names.SCOPE_LM_HEAD):
-            logits = fnn.Dense(
-                self.vocab_size, use_bias=False, dtype=jnp.float32,
-                name="head")(x)
+        # the logits, or with ``targets`` the mean next-token loss
+        logits = VocabHead(
+            self.vocab_size, use_bias=False, dtype=jnp.float32,
+            name="head")(x, targets)
         load, rows, index_loss, pairs = (
             jnp.stack(a) for a in zip(*routing))
         # a dense layer routes nothing: its zeros are no expert layer's
@@ -476,8 +478,7 @@ def make_moe_lm_loss_fn(model: MoEDecoder):
 
     def loss_fn(params, state, batch):
         tokens, targets = batch
-        logits, measured = model.apply({"params": params}, tokens)
-        loss = lm_cross_entropy(logits, targets)
+        loss, measured = model.apply({"params": params}, tokens, targets)
         if model.selected_layers:
             loss = loss + jnp.sum(measured["attn_index_loss"])
         return loss, measured

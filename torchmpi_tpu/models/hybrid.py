@@ -67,6 +67,7 @@ from ..parallel.ssm import (
 from ..telemetry import names as _names
 from .decoder import rotary
 from .embedding import TokenEmbed
+from .lm_head import VocabHead
 from .transformer import recomputed
 
 
@@ -207,7 +208,8 @@ class HybridDecoderBlock(fnn.Module):
 
 class HybridDecoder(fnn.Module):
     """Decoder-only LM over ``HybridDecoderBlock``s, every layer the same.
-    Returns the logits ``[B, T, vocab]`` float32: the model keeps no state,
+    Returns the logits ``[B, T, vocab]`` float32, or with ``targets`` the
+    mean next-token loss (``lm_head.VocabHead``): the model keeps no state,
     so its loss is ``models.make_lm_loss_fn``'s, as GPT-2's. The heads, the
     groups, the columns and the vocabulary given are those this device
     holds (the module's docstring: a layer held by share)."""
@@ -235,7 +237,7 @@ class HybridDecoder(fnn.Module):
     dtype: Any = jnp.float32
 
     @fnn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, targets=None):
         m = self.multipliers
         note_attention_step()  # each layer's attention counts itself
         batch, t = tokens.shape
@@ -263,7 +265,6 @@ class HybridDecoder(fnn.Module):
         with jax.named_scope(_names.SCOPE_LM_NORM):
             x = fnn.RMSNorm(
                 epsilon=self.norm_eps, dtype=jnp.float32, name="norm")(x)
-        with jax.named_scope(_names.SCOPE_LM_HEAD):
-            return m.lm_head * fnn.Dense(
-                self.vocab_size, use_bias=False, dtype=jnp.float32,
-                name="head")(x)
+        return VocabHead(
+            self.vocab_size, use_bias=False, dtype=jnp.float32,
+            scale=m.lm_head, name="head")(x, targets)

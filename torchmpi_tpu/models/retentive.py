@@ -47,6 +47,7 @@ from ..parallel.retention import note_retention_step, power_retention
 from ..telemetry import names as _names
 from .decoder import rotary
 from .embedding import TokenEmbed
+from .lm_head import VocabHead
 from .transformer import recomputed
 
 # what a seeded gate lets through of the state, a position: ``1 - 1 / n``
@@ -121,7 +122,8 @@ class RetentionDecoderBlock(fnn.Module):
 
 class RetentionDecoder(fnn.Module):
     """Decoder-only LM over ``RetentionDecoderBlock``s, every layer the
-    same. Returns the logits ``[B, T, vocab]`` float32: the model keeps no
+    same. Returns the logits ``[B, T, vocab]`` float32, or with ``targets``
+    the mean next-token loss (``lm_head.VocabHead``): the model keeps no
     state, so its loss is ``models.make_lm_loss_fn``'s, as GPT-2's. The
     heads, the columns and the vocabulary given are those this device holds
     (the module's docstring: a layer held by share)."""
@@ -141,7 +143,7 @@ class RetentionDecoder(fnn.Module):
     dtype: Any = jnp.float32
 
     @fnn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, targets=None):
         batch, t = tokens.shape
         note_retention_step(self.num_layers, batch, self.num_kv_heads,
                             self.head_dim, -(-t // self.chunk))
@@ -163,7 +165,6 @@ class RetentionDecoder(fnn.Module):
         with jax.named_scope(_names.SCOPE_LM_NORM):
             x = fnn.RMSNorm(
                 epsilon=self.norm_eps, dtype=jnp.float32, name="norm")(x)
-        with jax.named_scope(_names.SCOPE_LM_HEAD):
-            return fnn.Dense(
-                self.vocab_size, use_bias=False, dtype=jnp.float32,
-                name="head")(x)
+        return VocabHead(
+            self.vocab_size, use_bias=False, dtype=jnp.float32,
+            name="head")(x, targets)

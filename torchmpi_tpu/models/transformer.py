@@ -27,6 +27,7 @@ from ..parallel.ring_attention import (
 )
 from ..telemetry import names as _names
 from .embedding import TokenEmbed
+from .lm_head import VocabHead
 
 
 def lm_cross_entropy(logits, targets):
@@ -58,12 +59,13 @@ def make_lm_loss_fn(model: fnn.Module):
     ``batch = (tokens_in, tokens_target)``, both ``[B, T]`` int32. Mean
     cross-entropy over every position (the engine's batch contract matches
     ``models.mnist.make_loss_fn`` so LMs drive the same train loops the
-    classifiers do)."""
+    classifiers do). ``model(tokens, targets)`` is that loss."""
 
     def loss_fn(params, batch):
         tokens, targets = batch
-        return lm_cross_entropy(
-            model.apply({"params": params}, tokens), targets)
+        # the model's head makes the loss itself (``lm_head.VocabHead``): no
+        # logits between the two
+        return model.apply({"params": params}, tokens, targets)
 
     return loss_fn
 
@@ -137,8 +139,9 @@ class LongContextTransformer(fnn.Module):
     dtype: Any = jnp.float32
 
     @fnn.compact
-    def __call__(self, tokens):
-        # tokens: [B, T_local] int32
+    def __call__(self, tokens, targets=None):
+        # tokens: [B, T_local] int32; with ``targets`` the mean next-token
+        # loss over the local positions, not the logits
         note_attention_step()
         t_local = tokens.shape[1]
         if self.sp_axis is not None:
@@ -180,5 +183,6 @@ class LongContextTransformer(fnn.Module):
             )(x)
         with jax.named_scope(_names.SCOPE_LM_NORM):
             x = fnn.LayerNorm(dtype=jnp.float32)(x)
-        with jax.named_scope(_names.SCOPE_LM_HEAD):
-            return fnn.Dense(self.vocab_size, dtype=jnp.float32)(x)
+        # named as flax named it when it was ``fnn.Dense``
+        return VocabHead(
+            self.vocab_size, dtype=jnp.float32, name="Dense_0")(x, targets)

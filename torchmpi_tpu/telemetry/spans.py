@@ -108,7 +108,10 @@ SCOPE_MOE_DENSE = "tm.moe.dense"      # a leading layer's dense feed-forward,
 # models/decoder.py, models/transformer.py: the parts of a language model's
 # step that are no attention and no expert layer. None encloses or lies
 # inside a scope above. What XLA fuses across a boundary bears its root's
-# scope, so a fusion goes to one side whole.
+# scope, so a fusion goes to one side whole. One of them lies inside
+# another: the head's rule (models/lm_head.py) walks the rows in a loop
+# under ``tm.lm.head`` and opens ``tm.lm.loss`` inside the loop's body, and
+# a reader takes the innermost (the last) name.
 SCOPE_LM_EMBED = "tm.lm.embed"        # the embedding's gather (GPT-2: and
 #                                       the positions'); backward: the rows
 #                                       sorted by id, summed by blocks, one
@@ -124,8 +127,13 @@ SCOPE_LM_MLP = "tm.lm.mlp"            # GPT-2's feed-forward: two products
 SCOPE_MOE_ROUTER = "tm.moe.router"    # the router's product, precision
 #                                       highest
 SCOPE_LM_HEAD = "tm.lm.head"          # the product with the vocabulary
+#                                       matrix and, in the same visit of a
+#                                       block of rows, the two gradient
+#                                       products (forward's phase);
+#                                       backward: the multiply by the
+#                                       cotangent
 SCOPE_LM_LOSS = "tm.lm.loss"          # the float32 log-softmax, the pick,
-#                                       the mean
+#                                       the mean, ``softmax - onehot``
 
 # models/hybrid.py: a state-space mixer beside the attention of a block
 # (parallel/ssm.py). Named ``tm.lm.*`` so that the benchmark's reader, which
@@ -187,6 +195,14 @@ GAUGE_RETENTION_STATE_BYTES = "tm_retention_state_bytes_per_step"
 # jax's scatter-add). The benchmark's ``embed_grad_sorted_share`` reads it
 # against the step's tokens
 GAUGE_EMBED_GRAD_SORTED_ROWS = "tm_embed_grad_sorted_rows_per_step"
+# -- the gauges models/lm_head.py ``head_loss`` sets the same way: the token
+# rows of the step most recently traced whose loss came from the vocabulary
+# head's own rule (the product, the log-softmax and the three gradients a
+# block of rows at a time), and the blocks it walks (1: one visit, no loop).
+# The benchmark's ``lm_head_blocked_share`` reads the first against the
+# step's tokens
+GAUGE_LM_HEAD_BLOCKED_ROWS = "tm_lm_head_blocked_rows_per_step"
+GAUGE_LM_HEAD_BLOCKS = "tm_lm_head_blocks_per_step"
 
 # -- what a device trace calls the attention kernels (an event's name is
 # the kernel's HLO instruction): jax's splash attention in
