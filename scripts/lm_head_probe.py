@@ -39,7 +39,7 @@ import numpy as np  # noqa: E402
 from jax import lax  # noqa: E402
 
 from torchmpi_tpu.models import lm_head  # noqa: E402
-from torchmpi_tpu.models.transformer import lm_cross_entropy  # noqa: E402
+from torchmpi_tpu.models.lm import lm_cross_entropy  # noqa: E402
 
 # per_chip_batch x sequence_length, the vocabulary held, the width, a bias,
 # the scale on the logits; the last norm hands every head float32 rows

@@ -37,6 +37,10 @@ if "xla_force_host_platform_device_count" not in flags:
 
 import pytest  # noqa: E402
 
+# cases a configuration's test files import and run as their own: pytest
+# rewrites the assertions of the files it collects, and of these by name
+pytest.register_assert_rewrite("decoder_cases", "selected_attention_cases")
+
 
 @pytest.fixture(autouse=True)
 def _fresh_runtime():

@@ -18,7 +18,7 @@ from torchmpi_tpu.models.embedding import (
     embedding_lookup,
     sorted_embedding_grad,
 )
-from torchmpi_tpu.models.transformer import init_lm_params, make_lm_loss_fn
+from torchmpi_tpu.models.lm import init_lm_params, make_lm_loss_fn
 from torchmpi_tpu.telemetry import names
 
 V = 97  # the rehearsals' vocabulary

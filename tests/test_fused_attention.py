@@ -5,8 +5,8 @@ it stands in for (``_loops``) and against a plain ``t x t`` masked softmax
 in float32: outputs and the gradients of ``q``, ``k``, ``v``. Then the
 choice between the two, the gauges that say which was taken, and the two
 readers the benchmark gained. What a lowering for a TPU takes is in
-``test_decoder_chip_compile.py`` (the one file that loads the TPU's
-compiler)."""
+``test_decoder_cells.py`` and the ``test_chip_<config>.py`` files (the
+files that load the TPU's compiler)."""
 
 import math
 import re
@@ -22,7 +22,7 @@ import pytest
 
 from torchmpi_tpu import telemetry
 from torchmpi_tpu.models import MoEDecoder
-from torchmpi_tpu.models.transformer import recomputed
+from torchmpi_tpu.models.lm import recomputed
 from torchmpi_tpu.parallel import blocked_self_attention, full_self_attention
 from torchmpi_tpu.parallel.ring_attention import (
     LANES,

@@ -491,7 +491,7 @@ def test_the_mixers_scopes_nest_under_fwd_bwd_in_the_lowered_step():
         if bucket not in (None, model_scopes.UNNAMED):
             assert scopes.scope_of(op) == "tm.fwd_bwd", op
             seen.setdefault(bucket, set()).add(model_scopes.phase_of(op))
-    mixer = set(names.MODEL_SCOPE_NAMES[18:22])
+    mixer = set(names.SSM_SCOPE_NAMES)
     assert mixer == {"tm.lm.ssm_proj", "tm.lm.ssm_conv", "tm.lm.ssm_scan",
                      "tm.lm.ssm_gate"}
     assert set(seen) == mixer | {

@@ -248,7 +248,7 @@ def test_the_rule_is_all_that_differs_in_the_expert_layer():
 # ``keye-vl-2-30b-a3b``, whose layers all select, as it was. Lowered for the
 # TPU at the cells' own sizes, where the loops are dropped, all three are
 # the parent's text letter for letter:
-# ``test_decoder_chip_compile.py`` pins those.
+# each ``test_chip_<config>.py``'s ``PIN`` holds one.
 # PR 42 meant one change too: the token lookup has a derivative rule of its
 # own (``models/embedding.py``), so each step's backward ends in a sort, a
 # loop of one-hot products and a gather where jax's scatter-add of the
@@ -256,7 +256,7 @@ def test_the_rule_is_all_that_differs_in_the_expert_layer():
 # 55eca190dcff1715 and 877,075 9b3ffce1528a44a1 before). At the rehearsals'
 # width of 64 all three take it; at the cells' own widths two of them keep
 # jax's transpose (``embedding.takes_sorted_sum``;
-# ``test_decoder_chip_compile.py`` pins those).
+# ``test_chip_<config>.py`` pins those).
 # PR 43 meant one change as well: the head and its loss are one function
 # with a derivative rule of its own (``models/lm_head.py``) that makes the
 # three gradients in forward, while the logits exist; at the rehearsals'

@@ -29,7 +29,7 @@ from torchmpi_tpu.models.lm_head import (
     blocked_head_loss,
     head_loss,
 )
-from torchmpi_tpu.models.transformer import lm_cross_entropy
+from torchmpi_tpu.models.lm import lm_cross_entropy
 from torchmpi_tpu.telemetry import names
 
 V, D = 97, 24  # the rehearsals' vocabulary

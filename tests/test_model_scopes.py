@@ -7,9 +7,11 @@ they reach every phase of it, move no operation of an older scope, and
 change no byte of the compiled program."""
 
 import contextlib
+import functools
 import re
 import sys
 from pathlib import Path
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -37,10 +39,10 @@ ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 
 SEQ, VOCAB = 24, 61
-OLD = names.MODEL_SCOPE_NAMES[:11]   # what the benchmark's metrics read
-NEW = names.MODEL_SCOPE_NAMES[11:]   # what this file is about
-SSM = names.MODEL_SCOPE_NAMES[18:22]  # the state-space mixer's (PR 39)
-RET = names.MODEL_SCOPE_NAMES[22:]   # power retention's (PR 41)
+OLD = names.ATTN_MOE_SCOPE_NAMES      # what the benchmark's metrics read
+SSM = names.SSM_SCOPE_NAMES           # the state-space mixer's (PR 39)
+RET = names.RETENTION_SCOPE_NAMES     # power retention's (PR 41)
+NEW = names.LM_SCOPE_NAMES + SSM + RET  # what this file is about
 EVERY_LM = {"tm.lm.embed", "tm.lm.norm", "tm.attn.proj", "tm.lm.head",
             "tm.lm.loss"}
 # the scopes opened inside a block are recomputed with it; the embedding,
@@ -59,42 +61,49 @@ def _decoder(**over):
     return MoEDecoder(**kw)
 
 
+class Family(NamedTuple):
+    build: Callable  # () -> the model at this file's sizes
+    own: set         # the new scopes it opens beside ``EVERY_LM``
+
+
+ROUTED = {"tm.moe.router"}
 FAMILIES = {
     # GPT-2's: LayerNorm, one qkv product, T x T attention, a GELU
     # feed-forward, each block recomputed
-    "gpt2": lambda: LongContextTransformer(
+    "gpt2": Family(lambda: LongContextTransformer(
         vocab_size=VOCAB, num_layers=2, num_heads=2, head_dim=8, d_model=16,
-        max_len=32, remat=True),
+        max_len=32, remat=True), {"tm.lm.mlp"}),
     # smallthinker-21b-a3b's: the router read before attention
-    "smallthinker": _decoder,
+    "smallthinker": Family(_decoder, ROUTED),
     # laguna-s-2-1's: heads by layer, a gate a head, the router after the
     # second norm, a shared expert, a dense leading layer
-    "laguna": lambda: _decoder(
+    "laguna": Family(lambda: _decoder(
         num_layers=5, num_heads=(2, 3, 3, 3), num_kv_heads=1,
         rope_layout=(1,), rope_theta=1e4,
         rope_full=Rotary(5e5, 4, 128.0, 8192, 32.0, 1.0, 1.4852),
         activation=jax.nn.silu, router_after_norm=True, head_gate=True,
         route_weights=sigmoid_route_weights(2.5), shared_width=16,
-        dense_layers=1, dense_width=24),
+        dense_layers=1, dense_width=24), ROUTED),
     # falcon-h1-34b's: a state-space mixer beside attention in every block,
     # a gated feed-forward under GPT-2's scope, no router
-    "hybrid": lambda: HybridDecoder(
+    "hybrid": Family(lambda: HybridDecoder(
         vocab_size=VOCAB, num_layers=2, d_model=32, num_heads=2,
         num_kv_heads=1, head_dim=8, ssm_heads=2, ssm_head_dim=8,
         ssm_groups=1, ssm_state=6, mlp_width=24, chunk=8, attn_block=8,
-        remat=True),
+        remat=True), {"tm.lm.mlp", *SSM}),
     # brumby-14b's: power retention where attention stood in every block (no
     # attention's scope at all), a norm on each query and key head, a gated
     # feed-forward under GPT-2's scope, no router
-    "retentive": lambda: RetentionDecoder(
+    "retentive": Family(lambda: RetentionDecoder(
         vocab_size=VOCAB, num_layers=2, d_model=32, num_heads=5,
         num_kv_heads=1, head_dim=32, mlp_width=24, chunk=8, remat=True),
+        {"tm.lm.mlp", *RET}),
     # keye-vl-2-30b-a3b's: every layer selects, with a norm on each query
     # and key head; the indexer's projections stay under tm.attn.index
-    "selected": lambda: _decoder(
+    "selected": Family(lambda: _decoder(
         window_layout=(0,), rope_layout=(1,), selected_layout=(1,),
         index_top_k=9, index_heads=3, index_dim=8, router_after_norm=True,
-        qk_norm=True),
+        qk_norm=True), ROUTED),
 }
 
 
@@ -104,7 +113,7 @@ def _one_device():
 
 
 def _engine(family):
-    model = FAMILIES[family]()
+    model = FAMILIES[family].build()
     params = init_lm_params(model, SEQ)
     if isinstance(model, MoEDecoder):
         return AllReduceSGDEngine(
@@ -135,6 +144,11 @@ def _op_names(family):
     return names_
 
 
+# ``_op_names(family)`` of the step as the model traces it, no name dropped:
+# made once a family for the tests of this file that read it
+as_traced = functools.lru_cache(maxsize=None)(_op_names)
+
+
 @contextlib.contextmanager
 def _without(monkeypatch, dropped):
     """``jax.named_scope`` opens nothing for the names in ``dropped`` (every
@@ -157,14 +171,12 @@ def test_new_scopes_reach_every_phase_under_fwd_bwd(family):
                    "tm.lm.ssm_gate", "tm.lm.ret_gate", "tm.lm.ret_chunk",
                    "tm.lm.ret_state")
     seen = {}
-    for op in _op_names(family):
+    for op in as_traced(family):
         bucket = model_scopes.bucket_of(op)
         if bucket in NEW:
             assert scopes.scope_of(op) == "tm.fwd_bwd", op
             seen.setdefault(bucket, set()).add(model_scopes.phase_of(op))
-    own = {"gpt2": {"tm.lm.mlp"}, "hybrid": {"tm.lm.mlp", *SSM},
-           "retentive": {"tm.lm.mlp", *RET}}.get(family, {"tm.moe.router"})
-    assert set(seen) == EVERY_LM | own, seen
+    assert set(seen) == EVERY_LM | FAMILIES[family].own, seen
     for scope, phases in seen.items():
         want = {"forward", "backward"}
         if scope in IN_BLOCKS:
@@ -192,7 +204,7 @@ def test_older_inner_scopes_read_what_they_read(family, monkeypatch):
 
     with _without(monkeypatch, NEW):
         before = older(_op_names(family))
-    after = older(_op_names(family))
+    after = older(as_traced(family))
     # the retentive family opens none of the older scopes: no attention
     assert after == before and bool(before) == (family != "retentive")
     if family == "gpt2":  # its attention bears the decoders' name now

@@ -743,12 +743,15 @@ def test_model_scopes_nest_under_fwd_bwd_in_the_lowered_step(selecting):
         "tm.lm.ret_gate", "tm.lm.ret_chunk", "tm.lm.ret_state")
     cfg = tiny_cfg()
     model = tiny_model(cfg)
-    opened = set(names.MODEL_SCOPE_NAMES[:5])
+    experts = {names.SCOPE_MOE_ROUTE, names.SCOPE_MOE_EXPERTS,
+               names.SCOPE_MOE_COMBINE}
+    opened = experts | {names.SCOPE_ATTN_FULL, names.SCOPE_ATTN_WINDOW}
     if selecting:
         model = model.clone(
             window_layout=(0,), rope_layout=(1,), selected_layout=(1,),
             index_top_k=9, index_heads=3, index_dim=8)
-        opened = set(names.MODEL_SCOPE_NAMES[2:8])
+        opened = experts | {names.SCOPE_ATTN_INDEX, names.SCOPE_ATTN_SELECT,
+                            names.SCOPE_ATTN_SPARSE}
     mpi.start(devices=jax.devices()[:1])
     engine = AllReduceSGDEngine(
         make_moe_lm_loss_fn(model), seeded_params(model, SEQ),
@@ -763,7 +766,7 @@ def test_model_scopes_nest_under_fwd_bwd_in_the_lowered_step(selecting):
     seen = {}
     for op in op_names:
         inner = inner_scopes.inner_scope_of(op)
-        if inner in names.MODEL_SCOPE_NAMES[:11]:
+        if inner in names.ATTN_MOE_SCOPE_NAMES:
             # the first tm. component is the engine's: fwd_bwd stays whole
             assert scopes.scope_of(op) == "tm.fwd_bwd", op
             seen.setdefault(inner, set()).add("transpose(" in op)

@@ -476,7 +476,7 @@ def test_the_retentions_scopes_nest_under_fwd_bwd_in_the_lowered_step():
             assert len(inner) <= 1 or (
                 inner[0] == "tm.lm.ret_state" and len(inner) == 2
                 and inner[1] in RET[:2]), op
-    assert names.MODEL_SCOPE_NAMES[-3:] == RET
+    assert names.RETENTION_SCOPE_NAMES == RET
     assert set(seen) == set(RET) | {
         "tm.lm.embed", "tm.lm.norm", "tm.attn.proj", "tm.lm.mlp",
         "tm.lm.head", "tm.lm.loss"}, seen

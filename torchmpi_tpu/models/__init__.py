@@ -6,6 +6,7 @@ from .decoder import (
     make_moe_lm_loss_fn,
 )
 from .hybrid import HybridDecoder, HybridDecoderBlock, Multipliers
+from .lm import init_lm_params, make_lm_loss_fn
 from .lm_head import VocabHead
 from .mlp import MLP6
 from .mnist import (
@@ -24,12 +25,7 @@ from .resnet import (
     make_stateful_loss_fn,
 )
 from .retentive import RetentionDecoder, RetentionDecoderBlock
-from .transformer import (
-    LongContextTransformer,
-    RingAttentionBlock,
-    init_lm_params,
-    make_lm_loss_fn,
-)
+from .transformer import LongContextTransformer, RingAttentionBlock
 
 __all__ = [
     "LogisticRegression",

@@ -83,21 +83,8 @@ from ..parallel.selected_attention import (
 )
 from ..telemetry import names as _names
 from .embedding import TokenEmbed
+from .lm import recomputed, rotary
 from .lm_head import VocabHead
-from .transformer import recomputed
-
-
-def rotary(x, theta: float):
-    """Rotary position over the whole head of ``x`` ``[b, t, h, d]``, its
-    halves rotated against each other, positions ``0 .. t - 1``; float32
-    inside, ``x``'s dtype out."""
-    half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv[None, :]
-    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
 class Rotary(NamedTuple):

@@ -10,8 +10,8 @@ block is one mixer then a feed-forward of experts, with a router in every
 model; this one has two mixers side by side, no router and a multiplier at
 every seam. What the two share is called, not copied: attention is
 ``parallel.ring_attention.blocked_self_attention``, the rotation
-``decoder.rotary``, the loss ``transformer.lm_cross_entropy`` through
-``transformer.make_lm_loss_fn``, the scopes ``telemetry.names``'; the
+``lm.rotary``, the loss ``lm_head.VocabHead``'s through
+``lm.make_lm_loss_fn``, the scopes ``telemetry.names``'; the
 mixer's sequence operations are ``parallel.ssm``'s.
 
 One layer, input ``h`` ``[t, d]``, the mixer's ``H`` heads of ``P`` in ``G``
@@ -65,10 +65,9 @@ from ..parallel.ssm import (
     ssd_chunked_scan,
 )
 from ..telemetry import names as _names
-from .decoder import rotary
 from .embedding import TokenEmbed
+from .lm import recomputed, rotary
 from .lm_head import VocabHead
-from .transformer import recomputed
 
 
 class Multipliers(NamedTuple):

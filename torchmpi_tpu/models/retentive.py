@@ -8,9 +8,9 @@ A sibling of ``models/decoder.py``'s and ``models/hybrid.py``'s blocks, not
 more fields on either: the first has a router in every model, the second two
 mixers from one norm and a multiplier at every seam, and both files' lowered
 steps are pinned letter for letter. What the three share is called, not
-copied: the rotation is ``decoder.rotary``, the head norm ``fnn.RMSNorm`` as
-``decoder.py`` spells it, the recomputation ``transformer.recomputed``, the
-loss ``transformer.lm_cross_entropy`` through ``transformer.make_lm_loss_fn``,
+copied: the rotation is ``lm.rotary``, the head norm ``fnn.RMSNorm`` as
+``decoder.py`` spells it, the recomputation ``lm.recomputed``, the
+loss ``lm_head.VocabHead``'s through ``lm.make_lm_loss_fn``,
 the scopes ``telemetry.names``'; the sequence operation is
 ``parallel.retention.power_retention``.
 
@@ -45,10 +45,9 @@ import jax.numpy as jnp
 
 from ..parallel.retention import note_retention_step, power_retention
 from ..telemetry import names as _names
-from .decoder import rotary
 from .embedding import TokenEmbed
+from .lm import recomputed, rotary
 from .lm_head import VocabHead
-from .transformer import recomputed
 
 # what a seeded gate lets through of the state, a position: ``1 - 1 / n``
 # with ``n`` log-uniform between these, by head and layer

@@ -161,14 +161,28 @@ SCOPE_RET_STATE = "tm.lm.ret_state"   # the symmetric square, a chunk's own
 #                                       names (the readers go by the
 #                                       innermost, the last, name)
 
-MODEL_SCOPE_NAMES = (
+# The scopes by group, so that a reader or a test names the group it means
+# and a model that brings names of its own appends a group and moves no
+# other's place. ``MODEL_SCOPE_NAMES`` is the groups in the order they came.
+ATTN_MOE_SCOPE_NAMES = (    # attention's and the expert layer's: what the
+    #                         benchmark's older per-layer metrics read
     SCOPE_ATTN_FULL, SCOPE_ATTN_WINDOW, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
     SCOPE_MOE_COMBINE, SCOPE_ATTN_INDEX, SCOPE_ATTN_SELECT,
     SCOPE_ATTN_SPARSE, SCOPE_ATTN_GATE, SCOPE_MOE_SHARED, SCOPE_MOE_DENSE,
+)
+LM_SCOPE_NAMES = (          # the rest of a language model's step
     SCOPE_LM_EMBED, SCOPE_LM_NORM, SCOPE_ATTN_PROJ, SCOPE_LM_MLP,
     SCOPE_MOE_ROUTER, SCOPE_LM_HEAD, SCOPE_LM_LOSS,
+)
+SSM_SCOPE_NAMES = (         # the state-space mixer's
     SCOPE_SSM_PROJ, SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_SSM_GATE,
+)
+RETENTION_SCOPE_NAMES = (   # power retention's
     SCOPE_RET_GATE, SCOPE_RET_CHUNK, SCOPE_RET_STATE,
+)
+MODEL_SCOPE_NAMES = (
+    ATTN_MOE_SCOPE_NAMES + LM_SCOPE_NAMES + SSM_SCOPE_NAMES
+    + RETENTION_SCOPE_NAMES
 )
 
 # -- the gauge models/decoder.py sets from static shapes while its step is
