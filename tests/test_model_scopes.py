@@ -22,6 +22,7 @@ import pytest
 import torchmpi_tpu as mpi
 from torchmpi_tpu.engine import AllReduceSGDEngine
 from torchmpi_tpu.models import (
+    GatedDeltaDecoder,
     HybridDecoder,
     LongContextTransformer,
     MoEDecoder,
@@ -42,13 +43,14 @@ SEQ, VOCAB = 24, 61
 OLD = names.ATTN_MOE_SCOPE_NAMES      # what the benchmark's metrics read
 SSM = names.SSM_SCOPE_NAMES           # the state-space mixer's (PR 39)
 RET = names.RETENTION_SCOPE_NAMES     # power retention's (PR 41)
-NEW = names.LM_SCOPE_NAMES + SSM + RET  # what this file is about
+GDN = names.GDN_SCOPE_NAMES           # the gated delta rule's (PR 45)
+NEW = names.LM_SCOPE_NAMES + SSM + RET + GDN  # what this file is about
 EVERY_LM = {"tm.lm.embed", "tm.lm.norm", "tm.attn.proj", "tm.lm.head",
             "tm.lm.loss"}
 # the scopes opened inside a block are recomputed with it; the embedding,
 # the last norm's model-level call, the head and the loss are not
 IN_BLOCKS = {"tm.lm.norm", "tm.attn.proj", "tm.lm.mlp", "tm.moe.router",
-             *SSM, *RET}
+             *SSM, *RET, *GDN}
 
 
 def _decoder(**over):
@@ -98,6 +100,15 @@ FAMILIES = {
         vocab_size=VOCAB, num_layers=2, d_model=32, num_heads=5,
         num_kv_heads=1, head_dim=32, mlp_width=24, chunk=8, remat=True),
         {"tm.lm.mlp", *RET}),
+    # qwen3-next-80b-a3b's: three layers of the gated delta rule to one of
+    # gated softmax attention, the router after the second norm, a gated
+    # shared expert
+    "gated_delta": Family(lambda: GatedDeltaDecoder(
+        vocab_size=VOCAB, num_layers=4, d_model=32, num_heads=4,
+        num_kv_heads=2, head_dim=16, rotary_dim=4, key_heads=2,
+        value_heads=4, key_dim=8, value_dim=8, expert_width=16,
+        shared_width=16, num_experts=8, top_k=3, held=tuple(range(8)),
+        chunk=8, attn_block=8, remat=True), ROUTED | set(GDN)),
     # keye-vl-2-30b-a3b's: every layer selects, with a norm on each query
     # and key head; the indexer's projections stay under tm.attn.index
     "selected": Family(lambda: _decoder(
@@ -115,7 +126,7 @@ def _one_device():
 def _engine(family):
     model = FAMILIES[family].build()
     params = init_lm_params(model, SEQ)
-    if isinstance(model, MoEDecoder):
+    if isinstance(model, (MoEDecoder, GatedDeltaDecoder)):
         return AllReduceSGDEngine(
             make_moe_lm_loss_fn(model), params, optimizer=optax.sgd(0.1),
             model_state=init_moe_state(model))
@@ -135,7 +146,7 @@ def _op_names(family):
     lowered = _lowered(_engine(family))
     names_ = set(re.findall(
         r'"(jit\(tm_train_step\)[^"]*)"', lowered.as_text(debug_info=True)))
-    if family == "retentive":
+    if family in ("retentive", "gated_delta"):
         # an operation inside a scan's body is named from the body's own
         # function on in the lowered text; the compiled step has its path
         names_ |= set(re.findall(
@@ -169,7 +180,8 @@ def test_new_scopes_reach_every_phase_under_fwd_bwd(family):
                    "tm.moe.router", "tm.lm.head", "tm.lm.loss",
                    "tm.lm.ssm_proj", "tm.lm.ssm_conv", "tm.lm.ssm_scan",
                    "tm.lm.ssm_gate", "tm.lm.ret_gate", "tm.lm.ret_chunk",
-                   "tm.lm.ret_state")
+                   "tm.lm.ret_state", "tm.lm.gdn_proj", "tm.lm.gdn_conv",
+                   "tm.lm.gdn_gate", "tm.lm.gdn_chunk", "tm.lm.gdn_state")
     seen = {}
     for op in as_traced(family):
         bucket = model_scopes.bucket_of(op)

@@ -740,7 +740,10 @@ def test_model_scopes_nest_under_fwd_bwd_in_the_lowered_step(selecting):
         "tm.lm.ssm_proj", "tm.lm.ssm_conv", "tm.lm.ssm_scan",
         "tm.lm.ssm_gate",
         # power retention's (tests/test_retention_decoder.py)
-        "tm.lm.ret_gate", "tm.lm.ret_chunk", "tm.lm.ret_state")
+        "tm.lm.ret_gate", "tm.lm.ret_chunk", "tm.lm.ret_state",
+        # the gated delta rule's (tests/test_deltanet_decoder.py)
+        "tm.lm.gdn_proj", "tm.lm.gdn_conv", "tm.lm.gdn_gate",
+        "tm.lm.gdn_chunk", "tm.lm.gdn_state")
     cfg = tiny_cfg()
     model = tiny_model(cfg)
     experts = {names.SCOPE_MOE_ROUTE, names.SCOPE_MOE_EXPERTS,

@@ -1,3 +1,4 @@
+from .deltanet import GatedDeltaDecoder, GatedDeltaDecoderBlock
 from .decoder import (
     MoEDecoder,
     MoEDecoderBlock,
@@ -44,6 +45,8 @@ __all__ = [
     "Multipliers",
     "RetentionDecoder",
     "RetentionDecoderBlock",
+    "GatedDeltaDecoder",
+    "GatedDeltaDecoderBlock",
     "VocabHead",
     "make_moe_lm_loss_fn",
     "init_moe_state",

@@ -67,7 +67,6 @@ from jax import lax
 
 from .. import telemetry as _telemetry
 from ..parallel.ep import (
-    moe_local_experts,
     note_expert_layers,
     note_expert_load,
     softmax_route_weights,
@@ -83,7 +82,7 @@ from ..parallel.selected_attention import (
 )
 from ..telemetry import names as _names
 from .embedding import TokenEmbed
-from .lm import recomputed, rotary
+from .lm import recomputed, rotary, sparse_feed_forward
 from .lm_head import VocabHead
 
 
@@ -207,15 +206,14 @@ class MoEDecoderBlock(fnn.Module):
                 * dense(width, name + "_up")(m))
 
         sparse = self.dense_width is None
-        if sparse:
-            router = fnn.Dense(
-                self.num_experts, use_bias=False, dtype=jnp.float32,
-                precision=lax.Precision.HIGHEST, name="router")
         norm = lambda name: fnn.RMSNorm(  # noqa: E731
             epsilon=self.norm_eps, dtype=jnp.float32, name=name)
         if sparse and not self.router_after_norm:
             with jax.named_scope(_names.SCOPE_MOE_ROUTER):
-                logits = router(x.astype(jnp.float32))
+                logits = fnn.Dense(
+                    self.num_experts, use_bias=False, dtype=jnp.float32,
+                    precision=lax.Precision.HIGHEST, name="router"
+                )(x.astype(jnp.float32))
 
         with jax.named_scope(_names.SCOPE_LM_NORM):
             h = norm("norm_attn")(x)
@@ -273,26 +271,14 @@ class MoEDecoderBlock(fnn.Module):
                 x = x + gated(h.astype(self.dtype), self.dense_width, "mlp")
             return x, (jnp.zeros((n,), jnp.float32), jnp.float32(0.0),
                        index_loss, pairs)
-        if self.router_after_norm:
-            with jax.named_scope(_names.SCOPE_MOE_ROUTER):
-                logits = router(h.astype(jnp.float32))
-        if self.shared_width is not None:
-            with jax.named_scope(_names.SCOPE_MOE_SHARED):
-                x = x + gated(
-                    h.astype(self.dtype), self.shared_width, "shared")
-        init = fnn.initializers.lecun_normal(in_axis=-2, out_axis=-1)
-        y, load, rows = moe_local_experts(
-            h.astype(self.dtype).reshape(b * t, d),
-            logits.reshape(b * t, self.num_experts),
-            self.top_k,
-            self.param("experts_gate", init, (n, d, f), jnp.float32),
-            self.param("experts_up", init, (n, d, f), jnp.float32),
-            self.param("experts_down", init, (n, f, d), jnp.float32),
-            tuple(self.held),
-            activation=self.activation,
+        x, load, rows = sparse_feed_forward(
+            self, x, h, expert_width=f, num_experts=self.num_experts,
+            top_k=self.top_k, held=self.held, activation=self.activation,
+            dtype=self.dtype,
+            logits=None if self.router_after_norm else logits,
             route_weights=self.route_weights,
-        )
-        return x + y.reshape(b, t, d), (load, rows, index_loss, pairs)
+            shared_width=self.shared_width)
+        return x, (load, rows, index_loss, pairs)
 
 
 class MoEDecoder(fnn.Module):

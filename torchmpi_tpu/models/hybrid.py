@@ -66,7 +66,7 @@ from ..parallel.ssm import (
 )
 from ..telemetry import names as _names
 from .embedding import TokenEmbed
-from .lm import recomputed, rotary
+from .lm import a_log_init, dt_bias_init, recomputed, rotary
 from .lm_head import VocabHead
 
 
@@ -82,19 +82,6 @@ class Multipliers(NamedTuple):
     ssm_out: float = 1.0
     ssm: Tuple[float, ...] = (1.0,) * 5  # on z, x, B, C, dt of W_in's output
     mlp: Tuple[float, float] = (1.0, 1.0)  # on the gate's product, the output
-
-
-def _a_log_init(key, shape, dtype=jnp.float32):
-    """``A`` uniform in [1, 16] (Mamba-2's published initialisation)."""
-    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
-
-
-def _dt_bias_init(key, shape, dtype=jnp.float32):
-    """``delta`` log-uniform in [1e-3, 1e-1], through the inverse of the
-    softplus."""
-    dt = jnp.exp(jax.random.uniform(
-        key, shape, dtype, math.log(1e-3), math.log(1e-1)))
-    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 def _uniform_init(bound):
@@ -149,11 +136,11 @@ class HybridDecoderBlock(fnn.Module):
         with jax.named_scope(_names.SCOPE_SSM_SCAN):
             x, b_, c_ = jnp.split(xbc, [inner, inner + bc], axis=-1)
             delta = jax.nn.softplus(dt + self.param(
-                "dt_bias", _dt_bias_init, (self.ssm_heads,), f32))
+                "dt_bias", dt_bias_init, (self.ssm_heads,), f32))
             y = ssd_chunked_scan(
                 x.reshape(b, t, self.ssm_heads, self.ssm_head_dim), delta,
                 -jnp.exp(self.param(
-                    "A_log", _a_log_init, (self.ssm_heads,), f32)),
+                    "A_log", a_log_init, (self.ssm_heads,), f32)),
                 b_.reshape(b, t, self.ssm_groups, self.ssm_state),
                 c_.reshape(b, t, self.ssm_groups, self.ssm_state),
                 self.param("D", fnn.initializers.ones, (self.ssm_heads,), f32),

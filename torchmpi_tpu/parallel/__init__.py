@@ -1,3 +1,4 @@
+from .deltanet import gated_delta_rule
 from .ep import (
     moe_dispatch_combine,
     moe_load_stats,
@@ -39,6 +40,7 @@ __all__ = [
     "ssd_chunked_scan",
     "gated_group_norm",
     "power_retention",
+    "gated_delta_rule",
     "symmetric_square",
     "MPLinear",
     "MPLinearOutputSplit",

@@ -325,7 +325,7 @@ SAVED = "tm_attn_saved"  # checkpoint_name of what an attention call's
 #                          forward kernels hand to its backward kernels: the
 #                          one name a recomputed block keeps (it is
 #                          ``selected_attention``'s too; the policy is
-#                          ``models.transformer.recomputed``)
+#                          ``models.lm.recomputed``)
 
 
 def _kernels_take(head_dim: int) -> bool:

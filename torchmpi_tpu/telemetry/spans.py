@@ -161,6 +161,25 @@ SCOPE_RET_STATE = "tm.lm.ret_state"   # the symmetric square, a chunk's own
 #                                       names (the readers go by the
 #                                       innermost, the last, name)
 
+# models/deltanet.py: a Gated DeltaNet mixer in the layers that have no
+# softmax attention (parallel/deltanet.py). The softmax layers of that model
+# stand under ``tm.attn.*`` as any decoder's, the expert layer under
+# ``tm.moe.*``.
+SCOPE_GDN_PROJ = "tm.lm.gdn_proj"     # the mixer's three products: into
+#                                       [q | k | v | z], into [b | a], out
+SCOPE_GDN_CONV = "tm.lm.gdn_conv"     # the causal depthwise taps over
+#                                       [q | k | v] and the SiLU
+SCOPE_GDN_GATE = "tm.lm.gdn_gate"     # the L2 norms of q and k, beta, g,
+#                                       the cumulative sums and the decays,
+#                                       the output norm and silu(z)
+SCOPE_GDN_CHUNK = "tm.lm.gdn_chunk"   # what is made a chunk at a time
+#                                       before the loop: K K^T, the
+#                                       triangular system's inverse, the
+#                                       corrected values and keys, Q K^T
+SCOPE_GDN_STATE = "tm.lm.gdn_state"   # the loop over the chunks: the values
+#                                       a chunk really writes, its output,
+#                                       the carried state
+
 # The scopes by group, so that a reader or a test names the group it means
 # and a model that brings names of its own appends a group and moves no
 # other's place. ``MODEL_SCOPE_NAMES`` is the groups in the order they came.
@@ -180,9 +199,13 @@ SSM_SCOPE_NAMES = (         # the state-space mixer's
 RETENTION_SCOPE_NAMES = (   # power retention's
     SCOPE_RET_GATE, SCOPE_RET_CHUNK, SCOPE_RET_STATE,
 )
+GDN_SCOPE_NAMES = (         # the gated delta rule's
+    SCOPE_GDN_PROJ, SCOPE_GDN_CONV, SCOPE_GDN_GATE, SCOPE_GDN_CHUNK,
+    SCOPE_GDN_STATE,
+)
 MODEL_SCOPE_NAMES = (
     ATTN_MOE_SCOPE_NAMES + LM_SCOPE_NAMES + SSM_SCOPE_NAMES
-    + RETENTION_SCOPE_NAMES
+    + RETENTION_SCOPE_NAMES + GDN_SCOPE_NAMES
 )
 
 # -- the gauge models/decoder.py sets from static shapes while its step is
@@ -203,6 +226,11 @@ GAUGE_SSM_CHUNKS = "tm_ssm_chunks_per_step"
 GAUGE_RETENTION_KV_HEADS_HELD = "tm_retention_kv_heads_held_per_step"
 GAUGE_RETENTION_CHUNKS = "tm_retention_chunks_per_step"
 GAUGE_RETENTION_STATE_BYTES = "tm_retention_state_bytes_per_step"
+# -- the gauge parallel/deltanet.py ``note_gdn_step`` sets the same way for
+# models/deltanet.py: the chunks the gated delta rule runs over (the layers
+# that have it x sequences x chunks). The benchmark's ``gdn_chunks_per_step``
+# reads it
+GAUGE_GDN_CHUNKS = "tm_gdn_chunks_per_step"
 # -- the gauge models/embedding.py ``TokenEmbed`` sets the same way: the
 # token rows of the step most recently traced whose embedding gradient is
 # summed by sorted ids before it touches the table (0 where the shapes keep
