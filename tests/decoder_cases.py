@@ -215,6 +215,22 @@ def kernel_calls(text):
         re.findall(r"%([A-Za-z_]+?)[.\d]* = [^\n]*tpu_custom_call", text))
 
 
+def kernel_phases(text, kernel):
+    """By (innermost scope, phase), as the benchmark's reader of a device
+    trace files an event under ``tm.fwd_bwd`` (``benchmark/model_scopes.py``),
+    how often a compiled step's text calls the kernels whose name starts
+    with ``kernel``: a kernel whose call bore no ``op_name`` would read
+    under no scope."""
+    from benchmark import model_scopes
+
+    return Counter(
+        ((model_scopes.BUCKET.findall(op) or [None])[-1],
+         model_scopes.phase_of(op))
+        for op in re.findall(
+            r'%%%s[\w.]* = [^\n]*tpu_custom_call[^\n]*op_name="([^"]*)"'
+            % kernel, text))
+
+
 def whole_logits(text, cfg):
     """The arrays of a compiled step's text, of any type, with a row for
     every token of the step and a column for every id: the ``[rows, V]``

@@ -13,5 +13,5 @@ from decoder_cases import (  # noqa: F401 - collected here, for CONFIG
 )
 
 CONFIG = "falcon-h1-34b"
-MORE = {"ssm_heads_held_share", "attn_kernel_share"}
+MORE = {"ssm_heads_held_share", "attn_kernel_share", "conv_kernel_share"}
 ABSENT = ("moe_", "attn_selected_pair_share", "attn_heads_held_share")

@@ -17,6 +17,6 @@ from decoder_cases import (  # noqa: F401 - collected here, for CONFIG
 CONFIG = "qwen3-next-80b-a3b"
 MORE = {"moe_grouped_rows_per_step", "moe_max_over_mean_load",
         "moe_held_route_share", "moe_compact_share", "attn_kernel_share",
-        "gdn_chunks_per_step"}
+        "gdn_chunks_per_step", "conv_kernel_share"}
 ABSENT = ("attn_selected_pair_share", "attn_heads_held_share", "ssm_",
           "retention_")

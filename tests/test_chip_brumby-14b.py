@@ -89,9 +89,10 @@ def test_the_retentive_cell_reads_what_the_hybrid_one_reads_but_attention():
     spec = benchmark_spec()
     fourth = per_layer_of(spec, cell_of("falcon-h1-34b"))
     fifth = per_layer_of(spec, cell_of(CONFIG))
+    # ``conv_kernel_share`` is the mixer's too: its convolution's kernels
     assert {m for m in fourth - fifth if not m.startswith("ssm_")} == {
         "attn_full_ms_per_step", "attn_kernel_share",
-        "attn_kernel_ms_per_step"}
+        "attn_kernel_ms_per_step", "conv_kernel_share"}
     assert fifth - fourth == {
         m["name"] for m in spec["per_layer"]
         if m["workloads"] == [cell_of(CONFIG)]}

@@ -10,6 +10,7 @@ from decoder_cases import (  # noqa: F401 - collected here, for CONFIG
     benchmark_spec,
     cell_of,
     compiled,
+    kernel_phases,
     lowered,
     one_chip,
     per_layer_of,
@@ -30,7 +31,10 @@ CONFIG = "falcon-h1-34b"
 # 2,436,298 a49bae8c575c46cb before); since PR 43 the head and its loss are
 # one function with a derivative rule of its own (``models/lm_head.py``), a
 # loop over blocks of 8,192 rows where the float32 logits of every row stood
-# (2,452,301 16a4c55366e3d917 before)
+# (2,452,301 16a4c55366e3d917 before). PR 46 left it letter for letter: the
+# mixer's convolution calls ``causal_conv1d_silu``, whose kernels are not
+# taken at 1,024 channels (with them the text was 2,410,797 f7462252725b96f7
+# and the step 9.6 ms longer on the chip)
 PIN = (2454333, "8028a6527d2dc8db")
 OWN = ["ssm_conv_ms_per_step", "ssm_gate_ms_per_step", "ssm_heads_held_share",
        "ssm_proj_ms_per_step", "ssm_scan_ms_per_step"]
@@ -76,11 +80,30 @@ def test_the_hybrid_cells_step_holds_its_scan_in_chunks(compiled):
     assert text.count(" while(") >= 3 * layers
 
 
+def test_the_hybrid_cells_convolution_stays_xlas(compiled):
+    """At 1,024 channels the mixer's convolution is not wide enough for the
+    kernels of ``ops/conv_kernel.py`` (``takes``: measured in this cell's
+    step on the chip, PR 46), so the step calls none of them and is the
+    parent's; ``conv_kernel_share`` is listed for the cell all the same and
+    reads 0 there, as ``attn_kernel_share`` does where heads are narrow."""
+    from torchmpi_tpu.ops import conv_kernel
+
+    cfg = compiled.cfg
+    channels = (cfg["mamba_n_heads"] * cfg["mamba_d_head"]
+                + 2 * cfg["mamba_n_groups"] * cfg["mamba_d_state"])
+    assert channels == 1024 < conv_kernel.WIDE
+    assert not conv_kernel.takes(
+        (cfg["per_chip_batch"], cfg["sequence_length"], channels), "float32",
+        cfg["mamba_d_conv"])
+    assert not kernel_phases(compiled.text, "tm_conv_silu")
+
+
 def test_the_hybrid_cell_reads_the_feed_forward_and_full_attention():
     """GPT-2's feed-forward scope and the decoders' full attention and
     kernels, and nothing of an expert layer."""
     fourth = per_layer_of(benchmark_spec(), cell_of(CONFIG))
     assert {"mlp_ms_per_step", "attn_full_ms_per_step", "attn_kernel_share",
-            "attn_kernel_ms_per_step", "fwd_bwd_unnamed_share"} <= fourth
+            "attn_kernel_ms_per_step", "fwd_bwd_unnamed_share",
+            "conv_kernel_share"} <= fourth
     assert not [m for m in fourth if m.startswith("moe_")]
     assert not fourth & {"attn_window_ms_per_step", "mlp_dense_ms_per_step"}

@@ -10,6 +10,7 @@ from decoder_cases import (  # noqa: F401 - collected here, for CONFIG
     benchmark_spec,
     cell_of,
     compiled,
+    kernel_phases,
     lowered,
     one_chip,
     per_layer_of,
@@ -21,8 +22,12 @@ from decoder_cases import (  # noqa: F401 - collected here, for CONFIG
 )
 
 CONFIG = "qwen3-next-80b-a3b"
-PIN = (1617018, "a72bda11e988512e")  # as PR 45 brought it
-OWN = ["gdn_chunk_ms_per_step", "gdn_chunks_per_step",
+# as PR 45 brought it 1,617,018 a72bda11e988512e; since PR 46 a linear
+# layer's convolution and its SiLU are one operation with a derivative rule
+# of its own (``parallel/ssm.py`` ``causal_conv1d_silu``), two kernels where
+# a pad, four slices and a SiLU stood, forward and differentiated by jax
+PIN = (1575977, "1fc29d592acdde1e")
+OWN = ["conv_kernel_share", "gdn_chunk_ms_per_step", "gdn_chunks_per_step",
        "gdn_conv_ms_per_step", "gdn_gate_ms_per_step", "gdn_peak_share",
        "gdn_proj_ms_per_step", "gdn_state_ms_per_step"]
 # 3 linear layers of 88,250,560 (the mixer 33,718,464), the full layer's
@@ -41,8 +46,12 @@ FITS_IN = 13.5 * 2**30
 # the one full layer's attention takes the fused kernels at heads of 256, 8
 # query heads to each of the 2 KV heads under tiles of 1,024: one forward
 # and one backward, the recomputed block keeps what forward made
-KERNELS = {"splash_mqa_fwd_residuals": 1, "splash_mqa_dkv_no_residuals": 1}
-ATTENTION_KERNELS = set(KERNELS)
+ATTENTION_KERNELS = {"splash_mqa_fwd_residuals", "splash_mqa_dkv_no_residuals"}
+# ... and a linear layer's convolution with its SiLU is one kernel forward,
+# once more in the block's recomputation and once more in its own checkpoint
+# (``models/deltanet.py``: for the L2 norms behind it), and one backward
+KERNELS = {**dict.fromkeys(ATTENTION_KERNELS, 1),
+           "tm_conv_silu_fwd": 9, "tm_conv_silu_bwd": 3}
 HOLDS = ("ragged-dot",)
 # not an instruction of XLA's own rematerialization
 HOLDS_NO = (r"\.remat",)
@@ -84,6 +93,23 @@ def test_the_gated_delta_cells_step_holds_its_state_by_chunks(compiled):
             "num_hidden_layers"]
 
 
+def test_the_gated_delta_cells_convolution_pads_nothing(compiled):
+    """A linear layer's convolution shifts its rows in VMEM
+    (``ops/conv_kernel.py``): no array of the sequence and the taps' reach
+    before it (``[1, 16387, 8192]``: the padded float32 copy of XLA's
+    lowering, 512 MiB, and its gradient) is in the compiled step, forward,
+    recomputed or backward; and the kernels' calls bear the scope the
+    expressions stood under, so ``gdn_conv_ms_per_step`` reads them: a
+    layer's forward, the block's recomputation with the mixer's own, and
+    backward."""
+    cfg, text = compiled.cfg, compiled.text
+    reach = cfg["sequence_length"] + cfg["linear_conv_kernel_dim"] - 1
+    assert reach == 16387 and not re.search(r"\[(1,)?%d," % reach, text)
+    scope = "tm.lm.gdn_conv"
+    assert kernel_phases(text, "tm_conv_silu") == {
+        (scope, "forward"): 3, (scope, "recompute"): 6, (scope, "backward"): 3}
+
+
 def test_the_gated_delta_cell_reads_what_the_shared_one_reads_but_the_window():
     """... and the dense layer's and the share of heads: its softmax layer is
     full, every layer has experts and every head is held; what it reads
@@ -95,8 +121,9 @@ def test_the_gated_delta_cell_reads_what_the_shared_one_reads_but_the_window():
         "attn_window_ms_per_step", "mlp_dense_ms_per_step",
         "attn_heads_held_share"}
     # every layer has experts, so the share of them that took the compact
-    # tier is read here, as in the two cells whose every layer has
-    assert eighth - third == {"moe_compact_share"} | {
+    # tier is read here, as in the two cells whose every layer has; the
+    # convolution's share of kernels it reads with the hybrid cell
+    assert eighth - third == {"moe_compact_share", "conv_kernel_share"} | {
         m["name"] for m in spec["per_layer"]
         if m["workloads"] == [cell_of(CONFIG)]}
     assert {m["layer"] for m in spec["per_layer"]

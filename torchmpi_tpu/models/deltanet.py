@@ -11,7 +11,7 @@ share no parameter shape, and those files' lowered steps are pinned letter
 for letter. What they share is called, not copied: attention is
 ``parallel.ring_attention.blocked_self_attention``, the rotation
 ``lm.rotary`` (over the first ``rotary_dim`` of a head), the convolution
-``parallel.ssm.causal_conv1d``, the sparse feed-forward half
+with its SiLU ``parallel.ssm.causal_conv1d_silu``, the sparse feed-forward half
 ``lm.sparse_feed_forward`` (``parallel.ep.moe_local_experts`` under it), the
 recomputation ``lm.recomputed``, the loss ``lm_head.VocabHead``'s, the
 scopes ``telemetry.names``'; the sequence operation is
@@ -69,7 +69,7 @@ from ..parallel.ring_attention import (
     blocked_self_attention,
     note_attention_step,
 )
-from ..parallel.ssm import causal_conv1d
+from ..parallel.ssm import causal_conv1d_silu, note_conv_step
 from ..telemetry import names as _names
 from .embedding import TokenEmbed
 from .lm import (
@@ -156,8 +156,8 @@ class GatedDeltaDecoderBlock(fnn.Module):
             """``q`` and ``k`` normalised and rounded as the rule's products
             take them, ``v`` float32, from the product's ``[q | k | v]``."""
             with jax.named_scope(_names.SCOPE_GDN_CONV):
-                qkv = jax.nn.silu(causal_conv1d(
-                    qkv, taps, jnp.zeros(taps.shape[1:], f32)))
+                qkv = causal_conv1d_silu(
+                    qkv, taps, jnp.zeros(taps.shape[1:], f32))
             q, k, v = jnp.split(qkv, [kw, 2 * kw], axis=-1)
             q = q.reshape(b, t, self.key_heads, self.key_dim)
             k = k.reshape(b, t, self.key_heads, self.key_dim)
@@ -169,9 +169,11 @@ class GatedDeltaDecoderBlock(fnn.Module):
                         v.reshape(b, t, self.value_heads, self.value_dim))
 
         # made again in backward from the bfloat16 product: kept, the
-        # float32 copy, the taps' sums, their SiLU and the normalised heads
-        # are arrays of [t, 8192] (2 GiB at 16,384) that outlive the rule's
-        # backward
+        # convolution's float32 result and the normalised heads are arrays
+        # of [t, 8192] (1 GiB at 16,384) that outlive the rule's backward.
+        # The convolution keeps nothing in float32 itself (its own
+        # derivative rule: ``causal_conv1d_silu``); what this spares is the
+        # L2 norms' operands, at one more pass of the forward kernel
         q, k, v = jax.checkpoint(convolved)(qkv, self.param(
             "conv_kernel", _taps_init, (self.conv_width, 2 * kw + vw), f32))
         with jax.named_scope(_names.SCOPE_GDN_GATE):
@@ -295,9 +297,12 @@ class GatedDeltaDecoder(fnn.Module):
         note_expert_layers(
             tokens.size, self.top_k, self.num_layers, len(self.held))
         note_attention_step()  # each full layer's call below counts itself
-        note_gdn_step(
-            sum(self.is_linear(i) for i in range(self.num_layers)), batch,
-            -(-t // self.chunk))
+        linear = sum(self.is_linear(i) for i in range(self.num_layers))
+        note_gdn_step(linear, batch, -(-t // self.chunk))
+        note_conv_step(
+            linear, (batch, t, 2 * self.key_heads * self.key_dim
+                     + self.value_heads * self.value_dim),
+            self.dtype, self.conv_width)
         with jax.named_scope(_names.SCOPE_LM_EMBED):
             x = TokenEmbed(
                 self.vocab_size, self.d_model, dtype=self.dtype, name="embed"

@@ -59,8 +59,9 @@ from ..parallel.ring_attention import (
     note_attention_step,
 )
 from ..parallel.ssm import (
-    causal_conv1d,
+    causal_conv1d_silu,
     gated_group_norm,
+    note_conv_step,
     note_ssm_step,
     ssd_chunked_scan,
 )
@@ -128,11 +129,11 @@ class HybridDecoderBlock(fnn.Module):
         with jax.named_scope(_names.SCOPE_SSM_CONV):
             # uniform within 1 / sqrt(taps), a depthwise kernel's fan-in
             taps = _uniform_init(1.0 / math.sqrt(self.conv_width))
-            xbc = jax.nn.silu(causal_conv1d(
+            xbc = causal_conv1d_silu(
                 xbc,
                 self.param("conv_kernel", taps,
                            (self.conv_width, inner + 2 * bc), f32),
-                self.param("conv_bias", taps, (inner + 2 * bc,), f32)))
+                self.param("conv_bias", taps, (inner + 2 * bc,), f32))
         with jax.named_scope(_names.SCOPE_SSM_SCAN):
             x, b_, c_ = jnp.split(xbc, [inner, inner + bc], axis=-1)
             delta = jax.nn.softplus(dt + self.param(
@@ -229,6 +230,11 @@ class HybridDecoder(fnn.Module):
         batch, t = tokens.shape
         note_ssm_step(self.num_layers * self.ssm_heads,
                       self.num_layers * batch * -(-t // self.chunk))
+        note_conv_step(
+            self.num_layers,
+            (batch, t, self.ssm_heads * self.ssm_head_dim
+             + 2 * self.ssm_groups * self.ssm_state),
+            jnp.float32, self.conv_width)
         with jax.named_scope(_names.SCOPE_LM_EMBED):
             x = (m.embedding * TokenEmbed(
                 self.vocab_size, self.d_model, dtype=jnp.float32,

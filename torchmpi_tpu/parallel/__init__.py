@@ -19,7 +19,12 @@ from .ring_attention import (
     ring_self_attention,
 )
 from .selected_attention import selected_self_attention
-from .ssm import causal_conv1d, gated_group_norm, ssd_chunked_scan
+from .ssm import (
+    causal_conv1d,
+    causal_conv1d_silu,
+    gated_group_norm,
+    ssd_chunked_scan,
+)
 from .tp import MPLinear, MPLinearOutputSplit, shard_input_features
 
 __all__ = [
@@ -37,6 +42,7 @@ __all__ = [
     "blocked_self_attention",
     "selected_self_attention",
     "causal_conv1d",
+    "causal_conv1d_silu",
     "ssd_chunked_scan",
     "gated_group_norm",
     "power_retention",

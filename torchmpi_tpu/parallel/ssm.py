@@ -1,8 +1,11 @@
 """A state-space mixer's sequence operations (Mamba-2, arXiv:2405.21060)
 for heads held by share: the causal depthwise convolution over time, the
 selective state-space recurrence computed as the state-space dual's chunked
-scan, and the gated RMSNorm by groups of channels. XLA operations
-throughout; the gradients are jax's own of these.
+scan, and the gated RMSNorm by groups of channels. XLA operations, and the
+gradients jax's own of these, but for the convolution with its SiLU
+(``causal_conv1d_silu``): one operation under a derivative rule of its own,
+two Pallas kernels (``ops/conv_kernel.py``) where the program is lowered for
+a TPU and the shapes are whole tiles of enough channels.
 
 The recurrence, a head of ``P`` channels with a state ``[P, N]``, its
 group's ``B_t`` and ``C_t`` ``[N]``, ``delta_t > 0`` and ``A < 0`` one
@@ -31,6 +34,7 @@ group's heads lie on several devices, ``gated_group_norm`` takes the
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -38,6 +42,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .. import telemetry as _telemetry
+from ..ops import conv_kernel as _conv_kernel
 from ..telemetry import names as _names
 
 
@@ -66,6 +71,77 @@ def causal_conv1d(x, kernel, bias):
     for j in range(k):
         y = y + kernel[j].astype(jnp.float32) * padded[:, j:j + t]
     return y
+
+
+def note_conv_step(layers: int, shape, dtype, taps: int) -> None:
+    """Set, from static shapes while a step is traced, the elements that go
+    through ``causal_conv1d_silu`` (``layers`` calls over ``shape`` ``[b, t,
+    c]`` of ``dtype``) and those of them whose shapes take the kernels,
+    traced where jax's backend is a TPU (as ``tm_attn_kernel_calls_per_step``:
+    the platform of the lowering is not known yet)."""
+    elements = layers * math.prod(shape)
+    kernels = (_conv_kernel.takes(shape, dtype, taps)
+               and jax.default_backend() == "tpu")
+    _telemetry.metrics.gauge(
+        _names.GAUGE_CONV_ELEMENTS,
+        "elements (layers x sequences x positions x channels) that go "
+        "through causal_conv1d_silu in the step most recently traced").set(
+            elements)
+    _telemetry.metrics.gauge(
+        _names.GAUGE_CONV_KERNEL_ELEMENTS,
+        "those of tm_conv_elements_per_step whose shapes take the fused "
+        "kernels (whole tiles of positions, whole lanes), traced where "
+        "jax's backend is a TPU").set(elements if kernels else 0)
+
+
+def _conv_silu_plain(x, kernel, bias):
+    return jax.nn.silu(causal_conv1d(x, kernel, bias))
+
+
+@jax.custom_vjp
+def _conv_silu(x, kernel, bias):
+    return lax.platform_dependent(
+        x, kernel, bias, tpu=_conv_kernel.forward, default=_conv_silu_plain)
+
+
+def _conv_silu_fwd(x, kernel, bias):
+    return _conv_silu(x, kernel, bias), (x, kernel, bias)
+
+
+def _conv_silu_bwd(saved, dy):
+    def kernels(x, kernel, bias, dy):
+        dx, dtaps, dbias = _conv_kernel.backward(x, kernel, bias, dy)
+        return dx, dtaps.astype(kernel.dtype), dbias.astype(bias.dtype)
+
+    def plain(x, kernel, bias, dy):
+        return jax.vjp(_conv_silu_plain, x, kernel, bias)[1](dy)
+
+    return lax.platform_dependent(*saved, dy, tpu=kernels, default=plain)
+
+
+_conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def causal_conv1d_silu(x, kernel, bias):
+    """``jax.nn.silu(causal_conv1d(x, kernel, bias))``, float32, as one
+    operation in one of two executions chosen by what the call can observe.
+
+    Where ``x``'s positions are whole tiles and its channels whole lanes
+    and 2,048 or more (``ops/conv_kernel.py`` ``takes``; the width is what
+    was measured in a step on the chip): a derivative rule of its own that
+    keeps ``x`` as it came, the taps and the bias, and nothing of ``[t, c]``
+    in float32. Lowered for a TPU it is two kernels, forward and backward,
+    each reading its operands from HBM once and writing its results once:
+    the shifts are made in VMEM, the pre-activation again in backward, ``dx``
+    rounded to ``x``'s dtype, the taps' and the bias's gradients summed in
+    float32. Lowered for anything else the rule runs the expressions above
+    and jax's derivative of them (``lax.platform_dependent``: the platform
+    the program is lowered for, as ``blocked_self_attention``). Other
+    shapes (an odd length, channels that fill no lane, ``falcon-h1-34b``'s
+    1,024): the expressions, differentiated by jax."""
+    if not _conv_kernel.takes(x.shape, x.dtype, kernel.shape[0]):
+        return _conv_silu_plain(x, kernel, bias)
+    return _conv_silu(x, kernel, bias)
 
 
 def ssd_chunked_scan(x, dt, a, b, c, d, chunk: int = 128, dtype=None):

@@ -245,6 +245,14 @@ GAUGE_EMBED_GRAD_SORTED_ROWS = "tm_embed_grad_sorted_rows_per_step"
 # step's tokens
 GAUGE_LM_HEAD_BLOCKED_ROWS = "tm_lm_head_blocked_rows_per_step"
 GAUGE_LM_HEAD_BLOCKS = "tm_lm_head_blocks_per_step"
+# -- the gauges parallel/ssm.py ``note_conv_step`` sets the same way for
+# models/hybrid.py and models/deltanet.py: the elements (layers x sequences
+# x positions x channels) that go through ``causal_conv1d_silu``, and those
+# of them whose shapes take the fused kernels of ``ops/conv_kernel.py`` on a
+# TPU. The benchmark's ``conv_kernel_share`` reads the second against the
+# first
+GAUGE_CONV_ELEMENTS = "tm_conv_elements_per_step"
+GAUGE_CONV_KERNEL_ELEMENTS = "tm_conv_kernel_elements_per_step"
 
 # -- what a device trace calls the attention kernels (an event's name is
 # the kernel's HLO instruction): jax's splash attention in
