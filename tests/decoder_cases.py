@@ -30,6 +30,15 @@ part, and says
 - ``HOLDS`` and ``HOLDS_NO``: patterns the compiled text must and must not
   match.
 
+A file whose model's products bear names (``models.lm.product``) also imports
+``test_the_cells_step_keeps_the_products_the_rule_counted`` and says
+``NOTHING_KEPT``: the temporaries' bytes of the same step compiled with no
+product kept (``python3 scripts/recompute_probe.py <config> --keep none
+--compile``), and ``PRODUCTS``: the products (``convolution``s) of the
+compiled step and of that one. The step is lowered with the described
+chip's memory in the place of this process's (``models.lm.device_bytes``,
+the one function that reads it), so the program compiled here is the chip's.
+
 What a configuration alone has (its operation count, its published widths,
 the shapes only its step can hold) are cases of its own in those files, which
 may use everything here. ``tests/conftest.py`` registers this module for
@@ -148,10 +157,26 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+V5E_BYTES = 16_909_336_064  # a v5e's memory as its runtime reports it
+#                            (``bytes_limit``: my chip run, PR 47)
+
+
 def lowered_step(config, one_chip):
     """(cfg, the parameters' shapes, the cell's training step lowered for
-    the described chip)."""
+    the described chip, what ``models.lm.kinds_kept`` was asked and said
+    while it was traced: ``limit``, ``parameters``, ``beside``, ``kinds``,
+    ``kept``). The rule reads the described chip's memory, not this
+    process's, which has none."""
     from benchmark import configs
+    from torchmpi_tpu.models import lm
+
+    rule, kinds_kept = {}, lm.kinds_kept
+
+    def recorded(limit, parameters, beside, kinds):
+        kept = kinds_kept(limit, parameters, beside, kinds)
+        rule.update(limit=limit, parameters=parameters, beside=beside,
+                    kinds=kinds, kept=kept)
+        return kept
 
     cfg = configs.load(config)
     built = configs.build(config, cfg)
@@ -171,9 +196,14 @@ def lowered_step(config, one_chip):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         tree)
     tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one_chip)
-    return cfg, params, jax.jit(step, donate_argnums=(0, 1, 2)).lower(
-        place(params), place(jax.eval_shape(built.optimizer.init, params)),
-        place(state), (tokens, tokens))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(lm, "device_bytes", lambda: V5E_BYTES)
+        patch.setattr(lm, "kinds_kept", recorded)
+        step = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+            place(params),
+            place(jax.eval_shape(built.optimizer.init, params)),
+            place(state), (tokens, tokens))
+    return cfg, params, step, rule
 
 
 def compile_uncached(lowered):
@@ -197,16 +227,17 @@ class Compiled(NamedTuple):
     parameters: int  # how many the step trains
     step: Any        # the compiled step
     text: str        # ... and its text
+    rule: dict       # what the rule of ``models.lm`` counted and kept
 
 
 @pytest.fixture(scope="module")
 def compiled(lowered):
     """The file's step compiled, once for the cases that read it."""
-    cfg, params, step = lowered
+    cfg, params, step, rule = lowered
     step = compile_uncached(step)
     return Compiled(
         cfg, sum(a.size for a in jax.tree_util.tree_leaves(params)), step,
-        step.as_text())
+        step.as_text(), rule)
 
 
 def kernel_calls(text):
@@ -331,6 +362,41 @@ def test_the_cells_step_lowers_for_the_chip_to_the_text_it_had(
     text = re.sub(r"backend_config = \{[^\n]*", "", text)
     assert (len(text), hashlib.sha256(text.encode()).hexdigest()[:16]) == (
         request.module.PIN)
+
+
+GIB = 2**30
+STEP_FITS_IN = 14.0 * GIB  # the compiler rematerialized by itself from
+#                            14.54 GiB on (``tests/test_chip_qwen3-next-
+#                            80b-a3b.py``): what every step stays under
+
+
+def test_the_cells_step_keeps_the_products_the_rule_counted(
+        request, compiled):
+    """What ``models.lm``'s rule kept of the recomputed blocks' dense
+    products is in the compiled step at the bytes it counted: the
+    temporaries rise over ``NOTHING_KEPT`` (the same step compiled with no
+    product kept) by no more than the kept kinds' bytes and a tenth; the
+    rule's estimate of the step with nothing kept is no smaller than the
+    compiler's count of that step; and arguments and temporaries together
+    stay at or under 14.0 GiB with no rematerialization of the compiler's
+    own. And one product where there were two: the step's products
+    (``PRODUCTS``: its ``convolution``s, and those of the step with nothing
+    kept) are fewer by what backward no longer makes again, and as many
+    where the rule kept nothing."""
+    rule, memory = compiled.rule, compiled.step.memory_analysis()
+    made, made_again = request.module.PRODUCTS
+    assert compiled.text.count(" convolution(") == made, rule["kept"]
+    assert (made < made_again) if rule["kept"] else (made == made_again)
+    assert rule["limit"] == V5E_BYTES and rule["kinds"]
+    counted = sum(rule["kinds"][k][0] for k in rule["kept"])
+    nothing = request.module.NOTHING_KEPT
+    assert memory.temp_size_in_bytes - nothing <= 1.1 * counted, (
+        memory, counted)
+    assert 4 * rule["parameters"] + rule["beside"] >= (
+        memory.argument_size_in_bytes + nothing), rule
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            <= STEP_FITS_IN), memory
+    assert ".remat" not in compiled.text
 
 
 def per_layer_of(spec, cell):

@@ -15,6 +15,7 @@ from decoder_cases import (  # noqa: F401 - collected here, for CONFIG
     per_layer_of,
     row_scatters,
     test_the_cells_step_fits_the_chip,
+    test_the_cells_step_keeps_the_products_the_rule_counted,
     test_the_cells_step_lowers_for_the_chip_to_the_text_it_had,
     test_the_configuration_is_a_cell_of_the_benchmark,
     whole_logits,
@@ -28,8 +29,10 @@ CONFIG = "brumby-14b"
 # before); since PR 43 the head and its loss are one function with a
 # derivative rule of its own (``models/lm_head.py``), a loop over blocks of
 # 8,192 rows where the float32 logits of every row stood (894,277
-# 8eae3c7ec1685bf8 before)
-PIN = (896533, "0fa665a951ba9d9a")
+# 8eae3c7ec1685bf8 before); since PR 47 a block's backward reads the
+# feed-forward's gate and up products' results, kept by ``models.lm``'s
+# rule, and makes neither again (896,533 0fa665a951ba9d9a before)
+PIN = (895853, "8b35cad78cee94c3")
 OWN = ["retention_chunk_ms_per_step", "retention_gate_ms_per_step",
        "retention_heads_held_share", "retention_peak_share",
        "retention_state_ms_per_step"]
@@ -40,8 +43,14 @@ PARAMETERS = (4 * 41_303_297 + 2 * 18992 * 5120 + 5120,) * 2
 # their gradient were arrays, 2.32 GiB each: the head walks them by blocks
 # of 8,192 rows since PR 43), at 1 x 32,768 (not the fallback of 16,384)
 # under the 15.0 GiB ISSUE 41 set; the limit is what was measured and a
-# margin
-FITS_IN = 10.5 * 2**30
+# margin; 11.04 GiB since the blocks keep the feed-forward's gate and up
+# products' results (PR 47, on purpose: 2 x 136 MiB a layer, 1.06 GiB over
+# 4, the temporaries 5.954 -> 7.016 GiB)
+FITS_IN = 11.5 * 2**30
+# the temporaries of the step with no product kept (5.954 GiB;
+# ``scripts/recompute_probe.py brumby-14b --keep none --compile``)
+NOTHING_KEPT = 6_392_994_304
+PRODUCTS = (180, 188)  # 2 a layer fewer: the gate and the up
 KERNELS = {}  # no attention kernel at all: no ``tpu_custom_call``
 ATTENTION_KERNELS = set()
 HOLDS = ()
@@ -81,6 +90,19 @@ def test_the_retentive_cells_step_holds_its_state_by_chunks(compiled):
     # ... one over the blocks of the embedding's sorted gradient rows and
     # one over the head's blocks of rows
     assert text.count(" while(") == 3 * layers + 2
+
+
+def test_the_retentive_cells_step_makes_its_gate_and_up_products_once(
+        compiled):
+    """One product where there were two: of the ``[32768, 2176]`` results
+    a layer's products make (the compiled step's ``convolution``s), the
+    gate's and the up's forward and the gradient of their product's input
+    in backward, 3 a layer; made again with the block they were 5. Both
+    kinds the file names are kept: all it has."""
+    layers = compiled.cfg["num_hidden_layers"]
+    assert compiled.rule["kept"] == ("tm_kept_mlp_gate", "tm_kept_mlp_up")
+    assert len(re.findall(
+        r"= bf16\[32768,2176\]\S* convolution\(", compiled.text)) == 3 * layers
 
 
 def test_the_retentive_cell_reads_what_the_hybrid_one_reads_but_attention():

@@ -11,10 +11,15 @@ from decoder_cases import (  # noqa: F401 - fixtures, for CONFIG
     kernel_calls,
     lowered,
     one_chip,
+    test_the_cells_step_keeps_the_products_the_rule_counted,
     whole_logits,
 )
 
 CONFIG = "gpt2-medium"
+# the temporaries of the step with no product kept (2.641 GiB: PR 43's
+# program; ``scripts/recompute_probe.py gpt2-medium --keep none --compile``)
+NOTHING_KEPT = 2_835_630_592
+PRODUCTS = (291, 363)  # 3 a layer fewer: ``qkv``, ``o``, the hidden
 
 
 def test_gpt2s_step_holds_its_attention_in_the_kernels(compiled):
@@ -22,9 +27,10 @@ def test_gpt2s_step_holds_its_attention_in_the_kernels(compiled):
     and one backward (the block's recomputation keeps the forward kernel's
     output and log-sum-exp, ``ring_attention.SAVED``, and does not run it
     again), with heads of 64 and one tile of 1,024; no ``[b, h, t, t]``
-    array of any type is left, and the step's temporaries, the kept
-    0.39 GiB among them, are no larger than when nothing was kept; nor is
-    an ``[8, 1024, 50257]`` array of logits left (``whole_logits``)."""
+    array of any type is left; the step's temporaries are the 2.64 GiB of
+    the step that kept the kernels' 0.39 GiB alone and the 3.0 GiB of the
+    three kinds of products' results the rule keeps here, all it names; nor
+    is an ``[8, 1024, 50257]`` array of logits left (``whole_logits``)."""
     from torchmpi_tpu.telemetry import names
 
     cfg, text = compiled.cfg, compiled.text
@@ -41,7 +47,14 @@ def test_gpt2s_step_holds_its_attention_in_the_kernels(compiled):
     # ... and 2.641 GiB (2,835,630,592 B) since the head's own rule
     # (PR 43): the float32 logits were 1.53 GiB an array, a block of 4,096
     # rows is 0.77
-    assert memory.temp_size_in_bytes <= 3_641_704_448, memory
+    # ... and 5.649 GiB (6,065,081,344 B) since the blocks keep their
+    # products' results (PR 47, on purpose: ``qkv`` 48 MiB, the residual
+    # after ``o`` 16 and the feed-forward's hidden 64 a layer, 3.0 GiB over
+    # 24, the bytes counted to the MiB; 10.19 GiB with the arguments): the
+    # bound is what was measured and a margin
+    assert memory.temp_size_in_bytes <= 6_100_000_000, memory
+    assert compiled.rule["kept"] == (
+        "tm_kept_mlp_gate", "tm_kept_qkv", "tm_kept_residual")
     assert not whole_logits(text, cfg)
     kernels = kernel_calls(text)
     layers = cfg["model"]["n_layer"]
@@ -60,3 +73,17 @@ def test_gpt2s_step_holds_its_attention_in_the_kernels(compiled):
     shapes = {tuple(int(d) for d in dims.split(","))
               for dims in re.findall(r"\w+\[([\d,]+)\]", text)}
     assert not [s for s in shapes if len(s) >= 4 and min(s[-2:]) >= 512]
+
+
+def test_gpt2s_step_makes_each_kept_product_once(compiled):
+    """One product where there were two: by the result's shape, the
+    products of the compiled step (its ``convolution``s). ``qkv`` forward
+    alone (it was made again with the block: 2 a layer); the feed-forward's
+    hidden forward and, in backward, its gradient (3 with the block's); of
+    the ``[8, 1024, 1024]`` results the ``o`` product's second goes (6 a
+    layer: 5): 72 products fewer, 363 -> 291."""
+    layers = compiled.cfg["model"]["n_layer"]
+    made = lambda shape: len(re.findall(  # noqa: E731
+        r"= bf16\[8,1024,%d\]\S* convolution\(" % shape, compiled.text))
+    assert (made(3072), made(4096), made(1024)) == (
+        layers, 2 * layers, 5 * layers)

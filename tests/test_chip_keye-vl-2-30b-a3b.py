@@ -16,6 +16,7 @@ from decoder_cases import (  # noqa: F401 - collected here, for CONFIG
     outside_fusions,
     per_layer_of,
     test_the_cells_step_fits_the_chip,
+    test_the_cells_step_keeps_the_products_the_rule_counted,
     test_the_cells_step_lowers_for_the_chip_to_the_text_it_had,
     test_the_configuration_is_a_cell_of_the_benchmark,
 )
@@ -30,7 +31,9 @@ CONFIG = "keye-vl-2-30b-a3b"
 # PR 43 the head and its loss are one function with a derivative rule of its
 # own (``models/lm_head.py``), a loop over blocks of 8,192 rows where the
 # float32 logits of every row stood (1,248,452 47bf5842f8ac3442 before)
-PIN = (1250741, "fe66531536376d56")
+# since PR 47 a block's backward reads the router's logits, the one kind
+# ``models.lm``'s rule keeps here (1,250,741 fe66531536376d56 before)
+PIN = (1250467, "75a91119112db72c")
 OWN = ["attn_index_kernel_ms_per_step", "attn_index_loss",
        "attn_index_ms_per_step", "attn_select_ms_per_step",
        "attn_selected_pair_share", "attn_sparse_kernel_roofline",
@@ -41,6 +44,13 @@ PARAMETERS = (465e6, 466e6)  # 4 layers of 96.9 M + 77.8 M of vocabulary
 # 2.12 GiB are the four layers' kept panels of index scores (5.01 GiB
 # without them, with a second run of their kernel)
 FITS_IN = 12.6 * 2**30
+# the temporaries of the step with no product kept, 11.65 GiB with the
+# arguments (``scripts/recompute_probe.py keye-vl-2-30b-a3b --keep none
+# --compile``); the rule's estimate of that step is 13.79 GiB, so of the
+# four kinds the file names it keeps the router's logits alone (32 MiB:
+# the indexer's projections, 0.31 GiB, would pass 14.0 by the estimate)
+NOTHING_KEPT = 6_927_643_648
+PRODUCTS = (115, 119)  # the router's product once a layer
 # every piece of the selected attention is a kernel of the repo's own (none
 # of jax's splash kernels is left); the index scores, the selection and both
 # forward kernels run once a step (the layer's recomputation keeps what they

@@ -11,6 +11,7 @@ from decoder_cases import (  # noqa: F401 - collected here, for CONFIG
     one_chip,
     per_layer_of,
     test_the_cells_step_fits_the_chip,
+    test_the_cells_step_keeps_the_products_the_rule_counted,
     test_the_cells_step_lowers_for_the_chip_to_the_text_it_had,
     test_the_configuration_is_a_cell_of_the_benchmark,
     two_tiers,
@@ -26,14 +27,25 @@ CONFIG = "laguna-s-2-1"
 # PR 43 the head and its loss are one function with a derivative rule of its
 # own (``models/lm_head.py``), a loop over blocks of 8,192 rows where the
 # float32 logits of every row stood (2,943,293 162f73ace4dfee71 before)
-PIN = (2945541, "df2d658f547a19f3")
+# since PR 47 a block's backward reads the results of the products the file
+# names, every kind of them kept by ``models.lm``'s rule, and makes none of
+# them again (2,945,541 df2d658f547a19f3 before)
+PIN = (2942474, "6f336c7eb11b63b6")
 OWN = ["attn_gate_ms_per_step", "attn_heads_held_share",
        "mlp_dense_ms_per_step", "moe_shared_ms_per_step"]
 PARAMETERS = (468.8e6, 469.0e6)  # 19.7 + 3 x 93.6 + 91.3 + 77.1 M
 # 12 B a parameter of state and 5.04 GiB of temporaries measured here
 # (10.29 GiB; 10.16 before the five layers' attention outputs and
 # log-sum-exps were kept), at 1 x 16,384 (not the fallback of 8,192)
-FITS_IN = 11 * 2**30
+# ... and 10.93 GiB since the blocks keep every kind the file names (PR 47:
+# the router's logits, ``q``, ``k``, ``v``, the head gate's columns, the
+# residual after ``o``, the shared expert's and the dense layer's gate and
+# up: 1.086 GiB counted, the temporaries 4.833 -> 5.693 GiB)
+FITS_IN = 11.5 * 2**30
+# the temporaries of the step with no product kept
+# (``scripts/recompute_probe.py laguna-s-2-1 --keep none --compile``)
+NOTHING_KEPT = 5_189_658_624
+PRODUCTS = (135, 174)
 # every layer's attention takes the fused kernels with 6 or 9 query heads to
 # the one KV head and a window of 512 under tiles of 1,024: one forward and
 # one backward a layer

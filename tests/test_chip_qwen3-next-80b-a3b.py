@@ -15,6 +15,7 @@ from decoder_cases import (  # noqa: F401 - collected here, for CONFIG
     one_chip,
     per_layer_of,
     test_the_cells_step_fits_the_chip,
+    test_the_cells_step_keeps_the_products_the_rule_counted,
     test_the_cells_step_lowers_for_the_chip_to_the_text_it_had,
     test_the_configuration_is_a_cell_of_the_benchmark,
     two_tiers,
@@ -26,7 +27,11 @@ CONFIG = "qwen3-next-80b-a3b"
 # layer's convolution and its SiLU are one operation with a derivative rule
 # of its own (``parallel/ssm.py`` ``causal_conv1d_silu``), two kernels where
 # a pad, four slices and a SiLU stood, forward and differentiated by jax
-PIN = (1575977, "1fc29d592acdde1e")
+# since PR 47 the model traces its two kinds of block once before the step
+# (``models.lm.products_kept``) and keeps none of their products here: the
+# same operations line for line, the numbers in the private functions' names
+# (``@_where_50`` -> ``@_where_51``) moved, 2,326 lines of 1fc29d592acdde1e's
+PIN = (1575977, "09af15f9c8ccb081")
 OWN = ["conv_kernel_share", "gdn_chunk_ms_per_step", "gdn_chunks_per_step",
        "gdn_conv_ms_per_step", "gdn_gate_ms_per_step", "gdn_peak_share",
        "gdn_proj_ms_per_step", "gdn_state_ms_per_step"]
@@ -43,6 +48,13 @@ PARAMETERS = (3 * 88_250_560 + 81_795_584 + 2 * 18992 * 2048 + 2048,) * 2
 # 13.13 with the normalised heads made again with it; 13.26 with the
 # triangular solve the chip ran faster.)
 FITS_IN = 13.5 * 2**30
+# the temporaries of the step with no product kept: this step, for the
+# rule's estimate of it is 14.5 GiB, over the 14.0 it may fill, and it
+# keeps none of the seven kinds the file names (PR 47; with the router's
+# logits and the residual kept, 0.375 GiB, the compiler's buffers came to
+# 11.11 GiB, 2 GiB LESS than with nothing kept: PERF.md section 7)
+NOTHING_KEPT = 9_005_765_120
+PRODUCTS = (230, 230)
 # the one full layer's attention takes the fused kernels at heads of 256, 8
 # query heads to each of the 2 KV heads under tiles of 1,024: one forward
 # and one backward, the recomputed block keeps what forward made

@@ -11,6 +11,7 @@ from decoder_cases import (  # noqa: F401 - collected here, for CONFIG
     lowered,
     one_chip,
     test_the_cells_step_fits_the_chip,
+    test_the_cells_step_keeps_the_products_the_rule_counted,
     test_the_cells_step_lowers_for_the_chip_to_the_text_it_had,
     test_the_configuration_is_a_cell_of_the_benchmark,
     two_tiers,
@@ -27,7 +28,10 @@ CONFIG = "smallthinker-21b-a3b"
 # one function with a derivative rule of its own (``models/lm_head.py``), a
 # loop over blocks of 8,192 rows where the float32 logits of every row stood
 # (1,498,767 6eca475fac54c18e before)
-PIN = (1501317, "3f936926817e2f53")
+# since PR 47 a block's backward reads the router's logits, ``q``, ``k``,
+# ``v`` and the residual after ``o``, kept by ``models.lm``'s rule, and makes
+# none of them again (1,501,317 3f936926817e2f53 before)
+PIN = (1499696, "81fc109608a4f86c")
 OWN = ["attn_full_ms_per_step", "attn_kernel_ms_per_step",
        "attn_kernel_share", "attn_window_ms_per_step", "moe_compact_share",
        "moe_experts_ms_per_step", "moe_grouped_rows_per_step",
@@ -41,7 +45,14 @@ PARAMETERS = (370e6, 371e6)  # 4 layers of 68.3 M + 97.2 M of vocabulary
 # array is dQ's 8 parts, 1.75 GiB) measured 3.69 GiB here, 7.84 GiB in all
 # (2.98 and 7.12 before the four layers' attention outputs, 112 MiB each,
 # and log-sum-exps were kept): well inside the chip's 15.75 GiB
+# ... and 8.28 GiB since the blocks keep the router's logits, ``q``, ``k``,
+# ``v`` and the residual after ``o`` (PR 47: 0.906 GiB counted, the
+# temporaries 3.475 -> 4.140 GiB)
 FITS_IN = 9 * 2**30
+# the temporaries of the step with no product kept
+# (``scripts/recompute_probe.py smallthinker-21b-a3b --keep none --compile``)
+NOTHING_KEPT = 3_730_833_920
+PRODUCTS = (64, 84)  # 5 a layer fewer: the router, ``q``, ``k``, ``v``, ``o``
 # a lowering for the TPU takes the fused attention kernels, though this
 # process's backend is the CPU: in each of the 4 layers one forward and one
 # backward, under the name the benchmark's reader looks for

@@ -208,7 +208,8 @@ def test_the_share_is_the_second_gauge_over_the_first(monkeypatch):
     from benchmark import configs
 
     spec = json.loads((configs.HERE.parents[1] / "BENCHMARK.json").read_text())
-    entry = spec["per_layer"][-1]
+    entry = next(
+        m for m in spec["per_layer"] if m["name"] == "conv_kernel_share")
     assert entry == {
         "name": "conv_kernel_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "gated delta rule",

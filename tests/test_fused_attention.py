@@ -221,15 +221,19 @@ def test_a_head_the_kernels_do_not_take_names_nothing_to_keep():
 def test_the_models_recomputation_is_inert_where_the_loops_run(
         config, monkeypatch):
     """The three model families at their rehearsal sizes on the CPU (heads
-    of 16 and 32: the loops, nothing named): through ``recomputed`` the
+    of 16 and 32: the loops, nothing of the attention's named), on a device
+    with no room for a product's result (``models.lm``'s rule keeps none:
+    what it keeps where there is room is ``tests/test_lm_recompute.py``'s):
+    through ``recomputed`` the
     loss and every leaf of the gradient are, bit for bit, those of a
     recomputation with no policy (``fnn.remat`` alone, every model's
     spelling before), and those of ``remat=False`` as closely as any
     recomputation's are (XLA rounds a block it makes again at other places
     than the one it ran forward: bits differ there with no policy too)."""
     from benchmark import configs
-    from torchmpi_tpu.models import decoder, hybrid, transformer
+    from torchmpi_tpu.models import decoder, hybrid, lm, transformer
 
+    monkeypatch.setattr(lm, "device_bytes", lambda: 1)
     cfg = configs.load(config, rehearse=True)
     assert cfg["remat"] is True
     ids = jax.random.randint(
@@ -249,7 +253,9 @@ def test_the_models_recomputation_is_inert_where_the_loops_run(
     loss, grads = loss_and_gradient(True)
     want_loss, want = loss_and_gradient(False)
     for module in (transformer, decoder, hybrid):
-        monkeypatch.setattr(module, "recomputed", fnn.remat)
+        monkeypatch.setattr(
+            module, "recomputed", lambda block_cls, keep=(): fnn.remat(
+                block_cls))
     plain_loss, plain = loss_and_gradient(True)
     assert float(loss) == float(plain_loss) and np.isfinite(float(loss))
     leaves, treedef = jax.tree_util.tree_flatten(grads)

@@ -82,7 +82,20 @@ from ..parallel.selected_attention import (
 )
 from ..telemetry import names as _names
 from .embedding import TokenEmbed
-from .lm import recomputed, rotary, sparse_feed_forward
+from .lm import (
+    HEAD_GATE,
+    INDEX,
+    MLP_GATE,
+    MLP_UP,
+    QKV,
+    RESIDUAL,
+    ROUTER,
+    product,
+    products_kept,
+    recomputed,
+    rotary,
+    sparse_feed_forward,
+)
 from .lm_head import VocabHead
 
 
@@ -174,16 +187,17 @@ class MoEDecoderBlock(fnn.Module):
     def _indexer(self, h):
         """The indexer's queries, keys and head weights from the normed
         input, detached from the model: float32, precision highest."""
-        b, t, _ = h.shape
-        exact = lambda n, name: fnn.Dense(  # noqa: E731
+        b, t, d = h.shape
+        exact = lambda n, name: product(fnn.Dense(  # noqa: E731
             n, use_bias=False, dtype=jnp.float32,
-            precision=lax.Precision.HIGHEST, name=name)
+            precision=lax.Precision.HIGHEST, name=name)(h), INDEX, d,
+            passes=6)
         h = lax.stop_gradient(h).astype(jnp.float32)
-        index_q = exact(self.index_heads * self.index_dim, "index_q")(h)
+        index_q = exact(self.index_heads * self.index_dim, "index_q")
         index_k = fnn.LayerNorm(
             epsilon=self.norm_eps, dtype=jnp.float32, name="index_k_norm"
-        )(exact(self.index_dim, "index_k")(h))
-        index_w = exact(self.index_heads, "index_w")(h)
+        )(exact(self.index_dim, "index_k"))
+        index_w = exact(self.index_heads, "index_w")
         index_q = index_q.reshape(b, t, self.index_heads, self.index_dim)
         if self.rope_theta is not None:
             index_q = rotary(index_q, self.rope_theta)
@@ -202,25 +216,29 @@ class MoEDecoderBlock(fnn.Module):
         def gated(m, width, name):
             """``(act(m W_gate) * (m W_up)) W_down``, ``width`` columns."""
             return dense(d, name + "_down")(
-                self.activation(dense(width, name + "_gate")(m))
-                * dense(width, name + "_up")(m))
+                self.activation(product(
+                    dense(width, name + "_gate")(m), MLP_GATE, d))
+                * product(dense(width, name + "_up")(m), MLP_UP, d))
 
         sparse = self.dense_width is None
         norm = lambda name: fnn.RMSNorm(  # noqa: E731
             epsilon=self.norm_eps, dtype=jnp.float32, name=name)
         if sparse and not self.router_after_norm:
             with jax.named_scope(_names.SCOPE_MOE_ROUTER):
-                logits = fnn.Dense(
+                logits = product(fnn.Dense(
                     self.num_experts, use_bias=False, dtype=jnp.float32,
                     precision=lax.Precision.HIGHEST, name="router"
-                )(x.astype(jnp.float32))
+                )(x.astype(jnp.float32)), ROUTER, d, passes=6)
 
         with jax.named_scope(_names.SCOPE_LM_NORM):
             h = norm("norm_attn")(x)
         with jax.named_scope(_names.SCOPE_ATTN_PROJ):
-            q = dense(self.num_heads * self.head_dim, "q")(h)
-            k = dense(self.num_kv_heads * self.head_dim, "k")(h)
-            v = dense(self.num_kv_heads * self.head_dim, "v")(h)
+            # named flat, before the reshape to heads
+            q = product(dense(self.num_heads * self.head_dim, "q")(h), QKV, d)
+            k = product(
+                dense(self.num_kv_heads * self.head_dim, "k")(h), QKV, d)
+            v = product(
+                dense(self.num_kv_heads * self.head_dim, "v")(h), QKV, d)
         # the reshapes on either side of the attention stand under no
         # scope: XLA merges one with the attention's own reshape next to it
         # into a single copy that bears both op_names, and a name here
@@ -235,8 +253,9 @@ class MoEDecoderBlock(fnn.Module):
                 k = norm("k_norm")(k).astype(self.dtype)
         if self.head_gate:
             with jax.named_scope(_names.SCOPE_ATTN_GATE):
-                gate = jax.nn.sigmoid(
-                    dense(self.num_heads, "head_gate")(h).astype(jnp.float32))
+                gate = jax.nn.sigmoid(product(
+                    dense(self.num_heads, "head_gate")(h), HEAD_GATE, d
+                ).astype(jnp.float32))
         index_loss = pairs = jnp.float32(0.0)
         selected = self.index_top_k is not None
         if selected:
@@ -261,7 +280,7 @@ class MoEDecoderBlock(fnn.Module):
                 attn = (attn * gate[..., None]).astype(attn.dtype)
         attn = attn.reshape(b, t, -1)
         with jax.named_scope(_names.SCOPE_ATTN_PROJ):
-            x = x + dense(d, "o")(attn)
+            x = product(x + dense(d, "o")(attn), RESIDUAL, attn.shape[-1])
 
         with jax.named_scope(_names.SCOPE_LM_NORM):
             h = norm("norm_moe")(x)
@@ -330,7 +349,9 @@ class MoEDecoder(fnn.Module):
     dense_layers: int = 0
     dense_width: int = 0
     remat: bool = False  # recompute each block in backward, but for what
-    #                      its attention's forward kernels kept: ``recomputed``
+    #                      its attention's forward kernels kept and the
+    #                      products' results the step has room for:
+    #                      ``recomputed``, ``products_kept``
     dtype: Any = jnp.float32
 
     def selects(self, i: int) -> bool:
@@ -350,35 +371,11 @@ class MoEDecoder(fnn.Module):
             raise ValueError(
                 f"dense_layers must leave a layer with experts, got "
                 f"{self.dense_layers} of {self.num_layers}")
-        note_expert_layers(
-            tokens.size, self.top_k, self.expert_layers, len(self.held))
-        _telemetry.metrics.gauge(
-            _names.GAUGE_ATTN_HEADS_HELD,
-            "query heads this rank holds, summed over the layers of the "
-            "step most recently traced").set(sum(
-                _of_layer(self.num_heads, i) for i in range(self.num_layers)))
-        note_attention_step()  # each layer's call below counts itself
-        note_selected_layers(
-            tokens.shape[0], tokens.shape[1], self.selected_layers)
-        with jax.named_scope(_names.SCOPE_LM_EMBED):
-            x = TokenEmbed(
-                self.vocab_size, self.d_model, dtype=self.dtype, name="embed"
-            )(tokens)
-        block_cls = MoEDecoderBlock
-        if self.remat:
-            # every layer kind keeps what its attention's forward kernels
-            # made. A selected layer: the output, log-sum-exps, thresholds
-            # and its panels of float32 index scores, 130 + 640 MiB a layer
-            # at 16,384 positions, which buy the index scores, the selection
-            # and the forward attention kernels once a step, not twice. A
-            # full or windowed layer: the fused kernels' output and
-            # log-sum-exp (2 B x head_dim + 4 B a query and head)
-            block_cls = recomputed(MoEDecoderBlock)
-        routing = []
+        blocks = []
         for i in range(self.num_layers):
             windowed = self.window_layout[i % len(self.window_layout)]
             rotated = self.rope_layout[i % len(self.rope_layout)]
-            x, measured = block_cls(
+            blocks.append(dict(
                 num_heads=_of_layer(self.num_heads, i),
                 num_kv_heads=_of_layer(self.num_kv_heads, i),
                 head_dim=self.head_dim, expert_width=self.expert_width,
@@ -398,8 +395,40 @@ class MoEDecoder(fnn.Module):
                 dense_width=(
                     self.dense_width if i < self.dense_layers else None),
                 dtype=self.dtype,
-                name=f"MoEDecoderBlock_{i}",  # the same with and without remat
-            )(x)
+                name=f"MoEDecoderBlock_{i}"))  # with and without remat
+        block_cls = MoEDecoderBlock
+        if self.remat:
+            # every layer kind keeps what its attention's forward kernels
+            # made. A selected layer: the output, log-sum-exps, thresholds
+            # and its panels of float32 index scores, 130 + 640 MiB a layer
+            # at 16,384 positions, which buy the index scores, the selection
+            # and the forward attention kernels once a step, not twice. A
+            # full or windowed layer: the fused kernels' output and
+            # log-sum-exp (2 B x head_dim + 4 B a query and head). And the
+            # dense products' results the step has room for
+            # (``products_kept``: it traces the blocks, so it stands before
+            # the attention calls are counted)
+            block_cls = recomputed(MoEDecoderBlock, keep=products_kept(
+                self, MoEDecoderBlock, blocks, jax.ShapeDtypeStruct(
+                    tokens.shape + (self.d_model,), self.dtype),
+                self.vocab_size))
+        note_expert_layers(
+            tokens.size, self.top_k, self.expert_layers, len(self.held))
+        _telemetry.metrics.gauge(
+            _names.GAUGE_ATTN_HEADS_HELD,
+            "query heads this rank holds, summed over the layers of the "
+            "step most recently traced").set(sum(
+                _of_layer(self.num_heads, i) for i in range(self.num_layers)))
+        note_attention_step()  # each layer's call below counts itself
+        note_selected_layers(
+            tokens.shape[0], tokens.shape[1], self.selected_layers)
+        with jax.named_scope(_names.SCOPE_LM_EMBED):
+            x = TokenEmbed(
+                self.vocab_size, self.d_model, dtype=self.dtype, name="embed"
+            )(tokens)
+        routing = []
+        for block in blocks:
+            x, measured = block_cls(**block)(x)
             routing.append(measured)
         with jax.named_scope(_names.SCOPE_LM_NORM):
             x = fnn.RMSNorm(

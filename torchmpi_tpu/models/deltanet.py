@@ -73,8 +73,14 @@ from ..parallel.ssm import causal_conv1d_silu, note_conv_step
 from ..telemetry import names as _names
 from .embedding import TokenEmbed
 from .lm import (
+    MIXER_GATES,
+    MIXER_IN,
+    QKV,
+    RESIDUAL,
     a_log_init,
     dt_bias_init,
+    product,
+    products_kept,
     recomputed,
     rotary,
     sparse_feed_forward,
@@ -143,14 +149,14 @@ class GatedDeltaDecoderBlock(fnn.Module):
         vw = self.value_heads * self.value_dim
         with jax.named_scope(_names.SCOPE_GDN_PROJ):
             h = u.astype(self.dtype)
-            qkv, z = jnp.split(
-                self._dense(2 * kw + 2 * vw, "in_qkvz")(h), [2 * kw + vw],
-                axis=-1)
+            qkv, z = jnp.split(product(
+                self._dense(2 * kw + 2 * vw, "in_qkvz")(h), MIXER_IN, d),
+                [2 * kw + vw], axis=-1)
             # small, so that ``g`` starts at ``-A dt`` and ``beta`` near 1/2
-            bb, a = jnp.split(fnn.Dense(
+            bb, a = jnp.split(product(fnn.Dense(
                 2 * self.value_heads, use_bias=False, dtype=self.dtype,
                 kernel_init=fnn.initializers.normal(0.02 / math.sqrt(d)),
-                name="in_ba")(h).astype(f32), 2, axis=-1)
+                name="in_ba")(h), MIXER_GATES, d).astype(f32), 2, axis=-1)
 
         def convolved(qkv, taps):
             """``q`` and ``k`` normalised and rounded as the rule's products
@@ -203,9 +209,10 @@ class GatedDeltaDecoderBlock(fnn.Module):
         n, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
         with jax.named_scope(_names.SCOPE_ATTN_PROJ):
             h = u.astype(self.dtype)
-            q_gate = self._dense(2 * n * hd, "q")(h)
-            k = self._dense(kv * hd, "k")(h)
-            v = self._dense(kv * hd, "v")(h)
+            # named flat, before the reshape to heads
+            q_gate = product(self._dense(2 * n * hd, "q")(h), QKV, d)
+            k = product(self._dense(kv * hd, "k")(h), QKV, d)
+            v = product(self._dense(kv * hd, "v")(h), QKV, d)
         # the reshapes stand under no scope, as in models/decoder.py
         q, gate = jnp.split(q_gate.reshape(b, t, n, 2 * hd), 2, axis=-1)
         k = k.reshape(b, t, kv, hd)
@@ -233,7 +240,10 @@ class GatedDeltaDecoderBlock(fnn.Module):
         with jax.named_scope(_names.SCOPE_LM_NORM):
             u = self._norm("norm_mix")(x)
         mix = self._linear_mixer(u) if self.linear else self._full_mixer(u)
-        x = x + mix.astype(x.dtype)
+        # the stream after the mixer's output product
+        x = product(x + mix.astype(x.dtype), RESIDUAL, (
+            self.value_heads * self.value_dim if self.linear
+            else self.num_heads * self.head_dim))
         with jax.named_scope(_names.SCOPE_LM_NORM):
             m = self._norm("norm_moe")(x)
         x, load, rows = sparse_feed_forward(
@@ -279,7 +289,9 @@ class GatedDeltaDecoder(fnn.Module):
     norm_eps: float = 1e-6
     attn_block: int = 1024
     remat: bool = False  # recompute each block in backward, but for what
-    #                      its attention's forward kernels kept: ``recomputed``
+    #                      its attention's forward kernels kept and the
+    #                      products' results the step has room for:
+    #                      ``recomputed``, ``products_kept``
     dtype: Any = jnp.float32
 
     selected_layers = 0  # no layer selects its keys
@@ -294,6 +306,27 @@ class GatedDeltaDecoder(fnn.Module):
     @fnn.compact
     def __call__(self, tokens, targets=None):
         batch, t = tokens.shape
+        blocks = [
+            dict(linear=self.is_linear(i), num_heads=self.num_heads,
+                 num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
+                 rotary_dim=self.rotary_dim, key_heads=self.key_heads,
+                 value_heads=self.value_heads, key_dim=self.key_dim,
+                 value_dim=self.value_dim, expert_width=self.expert_width,
+                 shared_width=self.shared_width,
+                 num_experts=self.num_experts, top_k=self.top_k,
+                 held=tuple(self.held), conv_width=self.conv_width,
+                 chunk=self.chunk, rope_theta=self.rope_theta,
+                 norm_eps=self.norm_eps, attn_block=self.attn_block,
+                 dtype=self.dtype,
+                 name=f"GatedDeltaDecoderBlock_{i}")  # with and without remat
+            for i in range(self.num_layers)]
+        block_cls = GatedDeltaDecoderBlock
+        if self.remat:
+            # before the attention calls are counted: it traces the blocks
+            block_cls = recomputed(GatedDeltaDecoderBlock, keep=products_kept(
+                self, GatedDeltaDecoderBlock, blocks,
+                jax.ShapeDtypeStruct((batch, t, self.d_model), self.dtype),
+                self.vocab_size))
         note_expert_layers(
             tokens.size, self.top_k, self.num_layers, len(self.held))
         note_attention_step()  # each full layer's call below counts itself
@@ -307,25 +340,9 @@ class GatedDeltaDecoder(fnn.Module):
             x = TokenEmbed(
                 self.vocab_size, self.d_model, dtype=self.dtype, name="embed"
             )(tokens)
-        block_cls = GatedDeltaDecoderBlock
-        if self.remat:
-            block_cls = recomputed(GatedDeltaDecoderBlock)
         routing = []
-        for i in range(self.num_layers):
-            x, measured = block_cls(
-                linear=self.is_linear(i), num_heads=self.num_heads,
-                num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
-                rotary_dim=self.rotary_dim, key_heads=self.key_heads,
-                value_heads=self.value_heads, key_dim=self.key_dim,
-                value_dim=self.value_dim, expert_width=self.expert_width,
-                shared_width=self.shared_width,
-                num_experts=self.num_experts, top_k=self.top_k,
-                held=tuple(self.held), conv_width=self.conv_width,
-                chunk=self.chunk, rope_theta=self.rope_theta,
-                norm_eps=self.norm_eps, attn_block=self.attn_block,
-                dtype=self.dtype,
-                name=f"GatedDeltaDecoderBlock_{i}",  # with and without remat
-            )(x)
+        for block in blocks:
+            x, measured = block_cls(**block)(x)
             routing.append(measured)
         with jax.named_scope(_names.SCOPE_LM_NORM):
             x = ZeroCentredRMSNorm(epsilon=self.norm_eps, name="norm")(x)

@@ -67,7 +67,13 @@ from ..parallel.ssm import (
 )
 from ..telemetry import names as _names
 from .embedding import TokenEmbed
-from .lm import a_log_init, dt_bias_init, recomputed, rotary
+from .lm import (
+    a_log_init,
+    dt_bias_init,
+    products_kept,
+    recomputed,
+    rotary,
+)
 from .lm_head import VocabHead
 
 
@@ -220,14 +226,38 @@ class HybridDecoder(fnn.Module):
     attn_block: int = 1024
     axis_name: Optional[str] = None
     remat: bool = False  # recompute each block in backward, but for what
-    #                      its attention's forward kernel kept (``recomputed``)
+    #                      its attention's forward kernel kept
+    #                      (``recomputed``; this file's products bear no
+    #                      name yet, so ``products_kept`` keeps none of them:
+    #                      with the feed-forward's gate kept, 0.33 GiB, the
+    #                      step for the described v5e held 0.97 GiB more:
+    #                      PERF.md section 7, PR 47)
     dtype: Any = jnp.float32
 
     @fnn.compact
     def __call__(self, tokens, targets=None):
         m = self.multipliers
-        note_attention_step()  # each layer's attention counts itself
         batch, t = tokens.shape
+        blocks = [
+            dict(num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
+                 head_dim=self.head_dim, ssm_heads=self.ssm_heads,
+                 ssm_head_dim=self.ssm_head_dim, ssm_groups=self.ssm_groups,
+                 ssm_state=self.ssm_state, mlp_width=self.mlp_width,
+                 multipliers=m, conv_width=self.conv_width, chunk=self.chunk,
+                 rope_theta=self.rope_theta, norm_eps=self.norm_eps,
+                 attn_block=self.attn_block, axis_name=self.axis_name,
+                 dtype=self.dtype,
+                 name=f"HybridDecoderBlock_{i}")  # with and without remat
+            for i in range(self.num_layers)]
+        block_cls = HybridDecoderBlock
+        if self.remat:
+            # before the attention calls are counted: it traces a block
+            # (and sets the gauges: nothing named, nothing kept)
+            block_cls = recomputed(HybridDecoderBlock, keep=products_kept(
+                self, HybridDecoderBlock, blocks,
+                jax.ShapeDtypeStruct((batch, t, self.d_model), self.dtype),
+                self.vocab_size))
+        note_attention_step()  # each layer's attention counts itself
         note_ssm_step(self.num_layers * self.ssm_heads,
                       self.num_layers * batch * -(-t // self.chunk))
         note_conv_step(
@@ -239,21 +269,8 @@ class HybridDecoder(fnn.Module):
             x = (m.embedding * TokenEmbed(
                 self.vocab_size, self.d_model, dtype=jnp.float32,
                 name="embed")(tokens)).astype(self.dtype)
-        block_cls = HybridDecoderBlock
-        if self.remat:
-            block_cls = recomputed(HybridDecoderBlock)
-        for i in range(self.num_layers):
-            x = block_cls(
-                num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
-                head_dim=self.head_dim, ssm_heads=self.ssm_heads,
-                ssm_head_dim=self.ssm_head_dim, ssm_groups=self.ssm_groups,
-                ssm_state=self.ssm_state, mlp_width=self.mlp_width,
-                multipliers=m, conv_width=self.conv_width, chunk=self.chunk,
-                rope_theta=self.rope_theta, norm_eps=self.norm_eps,
-                attn_block=self.attn_block, axis_name=self.axis_name,
-                dtype=self.dtype,
-                name=f"HybridDecoderBlock_{i}",  # with and without remat
-            )(x)
+        for block in blocks:
+            x = block_cls(**block)(x)
         with jax.named_scope(_names.SCOPE_LM_NORM):
             x = fnn.RMSNorm(
                 epsilon=self.norm_eps, dtype=jnp.float32, name="norm")(x)

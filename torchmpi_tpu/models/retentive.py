@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from ..parallel.retention import note_retention_step, power_retention
 from ..telemetry import names as _names
 from .embedding import TokenEmbed
-from .lm import recomputed, rotary
+from .lm import MLP_GATE, MLP_UP, product, products_kept, recomputed, rotary
 from .lm_head import VocabHead
 
 # what a seeded gate lets through of the state, a position: ``1 - 1 / n``
@@ -114,9 +114,12 @@ class RetentionDecoderBlock(fnn.Module):
         with jax.named_scope(_names.SCOPE_LM_NORM):
             r = norm("norm_mlp")(x).astype(self.dtype)
         with jax.named_scope(_names.SCOPE_LM_MLP):
-            gate = jax.nn.silu(dense(self.mlp_width, "mlp_gate")(r))
+            # before the SiLU, which is elementwise and fuses: made again
+            gate = jax.nn.silu(
+                product(dense(self.mlp_width, "mlp_gate")(r), MLP_GATE, d))
             return x + dense(d, "mlp_down")(
-                gate * dense(self.mlp_width, "mlp_up")(r)).astype(x.dtype)
+                gate * product(dense(self.mlp_width, "mlp_up")(r), MLP_UP, d)
+            ).astype(x.dtype)
 
 
 class RetentionDecoder(fnn.Module):
@@ -138,7 +141,8 @@ class RetentionDecoder(fnn.Module):
     rope_theta: float = 1e6
     norm_eps: float = 1e-6
     eps: float = 1e-12
-    remat: bool = False  # recompute each block in backward (``recomputed``)
+    remat: bool = False  # recompute each block in backward (``recomputed``:
+    #                      all but the products' results the step has room for)
     dtype: Any = jnp.float32
 
     @fnn.compact
@@ -150,17 +154,21 @@ class RetentionDecoder(fnn.Module):
             x = TokenEmbed(
                 self.vocab_size, self.d_model, dtype=jnp.float32,
                 name="embed")(tokens).astype(self.dtype)
+        blocks = [
+            dict(num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
+                 head_dim=self.head_dim, mlp_width=self.mlp_width,
+                 chunk=self.chunk, rope_theta=self.rope_theta,
+                 norm_eps=self.norm_eps, eps=self.eps, dtype=self.dtype,
+                 name=f"RetentionDecoderBlock_{i}")  # with and without remat
+            for i in range(self.num_layers)]
         block_cls = RetentionDecoderBlock
         if self.remat:
-            block_cls = recomputed(RetentionDecoderBlock)
-        for i in range(self.num_layers):
-            x = block_cls(
-                num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
-                head_dim=self.head_dim, mlp_width=self.mlp_width,
-                chunk=self.chunk, rope_theta=self.rope_theta,
-                norm_eps=self.norm_eps, eps=self.eps, dtype=self.dtype,
-                name=f"RetentionDecoderBlock_{i}",  # with and without remat
-            )(x)
+            block_cls = recomputed(RetentionDecoderBlock, keep=products_kept(
+                self, RetentionDecoderBlock, blocks,
+                jax.ShapeDtypeStruct(x.shape, x.dtype),
+                self.vocab_size))
+        for block in blocks:
+            x = block_cls(**block)(x)
         with jax.named_scope(_names.SCOPE_LM_NORM):
             x = fnn.RMSNorm(
                 epsilon=self.norm_eps, dtype=jnp.float32, name="norm")(x)

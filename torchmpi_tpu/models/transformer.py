@@ -26,7 +26,7 @@ from ..parallel.ring_attention import (
 )
 from ..telemetry import names as _names
 from .embedding import TokenEmbed
-from .lm import recomputed
+from .lm import MLP_GATE, QKV, RESIDUAL, product, products_kept, recomputed
 from .lm_head import VocabHead
 
 
@@ -45,8 +45,9 @@ class RingAttentionBlock(fnn.Module):
         with jax.named_scope(_names.SCOPE_LM_NORM):
             h = fnn.LayerNorm(dtype=jnp.float32)(x)
         with jax.named_scope(_names.SCOPE_ATTN_PROJ):
-            qkv = fnn.Dense(
-                3 * self.num_heads * self.head_dim, dtype=self.dtype)(h)
+            qkv = product(fnn.Dense(
+                3 * self.num_heads * self.head_dim, dtype=self.dtype)(h),
+                QKV, d_model)
             q, k, v = jnp.split(qkv, 3, axis=-1)
         # the reshapes on either side of the attention stand under no scope,
         # as in models/decoder.py
@@ -64,12 +65,16 @@ class RingAttentionBlock(fnn.Module):
                 attn = blocked_self_attention(q, k, v)
         attn = attn.reshape(x.shape[:2] + (-1,))
         with jax.named_scope(_names.SCOPE_ATTN_PROJ):
-            x = x + fnn.Dense(d_model, dtype=self.dtype)(attn)
+            x = product(x + fnn.Dense(d_model, dtype=self.dtype)(attn),
+                        RESIDUAL, attn.shape[-1])
 
         with jax.named_scope(_names.SCOPE_LM_NORM):
             h = fnn.LayerNorm(dtype=jnp.float32)(x)
         with jax.named_scope(_names.SCOPE_LM_MLP):
-            h = fnn.Dense(self.mlp_ratio * d_model, dtype=self.dtype)(h)
+            # before the GELU, which is elementwise and fuses: made again
+            h = product(fnn.Dense(
+                self.mlp_ratio * d_model, dtype=self.dtype)(h),
+                MLP_GATE, d_model)
             h = fnn.gelu(h)
             x = x + fnn.Dense(d_model, dtype=self.dtype)(h)
         return x
@@ -89,13 +94,26 @@ class LongContextTransformer(fnn.Module):
     sp_axis: Optional[str] = None
     sp_backend: str = "xla"  # ring-attention transport (see RingAttentionBlock)
     remat: bool = False  # recompute each block in backward (``recomputed``:
-    #                      all but the attention kernels' output and lse)
+    #                      all but the attention kernels' output and lse and
+    #                      the products' results the step has room for)
     dtype: Any = jnp.float32
 
     @fnn.compact
     def __call__(self, tokens, targets=None):
         # tokens: [B, T_local] int32; with ``targets`` the mean next-token
         # loss over the local positions, not the logits
+        blocks = [
+            dict(num_heads=self.num_heads, head_dim=self.head_dim,
+                 sp_axis=self.sp_axis, sp_backend=self.sp_backend,
+                 dtype=self.dtype, name=f"RingAttentionBlock_{i}")
+            for i in range(self.num_layers)]
+        block_cls = RingAttentionBlock
+        if self.remat:
+            # before the attention calls are counted: it traces a block
+            block_cls = recomputed(RingAttentionBlock, keep=products_kept(
+                self, RingAttentionBlock, blocks, jax.ShapeDtypeStruct(
+                    tokens.shape + (self.d_model,), self.dtype),
+                self.vocab_size))
         note_attention_step()
         t_local = tokens.shape[1]
         if self.sp_axis is not None:
@@ -118,23 +136,15 @@ class LongContextTransformer(fnn.Module):
         # activations ([B, T, D] x layers), so this trades one extra
         # forward per block for an O(num_layers) -> O(1) activation
         # footprint (the standard long-sequence memory lever on TPU); the
-        # fused attention kernels' output and log-sum-exp alone are kept
-        # (``recomputed``: 2 x [B, T, D] bytes + 4 a head and position a layer)
-        block_cls = RingAttentionBlock
-        if self.remat:
-            block_cls = recomputed(RingAttentionBlock)
-        for i in range(self.num_layers):
+        # fused attention kernels' output and log-sum-exp are kept
+        # (``recomputed``: 2 x [B, T, D] bytes + 4 a head and position a
+        # layer) and, where the step has the room, the products' results
+        # (``products_kept``, above)
+        for block in blocks:
             # explicit name: the remat wrapper would otherwise rename the
             # module path (Checkpoint...), making remat and non-remat
             # checkpoints incompatible — same params must drive both
-            x = block_cls(
-                num_heads=self.num_heads,
-                head_dim=self.head_dim,
-                sp_axis=self.sp_axis,
-                sp_backend=self.sp_backend,
-                dtype=self.dtype,
-                name=f"RingAttentionBlock_{i}",
-            )(x)
+            x = block_cls(**block)(x)
         with jax.named_scope(_names.SCOPE_LM_NORM):
             x = fnn.LayerNorm(dtype=jnp.float32)(x)
         # named as flax named it when it was ``fnn.Dense``

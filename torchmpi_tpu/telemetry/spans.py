@@ -253,6 +253,14 @@ GAUGE_LM_HEAD_BLOCKS = "tm_lm_head_blocks_per_step"
 # first
 GAUGE_CONV_ELEMENTS = "tm_conv_elements_per_step"
 GAUGE_CONV_KERNEL_ELEMENTS = "tm_conv_kernel_elements_per_step"
+# -- the gauges models/lm.py ``products_kept`` sets the same way for every
+# model whose blocks are recomputed: the bytes the dense products' named
+# results (``lm.product``) would hold over the layers of the step most
+# recently traced, and the bytes of the kinds the rule kept for backward to
+# read. The benchmark's ``recompute_kept_share`` reads the second against
+# the first
+GAUGE_RECOMPUTE_NAMED_BYTES = "tm_recompute_named_bytes_per_step"
+GAUGE_RECOMPUTE_KEPT_BYTES = "tm_recompute_kept_bytes_per_step"
 
 # -- what a device trace calls the attention kernels (an event's name is
 # the kernel's HLO instruction): jax's splash attention in
