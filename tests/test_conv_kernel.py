@@ -200,8 +200,9 @@ def _reader():
 
 def test_the_share_is_the_second_gauge_over_the_first(monkeypatch):
     """``conv_kernel_share``, the entry the benchmark lists it under (the
-    two cells whose models call the operation, beside each one's own
-    ``*_conv_ms_per_step``): 100 where the shapes take the kernels on a
+    three cells whose models run a short convolution, beside each one's own
+    ``*_conv_ms_per_step``; ``lfm2-8b-a1b``'s gated one has no kernel and
+    reads 0 whatever its shape): 100 where the shapes take the kernels on a
     TPU, 0 where they do not (``falcon-h1-34b``'s 1,024 channels), and None
     where the program has no such gauge (the parent of PR 46, a model
     without the convolution)."""
@@ -215,10 +216,11 @@ def test_the_share_is_the_second_gauge_over_the_first(monkeypatch):
         "source": "program_counter", "layer": "gated delta rule",
         "moves": "samples_per_s_per_chip",
         "workloads": ["qwen3-next-80b-a3b.stream.x1",
-                      "falcon-h1-34b.stream.x1"]}
+                      "falcon-h1-34b.stream.x1", "lfm2-8b-a1b.stream.x1"]}
     by_name = {m["name"]: m["workloads"] for m in spec["per_layer"]}
     assert entry["workloads"] == (
-        by_name["gdn_conv_ms_per_step"] + by_name["ssm_conv_ms_per_step"])
+        by_name["gdn_conv_ms_per_step"] + by_name["ssm_conv_ms_per_step"]
+        + by_name["short_conv_ms_per_step"])
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     ssm.note_conv_step(3, (1, 16384, 8192), jnp.bfloat16, 4)
     assert _reader().read({}) == 100.0
@@ -233,5 +235,7 @@ def test_the_share_is_the_second_gauge_over_the_first(monkeypatch):
         if k != names.GAUGE_CONV_KERNEL_ELEMENTS})
     assert _reader().read({}) is None
     monkeypatch.undo()
+    ssm.note_gated_conv_step(4, (2, 8192, 2048))
+    assert _reader().read({}) == 0.0  # the gated one: no kernel
     telemetry.metrics.gauge(names.GAUGE_CONV_ELEMENTS, "").set(0)
     assert _reader().read({}) is None  # no call of the operation

@@ -505,7 +505,7 @@ def test_the_scopes_nest_under_fwd_bwd_in_the_lowered_step():
                 if n in GDN]
             assert len(inner) <= 1, op
     assert names.GDN_SCOPE_NAMES == GDN
-    assert names.MODEL_SCOPE_NAMES[-5:] == GDN
+    assert set(GDN) < set(names.MODEL_SCOPE_NAMES)
     assert set(seen) >= set(GDN) | {
         "tm.lm.embed", "tm.lm.norm", "tm.attn.proj", "tm.attn.full",
         "tm.attn.gate", "tm.moe.router", "tm.moe.shared", "tm.moe.route",

@@ -33,7 +33,10 @@ from torchmpi_tpu.models import (
     make_lm_loss_fn,
     make_moe_lm_loss_fn,
 )
-from torchmpi_tpu.parallel import sigmoid_route_weights
+from torchmpi_tpu.parallel import (
+    biased_sigmoid_route_weights,
+    sigmoid_route_weights,
+)
 from torchmpi_tpu.telemetry import names
 
 ROOT = Path(__file__).resolve().parent.parent
@@ -44,13 +47,14 @@ OLD = names.ATTN_MOE_SCOPE_NAMES      # what the benchmark's metrics read
 SSM = names.SSM_SCOPE_NAMES           # the state-space mixer's (PR 39)
 RET = names.RETENTION_SCOPE_NAMES     # power retention's (PR 41)
 GDN = names.GDN_SCOPE_NAMES           # the gated delta rule's (PR 45)
-NEW = names.LM_SCOPE_NAMES + SSM + RET + GDN  # what this file is about
+SCONV = names.SCONV_SCOPE_NAMES       # the gated short convolution's (PR 48)
+NEW = names.LM_SCOPE_NAMES + SSM + RET + GDN + SCONV  # this file's
 EVERY_LM = {"tm.lm.embed", "tm.lm.norm", "tm.attn.proj", "tm.lm.head",
             "tm.lm.loss"}
 # the scopes opened inside a block are recomputed with it; the embedding,
 # the last norm's model-level call, the head and the loss are not
 IN_BLOCKS = {"tm.lm.norm", "tm.attn.proj", "tm.lm.mlp", "tm.moe.router",
-             *SSM, *RET, *GDN}
+             *SSM, *RET, *GDN, *SCONV}
 
 
 # the scopes of a family that hold nothing but products whose results bear a
@@ -58,6 +62,8 @@ IN_BLOCKS = {"tm.lm.norm", "tm.attn.proj", "tm.lm.mlp", "tm.moe.router",
 # the rule keeps every kind, a block's recomputation makes none of them again
 NOT_AGAIN = {family: {"tm.attn.proj", "tm.moe.router"} for family in (
     "smallthinker", "selected", "laguna", "gated_delta")}
+NOT_AGAIN["short_conv"] = {
+    "tm.attn.proj", "tm.moe.router", "tm.lm.sconv_proj"}
 
 
 def _decoder(**over):
@@ -116,6 +122,15 @@ FAMILIES = {
         value_heads=4, key_dim=8, value_dim=8, expert_width=16,
         shared_width=16, num_experts=8, top_k=3, held=tuple(range(8)),
         chunk=8, attn_block=8, remat=True), ROUTED | set(GDN)),
+    # lfm2-8b-a1b's: a gated short convolution where attention stands in
+    # four layers of five, a dense leading layer, a router that chooses by
+    # its scores plus a bias, the head the embedding's table
+    "short_conv": Family(lambda: _decoder(
+        num_layers=5, window_layout=(0,), rope_layout=(1,), rope_theta=1e6,
+        activation=jax.nn.silu, router_after_norm=True, qk_norm=True,
+        route_weights=biased_sigmoid_route_weights, expert_bias=True,
+        dense_layers=1, dense_width=24, conv_layout=(1, 0, 1, 1, 1),
+        tied_head=True), ROUTED | set(SCONV)),
     # keye-vl-2-30b-a3b's: every layer selects, with a norm on each query
     # and key head; the indexer's projections stay under tm.attn.index
     "selected": Family(lambda: _decoder(
@@ -188,7 +203,8 @@ def test_new_scopes_reach_every_phase_under_fwd_bwd(family):
                    "tm.lm.ssm_proj", "tm.lm.ssm_conv", "tm.lm.ssm_scan",
                    "tm.lm.ssm_gate", "tm.lm.ret_gate", "tm.lm.ret_chunk",
                    "tm.lm.ret_state", "tm.lm.gdn_proj", "tm.lm.gdn_conv",
-                   "tm.lm.gdn_gate", "tm.lm.gdn_chunk", "tm.lm.gdn_state")
+                   "tm.lm.gdn_gate", "tm.lm.gdn_chunk", "tm.lm.gdn_state",
+                   "tm.lm.sconv_proj", "tm.lm.sconv")
     seen = {}
     for op in as_traced(family):
         bucket = model_scopes.bucket_of(op)

@@ -743,7 +743,9 @@ def test_model_scopes_nest_under_fwd_bwd_in_the_lowered_step(selecting):
         "tm.lm.ret_gate", "tm.lm.ret_chunk", "tm.lm.ret_state",
         # the gated delta rule's (tests/test_deltanet_decoder.py)
         "tm.lm.gdn_proj", "tm.lm.gdn_conv", "tm.lm.gdn_gate",
-        "tm.lm.gdn_chunk", "tm.lm.gdn_state")
+        "tm.lm.gdn_chunk", "tm.lm.gdn_state",
+        # the gated short convolution's (tests/test_sconv_decoder.py)
+        "tm.lm.sconv_proj", "tm.lm.sconv")
     cfg = tiny_cfg()
     model = tiny_model(cfg)
     experts = {names.SCOPE_MOE_ROUTE, names.SCOPE_MOE_EXPERTS,

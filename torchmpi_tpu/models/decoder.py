@@ -1,17 +1,22 @@
 """A present-day sparse decoder: RMSNorm, grouped KV heads, full,
 sliding-window or selected attention by layer (rotary position over the
-whole head, over a part of it under YaRN's frequencies, or none, by layer),
-a count of query and KV heads by layer, a sigmoid gate on each head, the
-router read before attention or after the second norm, a gated feed-forward
-of routed experts of which this device holds some, a shared expert beside
-them, and leading layers whose feed-forward is dense.
+whole head, over a part of it under YaRN's frequencies, or none, by layer)
+or, by layer, a gated short convolution where attention stands, a count of
+query and KV heads by layer, a sigmoid gate on each head, the router read
+before attention or after the second norm, a gated feed-forward of routed
+experts of which this device holds some (chosen by the router's rule, which
+may add a bias that a rule outside the gradient moves once a step), a
+shared expert beside them, leading layers whose feed-forward is dense, and
+a vocabulary head of its own or the embedding's table.
 
 Built from a layer pattern: ``window_layout[l % period]`` says whether layer
 ``l`` attends within ``window`` (else over the whole causal prefix),
 ``selected_layout[l % period]`` whether it attends to the ``index_top_k``
 keys its own indexer selects for each query, and ``rope_layout[l %
 period]`` whether its queries and keys are rotated (else the layer has no
-positional encoding at all); ``num_heads`` and ``num_kv_heads`` are one
+positional encoding at all), ``conv_layout[l % period]`` whether its mixer
+is the convolution and not attention at all (a pattern may be as long as
+the model: a list by layer); ``num_heads`` and ``num_kv_heads`` are one
 number or, as the layouts, a pattern by layer. Attention is
 ``parallel.ring_attention.blocked_self_attention`` or
 ``parallel.selected_attention.selected_self_attention`` (no ``t x t``
@@ -36,6 +41,23 @@ W_g^e) * (m W_u^e)) W_d^e`` with ``chosen`` and ``w`` the router's rule's
 with ``shared_width``, ``(act(m S_g) * (m S_u)) S_d``, an expert every token
 takes at weight 1. The first ``dense_layers`` layers have no router and no
 experts: ``out = h' + (act(m D_g) * (m D_u)) D_d``.
+
+A convolution layer (``conv_taps``) has no query, key or value: ``[B | C |
+x] = a W_in``, three column blocks of ``d`` in that order; ``h' = h + (C *
+conv(B * x)) W_out``, the convolution causal and depthwise over ``conv_taps``
+positions, no bias, no activation (``parallel.ssm.gated_short_conv``). What
+follows the mixer is the same.
+
+With ``expert_bias`` a layer's router takes a bias ``[num_experts]`` beside
+its input, ``route_weights`` is called with it and gives the rule
+(``ep.biased_sigmoid_route_weights`` with its numbers bound), and the layer
+measures two things more: the tokens that chose each of ALL the experts, and
+the routes the bias turned. The bias is no parameter: the model is called
+with ``moe_bias``, a ``[num_experts]`` an expert layer, the engine carries
+it as model state, and ``make_moe_lm_loss_fn`` moves it once a step, outside the
+gradient: ``b_e <- b_e + u sign(mean(c) - c_e)`` from that step's counts
+``c`` (summed over ``axis_name``'s devices where the model names one, so
+that every device ends the step with the same bias).
 
 **A layer held by share.** The heads a layer is built with are the heads
 this device holds (a KV head with its group of query heads),
@@ -67,10 +89,12 @@ from jax import lax
 
 from .. import telemetry as _telemetry
 from ..parallel.ep import (
+    note_expert_bias,
     note_expert_layers,
     note_expert_load,
     softmax_route_weights,
 )
+from ..parallel.ssm import gated_short_conv, note_gated_conv_step
 from ..parallel.ring_attention import (
     blocked_self_attention,
     note_attention_step,
@@ -85,6 +109,7 @@ from .embedding import TokenEmbed
 from .lm import (
     HEAD_GATE,
     INDEX,
+    MIXER_IN,
     MLP_GATE,
     MLP_UP,
     QKV,
@@ -95,6 +120,7 @@ from .lm import (
     recomputed,
     rotary,
     sparse_feed_forward,
+    taps_init,
 )
 from .lm_head import VocabHead
 
@@ -182,6 +208,10 @@ class MoEDecoderBlock(fnn.Module):
     shared_width: Optional[int] = None  # not None: a shared expert
     dense_width: Optional[int] = None   # not None: a dense feed-forward of
     #                                     these columns, no router or expert
+    conv_taps: Optional[int] = None  # not None: the mixer is a gated short
+    #                                  convolution of these taps, no attention
+    expert_bias: bool = False  # the router's rule takes the layer's bias:
+    #                            ``route_weights(bias)`` gives the rule
     dtype: Any = jnp.float32
 
     def _indexer(self, h):
@@ -204,34 +234,19 @@ class MoEDecoderBlock(fnn.Module):
             index_k = rotary(index_k[:, :, None], self.rope_theta)[:, :, 0]
         return index_q, index_k, index_w
 
-    @fnn.compact
-    def __call__(self, x):
-        # x: [B, T, D] -> (x, (the tokens each held expert received, the
-        # rows the grouped products ran over, the indexer's loss, the
-        # pairs it selected: zeros in a layer that selects nothing))
-        b, t, d = x.shape
-        dense = lambda n, name: fnn.Dense(  # noqa: E731
-            n, use_bias=False, dtype=self.dtype, name=name)
+    def _dense(self, n, name):
+        return fnn.Dense(n, use_bias=False, dtype=self.dtype, name=name)
 
-        def gated(m, width, name):
-            """``(act(m W_gate) * (m W_up)) W_down``, ``width`` columns."""
-            return dense(d, name + "_down")(
-                self.activation(product(
-                    dense(width, name + "_gate")(m), MLP_GATE, d))
-                * product(dense(width, name + "_up")(m), MLP_UP, d))
-
-        sparse = self.dense_width is None
-        norm = lambda name: fnn.RMSNorm(  # noqa: E731
+    def _norm(self, name):
+        return fnn.RMSNorm(
             epsilon=self.norm_eps, dtype=jnp.float32, name=name)
-        if sparse and not self.router_after_norm:
-            with jax.named_scope(_names.SCOPE_MOE_ROUTER):
-                logits = product(fnn.Dense(
-                    self.num_experts, use_bias=False, dtype=jnp.float32,
-                    precision=lax.Precision.HIGHEST, name="router"
-                )(x.astype(jnp.float32)), ROUTER, d, passes=6)
 
-        with jax.named_scope(_names.SCOPE_LM_NORM):
-            h = norm("norm_attn")(x)
+    def _attention(self, x, h):
+        """``(x + Attn(h) W_o, the indexer's loss, the pairs it selected)``
+        from the stream ``x`` and its normed copy ``h``; zeros in a layer
+        that selects nothing."""
+        b, t, d = x.shape
+        dense, norm = self._dense, self._norm
         with jax.named_scope(_names.SCOPE_ATTN_PROJ):
             # named flat, before the reshape to heads
             q = product(dense(self.num_heads * self.head_dim, "q")(h), QKV, d)
@@ -281,23 +296,76 @@ class MoEDecoderBlock(fnn.Module):
         attn = attn.reshape(b, t, -1)
         with jax.named_scope(_names.SCOPE_ATTN_PROJ):
             x = product(x + dense(d, "o")(attn), RESIDUAL, attn.shape[-1])
+        return x, index_loss, pairs
+
+    def _short_conv(self, x, h):
+        """``x + (C * conv(B * x')) W_out`` with ``[B | C | x'] = h W_in``,
+        from the stream ``x`` and its normed copy ``h``."""
+        d = x.shape[-1]
+        with jax.named_scope(_names.SCOPE_SCONV_PROJ):
+            bcx = product(self._dense(3 * d, "in_proj")(h), MIXER_IN, d)
+        with jax.named_scope(_names.SCOPE_SCONV):
+            mixed = gated_short_conv(bcx, self.param(
+                "conv_kernel", taps_init, (self.conv_taps, d), jnp.float32))
+        with jax.named_scope(_names.SCOPE_SCONV_PROJ):
+            return product(
+                x + self._dense(d, "out_proj")(mixed), RESIDUAL, d)
+
+    @fnn.compact
+    def __call__(self, x, bias=None):
+        # x: [B, T, D] -> (x, (the tokens each held expert received, the
+        # rows the grouped products ran over, the indexer's loss, the
+        # pairs it selected: zeros in a layer that selects nothing; with
+        # ``expert_bias``, the tokens that chose each of ALL the experts
+        # under the router's ``bias`` [num_experts] (None: zeros) and the
+        # routes the bias turned))
+        d = x.shape[-1]
+        dense, norm = self._dense, self._norm
+
+        def gated(m, width, name):
+            """``(act(m W_gate) * (m W_up)) W_down``, ``width`` columns."""
+            return dense(d, name + "_down")(
+                self.activation(product(
+                    dense(width, name + "_gate")(m), MLP_GATE, d))
+                * product(dense(width, name + "_up")(m), MLP_UP, d))
+
+        sparse = self.dense_width is None
+        if sparse and not self.router_after_norm:
+            with jax.named_scope(_names.SCOPE_MOE_ROUTER):
+                logits = product(fnn.Dense(
+                    self.num_experts, use_bias=False, dtype=jnp.float32,
+                    precision=lax.Precision.HIGHEST, name="router"
+                )(x.astype(jnp.float32)), ROUTER, d, passes=6)
+
+        with jax.named_scope(_names.SCOPE_LM_NORM):
+            h = norm("norm_attn")(x)
+        index_loss = pairs = jnp.float32(0.0)
+        if self.conv_taps is None:
+            x, index_loss, pairs = self._attention(x, h)
+        else:
+            x = self._short_conv(x, h)
 
         with jax.named_scope(_names.SCOPE_LM_NORM):
             h = norm("norm_moe")(x)
         n, f = len(self.held), self.expert_width
+        zeros = (jnp.zeros((self.num_experts,), jnp.float32),
+                 jnp.float32(0.0)) if self.expert_bias else ()
         if not sparse:
             with jax.named_scope(_names.SCOPE_MOE_DENSE):
                 x = x + gated(h.astype(self.dtype), self.dense_width, "mlp")
             return x, (jnp.zeros((n,), jnp.float32), jnp.float32(0.0),
-                       index_loss, pairs)
-        x, load, rows = sparse_feed_forward(
+                       index_loss, pairs, *zeros)
+        if self.expert_bias and bias is None:
+            bias = zeros[0]
+        x, load, rows, *noted = sparse_feed_forward(
             self, x, h, expert_width=f, num_experts=self.num_experts,
             top_k=self.top_k, held=self.held, activation=self.activation,
             dtype=self.dtype,
             logits=None if self.router_after_norm else logits,
             route_weights=self.route_weights,
-            shared_width=self.shared_width)
-        return x, (load, rows, index_loss, pairs)
+            shared_width=self.shared_width,
+            route_bias=bias if self.expert_bias else None)
+        return x, (load, rows, index_loss, pairs, *(noted[0] if noted else ()))
 
 
 class MoEDecoder(fnn.Module):
@@ -306,18 +374,28 @@ class MoEDecoder(fnn.Module):
     layers]} float32)``: what each layer with experts measured of its
     routing; a model with selected layers adds ``"attn_index_loss"`` and
     ``"attn_selected_pairs"`` ``[layers]``: each layer's ``L_I`` and the
-    pairs it selected. With ``targets``, the mean next-token loss stands
-    where the logits do (``lm_head.VocabHead``).
+    pairs it selected; a model with ``expert_bias`` adds ``"moe_counts"``
+    ``[expert layers, num_experts]``, the tokens that chose each of ALL the
+    experts under ``moe_bias`` (a ``[num_experts]`` float32 an expert
+    layer; None: zeros), and ``"moe_biased_routes"`` ``[expert layers]``, the
+    routes the bias turned. With ``targets``, the mean next-token loss
+    stands where the logits do (``lm_head.VocabHead``).
 
     What differs by layer is given as a pattern, repeated over the depth:
-    ``window_layout``, ``rope_layout``, ``selected_layout``, and
-    ``num_heads`` / ``num_kv_heads`` where they are sequences (one number:
-    every layer's). ``rope_full`` is the rotation of the layers that attend
+    ``window_layout``, ``rope_layout``, ``selected_layout``,
+    ``conv_layout`` (1: the layer's mixer is a gated short convolution of
+    ``conv_taps`` taps, not attention), and ``num_heads`` / ``num_kv_heads``
+    where they are sequences (one number: every layer's). ``rope_full`` is the rotation of the layers that attend
     over the whole prefix where it is not the window layers' (``rope_theta``
     over the whole head). The first ``dense_layers`` layers have a dense
-    feed-forward of ``dense_width`` columns in place of the experts. The
-    heads, the columns and the experts given are the ones this device holds
-    (the module's docstring: a layer held by share)."""
+    feed-forward of ``dense_width`` columns in place of the experts. With
+    ``tied_head`` the vocabulary head is the embedding's table. The heads,
+    the columns, the experts and the vocabulary given are the ones this
+    device holds (the module's docstring: a layer held by share).
+    ``axis_name``: the axis of the devices that bring their own sequences
+    to the same experts, over which a step's counts are summed before they
+    move the bias (``make_moe_lm_loss_fn``); the forward pass makes no
+    collective."""
 
     vocab_size: int = 256
     num_layers: int = 4
@@ -348,6 +426,12 @@ class MoEDecoder(fnn.Module):
     shared_width: Optional[int] = None
     dense_layers: int = 0
     dense_width: int = 0
+    conv_layout: Sequence[int] = (0,)  # 1: a gated short convolution
+    conv_taps: int = 3
+    expert_bias: bool = False  # ``route_weights(bias)`` gives the rule
+    bias_update_rate: float = 1e-3  # ``u`` of the bias's rule
+    tied_head: bool = False
+    axis_name: Optional[str] = None
     remat: bool = False  # recompute each block in backward, but for what
     #                      its attention's forward kernels kept and the
     #                      products' results the step has room for:
@@ -356,6 +440,9 @@ class MoEDecoder(fnn.Module):
 
     def selects(self, i: int) -> bool:
         return bool(self.selected_layout[i % len(self.selected_layout)])
+
+    def convolves(self, i: int) -> bool:
+        return bool(self.conv_layout[i % len(self.conv_layout)])
 
     @property
     def selected_layers(self) -> int:
@@ -366,7 +453,7 @@ class MoEDecoder(fnn.Module):
         return self.num_layers - self.dense_layers
 
     @fnn.compact
-    def __call__(self, tokens, targets=None):
+    def __call__(self, tokens, targets=None, moe_bias=None):
         if not 0 <= self.dense_layers < self.num_layers:
             raise ValueError(
                 f"dense_layers must leave a layer with experts, got "
@@ -394,7 +481,8 @@ class MoEDecoder(fnn.Module):
                 shared_width=self.shared_width,
                 dense_width=(
                     self.dense_width if i < self.dense_layers else None),
-                dtype=self.dtype,
+                conv_taps=self.conv_taps if self.convolves(i) else None,
+                expert_bias=self.expert_bias, dtype=self.dtype,
                 name=f"MoEDecoderBlock_{i}"))  # with and without remat
         block_cls = MoEDecoderBlock
         if self.remat:
@@ -418,17 +506,24 @@ class MoEDecoder(fnn.Module):
             _names.GAUGE_ATTN_HEADS_HELD,
             "query heads this rank holds, summed over the layers of the "
             "step most recently traced").set(sum(
-                _of_layer(self.num_heads, i) for i in range(self.num_layers)))
+                _of_layer(self.num_heads, i) for i in range(self.num_layers)
+                if not self.convolves(i)))
         note_attention_step()  # each layer's call below counts itself
         note_selected_layers(
             tokens.shape[0], tokens.shape[1], self.selected_layers)
+        convolved = sum(self.convolves(i) for i in range(self.num_layers))
+        if convolved:
+            note_gated_conv_step(convolved, tokens.shape + (self.d_model,))
+        embed = TokenEmbed(
+            self.vocab_size, self.d_model, dtype=self.dtype, name="embed")
         with jax.named_scope(_names.SCOPE_LM_EMBED):
-            x = TokenEmbed(
-                self.vocab_size, self.d_model, dtype=self.dtype, name="embed"
-            )(tokens)
+            x = embed(tokens)
         routing = []
-        for block in blocks:
-            x, measured = block_cls(**block)(x)
+        for i, block in enumerate(blocks):
+            # an expert layer's bias, where the model was handed any
+            bias = (moe_bias[i - self.dense_layers],) if (
+                moe_bias is not None and i >= self.dense_layers) else ()
+            x, measured = block_cls(**block)(x, *bias)
             routing.append(measured)
         with jax.named_scope(_names.SCOPE_LM_NORM):
             x = fnn.RMSNorm(
@@ -436,8 +531,9 @@ class MoEDecoder(fnn.Module):
         # the logits, or with ``targets`` the mean next-token loss
         logits = VocabHead(
             self.vocab_size, use_bias=False, dtype=jnp.float32,
-            name="head")(x, targets)
-        load, rows, index_loss, pairs = (
+            name="head")(x, targets, *(
+                (embed.embedding,) if self.tied_head else ()))
+        load, rows, index_loss, pairs, *noted = (
             jnp.stack(a) for a in zip(*routing))
         # a dense layer routes nothing: its zeros are no expert layer's
         measured = {"moe_load": load[self.dense_layers:],
@@ -445,6 +541,9 @@ class MoEDecoder(fnn.Module):
         if self.selected_layers:
             measured.update(
                 attn_index_loss=index_loss, attn_selected_pairs=pairs)
+        if self.expert_bias:
+            counts, turned = (a[self.dense_layers:] for a in noted)
+            measured.update(moe_counts=counts, moe_biased_routes=turned)
         return logits, measured
 
 
@@ -452,7 +551,12 @@ def init_moe_state(model: MoEDecoder):
     """The model state the engine carries for ``make_moe_lm_loss_fn``: by
     layer with experts, the tokens each held expert received in the last
     step, and the rows the layer's grouped products ran over; with selected
-    layers, each layer's indexer loss and the pairs it selected."""
+    layers, each layer's indexer loss and the pairs it selected; with
+    ``expert_bias``, each router's bias over ALL its experts, from 0 (the
+    one entry the next step's forward pass READS; a leaf a layer, so that
+    whoever compares a state leaf by leaf beside the loads' thousands, as
+    the benchmark does, reads each router's), and the routes the bias
+    turned in the last step."""
     layers = jnp.zeros((model.num_layers,), jnp.float32)
     state = {
         "moe_load": jnp.zeros(
@@ -461,6 +565,11 @@ def init_moe_state(model: MoEDecoder):
     }
     if model.selected_layers:
         state.update(attn_index_loss=layers, attn_selected_pairs=layers)
+    if model.expert_bias:
+        state.update(
+            moe_bias=[jnp.zeros((model.num_experts,), jnp.float32)
+                      for _ in range(model.expert_layers)],
+            moe_biased_routes=jnp.zeros((model.expert_layers,), jnp.float32))
     return state
 
 
@@ -471,18 +580,40 @@ def make_moe_lm_loss_fn(model: MoEDecoder):
     rides the path batch norm's statistics take: no further output of the
     step). No auxiliary load-balancing loss; a model with selected layers
     adds each such layer's ``L_I``, whose gradient reaches its indexer
-    alone. Where the engine reads an epoch's loss it hands the state to
+    alone.
+
+    With ``expert_bias`` the forward pass reads ``state["moe_bias"]`` and
+    the new state holds the bias the step's counts moved it to, outside the
+    gradient: ``b_e + u sign(mean(c) - c_e)``, ``c_e`` the tokens of the
+    step that chose expert ``e`` among ALL the layer's experts, ``u`` the
+    model's ``bias_update_rate`` (DeepSeek-V3's auxiliary-loss-free
+    balancing, arXiv:2408.15664). Where the model names an ``axis_name``
+    the counts are ``psum``med over it first: every device then ends the
+    step with the same bias, and the engine's ``pmean`` of the model state
+    is the identity on it.
+
+    Where the engine reads an epoch's loss it hands the state to
     ``loss_fn.observe_state``, which sets ``tm_moe_held_routes_last_step``,
     ``tm_moe_max_over_mean_load``, ``tm_moe_grouped_rows_per_step`` and
-    ``tm_moe_compact_layers_last_step`` and, with selected layers,
+    ``tm_moe_compact_layers_last_step``; with selected layers,
     ``tm_attn_selected_pairs_per_step`` and
-    ``tm_attn_index_loss_last_step``."""
+    ``tm_attn_index_loss_last_step``; with ``expert_bias``,
+    ``tm_moe_bias_max_abs`` and ``tm_moe_biased_routes_last_step``."""
 
     def loss_fn(params, state, batch):
         tokens, targets = batch
-        loss, measured = model.apply({"params": params}, tokens, targets)
+        bias = (state["moe_bias"],) if model.expert_bias else ()
+        loss, measured = model.apply(
+            {"params": params}, tokens, targets, *bias)
         if model.selected_layers:
             loss = loss + jnp.sum(measured["attn_index_loss"])
+        if model.expert_bias:
+            counts = measured.pop("moe_counts")
+            if model.axis_name is not None:
+                counts = lax.psum(counts, model.axis_name)
+            moved = model.bias_update_rate * jnp.sign(
+                jnp.mean(counts, axis=-1, keepdims=True) - counts)
+            measured["moe_bias"] = [b + m for b, m in zip(bias[0], moved)]
         return loss, measured
 
     def observe_state(state):
@@ -491,6 +622,8 @@ def make_moe_lm_loss_fn(model: MoEDecoder):
             note_selection(
                 state["attn_index_loss"], state["attn_selected_pairs"],
                 [model.selects(i) for i in range(model.num_layers)])
+        if model.expert_bias:
+            note_expert_bias(state["moe_bias"], state["moe_biased_routes"])
 
     loss_fn.observe_state = observe_state
     return loss_fn

@@ -84,6 +84,7 @@ from .lm import (
     recomputed,
     rotary,
     sparse_feed_forward,
+    taps_init,
 )
 from .lm_head import VocabHead
 
@@ -104,12 +105,6 @@ class ZeroCentredRMSNorm(fnn.Module):
         return x * jax.lax.rsqrt(
             jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.epsilon
         ) * (1.0 + scale)
-
-
-def _taps_init(key, shape, dtype=jnp.float32):
-    """Uniform within ``1 / sqrt(taps)``, a depthwise kernel's fan-in."""
-    bound = 1.0 / math.sqrt(shape[0])
-    return jax.random.uniform(key, shape, dtype, -bound, bound)
 
 
 class GatedDeltaDecoderBlock(fnn.Module):
@@ -181,7 +176,7 @@ class GatedDeltaDecoderBlock(fnn.Module):
         # derivative rule: ``causal_conv1d_silu``); what this spares is the
         # L2 norms' operands, at one more pass of the forward kernel
         q, k, v = jax.checkpoint(convolved)(qkv, self.param(
-            "conv_kernel", _taps_init, (self.conv_width, 2 * kw + vw), f32))
+            "conv_kernel", taps_init, (self.conv_width, 2 * kw + vw), f32))
         with jax.named_scope(_names.SCOPE_GDN_GATE):
             beta = jax.nn.sigmoid(bb)
             g = -jnp.exp(self.param(
@@ -295,6 +290,7 @@ class GatedDeltaDecoder(fnn.Module):
     dtype: Any = jnp.float32
 
     selected_layers = 0  # no layer selects its keys
+    expert_bias = False  # ... and no router takes a bias
 
     def is_linear(self, i: int) -> bool:
         return (i + 1) % self.full_interval != 0

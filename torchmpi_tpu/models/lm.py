@@ -6,7 +6,8 @@ memory, the parameters' bytes and what the blocks' traced forward passes
 store, no field of a configuration), the rotation of a whole head
 (``rotary``), the sparse feed-forward half of a block
 (``sparse_feed_forward``), the seeded decay of a gated recurrence
-(``a_log_init``, ``dt_bias_init``), the engine's loss function of a model
+(``a_log_init``, ``dt_bias_init``) and taps of a short convolution
+(``taps_init``), the engine's loss function of a model
 that keeps no state (``make_lm_loss_fn``), the parameters of one (``init_lm_params``),
 and the loss written plainly (``lm_cross_entropy``: no program path calls
 it since the head makes its loss itself, ``lm_head.VocabHead``; it is the
@@ -308,6 +309,12 @@ def rotary(x, theta: float):
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
+def taps_init(key, shape, dtype=jnp.float32):
+    """Uniform within ``1 / sqrt(taps)``, a depthwise kernel's fan-in."""
+    bound = 1.0 / math.sqrt(shape[0])
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
 def a_log_init(key, shape, dtype=jnp.float32):
     """``A`` uniform in [1, 16] (Mamba-2's published initialisation; the
     gated delta rule's gate copies its parametrisation, ``g = -A softplus(a
@@ -328,7 +335,7 @@ def sparse_feed_forward(block: fnn.Module, x, h, *, expert_width: int,
                         activation: Callable, dtype, logits=None,
                         route_weights: Callable = softmax_route_weights,
                         shared_width: Optional[int] = None,
-                        shared_sigmoid: bool = False):
+                        shared_sigmoid: bool = False, route_bias=None):
     """The sparse feed-forward half of a block, called inside ``block``'s
     compact ``__call__`` (the parameters made here are ``block``'s own, under
     the names every sparse decoder's tree has): ``x + shared(h) + sum_{e
@@ -342,8 +349,14 @@ def sparse_feed_forward(block: fnn.Module, x, h, *, expert_width: int,
     ``h`` here, its product float32 at precision highest. ``shared_width``:
     a shared expert of that many columns beside the routed ones, which every
     token takes at weight 1 or, with ``shared_sigmoid``, at ``sigmoid(h .
-    w_s)``, one number a token (``shared_expert_gate``)."""
+    w_s)``, one number a token (``shared_expert_gate``). ``route_bias``:
+    ``[num_experts]`` float32, the buffer of a router that chooses by its
+    scores plus a bias; ``route_weights`` is then called with it and gives
+    the rule (``ep.biased_sigmoid_route_weights`` with its numbers bound),
+    and what that rule measured comes back after the rows."""
     b, t, d = x.shape
+    if route_bias is not None:
+        route_weights = route_weights(route_bias)
     dense = lambda n, name: fnn.Dense(  # noqa: E731
         n, use_bias=False, dtype=dtype, name=name)
     if logits is None:
@@ -368,11 +381,11 @@ def sparse_feed_forward(block: fnn.Module, x, h, *, expert_width: int,
             x = x + shared
     init = fnn.initializers.lecun_normal(in_axis=-2, out_axis=-1)
     n, f = len(held), expert_width
-    y, load, rows = moe_local_experts(
+    y, *measured = moe_local_experts(
         h.astype(dtype).reshape(b * t, d),
         logits.reshape(b * t, num_experts), top_k,
         block.param("experts_gate", init, (n, d, f), jnp.float32),
         block.param("experts_up", init, (n, d, f), jnp.float32),
         block.param("experts_down", init, (n, f, d), jnp.float32),
         tuple(held), activation=activation, route_weights=route_weights)
-    return x + y.reshape(b, t, d), load, rows
+    return (x + y.reshape(b, t, d), *measured)

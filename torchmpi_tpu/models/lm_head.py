@@ -199,19 +199,38 @@ class VocabHead(fnn.Dense):
     """``fnn.Dense`` (the same parameters ``kernel`` and ``bias``, the same
     initializers) times ``scale``: the logits to whoever asks for logits;
     with ``targets``, the mean next-token loss through ``head_loss`` and no
-    ``[rows, V]`` array. The one spelling of every model's head."""
+    ``[rows, V]`` array. The one spelling of every model's head.
+
+    With ``tied``, a ``[V, D]`` table (the model's token embedding), the
+    head has no parameter of its own: its matrix is the table's transpose,
+    so the table is ONE leaf that receives the head's blocked ``dW`` (made
+    ``[D, V]``, transposed by jax's rule of the transpose) and the lookup's
+    gradient (``models/embedding.py``) summed."""
 
     scale: float = 1.0
 
     @fnn.compact
-    def __call__(self, x, targets=None):
-        if targets is None:
+    def __call__(self, x, targets=None, tied=None):
+        if tied is None and targets is None:
             with jax.named_scope(_names.SCOPE_LM_HEAD):
                 return self.scale * super().__call__(x)
-        kernel = self.param(
-            "kernel", self.kernel_init, (x.shape[-1], self.features),
-            self.param_dtype)
-        bias = self.param(
-            "bias", self.bias_init, (self.features,), self.param_dtype
-        ) if self.use_bias else None
+        if tied is not None:
+            if self.use_bias or tied.shape != (self.features, x.shape[-1]):
+                raise ValueError(
+                    f"a tied head has no bias and a table of [{self.features}"
+                    f", {x.shape[-1]}]; got use_bias={self.use_bias} and "
+                    f"{tied.shape}")
+            with jax.named_scope(_names.SCOPE_LM_HEAD):
+                kernel, bias = tied.T, None
+        else:
+            kernel = self.param(
+                "kernel", self.kernel_init, (x.shape[-1], self.features),
+                self.param_dtype)
+            bias = self.param(
+                "bias", self.bias_init, (self.features,), self.param_dtype
+            ) if self.use_bias else None
+        if targets is None:
+            return _logits(
+                x.reshape(-1, x.shape[-1]), kernel, bias, self.scale
+            ).reshape(x.shape[:-1] + (self.features,))
         return head_loss(x, kernel, bias, self.scale, targets)

@@ -1,5 +1,6 @@
 from .deltanet import gated_delta_rule
 from .ep import (
+    biased_sigmoid_route_weights,
     moe_dispatch_combine,
     moe_load_stats,
     moe_local_experts,
@@ -23,6 +24,7 @@ from .ssm import (
     causal_conv1d,
     causal_conv1d_silu,
     gated_group_norm,
+    gated_short_conv,
     ssd_chunked_scan,
 )
 from .tp import MPLinear, MPLinearOutputSplit, shard_input_features
@@ -33,6 +35,7 @@ __all__ = [
     "moe_load_stats",
     "moe_local_experts",
     "sigmoid_route_weights",
+    "biased_sigmoid_route_weights",
     "softmax_route_weights",
     "pipeline_1f1b_value_and_grad",
     "pipeline_forward",
@@ -43,6 +46,7 @@ __all__ = [
     "selected_self_attention",
     "causal_conv1d",
     "causal_conv1d_silu",
+    "gated_short_conv",
     "ssd_chunked_scan",
     "gated_group_norm",
     "power_retention",

@@ -375,11 +375,62 @@ def sigmoid_route_weights(scale: float = 1.0):
     monotone: the largest logits, but ranked as the scores round), each
     over the chosen ones' sum, times ``scale`` (DeepSeek-V3's
     ``norm_topk_prob`` with a ``routed_scaling_factor``): the weights of a
-    token sum to ``scale``."""
+    token sum to ``scale``.
+
+    The divisor is the BARE sum, and this rule and the next are not one
+    rule with an option. Published routers differ here: softmax routers
+    that renormalise over the chosen (``softmax_route_weights``: the
+    ``qwen3_next`` and ``smallthinker`` families) add nothing; DeepSeek-V3's
+    published gate adds 1e-20 to the sum, which no float32 sum of sigmoids
+    can see, so ``laguna-s-2-1``'s file (``assumed.router``) takes the bare
+    sum of this function; the ``lfm2_moe`` family's adds 1e-6, which a sum
+    of four scores in (0, 1) does see (a weight's sixth digit, and the
+    benchmark's comparison with a plain reference reads it), and it also
+    chooses by one number and weighs by another
+    (:func:`biased_sigmoid_route_weights`). A configuration's file states
+    which its source has; program and reference take that one."""
 
     def rule(router_logits, top_k: int):
         top, chosen = lax.top_k(jax.nn.sigmoid(router_logits), top_k)
         return scale * top / jnp.sum(top, axis=-1, keepdims=True), chosen
+
+    return rule
+
+
+def biased_sigmoid_route_weights(bias, scale: float = 1.0,
+                                 eps: float = 1e-6):
+    """The rule of a router that CHOOSES by one number and WEIGHS by
+    another (DeepSeek-V3's auxiliary-loss-free balancing, arXiv:2408.15664;
+    the ``lfm2_moe`` family's ``use_expert_bias``): ``s = sigmoid(logit)``;
+    a token's experts are the ``top_k`` largest of ``s + bias``; their
+    weights are the bare ``s`` over the chosen ones' sum plus ``eps``, times
+    ``scale``. ``bias`` ``[E]`` float32 is a buffer, not a parameter: no
+    gradient reaches it (it moves which experts are chosen, and a choice has
+    no derivative); a rule outside the gradient moves it once a step from
+    the counts this rule hands back (``models/decoder.py``
+    ``make_moe_lm_loss_fn``).
+
+    A rule of three results: ``(weight, chosen, (counts, turned))`` with
+    ``counts`` ``[E]`` float32 the tokens that chose each of ALL the experts
+    and ``turned`` the routes whose expert is not among the token's
+    ``top_k`` largest bare scores (what the bias turned);
+    :func:`moe_local_experts` hands the third back beside ``load`` and
+    ``rows``."""
+    bias = lax.stop_gradient(bias.astype(jnp.float32))
+
+    def rule(router_logits, top_k: int):
+        s = jax.nn.sigmoid(router_logits)
+        _, chosen = lax.top_k(s + bias, top_k)
+        top = jnp.take_along_axis(s, chosen, axis=-1)
+        weight = scale * top / (jnp.sum(top, axis=-1, keepdims=True) + eps)
+        # a chosen expert under the token's top_k-th largest bare score is
+        # one the bare scores would not have chosen
+        edge = lax.top_k(s, top_k)[0][:, -1:]
+        counts = jnp.sum(
+            chosen[:, :, None] == jnp.arange(s.shape[-1])[None, None, :],
+            axis=(0, 1), dtype=jnp.float32)
+        turned = jnp.sum(top < edge, dtype=jnp.float32)
+        return weight, chosen, lax.stop_gradient((counts, turned))
 
     return rule
 
@@ -412,8 +463,12 @@ def moe_local_experts(
         takes and what each one's result counts for. The default,
         :func:`softmax_route_weights`, is the softmax over the token's
         ``top_k`` largest logits; :func:`sigmoid_route_weights` scores by
-        sigmoid, normalises over the chosen and scales. Everything after
-        it (ordering, the tiers, the products, the sum) is the same.
+        sigmoid, normalises over the chosen and scales;
+        :func:`biased_sigmoid_route_weights` chooses by the scores plus a
+        bias and weighs by the bare scores. Everything after it (ordering,
+        the tiers, the products, the sum) is the same. A rule may hand back
+        a third result, what it measured of its choice: it comes back
+        after ``rows``.
 
     Every route to a held expert is kept: the routes are ordered by
     expert (a stable sort: the held experts' groups first, the routes to
@@ -435,7 +490,8 @@ def moe_local_experts(
 
     Returns ``(y [T, d], load [held] float32, rows [] float32)``: the
     tokens each held expert received, and the rows this call's grouped
-    products ran over (``R`` or the compact tier's).
+    products ran over (``R`` or the compact tier's); then, under a rule of
+    three results, the rule's third.
     """
     T, d = x.shape
     E = router_logits.shape[-1]
@@ -455,7 +511,8 @@ def moe_local_experts(
     C = compact_rows(R, n, E)
     with jax.named_scope(_names.SCOPE_MOE_ROUTE):
         # [T, k] float32 weights, [T, k] expert ids
-        weight, chosen = route_weights(router_logits.astype(jnp.float32), k)
+        weight, chosen, *noted = route_weights(
+            router_logits.astype(jnp.float32), k)
         # slot of each route: its expert's place among the held, or n
         slot_of = np.full((E,), n, np.int32)
         slot_of[list(held)] = np.arange(n, dtype=np.int32)
@@ -464,13 +521,13 @@ def moe_local_experts(
     if C == R:
         y, sizes = _all_rows(
             activation, x, weight, slot, order, w_gate, w_up, w_down)
-        return y, sizes.astype(jnp.float32), jnp.float32(R)
+        return (y, sizes.astype(jnp.float32), jnp.float32(R), *noted)
     with jax.named_scope(_names.SCOPE_MOE_ROUTE):
         sizes = _group_sizes(slot, n)
     y = _tiered(
         C, activation, slot, order, sizes, x, weight, w_gate, w_up, w_down)
     rows = jnp.where(jnp.sum(sizes) <= C, C, R).astype(jnp.float32)
-    return y, sizes.astype(jnp.float32), rows
+    return (y, sizes.astype(jnp.float32), rows, *noted)
 
 
 def note_expert_layers(tokens: int, top_k: int, layers: int,
@@ -534,3 +591,23 @@ def note_expert_load(load, rows) -> None:
         "expert layers whose held routes fit the compact tier of rows in "
         "the last step read, so that no row work was sized for all routes",
     ).set(float(np.sum(rows < routes / len(rows))))
+
+
+def note_expert_bias(bias, turned) -> None:
+    """Publish, of a step read (host arrays), what a router that chooses by
+    its scores plus a bias (:func:`biased_sigmoid_route_weights`) carries:
+    the largest magnitude among the biases ``[layers, E]`` as the step left
+    them, and the routes ``[layers]`` whose expert the bare scores would not
+    have chosen, summed over the layers."""
+    m = _telemetry.metrics
+    m.gauge(
+        _names.GAUGE_MOE_BIAS_MAX_ABS,
+        "largest magnitude among the routers' expert biases, over every "
+        "expert layer, as the last step read left them",
+    ).set(float(np.max(np.abs(np.asarray(bias, np.float64)))))
+    m.gauge(
+        _names.GAUGE_MOE_BIASED_ROUTES,
+        "routes of the last step read whose expert is not among the "
+        "token's top_k largest bare scores (what the bias turned), summed "
+        "over the layers",
+    ).set(float(np.sum(np.asarray(turned, np.float64))))

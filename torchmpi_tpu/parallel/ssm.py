@@ -1,7 +1,9 @@
 """A state-space mixer's sequence operations (Mamba-2, arXiv:2405.21060)
 for heads held by share: the causal depthwise convolution over time, the
 selective state-space recurrence computed as the state-space dual's chunked
-scan, and the gated RMSNorm by groups of channels. XLA operations, and the
+scan, and the gated RMSNorm by groups of channels; and the one sequence operation
+of a mixer that is a convolution and nothing else (``gated_short_conv``: two
+elementwise gates around three taps). XLA operations, and the
 gradients jax's own of these, but for the convolution with its SiLU
 (``causal_conv1d_silu``): one operation under a derivative rule of its own,
 two Pallas kernels (``ops/conv_kernel.py``) where the program is lowered for
@@ -73,6 +75,19 @@ def causal_conv1d(x, kernel, bias):
     return y
 
 
+def _set_conv_gauges(elements: int, in_kernels: int) -> None:
+    _telemetry.metrics.gauge(
+        _names.GAUGE_CONV_ELEMENTS,
+        "elements (layers x sequences x positions x channels) that go "
+        "through a short causal convolution (causal_conv1d_silu, "
+        "gated_short_conv) in the step most recently traced").set(elements)
+    _telemetry.metrics.gauge(
+        _names.GAUGE_CONV_KERNEL_ELEMENTS,
+        "those of tm_conv_elements_per_step whose shapes take the fused "
+        "kernels (whole tiles of positions, whole lanes), traced where "
+        "jax's backend is a TPU").set(in_kernels)
+
+
 def note_conv_step(layers: int, shape, dtype, taps: int) -> None:
     """Set, from static shapes while a step is traced, the elements that go
     through ``causal_conv1d_silu`` (``layers`` calls over ``shape`` ``[b, t,
@@ -82,16 +97,34 @@ def note_conv_step(layers: int, shape, dtype, taps: int) -> None:
     elements = layers * math.prod(shape)
     kernels = (_conv_kernel.takes(shape, dtype, taps)
                and jax.default_backend() == "tpu")
-    _telemetry.metrics.gauge(
-        _names.GAUGE_CONV_ELEMENTS,
-        "elements (layers x sequences x positions x channels) that go "
-        "through causal_conv1d_silu in the step most recently traced").set(
-            elements)
-    _telemetry.metrics.gauge(
-        _names.GAUGE_CONV_KERNEL_ELEMENTS,
-        "those of tm_conv_elements_per_step whose shapes take the fused "
-        "kernels (whole tiles of positions, whole lanes), traced where "
-        "jax's backend is a TPU").set(elements if kernels else 0)
+    _set_conv_gauges(elements, elements if kernels else 0)
+
+
+def note_gated_conv_step(layers: int, shape) -> None:
+    """The same two gauges for ``layers`` calls of ``gated_short_conv``
+    whose convolution runs over ``shape`` ``[b, t, c]``: none of its
+    elements takes a kernel, whatever the shape, since the operation has
+    none (``ops/conv_kernel.py`` fuses the taps with a SiLU this mixer has
+    not)."""
+    _set_conv_gauges(layers * math.prod(shape), 0)
+
+
+def gated_short_conv(bcx, taps):
+    """The middle of a gated short-convolution mixer (the ``lfm2`` family's
+    ``conv`` layers): ``C * conv(B * x)`` from ``bcx`` ``[b, t, 3 c]``, the
+    three column blocks ``[B | C | x]`` of ONE product, and ``taps`` ``[k,
+    c]``: two elementwise gates around a causal depthwise convolution of
+    ``k`` taps (``causal_conv1d``: zeros before position 0, ``taps[k - 1]``
+    meets the current position), no bias, no activation, no state but ``k -
+    1`` positions. The gates' products and the taps' sum in float32, the
+    result in ``bcx``'s dtype. XLA's expressions, differentiated by jax: on
+    the chip that is the pad and the shifted float32 copies PR 46 measured
+    under ``causal_conv1d_silu``; the operation's kernel is not written
+    (``note_gated_conv_step`` says so to ``conv_kernel_share``)."""
+    gate_in, gate_out, x = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
+    mixed = causal_conv1d(
+        gate_in * x, taps, jnp.zeros(taps.shape[1:], jnp.float32))
+    return (gate_out * mixed).astype(bcx.dtype)
 
 
 def _conv_silu_plain(x, kernel, bias):

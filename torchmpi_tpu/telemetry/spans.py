@@ -180,6 +180,15 @@ SCOPE_GDN_STATE = "tm.lm.gdn_state"   # the loop over the chunks: the values
 #                                       a chunk really writes, its output,
 #                                       the carried state
 
+# models/decoder.py: a gated short convolution where attention stands in
+# the other layers (parallel/ssm.py ``gated_short_conv``; the ``lfm2``
+# family's ``conv`` layers). Such a layer opens no ``tm.attn.*`` scope.
+SCOPE_SCONV_PROJ = "tm.lm.sconv_proj"  # the mixer's two products: into
+#                                        [B | C | x] and out, with the sum
+#                                        into the residual stream
+SCOPE_SCONV = "tm.lm.sconv"            # the two elementwise gates and the
+#                                        taps between them
+
 # The scopes by group, so that a reader or a test names the group it means
 # and a model that brings names of its own appends a group and moves no
 # other's place. ``MODEL_SCOPE_NAMES`` is the groups in the order they came.
@@ -203,9 +212,12 @@ GDN_SCOPE_NAMES = (         # the gated delta rule's
     SCOPE_GDN_PROJ, SCOPE_GDN_CONV, SCOPE_GDN_GATE, SCOPE_GDN_CHUNK,
     SCOPE_GDN_STATE,
 )
+SCONV_SCOPE_NAMES = (       # the gated short convolution's
+    SCOPE_SCONV_PROJ, SCOPE_SCONV,
+)
 MODEL_SCOPE_NAMES = (
     ATTN_MOE_SCOPE_NAMES + LM_SCOPE_NAMES + SSM_SCOPE_NAMES
-    + RETENTION_SCOPE_NAMES + GDN_SCOPE_NAMES
+    + RETENTION_SCOPE_NAMES + GDN_SCOPE_NAMES + SCONV_SCOPE_NAMES
 )
 
 # -- the gauge models/decoder.py sets from static shapes while its step is
@@ -250,7 +262,8 @@ GAUGE_LM_HEAD_BLOCKS = "tm_lm_head_blocks_per_step"
 # x positions x channels) that go through ``causal_conv1d_silu``, and those
 # of them whose shapes take the fused kernels of ``ops/conv_kernel.py`` on a
 # TPU. The benchmark's ``conv_kernel_share`` reads the second against the
-# first
+# first. ``note_gated_conv_step`` sets them for models/decoder.py's gated
+# short convolution, whose elements take no kernel (the second reads 0)
 GAUGE_CONV_ELEMENTS = "tm_conv_elements_per_step"
 GAUGE_CONV_KERNEL_ELEMENTS = "tm_conv_kernel_elements_per_step"
 # -- the gauges models/lm.py ``products_kept`` sets the same way for every
@@ -261,6 +274,16 @@ GAUGE_CONV_KERNEL_ELEMENTS = "tm_conv_kernel_elements_per_step"
 # the first
 GAUGE_RECOMPUTE_NAMED_BYTES = "tm_recompute_named_bytes_per_step"
 GAUGE_RECOMPUTE_KEPT_BYTES = "tm_recompute_kept_bytes_per_step"
+# -- the gauges models/decoder.py ``make_moe_lm_loss_fn``'s
+# ``observe_state`` sets where the engine reads an epoch's loss, for a
+# router that chooses by its scores plus a bias (``ep.
+# biased_sigmoid_route_weights``): the largest magnitude among the biases of
+# every expert layer as the last step read left them, and the routes of
+# that step whose expert the bare scores would not have chosen, summed over
+# the layers. The benchmark's ``moe_bias_max_abs`` and
+# ``moe_biased_route_share`` read them
+GAUGE_MOE_BIAS_MAX_ABS = "tm_moe_bias_max_abs"
+GAUGE_MOE_BIASED_ROUTES = "tm_moe_biased_routes_last_step"
 
 # -- what a device trace calls the attention kernels (an event's name is
 # the kernel's HLO instruction): jax's splash attention in
